@@ -511,7 +511,6 @@ def run_shard_failover(args, run_dir: str, report_path: str) -> int:
         if k == victim:
             env["KME_FAULTS"] = schedule
             env["KME_FAULTS_STATE"] = os.path.join(gdir, "fault-state")
-        env.setdefault("JAX_PLATFORMS", "cpu")
         sups.append(subprocess.Popen(sup_cmd, env=env))
         prod = _Producer("127.0.0.1", port, per_group[k],
                          topic=group_topics(k)[0],
@@ -836,7 +835,6 @@ def run_feed_failover(args, run_dir: str, report_path: str) -> int:
     env = dict(os.environ)
     env["KME_FAULTS"] = schedule
     env["KME_FAULTS_STATE"] = state_dir
-    env.setdefault("JAX_PLATFORMS", "cpu")
     t0 = time.time()
     sup = subprocess.Popen(sup_cmd, env=env)
 
@@ -1128,7 +1126,6 @@ def run_reshard_storm(args, run_dir: str, report_path: str) -> int:
     env = dict(os.environ)
     env.pop("KME_FAULTS", None)     # the reshard itself is the attack
     env.pop("KME_FAULTS_STATE", None)
-    env.setdefault("JAX_PLATFORMS", "cpu")
 
     # 10 Hz heartbeat sampling across BOTH generations: (wall time,
     # input offset) — the migration-pause evidence
@@ -1757,7 +1754,6 @@ def run_storm(args, run_dir: str, report_path: str) -> int:
     env = dict(os.environ)
     env.pop("KME_FAULTS", None)     # the storm itself is the attack
     env.pop("KME_FAULTS_STATE", None)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     t0 = time.time()
     srv = subprocess.Popen(serve_cmd, env=env)
     producer = _StormProducer("127.0.0.1", port, lines, windows,
@@ -2165,7 +2161,6 @@ def main(argv=None) -> int:
     env = dict(os.environ)
     env["KME_FAULTS"] = schedule
     env["KME_FAULTS_STATE"] = state_dir
-    env.setdefault("JAX_PLATFORMS", "cpu")
     t0 = time.time()
     sup = subprocess.Popen(sup_cmd, env=env)
 

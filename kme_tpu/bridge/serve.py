@@ -40,7 +40,10 @@ def main(argv=None) -> int:
     p.add_argument("--slots", type=int, default=128)
     p.add_argument("--max-fills", type=int, default=16)
     p.add_argument("--width", type=int, default=8)
-    p.add_argument("--shards", type=int, default=1)
+    p.add_argument("--shards", type=int, default=1,
+                   help="devices the lanes engine shards symbols over "
+                        "(engine=seq serves on one device: anything but "
+                        "1 is refused)")
     p.add_argument("--strict", action="store_true",
                    help="die on malformed input records like the "
                         "reference's serde does (KProcessor.java:513-517)")
@@ -184,9 +187,10 @@ def main(argv=None) -> int:
                         "in flight — batch N+1's parse/plan/dispatch "
                         "runs under batch N's device step; offsets and "
                         "checkpoints still advance only once a batch's "
-                        "outputs are visible (needs engine=seq, "
-                        "compat=fixed and the native host runtime; "
-                        "anything else serves serial with a note)")
+                        "outputs are visible (needs engine=seq and "
+                        "compat=fixed, else serves serial with a note; "
+                        "a native host runtime that failed to build is "
+                        "an error)")
     p.add_argument("--group", default=None, metavar="K/N",
                    help="serve shard group K of an N-group multi-leader "
                         "topology (ISSUE 9): the service consumes "
@@ -351,6 +355,9 @@ def main(argv=None) -> int:
                            "min_ops": args.slo_min_ops,
                            "min_records_per_s":
                                args.slo_min_records_per_sec}))
+    print("kme-serve: " + " ".join(f"{k}={v}" for k, v in {
+        "engine": svc.engine_in_effect(), "pipeline": svc.pipeline,
+        **svc.runs_on}.items()), file=sys.stderr)
     msrv = None
     if args.metrics_port is not None:
         from kme_tpu.telemetry import start_metrics_server

@@ -79,6 +79,14 @@ class MatchService:
             raise ValueError(f"unknown engine {engine!r}")
         if compat not in ("java", "fixed"):
             raise ValueError(f"unknown compat {compat!r}")
+        if engine == "seq" and shards != 1:
+            # the served seq engine is one SeqSession on one device; the
+            # sharded SeqMeshSession is reachable from `bench --suite
+            # shards` only until it is wired in here
+            raise ValueError(
+                f"engine='seq' serves on one device; shards={shards} is "
+                f"not wired into kme-serve (use engine='lanes' for the "
+                f"sharded sweep engine)")
         if engine == "lanes" and compat != "fixed":
             raise ValueError("the lanes engine is fixed-mode only; use "
                              "engine='seq' (stock wire surface), "
@@ -161,16 +169,18 @@ class MatchService:
         # under batch N's device step; offsets/checkpoints advance only
         # at collect time, so the durability contract is unchanged.
         # Needs the seq engine (submit/collect), fixed mode and the
-        # native host runtime (buffer reconstruction); anything else
-        # serves serial with a note.
+        # native host runtime (buffer reconstruction). A configuration
+        # that cannot pipeline serves serial with a note, and so does
+        # an explicit KME_NATIVE=0; a native library that failed to
+        # build is an error (native.require_library).
         self.pipeline = 0
         self._pipe = None
         if pipeline:
-            from kme_tpu.native import load_library
+            from kme_tpu.native import require_library
 
             if (engine == "seq" and compat == "fixed"
                     and not annotate_rejects
-                    and load_library() is not None):
+                    and require_library() is not None):
                 import collections
 
                 self.pipeline = int(pipeline)
@@ -268,6 +278,15 @@ class MatchService:
                             backoff_ms=ctl.backoff_ms)
 
                 ctl.on_transition = _overload_event
+        # what this process runs on (start-up line + heartbeat). The
+        # device engines resolve the backend HERE, before any state is
+        # built or restored: no TPU and no JAX_PLATFORMS=cpu raises
+        # (kme_tpu/_jaxsetup.py). Host engines stay off jax.
+        self.runs_on = {}
+        if engine in ("seq", "lanes"):
+            from kme_tpu import _jaxsetup
+
+            self.runs_on = _jaxsetup.describe()
         resumed = False
         if checkpoint_dir is not None:
             resumed = self._try_resume(engine, compat, shards, width)
@@ -987,8 +1006,7 @@ class MatchService:
     # ------------------------------------------------------------------
 
     def _parse(self, value: str):
-        from kme_tpu.runtime.sequencer import EnvelopeError
-        from kme_tpu.wire import parse_order
+        from kme_tpu.wire import EnvelopeError, parse_order
 
         try:
             m = parse_order(value)
@@ -1716,6 +1734,13 @@ class MatchService:
         self._native = eng
         self._session = None
 
+    def engine_in_effect(self) -> str:
+        """The engine serving RIGHT NOW: the requested one, or "native"
+        once a java-mode seq stream degraded (_degrade_to_native)."""
+        if self._session is not None:
+            return self.engine_kind
+        return "native" if self._native is not None else "oracle"
+
     def metrics(self) -> Optional[dict]:
         """On-device counters+gauges (lanes engine; None for oracle)."""
         return self._session.metrics() if self._session is not None else None
@@ -1869,6 +1894,12 @@ class MatchService:
                        "tick": tick, "closing": closing,
                        "degraded": self.degraded or self._slo_reason,
                        "role": "follower" if self.follower else "leader",
+                       # additive: the engine IN EFFECT (a java stream
+                       # that left the device surface reads "native"),
+                       # what it runs on, the pipeline depth in effect
+                       "engine": self.engine_in_effect(),
+                       "pipeline": self.pipeline,
+                       **self.runs_on,
                        "epoch": self.epoch,
                        "sample_seq": seq,
                        "every": getattr(self, "_hb_every", 1.0),

@@ -1,10 +1,11 @@
 """Native host-runtime bindings: build-on-demand C++ via ctypes.
 
 The C++ sources compile once per source hash with the system toolchain
-(g++) into a cached shared object next to the package; everything
-degrades gracefully to the pure-Python implementations when no compiler
-is available (`load_library()` returns None). `KME_NATIVE=0` disables
-the native path outright.
+(g++) into a cached shared object next to the package. `KME_NATIVE=0`
+disables the native path outright (the pure-Python twins are the test
+references). `load_library()` also returns None when no compiler is
+available; the served seq path does not accept that silently — it
+calls `require_library()`.
 """
 
 from __future__ import annotations
@@ -135,6 +136,20 @@ def load_library() -> Optional[ctypes.CDLL]:
               f"fallback", file=sys.stderr)
         _lib = None
     return _lib
+
+
+def require_library() -> Optional[ctypes.CDLL]:
+    """load_library() for the served path: None ONLY under an explicit
+    KME_NATIVE=0. A library that could not be built or loaded raises —
+    kme-serve must not drop to the Python twins (several times slower,
+    and no pipelined serving) behind the operator's back."""
+    lib = load_library()
+    if lib is None and os.environ.get("KME_NATIVE", "1") != "0":
+        raise RuntimeError(
+            "kme_tpu.native: the host runtime library could not be built "
+            "or loaded (g++ output above); set KME_NATIVE=0 to run the "
+            "pure-Python twins on purpose")
+    return lib
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:

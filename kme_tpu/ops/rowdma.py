@@ -33,17 +33,13 @@ from __future__ import annotations
 
 import numpy as np
 
-import kme_tpu._jaxsetup  # noqa: F401
+from kme_tpu import _jaxsetup
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LN = 128  # minor (lane) dim of every row tile
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _i32(x) -> np.int32:
@@ -152,7 +148,7 @@ def gather_lane_rows(flat: jax.Array, lanes: jax.Array) -> jax.Array:
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         scratch_shapes=[pltpu.SemaphoreType.DMA((W,))],
-        interpret=_interpret(),
+        interpret=_jaxsetup.interpret(),
     )(lanes.astype(jnp.int32), flat)
 
 
@@ -171,5 +167,5 @@ def scatter_lane_rows(flat: jax.Array, lanes: jax.Array,
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[pltpu.SemaphoreType.DMA((W,))],
         input_output_aliases={1: 0},
-        interpret=_interpret(),
+        interpret=_jaxsetup.interpret(),
     )(lanes.astype(jnp.int32), flat, rows.astype(jnp.int32))

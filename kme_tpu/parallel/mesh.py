@@ -46,13 +46,6 @@ from kme_tpu.engine import lanes as L
 AXIS = "symbol"
 
 
-def _shard_map(fn, mesh, in_specs, out_specs):
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:  # pragma: no cover - older jax fallback
-        from jax.experimental.shard_map import shard_map as sm
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-
 @functools.lru_cache(maxsize=None)
 def build_mesh(shards: int) -> Mesh:
     """One Mesh per shard count per process — sessions share it, so the
@@ -101,8 +94,9 @@ def build_sharded_step(cfg: L.LaneConfig, mesh: Mesh):
         "fill_aid": P(None, AXIS), "fill_price": P(None, AXIS),
         "fill_size": P(None, AXIS), "err": P(),
     }
-    return _shard_map(inner, mesh, (st_specs, batch_specs),
-                      (st_specs, out_specs))
+    return jax.shard_map(inner, mesh=mesh,
+                         in_specs=(st_specs, batch_specs),
+                         out_specs=(st_specs, out_specs))
 
 
 def build_sharded_chunk(cfg: L.LaneConfig, mesh: Mesh, T: int, M: int):
@@ -145,8 +139,9 @@ def build_sharded_settle(cfg: L.LaneConfig, mesh: Mesh):
         return inner(state, local, credit_size, mode)
 
     st_specs = state_specs(L.make_lane_state(cfg))
-    return _shard_map(settle, mesh, (st_specs, P(), P(), P()),
-                      (st_specs, P()))
+    return jax.shard_map(settle, mesh=mesh,
+                         in_specs=(st_specs, P(), P(), P()),
+                         out_specs=(st_specs, P()))
 
 
 @functools.lru_cache(maxsize=None)

@@ -184,24 +184,6 @@ def _split64(v):
     return lo, (v >> 32).astype(jnp.int32)
 
 
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """shard_map with varying-mesh-axes checking off: the body contains
-    a pallas_call, whose out_shapes carry no vma annotation."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:  # pragma: no cover - older jax fallback
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except TypeError:  # older jax spells the flag check_rep
-        try:
-            return sm(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
-        except TypeError:  # pragma: no cover - jax without either flag
-            return sm(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs)
-
-
 @functools.lru_cache(maxsize=None)
 def build_seq_mesh_scan(local_cfg: SQ.SeqConfig, shards: int, K: int):
     """Jitted (state, wins) -> (state, out_planes): a lax.scan over K
@@ -237,9 +219,10 @@ def build_seq_mesh_scan(local_cfg: SQ.SeqConfig, shards: int, K: int):
     # NO jit-level donation: it composes badly with the kernel's
     # input_output_aliases (clobbered aliased outputs — the documented
     # hazard in build_seq_step's NOTE), at the cost of one state copy
-    # per dispatch.
-    sharded = _shard_map(run, mesh, (specs, win_specs),
-                         (specs, P()))
+    # per dispatch. Varying-mesh-axes checking is off: the body holds a
+    # pallas_call, whose out_shapes carry no vma annotation.
+    sharded = jax.shard_map(run, mesh=mesh, in_specs=(specs, win_specs),
+                            out_specs=(specs, P()), check_vma=False)
     return jax.jit(sharded)   # outs: (K, shards, NROWS, 128) replicated
 
 

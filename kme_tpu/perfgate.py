@@ -3,21 +3,21 @@
 `kme-bench --baseline BENCH.json --gate` runs the bench, then compares
 its detail metrics against a recorded baseline and exits non-zero on a
 regression beyond the noise tolerance. CI wires this against the
-repo's BENCH_r0N.json artifacts.
+repo's CPU-recorded BASELINE_*.json files.
 
 Two artifact realities shape the loader:
 
 - The recorded baselines hold the bench's stderr under a "tail" key
   that is the LAST N BYTES of the stream — routinely TRUNCATED
-  mid-JSON (BENCH_r05.json starts mid-object). So metrics are
+  mid-JSON (a driver artifact can start mid-object). So metrics are
   extracted with a `"name": number` regex over the raw text, never by
   parsing the whole document; the first occurrence wins (the root
   detail object precedes the nested java/ sub-dicts that repeat metric
   names).
-- Baselines may be recorded on a different backend (the checked-in
-  ones are TPU; CI gates on CPU). Cross-backend magnitudes are not
-  comparable, so a backend mismatch demotes the gate to ADVISORY:
-  the report is still printed/written, but the exit code stays 0.
+- Baselines may be recorded on a different backend. Cross-backend
+  magnitudes are not comparable, so a backend mismatch demotes the
+  gate to ADVISORY: the report is still printed/written, but the exit
+  code stays 0.
 
 Direction matters: throughput regresses by FALLING, latency by RISING.
 `pipeline_speedup` stays advisory — it is a ratio of two wall clocks

@@ -454,22 +454,26 @@ def load_seq_session(ckpt_dir: str, cfg=None):
     (None, 0)."""
     for offset, path in list_snapshots(ckpt_dir):
         try:
-            return _restore_seq_one(path, cfg), offset
-        except SnapshotCapacityError:
-            raise          # operator error, not corruption: surface it
+            # host-only read + parse + digest: whatever a torn or
+            # bit-flipped file raises in here means "unreadable"
+            data, meta = _load_file(path)
         except Exception as e:
             import sys
 
             print(f"kme_tpu.checkpoint: skipping unreadable snapshot "
                   f"{path}: {e}", file=sys.stderr)
+            continue
+        # the restore itself is NOT guarded: a capacity mismatch is an
+        # operator error, and a device failure while importing the
+        # planes must not pass for "no snapshot, start fresh"
+        return _restore_seq(data, meta, cfg), offset
     return None, 0
 
 
-def _restore_seq_one(path: str, cfg):
+def _restore_seq(data, meta, cfg):
     from kme_tpu.engine import seq as SQ
     from kme_tpu.runtime.seqsession import SeqSession
 
-    data, meta = _load_file(path)
     explicit_cfg = cfg is not None
     if meta["kind"] == "seqjava":
         from kme_tpu.runtime.javasnap import import_seqjava
@@ -702,7 +706,7 @@ def restore_seq_snapshot(path: str, cfg=None):
     form) into a SeqSession. Raises on corruption or capacity mismatch
     — the offset-addressed loaders (telemetry/xray.py) use this to
     restore a SPECIFIC anchor instead of the newest snapshot."""
-    return _restore_seq_one(path, cfg)
+    return _restore_seq(*_load_file(path), cfg)
 
 
 # ---------------------------------------------------------------------------

@@ -30,17 +30,11 @@ import numpy as np
 
 from kme_tpu import opcodes as op
 from kme_tpu.engine import lanes as L
-from kme_tpu.wire import OrderMsg
+from kme_tpu.wire import EnvelopeError, OrderMsg  # noqa: F401 (re-export)
 
 
 class CapacityError(RuntimeError):
     """The workload exceeds a static device capacity (symbols, accounts)."""
-
-
-class EnvelopeError(RuntimeError):
-    """A wire value falls outside the Jackson-parseable envelope (int32
-    price/size) — input on which the reference's deserializer throws and
-    its Streams thread dies (KProcessor.java:513-517)."""
 
 
 @dataclasses.dataclass

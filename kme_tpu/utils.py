@@ -5,13 +5,9 @@ from __future__ import annotations
 
 def async_prefetch(values) -> None:
     """Start device->host copies for every array in `values` without
-    blocking — np.asarray afterwards finds the bytes already in flight.
-    Non-arrays (or older jax without the API) are skipped."""
+    blocking — np.asarray afterwards finds the bytes already in flight."""
     for v in values:
-        try:
-            v.copy_to_host_async()
-        except AttributeError:
-            pass
+        v.copy_to_host_async()
 
 
 def pow2_bucket(n: int, lo: int = 64) -> int:

@@ -81,6 +81,15 @@ FRAME_SIZE_TRACED = FRAME_SIZE + _TID_WORD.size   # 80
 _FRAME_HDR = struct.Struct("<BBBBI")
 
 
+class EnvelopeError(RuntimeError):
+    """A wire value falls outside the Jackson-parseable envelope (int32
+    price/size) — input on which the reference's deserializer throws and
+    its Streams thread dies (KProcessor.java:513-517). Defined here, not
+    next to the scheduler that first raised it, so the host-only layers
+    (native oracle, the serve loop's parser) can name it without
+    importing a device module."""
+
+
 class WireFrameError(ValueError):
     """A binary frame failed validation. `reason` is one of
     "truncated", "bad_magic", "version_skew", "bad_kind",

@@ -559,7 +559,7 @@ def cancel_storm_stream(num_events: int, num_symbols: int,
     """Cancel blizzard (HFT quote-stuffing shape): ~3/4 of events are
     cancels, and most of those target oids that were never submitted —
     driving the engine's rej_cancel ratio to ~10x the reference
-    harness's steady state (~7k/105k in BENCH_r05). The remaining
+    harness's steady state (~7k/105k on the zipf stream). The remaining
     events are fresh buy/sell flow, so cancels and new orders arrive
     interleaved — the stream the priority-aware shedder must split
     (cancels drain the book: admit; new orders grow it: shed)."""

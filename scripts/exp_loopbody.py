@@ -9,7 +9,7 @@ import sys
 import time
 
 sys.path.insert(0, "/root/repo")
-import kme_tpu._jaxsetup  # noqa: F401
+import kme_tpu._jaxsetup
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -138,7 +138,7 @@ def build(variant: str):
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
             scratch_shapes=[pltpu.SMEM((4,), I32),
                             pltpu.VMEM((2, LN), I32)],
-            interpret=jax.default_backend() != "tpu",
+            interpret=kme_tpu._jaxsetup.interpret(),
         )(data)
 
     return jax.jit(call)

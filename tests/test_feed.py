@@ -424,6 +424,11 @@ def test_feed_server_fanout_filtered_and_wildcard(tmp_path):
                 break
             assert time.monotonic() < deadline, "fan-out stalled"
             time.sleep(0.01)
+        # the server is single-threaded: park the serving thread before
+        # pumping from this one (two threads in step() raced in _pump)
+        stop.set()
+        th.join(10)
+        assert not th.is_alive()
         srv.drain(10.0)
         write_health(str(tmp_path / "feed.health"), srv)
     finally:
