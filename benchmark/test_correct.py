@@ -1,0 +1,90 @@
+"""`correct` shown to fail. The benchmark's own runs do not run these.
+
+1. The control of each configuration (the plain reference of another
+   guarantee, see `control` in benchmark/configs/*.json) must not pass
+   the byte comparison.
+2. A whole run of the harness with the look for a chip waived and the
+   timed path broken underneath (one MatchOut record altered where the
+   serve loop produces it) must report `correct: false`; the same run
+   on the sound host reports true, and under `--control` false.
+3. A stream drawn more slowly than the server's patience (it ends
+   itself when its input stays silent) does not lose the run: the
+   server is fed while the stream is drawn, and the window opens only
+   after the last message exists."""
+
+import itertools
+import os
+import time
+
+import pytest
+
+from benchmark import generators, judge, run
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+CELL = "java-harness-sat"   # small state: a test run can hold it
+
+
+@pytest.mark.parametrize("seed", [1, 2, 2 ** 31 + 3])
+@pytest.mark.parametrize("name", ["fixed-zipf-1k", "java-harness"])
+def test_control_reference_fails_the_comparison(name, seed):
+    config = run.load_json(os.path.join(HERE, "configs", f"{name}.json"))
+    s = config["stream"]
+    msgs = list(itertools.islice(generators.open_stream(
+        s["generator"], s["events"], seed, s["params"]), 12000))
+    want = judge.make_reference(config["reference"]).process_wire(msgs)
+    ctrl = judge.make_reference(
+        config["control"]["reference"]).process_wire(msgs)
+    flat = lambda groups: [ln for g in groups for ln in g]  # noqa: E731
+    assert judge.differing(flat(want), flat(want)) == 0
+    assert judge.differing(flat(ctrl), flat(want)) > 0
+
+
+def rehearse(tmp_path, **kw):
+    return run.run_cell(CELL, seed=11, seconds=3, trace=False,
+                        allow_cpu=True, events=60000,
+                        out=str(tmp_path / "run"), **kw)
+
+
+def test_sound_run_is_correct(tmp_path):
+    result = rehearse(tmp_path)
+    assert result["correct"] is True and result["failed"] == 0
+    assert result["rehearsal"] == "cpu"
+    assert all(k.startswith("cpu_rehearsal.") for k in result["metrics"])
+
+
+def test_control_run_is_not_correct(tmp_path):
+    assert rehearse(tmp_path, control=True)["correct"] is False
+
+
+def test_broken_timed_path_is_not_correct(tmp_path):
+    result = rehearse(tmp_path, host_module="benchmark.broken_host")
+    assert result["correct"] is False
+
+
+def test_without_the_switch_the_cpu_is_refused(tmp_path):
+    with pytest.raises(run.RunFailure, match="not the chip"):
+        run.run_cell(CELL, seed=11, seconds=3, trace=False, events=60000,
+                     out=str(tmp_path / "run"))
+
+
+def slow_harness_stream(num_events, seed=0, **params):
+    """`harness_stream` at about 4,000 messages a second: faster than the
+    interpreter serves, slower than the start-up hides."""
+    for k, m in enumerate(generators.harness_stream(num_events, seed,
+                                                    **params)):
+        if k % 20 == 0:
+            time.sleep(0.005)
+        yield m
+
+
+def test_a_slowly_drawn_stream_does_not_lose_the_run(tmp_path, monkeypatch):
+    traffic, config = run.load_cell(CELL)
+    traffic["stream"] = dict(
+        config["stream"],
+        generator="benchmark.test_correct:slow_harness_stream")
+    monkeypatch.setattr(run, "load_cell", lambda cell: (traffic, config))
+    monkeypatch.setattr(run, "IDLE_EXIT_S", 2.0)
+    result = rehearse(tmp_path)
+    assert result["correct"] is True and result["failed"] == 0
+    # the window opened after the stream's end, not at the warm-up's
+    assert result["attempted"] < 60000 - traffic["warmup_messages"] - 10000

@@ -1,0 +1,166 @@
+"""The yardstick's own arithmetic: the copied generators against the
+program's, the kernel's byte count against `SeqConfig`, the layer-metric
+reductions, the peaks table, the paced schedule."""
+
+import itertools
+import json
+import os
+
+import pytest
+
+from benchmark import client, generators, kernel_cost, layers, peaks, run
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+
+@pytest.mark.parametrize("seed", [0, 7, 2 ** 31 + 11])
+def test_generators_are_the_programs(seed):
+    from kme_tpu import workload
+
+    mine = list(generators.open_stream(
+        "zipf_symbol_stream", 3000, seed,
+        {"num_symbols": 64, "num_accounts": 128, "zipf_a": 1.2}))
+    assert mine == workload.zipf_symbol_stream(3000, 64, 128, seed=seed,
+                                               zipf_a=1.2)
+    mine = list(generators.open_stream("harness_stream", 3000, seed, {}))
+    assert mine == workload.harness_stream(3000, seed=seed)
+
+
+def test_program_generator_by_name():
+    got = list(generators.open_stream(
+        "kme_tpu.workload:cancel_heavy_stream", 500, 3,
+        {"num_symbols": 8, "num_accounts": 16}))
+    assert len(got) >= 500
+    with pytest.raises(ValueError):
+        generators.open_stream("no_such_stream", 1, 0, {})
+
+
+def test_kernel_bytes_follow_seqconfig():
+    from kme_tpu.engine import seq as SQ
+
+    for name in ("fixed-zipf-1k", "java-harness"):
+        config = run.load_json(os.path.join(HERE, "configs", f"{name}.json"))
+        compat = kernel_cost.serve_option(config, "--compat")
+        cfg = SQ.SeqConfig(compat=compat)
+        assert (cfg.batch, cfg.fill_cap) == (kernel_cost.KERNEL_BATCH,
+                                             kernel_cost.FILL_CAP)
+        assert SQ.out_rows(cfg) == kernel_cost.out_rows()
+        assert kernel_cost.seq_call_bytes(config) == 4 * (
+            kernel_cost.MSG_PLANES[compat] * cfg.batch
+            + SQ.out_rows(cfg) * SQ.LN)
+
+
+def test_peaks_unknown_kind_is_an_error():
+    assert peaks.of("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
+    with pytest.raises(KeyError):
+        peaks.of("cpu")
+
+
+def hb(t, batches, records, plan, produce_sum, produce_n):
+    return {"time": t, "metrics": {
+        "counters": {"service_batches": batches,
+                     "service_records": records},
+        "gauges": {"plan_s": plan, "parse_ns_per_msg": 900},
+        "latencies": {"lat_produce": {"sum_s": produce_sum,
+                                      "count": produce_n}}}}
+
+
+def test_layer_reductions():
+    ctx = {"hb_a": hb(100.0, 10, 20000, 1.0, 50.0, 20000),
+           "hb_b": hb(120.0, 20, 40000, 1.5, 4050.0, 40000),
+           "client": {"first_output_s": 15.5},
+           "trace": {"window_s": 10.0, "programs": {
+               "jit_call_scan(1)": {"seconds": 0.05, "runs": 5}}},
+           "config": {"serve": ["--compat", "fixed"]},
+           "device_kind": "TPU v5 lite"}
+    H = {"from": "heartbeat"}
+    B = "counters.service_batches"
+    assert layers.read({**H, "reduce": "seconds_per_delta", "key": B,
+                        "scale": 1000}, ctx) == pytest.approx(2000.0)
+    assert layers.read({**H, "reduce": "delta_per", "key": "gauges.plan_s",
+                        "per": B, "scale": 1000}, ctx) == pytest.approx(50.0)
+    assert layers.read({**H, "reduce": "hist_mean",
+                        "key": "latencies.lat_produce"},
+                       ctx) == pytest.approx(0.2)
+    assert layers.read({**H, "reduce": "last",
+                        "key": "gauges.parse_ns_per_msg"}, ctx) == 900
+    assert layers.read({**H, "reduce": "share_of_window",
+                        "key": "gauges.plan_s"}, ctx) == pytest.approx(0.025)
+    assert layers.read({"reduce": "sum", "terms": [
+        {**H, "reduce": "seconds_per_delta", "key": B, "sign": 1},
+        {**H, "reduce": "hist_mean", "key": "latencies.lat_produce",
+         "sign": -1}]}, ctx) == pytest.approx(1.8)
+    assert layers.read({"from": "client", "key": "first_output_s"},
+                       ctx) == 15.5
+    # 0.005 device s per traced s, 1000 messages per s -> 5 us a message
+    assert layers.read({"from": "trace", "program": "^jit_call_scan",
+                        "reduce": "program_us_per_message"},
+                       ctx) == pytest.approx(5.0)
+    roof = layers.read({"from": "trace", "program": "^jit_call_scan",
+                        "reduce": "program_roofline", "scale": 100,
+                        "bytes": "benchmark.kernel_cost:seq_call_bytes"},
+                       ctx)
+    nbytes = kernel_cost.seq_call_bytes(ctx["config"])
+    assert roof == pytest.approx(100 * 5 * nbytes / 819e9 / 0.05)
+    # nothing to read -> None, and the metric is left out
+    assert layers.read({**H, "reduce": "last", "key": "gauges.absent"},
+                       ctx) is None
+    assert layers.read({"from": "trace", "program": "^nothing",
+                        "reduce": "program_us_per_message"}, ctx) is None
+    assert layers.read({"from": "client", "key": "gen_late_p99_ms"},
+                       ctx) is None
+
+
+def test_every_listed_metric_has_its_file():
+    bench = run.load_json(os.path.join(os.path.dirname(HERE),
+                                       "BENCHMARK.json"))
+    for m in bench["per_layer"]:
+        spec = run.load_json(os.path.join(HERE, "layer_metrics",
+                                          f"{m['name']}.json"))
+        assert spec.get("cells") == m.get("workloads")
+        assert (spec["unit"], spec["layer"], spec["moves"]) == (
+            m["unit"], m["layer"], m["moves"])
+    for w in bench["workloads"]:
+        traffic, config = run.load_cell(w["name"])
+        assert traffic["config"] == w["config"] == config["name"]
+        reports = {m["name"] for m in bench["end_to_end"]
+                   if w["name"] in m.get("workloads", [w["name"]])}
+        assert {m["name"] for m in layers.load_for(w["name"], reports)} \
+            == {m["name"] for m in bench["per_layer"]
+                if w["name"] in m.get("workloads", [w["name"]])
+                and m["moves"] in reports}
+
+
+def test_paced_schedules_keep_the_mean_rate():
+    even = client.due_offsets({"rate_per_s": 660}, 30)
+    assert len(even) == 19800 and even[1] == pytest.approx(1 / 660)
+    bursts = client.due_offsets(
+        {"rate_per_s": 600, "spacing": {"kind": "bursts", "period_s": 5,
+                                        "burst_s": 0.5,
+                                        "burst_share": 0.5}}, 30)
+    assert len(bursts) == 18000 and bursts == sorted(bursts)
+    assert sum(1 for d in bursts if d % 5 < 0.5) == 9000
+
+
+def test_percentile_is_nearest_rank():
+    assert run.percentile(list(range(1, 101)), 99) == 99
+    assert run.percentile(list(range(1, 101)), 50) == 50
+    assert run.percentile([1.0], 99) == 1.0
+
+
+def test_heartbeats_go_one_at_a_time_and_none_after_the_closing(monkeypatch):
+    from kme_tpu.bridge.service import MatchService
+
+    from benchmark import host
+
+    written = []
+    monkeypatch.setattr(
+        MatchService, "_write_heartbeat",
+        lambda self, path, seen, tick=0, closing=False:
+        written.append((seen, closing)))
+    assert host.serialise_heartbeats() is True
+    write = MatchService._write_heartbeat
+    write(None, "health.json", 1, 1)
+    write(None, "health.json", 2, 2, closing=True)
+    write(None, "health.json", 3, 3)     # the beater, woken too late
+    assert written == [(1, False), (2, True)]
