@@ -27,10 +27,46 @@ makes them on its own:
 
 import functools
 import os
+import time
 
 import jax
+import jax.monitoring
 
 jax.config.update("jax_enable_x64", True)
+
+
+def _process_age_s() -> float:
+    """Seconds since this process was created (Linux /proc; 0.0 where
+    that cannot be read)."""
+    try:
+        with open("/proc/self/stat") as f:
+            start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            up = float(f.read().split()[0])
+        return max(0.0, up - start_ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError):
+        return 0.0
+
+
+# what the process paid before it could serve, for the heartbeat's
+# start-up gauges: process start -> jax imported (here), then the first
+# backend check (describe(), below)
+startup = {"startup_import_s": round(_process_age_s(), 3),
+           "startup_backend_s": 0.0}
+
+# every program XLA compiled (or took from the persistent cache) in this
+# process: JAX's own compile-duration event, the one its debug log
+# prints as "Finished XLA compilation of ..."
+compiles = {"n": 0, "seconds": 0.0}
+
+
+def _on_duration(event: str, seconds: float, **_kw) -> None:
+    if event == "/jax/core/compile/backend_compile_duration":
+        compiles["n"] += 1
+        compiles["seconds"] += seconds
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -85,8 +121,11 @@ def interpret() -> bool:
 def describe() -> dict:
     """What a device process runs on, for its start-up line and
     heartbeat. Resolves the backend, so it raises like backend()."""
+    t0 = time.perf_counter()
     platform = backend()
     devs = jax.devices()
+    if not startup["startup_backend_s"]:
+        startup["startup_backend_s"] = round(time.perf_counter() - t0, 3)
     return {"backend": platform, "interpret": interpret(),
             "device_kind": devs[0].device_kind, "device_count": len(devs),
             "compile_cache_dir": cache_dir()}

@@ -46,8 +46,23 @@ from kme_tpu import faults
 TOPIC_IN = "MatchIn"    # topic.js:17
 TOPIC_OUT = "MatchOut"  # topic.js:21
 
+_DEVICE_MS_HELP = ("what the host waited on the device for the last "
+                   "batch (ms): dispatch + fetch on the serial path; "
+                   "under --pipeline only the fetch's wait, the device "
+                   "work the pipeline hid is not in it")
+
 
 class MatchService:
+    # the spans that PARTITION one iteration of the serve loop (names of
+    # its PhaseTimer): what is left of the loop's wall after their sum
+    # is time no span covers (benchmark metric loop_other_ms_per_batch)
+    LOOP_SPANS = ("poll_wait", "parse_batch", "session_submit",
+                  "session_collect", "process_wire", "produce_buffer",
+                  "produce_lines", "publish_batch", "checkpoint")
+    # spans nested inside those
+    INNER_SPANS = ("engine_refresh", "checkpoint_drain", "broker_sync",
+                   "snapshot_save")
+
     def __init__(self, broker, engine: str = "lanes",
                  compat: str = "fixed", batch: int = 1024,
                  symbols: int = 1024, accounts: int = 4096,
@@ -287,10 +302,15 @@ class MatchService:
             from kme_tpu import _jaxsetup
 
             self.runs_on = _jaxsetup.describe()
+        import time as _t
+
+        t_session0 = _t.perf_counter()
+        self._startup_session_s = 0.0
         resumed = False
         if checkpoint_dir is not None:
             resumed = self._try_resume(engine, compat, shards, width)
         if resumed:
+            self._startup_session_s = _t.perf_counter() - t_session0
             self._restore_sample_seq()
             self._init_exactly_once(resumed=True)
             self._init_telemetry()
@@ -322,6 +342,7 @@ class MatchService:
             self._oracle = OracleEngine(compat, **kw)
         else:
             raise ValueError(f"unknown engine {engine!r}")
+        self._startup_session_s = _t.perf_counter() - t_session0
         self._init_exactly_once(resumed=False)
         self._init_telemetry()
         self._init_observability(resumed=False)
@@ -695,6 +716,9 @@ class MatchService:
                     MatchOut record (observed broker-side via
                     deliver_observer, since serve hosts the broker)
         """
+        import threading
+        import time as _t
+
         from kme_tpu.telemetry import PhaseTimer
 
         t = self.telemetry
@@ -717,6 +741,26 @@ class MatchService:
         self._batch_ordinal = 0
         self._last_produce_s = 0.0
         self._phase_snap = {}
+        # one heartbeat writer at a time: the beater thread and the
+        # serve loop share `<health-file>.tmp`
+        self._hb_lock = threading.Lock()
+        # every span and counter is in the registry before the first
+        # heartbeat: a reader of two snapshots needs the key in both
+        self._loop_t0 = _t.perf_counter()
+        # None: no batch yet; (ordinal, t0): the first one is in
+        # flight since t0; False: gauge first_batch_s is set
+        self._first_batch = None
+        jaxsetup = sys.modules.get("kme_tpu._jaxsetup")
+        t.publish_gauges({
+            "startup_import_s": 0.0, "startup_backend_s": 0.0,
+            **(jaxsetup.startup if jaxsetup is not None else {}),
+            "startup_session_s": round(self._startup_session_s, 3),
+            "first_batch_s": 0.0})
+        t.gauge("left_device_at_offset",
+                "input offset of the batch at which a java-mode seq "
+                "service left the device for the native engine "
+                "(_degrade_to_native); -1: it has not").set(-1)
+        self._publish_spans()
         # slowest recent orders, worst first: published as registry
         # exemplars so a cluster p99 outlier (kme-agg) resolves to a
         # concrete waterfall (kme-trace --order AID:OID)
@@ -918,22 +962,35 @@ class MatchService:
             return
         self.checkpoint()
 
+    def _span(self, name: str, ordinal: Optional[int] = None):
+        """One span of the serve loop's timer, tied to its batch."""
+        return self._ptimer.phase(
+            name, batch=self._batch_ordinal if ordinal is None else ordinal)
+
     def checkpoint(self) -> None:
         """Snapshot engine state + input offset (batch boundary)."""
-        from kme_tpu.runtime import checkpoint as ck
+        with self._span("checkpoint"):
+            self._checkpoint()
 
-        if getattr(self, "_pipe", None):
-            # a snapshot must capture engine state at a committed
-            # offset boundary — collect every in-flight batch first
-            self._drain_pipeline()
-        # make the input log durable BEFORE committing an offset into it:
-        # the snapshot is fsync'd, so without this a power loss could
-        # leave an offset addressing MatchIn records the OS never wrote
-        # (resume would silently skip input)
+    def _checkpoint_drain(self) -> None:
+        """A snapshot must capture engine state at a committed offset
+        boundary — collect every in-flight batch first."""
+        with self._span("checkpoint_drain"):
+            if getattr(self, "_pipe", None):
+                self._drain_pipeline()
+
+    def _broker_sync(self) -> bool:
+        """Make the input log durable BEFORE committing an offset into
+        it: the snapshot is fsync'd, so without this a power loss could
+        leave an offset addressing MatchIn records the OS never wrote
+        (resume would silently skip input). False: the sync failed and
+        the snapshot is deferred."""
         sync = getattr(self.broker, "sync", None)
-        if sync is not None:
-            from kme_tpu.bridge.broker import BrokerError
+        if sync is None:
+            return True
+        from kme_tpu.bridge.broker import BrokerError
 
+        with self._span("broker_sync"):
             try:
                 sync()
             except (BrokerError, OSError) as e:
@@ -941,7 +998,36 @@ class MatchService:
                 # failing (disk full / EIO) — defer, don't die
                 print(f"kme-serve: broker sync failed before checkpoint "
                       f"({e}); snapshot deferred", file=sys.stderr)
-                return
+                return False
+        return True
+
+    def _snapshot_save(self, extra: dict) -> None:
+        """Write the engine in effect to a durable snapshot at
+        `self.offset` (runtime/checkpoint.py)."""
+        from kme_tpu.runtime import checkpoint as ck
+
+        with self._span("snapshot_save"):
+            if self._session is not None:
+                from kme_tpu.runtime.seqsession import SeqSession
+
+                save = (ck.save_seq_session
+                        if isinstance(self._session, SeqSession)
+                        else ck.save_session)
+                save(self.checkpoint_dir, self._session, self.offset,
+                     keep=self.checkpoint_keep, extra=extra)
+            elif self._native is not None:
+                ck.save_native(self.checkpoint_dir, self._native,
+                               self.offset, keep=self.checkpoint_keep,
+                               extra=extra)
+            else:
+                ck.save_oracle(self.checkpoint_dir, self._oracle,
+                               self.offset, keep=self.checkpoint_keep,
+                               extra=extra)
+
+    def _checkpoint(self) -> None:
+        self._checkpoint_drain()
+        if not self._broker_sync():
+            return
         # the heartbeat sample cursor rides EVERY snapshot (not just
         # exactly-once leaders'): a resumed service continues the TSDB
         # sequence so replayed heartbeat samples dedup on ingestion
@@ -975,23 +1061,7 @@ class MatchService:
                 # the transfer LEGS themselves regenerate from MatchIn
                 # replay and dedup on their (epoch, out_seq) stamps
                 extra["pending_reserve"] = dict(self._xfer)
-        if self._session is not None:
-            from kme_tpu.runtime.seqsession import SeqSession
-
-            if isinstance(self._session, SeqSession):
-                ck.save_seq_session(self.checkpoint_dir, self._session,
-                                    self.offset, keep=self.checkpoint_keep,
-                                    extra=extra)
-            else:
-                ck.save_session(self.checkpoint_dir, self._session,
-                                self.offset, keep=self.checkpoint_keep,
-                                extra=extra)
-        elif self._native is not None:
-            ck.save_native(self.checkpoint_dir, self._native, self.offset,
-                           keep=self.checkpoint_keep, extra=extra)
-        else:
-            ck.save_oracle(self.checkpoint_dir, self._oracle, self.offset,
-                           keep=self.checkpoint_keep, extra=extra)
+        self._snapshot_save(extra)
         self._last_ckpt_offset = self.offset
         if self.journal is not None:
             # the journal is best-effort relative to the broker log, but
@@ -1031,19 +1101,46 @@ class MatchService:
         record stream. Returns the number of input records consumed."""
         if self._pipe is not None and self._session is not None:
             return self._step_pipelined(timeout)
-        from kme_tpu.bridge.broker import BrokerError
-
-        try:
-            recs = self.broker.fetch(self.topic_in, self.offset, self.batch,
-                                     timeout=timeout)
-        except BrokerError:
-            # topics not provisioned yet — keep polling, like a Streams
-            # app waiting for its source topic
-            self.clock.sleep(min(timeout, 0.05))
-            return 0
+        recs = self._poll(self.offset, timeout)
         if not recs:
             return 0
         return self._process_batch(recs)
+
+    def _poll(self, offset: int, timeout: float):
+        """Wait up to `timeout` for input from `offset` on. None where
+        the topics are not provisioned yet — keep polling, like a
+        Streams app waiting for its source topic."""
+        from kme_tpu.bridge.broker import BrokerError
+
+        with self._ptimer.phase("poll_wait"):
+            try:
+                return self.broker.fetch(self.topic_in, offset, self.batch,
+                                         timeout=timeout)
+            except BrokerError:
+                self.clock.sleep(min(timeout, 0.05))
+                return None
+
+    def _parse_records(self, recs, fetch_us: int) -> tuple:
+        """Per-record parse of a fetched batch, the serial path's (drop
+        or die on a malformed record, `_parse`) ->
+        (msgs, offsets, drops, admission stamps)."""
+        lat = self._lat
+        msgs, offs, drops, atss = [], [], [], []
+        with self._span("parse_batch"):
+            for r in recs:
+                ats = getattr(r, "ats", None)
+                if ats is not None:
+                    # ingress = broker admission -> this fetch;
+                    # per-record, from the intended-start stamp
+                    lat["ingress"].observe(max(0, fetch_us - ats) * 1e-6)
+                m = self._parse(r.value)
+                if m is not None:
+                    msgs.append(m)
+                    offs.append(r.offset)
+                    atss.append(ats)
+                else:
+                    drops.append((-1, r.offset))
+        return msgs, offs, drops, atss
 
     def _process_batch(self, recs) -> int:
         """Serial batch processing: parse, engine, produce, commit —
@@ -1054,36 +1151,23 @@ class MatchService:
 
         fetch_us = self.clock.time_us()
         lat = self._lat
-        msgs, offs, drops, atss = [], [], [], []
-        for r in recs:
-            ats = getattr(r, "ats", None)
-            if ats is not None:
-                # ingress = broker admission -> this fetch; per-record,
-                # from the intended-start stamp
-                lat["ingress"].observe(max(0, fetch_us - ats) * 1e-6)
-            m = self._parse(r.value)
-            if m is not None:
-                msgs.append(m)
-                offs.append(r.offset)
-                atss.append(ats)
-            else:
-                drops.append((-1, r.offset))
-        out = reasons = None
         self._batch_ordinal += 1
+        msgs, offs, drops, atss = self._parse_records(recs, fetch_us)
+        out = reasons = None
         self._last_produce_s = 0.0
         phases = getattr(self._session, "phases", None)
         p0 = dict(phases) if phases is not None else {}
         t_engine0 = _t.perf_counter()
         if msgs:
             if self._native is not None:
-                with self._ptimer.phase("serve_engine"):
-                    self._flow("s")
-                    out = self._native_produce(msgs)
+                out = self._native_produce(msgs)
             elif self._session is not None:
                 try:
-                    with self._ptimer.phase("serve_engine"):
+                    with self._span("process_wire"):
                         self._flow("s")
                         out = self._session.process_wire(msgs)
+                    if self._first_batch is None:
+                        self._first_batch_done(t_engine0)
                 except Exception as e:
                     from kme_tpu.runtime.seqsession import \
                         UnsupportedJavaOp
@@ -1098,14 +1182,14 @@ class MatchService:
                     # continues there — the batch replays on the
                     # native engine from the same state
                     self._degrade_to_native(str(e))
-                    out = self._native_produce(msgs)
+                    out = self._native_produce(msgs, flow=False)
                 else:
                     reasons = self._session.last_reasons
                     self._produce_lines(out)
             else:
                 from kme_tpu.wire import dumps_order
 
-                with self._ptimer.phase("serve_engine"):
+                with self._span("process_wire"):
                     self._flow("s")
                     out = [[f"{rec.key} {dumps_order(rec.value)}"
                             for rec in self._oracle.process(m)]
@@ -1136,8 +1220,7 @@ class MatchService:
             if dev_d > 0:
                 lat["device"].observe(dev_d, n)
                 self.telemetry.gauge(
-                    "device_ms_per_batch",
-                    "device wall time of the last batch").set(
+                    "device_ms_per_batch", _DEVICE_MS_HELP).set(
                     round(dev_d * 1e3, 3))
             if self._last_produce_s > 0:
                 lat["produce"].observe(self._last_produce_s, n)
@@ -1207,21 +1290,23 @@ class MatchService:
 
         from kme_tpu.wire import WireBatch
 
-        try:
-            payload = b"\n".join(
-                v if isinstance(v, bytes) else v.encode()
-                for v in (r.value for r in recs))
-            wb = WireBatch.parse_buffer(payload)
-        except (ValueError, OverflowError, UnicodeEncodeError,
-                AttributeError):
-            return None
-        if wb.n != len(recs):
-            return None  # embedded newlines / empty values
-        lim = 1 << 31
-        if not (np.all(wb.price >= -lim) and np.all(wb.price < lim)
-                and np.all(wb.size >= -lim) and np.all(wb.size < lim)):
-            return None
-        return wb
+        with self._span("parse_batch", self._batch_ordinal + 1):
+            try:
+                payload = b"\n".join(
+                    v if isinstance(v, bytes) else v.encode()
+                    for v in (r.value for r in recs))
+                wb = WireBatch.parse_buffer(payload)
+            except (ValueError, OverflowError, UnicodeEncodeError,
+                    AttributeError):
+                return None
+            if wb.n != len(recs):
+                return None  # embedded newlines / empty values
+            lim = 1 << 31
+            if not (np.all(wb.price >= -lim) and np.all(wb.price < lim)
+                    and np.all(wb.size >= -lim)
+                    and np.all(wb.size < lim)):
+                return None
+            return wb
 
     def _step_pipelined(self, timeout: float = 0.5) -> int:
         """Poll once in pipelined mode: parse + plan + DISPATCH this
@@ -1232,14 +1317,9 @@ class MatchService:
         window; self.offset still advances only at collect time, so
         the at-least-once replay contract (H5 batch-boundary commit)
         is unchanged."""
-        from kme_tpu.bridge.broker import BrokerError
-
         fetch_off = self._pipe[-1][0] if self._pipe else self.offset
-        try:
-            recs = self.broker.fetch(self.topic_in, fetch_off, self.batch,
-                                     timeout=timeout)
-        except BrokerError:
-            self.clock.sleep(min(timeout, 0.05))
+        recs = self._poll(fetch_off, timeout)
+        if recs is None:
             return 0
         if not recs:
             # idle input: finish the in-flight window so output
@@ -1272,9 +1352,11 @@ class MatchService:
             # the cadenced checkpoint fires at this batch's collect
             self._drain_pipeline()
         self._batch_ordinal += 1
+        if self._first_batch is None:
+            self._first_batch = (self._batch_ordinal, _t.perf_counter())
         phases = self._session.phases
         p0 = dict(phases)
-        with self._ptimer.phase("serve_engine"):
+        with self._span("session_submit"):
             self._flow("s")
             handle = self._session.submit(wb)
         plan_d = phases.get("plan_s", 0.0) - p0.get("plan_s", 0.0)
@@ -1299,8 +1381,10 @@ class MatchService:
         self._last_produce_s = 0.0
         phases = self._session.phases
         p0 = dict(phases)
-        with self._ptimer.phase("serve_engine"):
+        with self._span("session_collect", ordinal):
             buf, line_off, msg_lines = self._session.collect(handle)
+        if self._first_batch and self._first_batch[0] == ordinal:
+            self._first_batch_done(self._first_batch[1])
         reasons = self._session.last_reasons
         # device attribution under pipelining: what the batch WAITED at
         # fetch time (overlapped device work the host never sees is the
@@ -1314,8 +1398,7 @@ class MatchService:
         if dev_d > 0:
             lat["device"].observe(dev_d, n)
             self.telemetry.gauge(
-                "device_ms_per_batch",
-                "device wall time of the last batch").set(
+                "device_ms_per_batch", _DEVICE_MS_HELP).set(
                 round(dev_d * 1e3, 3))
         if self._last_produce_s > 0:
             lat["produce"].observe(self._last_produce_s, n)
@@ -1384,7 +1467,7 @@ class MatchService:
         import time as _t
 
         t0 = _t.perf_counter()
-        with self._ptimer.phase("serve_produce"):
+        with self._span("produce_buffer", ordinal):
             self._flow("f", ordinal)
             text = buf.decode("ascii")
             lo = line_off.tolist()
@@ -1398,10 +1481,64 @@ class MatchService:
         Runs on the POLL THREAD only: the engine refresh touches device
         arrays, which the heartbeat/HTTP threads must never do — they
         read registry snapshots."""
+        with self._span("publish_batch"):
+            self._publish_gauges()
+            now = self.clock.monotonic()
+            if now - self._last_engine_pub >= 1.0:
+                self._last_engine_pub = now
+                self._engine_refresh()
+        self._publish_spans(1, nrecs, ndropped)
+
+    def _publish_spans(self, batches: int = 0, nrecs: int = 0,
+                       ndropped: int = 0) -> None:
+        """The batch counters, and every span of the loop's timer and
+        of the session's as two cumulative gauges, `<name>_s` and
+        `<name>_n`, with the loop's own wall (`serve_loop_s`), the lane
+        switches and the XLA compile totals beside them — all at ONE
+        instant, after the batch's last span has closed: a difference
+        of two heartbeats then holds whole batches of each (a counter
+        stepped before the engine refresh and a gauge set after it
+        would be a batch apart in most heartbeats)."""
+        import time as _t
+
         t = self.telemetry
-        t.counter("service_batches").inc()
+        t.counter("service_batches").inc(batches)
         t.counter("service_records").inc(nrecs)
         t.counter("service_dropped").inc(ndropped)
+        gauges = self._ptimer.gauges(self.LOOP_SPANS + self.INNER_SPANS)
+        timer = getattr(self._session, "timer", None)
+        if timer is not None:
+            gauges.update(timer.gauges(getattr(self._session, "SPANS", ())))
+            # host-path attribution: cumulative wall seconds the serve
+            # loop spent OFF the device (plan + reconstruction)
+            gauges["host_path_s"] = round(
+                gauges.get("plan_s", 0.0) + gauges.get("recon_s", 0.0), 6)
+        gauges["serve_loop_s"] = round(_t.perf_counter() - self._loop_t0, 6)
+        t.counter("lane_switches",
+                  "HBM book-cache lane switches the seq kernel made "
+                  "(host count over each plan, by the kernel's "
+                  "rule)").set(getattr(self._session, "lane_switches", 0))
+        # host engines never load jax: nothing compiles
+        jaxsetup = sys.modules.get("kme_tpu._jaxsetup")
+        compiles = (jaxsetup.compiles if jaxsetup is not None
+                    else {"n": 0, "seconds": 0.0})
+        t.counter("xla_compiles",
+                  "programs XLA compiled or took from the persistent "
+                  "cache in this process").set(compiles["n"])
+        gauges["xla_compile_s"] = round(compiles["seconds"], 6)
+        t.publish_gauges(gauges)
+
+    def _first_batch_done(self, t0: float) -> None:
+        """Gauge first_batch_s, set once: the first batch's submit to
+        its collect (trace, lowering, compile-cache read, first run)."""
+        import time as _t
+
+        self._first_batch = False
+        self.telemetry.gauge("first_batch_s").set(
+            round(_t.perf_counter() - t0, 3))
+
+    def _publish_gauges(self) -> None:
+        t = self.telemetry
         t.gauge("service_offset").set(self.offset)
         if faults.active():
             t.gauge("faults_injected").set(faults.fired_total())
@@ -1460,28 +1597,15 @@ class MatchService:
             t.gauge("journal_lag_bytes",
                     "bytes accepted by the journal but not yet "
                     "committed by its writer").set(self.journal.lag_bytes)
-        ph = getattr(self._session, "phases", None) \
-            if self._session is not None else None
-        if ph:
-            # host-path attribution (ISSUE: live gauges): cumulative
-            # wall seconds the serve loop spent OFF the device
-            plan = ph.get("plan_s", 0.0)
-            recon = ph.get("recon_s", 0.0)
-            t.gauge("plan_s",
-                    "cumulative host planning wall (s)").set(
-                round(plan, 6))
-            t.gauge("recon_s",
-                    "cumulative output reconstruction wall (s)").set(
-                round(recon, 6))
-            t.gauge("host_path_s",
-                    "cumulative host-path wall: plan + "
-                    "reconstruction (s)").set(round(plan + recon, 6))
         if self._pipe is not None:
             t.gauge("pipeline_depth",
                     "in-flight pipelined batches").set(len(self._pipe))
-        now = self.clock.monotonic()
-        if now - self._last_engine_pub >= 1.0:
-            self._last_engine_pub = now
+
+    def _engine_refresh(self) -> None:
+        """The once-a-second block of _publish_batch: engine counters,
+        SLO, profiler, trigger capture."""
+        t = self.telemetry
+        with self._span("engine_refresh"):
             if self._session is not None:
                 self._session.metrics()   # publishes counters + gauges
                 self._session.histograms()  # publishes bucket counts
@@ -1633,7 +1757,8 @@ class MatchService:
 
     def _flow(self, phase: str, ordinal: Optional[int] = None) -> None:
         """Trace flow arrow endpoint for the current batch: "s" inside
-        the engine span, "f" inside the produce span — Perfetto draws
+        the engine span (process_wire / session_submit), "f" inside
+        the produce span (produce_lines / produce_buffer) — Perfetto draws
         the causality arrow submit -> produce across tracks. Pipelined
         collects pass their submit-time ordinal explicitly (newer
         batches may have submitted in between)."""
@@ -1649,7 +1774,7 @@ class MatchService:
         import time as _t
 
         t0 = _t.perf_counter()
-        with self._ptimer.phase("serve_produce"):
+        with self._span("produce_lines"):
             self._flow("f")
             for lines in out:
                 for ln in lines:
@@ -1659,10 +1784,15 @@ class MatchService:
         # once per step (native partial + REJ annotations)
         self._last_produce_s += _t.perf_counter() - t0
 
-    def _native_produce(self, msgs):
+    def _native_produce(self, msgs, flow: bool = True):
         # byte-faithful death handling: forward every completed
         # message's records, THEN die like the reference thread
-        out, exc = self._native.process_wire_partial(msgs)
+        # (flow=False: the batch's arrow already started in the
+        # session this batch left)
+        with self._span("process_wire"):
+            if flow:
+                self._flow("s")
+            out, exc = self._native.process_wire_partial(msgs)
         self._produce_lines(out)
         if exc is not None:
             raise exc
@@ -1733,6 +1863,7 @@ class MatchService:
         eng.load_state(to_native_dump(export_seqjava(self._session)))
         self._native = eng
         self._session = None
+        self.telemetry.gauge("left_device_at_offset").set(self.offset)
 
     def engine_in_effect(self) -> str:
         """The engine serving RIGHT NOW: the requested one, or "native"
@@ -1846,13 +1977,25 @@ class MatchService:
                     self._drain_pipeline()
             finally:
                 if beat_stop is not None:
+                    # the beater may be in the middle of a beat: let it
+                    # finish, so that no beat follows the closing one
                     beat_stop.set()
+                    t.join(timeout=30.0)
                     self._write_heartbeat(health_file, seen,
                                           tick_box[0], closing=True)
         return seen
 
     def _write_heartbeat(self, path: Optional[str], seen: int,
                          tick: int = 0, closing: bool = False) -> None:
+        """One heartbeat, one writer at a time: the beater thread and
+        the serve loop share `<path>.tmp`, and whichever lost the
+        rename used to raise (in the serve loop: exit 1 after the work
+        was done)."""
+        with self._hb_lock:
+            self._write_heartbeat_locked(path, seen, tick, closing)
+
+    def _write_heartbeat_locked(self, path: Optional[str], seen: int,
+                                tick: int, closing: bool) -> None:
         import json
         import os
 

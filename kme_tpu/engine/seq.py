@@ -1559,6 +1559,9 @@ def build_seq_step(cfg: SeqConfig):
             input_output_aliases={NSMEM + k: k for k in range(nstate)},
             scratch_shapes=scratches,
             interpret=_jaxsetup.interpret(),
+            # the kernel's stable name (and named scope) in a device
+            # trace, whatever the program around it is called
+            name="seq_step",
         )(*[msgs[f] for f in MSG_FIELDS],
           *[state[k] for k in KEYS])
         new_state = dict(zip(KEYS, outs[:nstate]))

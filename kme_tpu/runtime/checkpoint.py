@@ -365,6 +365,19 @@ def _restore_one(path: str, shards: Optional[int], width: Optional[int]):
     return ses
 
 
+def _snapshot_export(session) -> dict:
+    """The device -> host half of a seq snapshot (span
+    `snapshot_export` of the session's timer)."""
+    with session.timer.phase("snapshot_export"):
+        if session.cfg.compat == "java":
+            from kme_tpu.runtime.javasnap import export_seqjava
+
+            return export_seqjava(session)
+        from kme_tpu.engine import seq as SQ
+
+        return SQ.export_canonical(session.cfg, session.state)
+
+
 def save_seq_session(ckpt_dir: str, session, offset: int,
                      keep: Optional[int] = None,
                      extra: Optional[dict] = None) -> str:
@@ -372,13 +385,11 @@ def save_seq_session(ckpt_dir: str, session, offset: int,
     canonical layout as lanes snapshots (slot_* / flat s64 positions /
     bal), so snapshots restore across ENGINES as well as across
     shard/width topologies."""
-    from kme_tpu.engine import seq as SQ
-
     if session.cfg.compat == "java":
         return _save_seqjava(ckpt_dir, session, offset, keep=keep,
                              extra=extra)
     os.makedirs(ckpt_dir, exist_ok=True)
-    canon = SQ.export_canonical(session.cfg, session.state)
+    canon = _snapshot_export(session)
     r = session.router
     meta = {
         "version": 1,
@@ -403,7 +414,8 @@ def save_seq_session(ckpt_dir: str, session, offset: int,
     payload["filloff"] = np.zeros(1, np.int64)
     payload["meta"] = np.frombuffer(
         json.dumps(meta).encode(), dtype=np.uint8)
-    return _atomic_savez(ckpt_dir, offset, payload, keep=keep)
+    with session.timer.phase("snapshot_write"):
+        return _atomic_savez(ckpt_dir, offset, payload, keep=keep)
 
 
 def _save_seqjava(ckpt_dir: str, session, offset: int,
@@ -414,10 +426,8 @@ def _save_seqjava(ckpt_dir: str, session, offset: int,
     garbage keys included: they are parity-relevant state), resting
     orders with direction tags and bucket seq, balances, and the
     router id maps."""
-    from kme_tpu.runtime.javasnap import export_seqjava
-
     os.makedirs(ckpt_dir, exist_ok=True)
-    snap = export_seqjava(session)
+    snap = _snapshot_export(session)
     meta = {
         "version": 1,
         "kind": "seqjava",
@@ -435,7 +445,8 @@ def _save_seqjava(ckpt_dir: str, session, offset: int,
                if k not in ("aid_idx", "sid_lane", "oid_sid")}
     payload["meta"] = np.frombuffer(
         json.dumps(meta).encode(), dtype=np.uint8)
-    return _atomic_savez(ckpt_dir, offset, payload, keep=keep)
+    with session.timer.phase("snapshot_write"):
+        return _atomic_savez(ckpt_dir, offset, payload, keep=keep)
 
 
 def _seqjava_snap_from_file(data, meta) -> dict:

@@ -45,6 +45,11 @@ _session._LERR_NAMES[SQ.LERR_JAVA_CAP] = \
 _TRADE_ACTS = {op.BUY: SQ.L_BUY, op.SELL: SQ.L_SELL}
 
 
+# java mode: the kernel's `valid` gate on a trade (engine/seq.py, TRADE
+# section): 0 <= price < 126 and size > 0
+_JAVA_PRICE_END = 126
+
+
 class UnsupportedJavaOp(RuntimeError):
     """The java-compat DEVICE surface excludes barriers and negative-sid
     symbols (dead or broken reference paths — Q3-Q6 and the ±sid book
@@ -130,6 +135,18 @@ class SeqRouter:
                 raise EnvelopeError(
                     f"message {i}: price/size outside int32 "
                     f"(price={m.price}, size={m.size})")
+            if (java and m.action in _TRADE_ACTS
+                    and not (0 <= m.price < _JAVA_PRICE_END and m.size > 0)):
+                # the reference runs unvalidated fields and the stock
+                # harness draws floor(N(50, 10)): about one trade in a
+                # million is zero or negative. On the device that is a
+                # sticky LERR_JAVA_DOMAIN AFTER the state was touched
+                # (fatal); seen here, before anything is, the stream
+                # leaves the device surface like a barrier does
+                raise UnsupportedJavaOp(
+                    f"message {i}: trade outside the java device domain "
+                    f"(price={m.price}, size={m.size}); use the native "
+                    f"engine")
         for i, m in enumerate(msgs):
             a = m.action
             aid, sid, oid = jl.jlong(m.aid), jl.jlong(m.sid), jl.jlong(m.oid)
@@ -382,12 +399,43 @@ def make_seq_router(num_lanes: int, num_accounts: int,
     return NativeSeqRouter(num_lanes, num_accounts, lib)
 
 
+# acts that touch a book: the kernel's `needs_books` (engine/seq.py,
+# is_trade | is_cancel | is_barrier)
+_BOOK_ACTS = (SQ.L_BUY, SQ.L_SELL, SQ.L_CANCEL, SQ.L_PAYOUT_YES,
+              SQ.L_PAYOUT_NO, SQ.L_REMOVE_SYMBOL)
+
+
+def count_lane_switches(cfg: SQ.SeqConfig, stacked: dict) -> int:
+    """HBM lane switches the kernel will make over one dispatch's
+    (K, B) `act` / `lane` planes, by its own rule: within a kernel call
+    (one row) a book-touching message whose lane differs from the lane
+    cached before it loads that lane's books (and flushes the cached
+    one); nothing is cached at the start of a call. 0 where the books
+    live in VMEM."""
+    if not cfg.hbm_books:
+        return 0
+    needs = np.isin(stacked["act"], _BOOK_ACTS)
+    call = np.nonzero(needs)[0]         # row-major: the kernel's order
+    if not len(call):
+        return 0
+    lane = stacked["lane"][needs]
+    return 1 + int(np.count_nonzero((call[1:] != call[:-1])
+                                    | (lane[1:] != lane[:-1])))
+
+
 class SeqSession:
     """Drop-in fixed-mode engine over the sequential mega-kernel.
 
     Same public surface as LaneSession (process / process_wire /
     metrics / export_state); single-device (the sharded path stays on
     the lanes engine)."""
+
+    # every span this session records (PhaseTimer names): the serve
+    # loop registers each as a heartbeat gauge pair before the first
+    # heartbeat, so that a reader of two snapshots finds it in both
+    SPANS = ("plan_s", "stage_s", "dispatch_s", "fetch_s", "recon_s",
+             "session_metrics", "metrics_export", "metrics_count",
+             "snapshot_export", "snapshot_write")
 
     def __init__(self, cfg: SQ.SeqConfig) -> None:
         self.cfg = cfg
@@ -419,6 +467,10 @@ class SeqSession:
         # submit was still in flight (device busy) counts as overlapped
         self._h2d_total_s = 0.0
         self._h2d_overlap_s = 0.0
+        # HBM book-cache lane switches the kernel made (the host's
+        # count over each plan, count_lane_switches); the serve loop
+        # publishes it as counter `lane_switches`
+        self.lane_switches = 0
 
     # ------------------------------------------------------------------
 
@@ -480,6 +532,7 @@ class SeqSession:
         Returns (cols, host_rejects, host dict, fills (4, F))."""
         with self.timer.phase("plan_s"):
             cols, host_rejects, stacked, cnts, K = self._plan(msgs)
+        self.lane_switches += count_lane_switches(self.cfg, stacked)
         with self.timer.phase("dispatch_s"):
             self.state, outp = SQ.build_seq_scan(self.cfg, K)(
                 self.state, stacked)
@@ -563,6 +616,10 @@ class SeqSession:
                     "route beyond-int64 streams through process_wire")
         with self.timer.phase("plan_s"):
             cols, host_rejects, stacked, cnts, K = self._plan(msgs)
+        # counted here (the planes are a rotating native buffer) and
+        # added at collect(), with the batch's other counters: a reader
+        # of two heartbeats then finds the same batches in each
+        switches = count_lane_switches(self.cfg, stacked)
         with self.timer.phase("stage_s"):
             # explicit async H2D staging: device_put enqueues the copy
             # of batch N+1's input planes while the device still runs
@@ -597,7 +654,7 @@ class SeqSession:
         self.windows.append(("submit", self._n_submit, t0,
                              perf_counter()))
         self._n_submit += 1
-        return (msgs, cols, host_rejects, outp, cnts, K)
+        return (msgs, cols, host_rejects, outp, cnts, K, switches)
 
     @property
     def h2d_overlap_frac(self) -> float:
@@ -615,7 +672,8 @@ class SeqSession:
         from time import perf_counter
 
         t0 = perf_counter()
-        batch, cols, host_rejects, outp, cnts, K = handle
+        batch, cols, host_rejects, outp, cnts, K, switches = handle
+        self.lane_switches += switches
         with self.timer.phase("fetch_s"):
             host, fills = self._fetch_outputs(outp, cnts, K)
         with self.timer.phase("recon_s"):
@@ -930,31 +988,42 @@ class SeqSession:
     # ------------------------------------------------------------------
 
     def metrics(self) -> Dict[str, int]:
-        counters = dict(zip(SQ.METRIC_NAMES, self._metrics.tolist()))
-        if self.cfg.compat == "java":
-            j = SQ.export_java(self.cfg, self.state)
-            used = j["slot_size"] > 0
-            counters.update({
-                "open_orders": int(used.sum()),
-                "books": int(j["book_exists"].sum()),
-                "accounts": int(j["bal_used"].sum()),
-                "positions": len(j["positions"]),
-                "max_book_depth": int(used.sum(axis=2).max())
-                if used.size else 0,
-            })
-        else:
-            canon = SQ.export_canonical(self.cfg, self.state)
-            used = canon["slot_used"]
-            depth = used.sum(axis=2)
-            counters.update({
-                "open_orders": int(used.sum()),
-                "books": int(canon["book_exists"].sum()),
-                "accounts": int(canon["bal_used"].sum()),
-                "positions": int((canon["pos_amt"] != 0).sum()),
-                "max_book_depth": int(depth.max()) if depth.size else 0,
-            })
-        self._publish(counters)
+        with self.timer.phase("session_metrics"):
+            counters = dict(zip(SQ.METRIC_NAMES, self._metrics.tolist()))
+            counters.update(
+                self._count_for_metrics(self._export_for_metrics()))
+            self._publish(counters)
         return counters
+
+    def _export_for_metrics(self) -> dict:
+        """The device -> host fetch of metrics(): the whole state."""
+        with self.timer.phase("metrics_export"):
+            if self.cfg.compat == "java":
+                return SQ.export_java(self.cfg, self.state)
+            return SQ.export_canonical(self.cfg, self.state)
+
+    def _count_for_metrics(self, ex: dict) -> Dict[str, int]:
+        """The numpy reductions of metrics() over that export."""
+        with self.timer.phase("metrics_count"):
+            if self.cfg.compat == "java":
+                used = ex["slot_size"] > 0
+                return {
+                    "open_orders": int(used.sum()),
+                    "books": int(ex["book_exists"].sum()),
+                    "accounts": int(ex["bal_used"].sum()),
+                    "positions": len(ex["positions"]),
+                    "max_book_depth": int(used.sum(axis=2).max())
+                    if used.size else 0,
+                }
+            used = ex["slot_used"]
+            depth = used.sum(axis=2)
+            return {
+                "open_orders": int(used.sum()),
+                "books": int(ex["book_exists"].sum()),
+                "accounts": int(ex["bal_used"].sum()),
+                "positions": int((ex["pos_amt"] != 0).sum()),
+                "max_book_depth": int(depth.max()) if depth.size else 0,
+            }
 
     def histograms(self) -> Dict[str, list]:
         """Device-accumulated distribution histograms (HIST_NAMES ->

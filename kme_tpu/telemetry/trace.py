@@ -11,12 +11,20 @@ When a TraceRecorder is installed (module-global via install(), as
 is also emitted as a Chrome trace event; save() writes the standard
 {"traceEvents": [...]} JSON that chrome://tracing / Perfetto load
 directly.
+
+In a process that has already imported jax, every span also enters a
+`jax.profiler.TraceAnnotation` of the same name: whoever has a profiler
+trace running (the benchmark's host, `--capture-dir`) finds the
+program's spans in the `.xplane.pb` beside the device operations, on
+one clock. This module never imports jax itself: `--engine oracle`, the
+tools and the benchmark's parent stay free of it.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -128,25 +136,51 @@ def get_tracer() -> TraceRecorder | None:
     return _tracer
 
 
+# the spans open on each thread, outermost first: a span's parent is
+# the one open on the same thread, whichever timer opened it
+_open = threading.local()
+
+
 class PhaseTimer:
-    """Accumulating span timer.
+    """Accumulating span timer — the program's one span primitive.
 
     `totals` maps phase name -> cumulative seconds across every span
-    since the last reset(). Sessions expose it directly as
-    `self.phases`."""
+    since the last reset(), `counts` the number of spans entered.
+    Sessions expose `totals` directly as `self.phases`. Spans nest; a
+    span given no `batch` takes its parent's, so the serve loop's batch
+    ordinal ties a batch's submit, collect, produce and publish
+    together down into the session's spans."""
 
     def __init__(self, track: str = "main"):
         self.totals: dict = {}
+        self.counts: dict = {}
         self.track = track
 
     @contextmanager
     def phase(self, name: str, **args):
+        stack = _open.__dict__.setdefault("stack", [])
+        if "batch" not in args and stack and "batch" in stack[-1]:
+            args["batch"] = stack[-1]["batch"]
+        # the profiler's own span, on the device trace's clock: built
+        # only while somebody has a trace running, one flag test when
+        # nobody has
+        prof = sys.modules.get("jax.profiler")
+        ann = None
+        if prof is not None and prof.TraceAnnotation.is_enabled():
+            ann = prof.TraceAnnotation(name, **args)
+        stack.append(args)
         t0 = time.perf_counter()
         try:
-            yield
+            if ann is None:
+                yield
+            else:
+                with ann:
+                    yield
         finally:
             dt = time.perf_counter() - t0
+            stack.pop()
             self.totals[name] = self.totals.get(name, 0.0) + dt
+            self.counts[name] = self.counts.get(name, 0) + 1
             tr = _tracer
             if tr is not None:
                 tr.add(name, t0, dt, track=self.track,
@@ -155,6 +189,19 @@ class PhaseTimer:
     def add(self, name: str, seconds: float) -> None:
         """Fold an externally-timed duration into the totals."""
         self.totals[name] = self.totals.get(name, 0.0) + seconds
+        self.counts[name] = self.counts.get(name, 0) + 1
+
+    def gauges(self, also=()) -> dict:
+        """Every span as two cumulative heartbeat gauges: `<name>_s`
+        (seconds; a phase already named `..._s` keeps its name) and
+        `<name>_n` (entries). Names in `also` not entered yet read 0."""
+        out = {}
+        for name in (*also, *self.totals):
+            base = name[:-2] if name.endswith("_s") else name
+            out[base + "_s"] = round(self.totals.get(name, 0.0), 6)
+            out[base + "_n"] = self.counts.get(name, 0)
+        return out
 
     def reset(self) -> None:
         self.totals.clear()
+        self.counts.clear()

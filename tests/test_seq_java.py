@@ -215,3 +215,45 @@ def test_java_seq_service_degrades_on_barrier(tmp_path):
         "service should have degraded to the native engine"
     got = list(consume_lines(b, follow=False))
     assert got == want
+
+
+@pytest.mark.parametrize("bad", [
+    OrderMsg(action=op.SELL, oid=901, aid=3, sid=2, price=-1, size=3),
+    OrderMsg(action=op.BUY, oid=902, aid=2, sid=1, price=45, size=0),
+    OrderMsg(action=op.BUY, oid=903, aid=1, sid=2, price=44, size=-7),
+    OrderMsg(action=op.SELL, oid=904, aid=4, sid=1, price=126, size=5),
+], ids=["price-1", "size0", "size-7", "price126"])
+def test_java_seq_service_degrades_on_out_of_domain_trade(bad):
+    """The stock harness draws prices and sizes as floor(N(50, 10)):
+    about one trade in a million is zero or negative (seed 48151623 of
+    the benchmark's stream: message 85,268 is a SELL at price -1). The
+    java device domain excludes it; on the device it was a sticky
+    LERR_JAVA_DOMAIN after the state had been touched and the server
+    died (rc 1). The router now refuses it before anything is touched,
+    and the service continues on the native engine, byte-exact vs an
+    uninterrupted java-oracle run. (The cases are ones the reference
+    itself survives: a resting order at a negative price can later kill
+    it with Q7's NPE, and then every engine dies with it.)"""
+    from kme_tpu.bridge.broker import InProcessBroker
+    from kme_tpu.bridge.consume import consume_lines
+    from kme_tpu.bridge.provision import provision
+    from kme_tpu.bridge.service import MatchService
+    from kme_tpu.wire import dumps_order
+
+    msgs = harness_stream(300, seed=21)
+    mixed = msgs[:200] + [bad] + msgs[200:]
+    ora = OracleEngine("java")
+    want = [r.wire() for m in mixed for r in ora.process(m.copy())]
+    b = InProcessBroker()
+    provision(b)
+    for m in mixed:
+        b.produce("MatchIn", None, dumps_order(m))
+    svc = MatchService(b, engine="seq", compat="java", batch=64,
+                       symbols=8, accounts=128, slots=256, max_fills=64)
+    assert svc.run(max_messages=len(mixed)) == len(mixed)
+    assert svc.engine_in_effect() == "native" and svc._session is None
+    assert list(consume_lines(b, follow=False)) == want
+    # the heartbeat says when: the offset of the batch that left (the
+    # trade is message 200, in the fourth batch of 64)
+    snap = svc.telemetry.snapshot()
+    assert snap["gauges"]["left_device_at_offset"] == 192

@@ -1,0 +1,514 @@
+"""The program's own spans and counters (PR 26): PhaseTimer as the one
+span primitive, the serve loop's and the session's spans as heartbeat
+gauges, `lane_switches`, `xla_compiles`, and the heartbeat's one writer
+at a time. CPU, small shapes; nothing here is a timing of the device."""
+
+import json
+import logging
+import random
+import subprocess
+import sys
+import threading
+import types
+
+import numpy as np
+import pytest
+
+from kme_tpu.bridge.broker import InProcessBroker
+from kme_tpu.bridge.provision import provision
+from kme_tpu.bridge.service import TOPIC_IN, MatchService
+from kme_tpu.telemetry import PhaseTimer, TraceRecorder, install
+from kme_tpu.wire import dumps_order
+from kme_tpu.workload import harness_stream, zipf_symbol_stream
+
+# ---------------------------------------------------------------------------
+# the primitive
+
+
+def test_phase_counts_entries_and_nests():
+    t = PhaseTimer(track="unit")
+    rec = TraceRecorder()
+    install(rec)
+    try:
+        with t.phase("outer", batch=7):
+            with t.phase("inner"):
+                pass
+            with t.phase("inner"):
+                pass
+    finally:
+        install(None)
+    assert t.counts == {"outer": 1, "inner": 2}
+    assert t.totals["outer"] >= t.totals["inner"] > 0
+    ev = {e["name"]: e for e in rec.trace_events() if e.get("ph") == "X"}
+    # a span takes its parent's batch ordinal, and lies inside it
+    assert ev["inner"]["args"] == {"batch": 7} == ev["outer"]["args"]
+    assert ev["outer"]["ts"] <= ev["inner"]["ts"]
+    assert (ev["inner"]["ts"] + ev["inner"]["dur"]
+            <= ev["outer"]["ts"] + ev["outer"]["dur"])
+    t.add("inner", 1.0)
+    assert t.counts["inner"] == 3
+    g = t.gauges(also=("plan_s", "never_entered"))
+    assert g["inner_n"] == 3 and g["inner_s"] >= 1.0
+    # a phase already named ..._s keeps its name; unseen names read 0
+    assert g["plan_s"] == 0.0 and g["plan_n"] == 0
+    assert g["never_entered_s"] == 0.0 and "plan_s_s" not in g
+    t.reset()
+    assert t.totals == {} and t.counts == {}
+
+
+def test_parent_is_per_thread():
+    t = PhaseTimer()
+    seen = {}
+
+    def other():
+        with t.phase("elsewhere") as _:
+            pass
+        seen.update(t.counts)
+
+    rec = TraceRecorder()
+    install(rec)
+    try:
+        with t.phase("outer", batch=3):
+            th = threading.Thread(target=other)
+            th.start()
+            th.join(timeout=10)
+            assert not th.is_alive()
+    finally:
+        install(None)
+    ev = {e["name"]: e for e in rec.trace_events() if e.get("ph") == "X"}
+    assert "args" not in ev["elsewhere"]      # no parent on that thread
+    assert seen["elsewhere"] == 1
+
+
+def test_telemetry_and_spans_import_no_jax():
+    code = ("import sys\n"
+            "import kme_tpu.telemetry\n"
+            "from kme_tpu.telemetry import PhaseTimer\n"
+            "t = PhaseTimer()\n"
+            "with t.phase('x', batch=1):\n"
+            "    pass\n"
+            "assert t.counts == {'x': 1}\n"
+            "assert 'jax' not in sys.modules, 'jax was imported'\n")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
+
+
+def _fake_profiler(monkeypatch, calls, tracing=True, broken=False):
+    class FakeAnnotation:
+        def __init__(self, name, **kw):
+            self.what = (name, kw)
+
+        @staticmethod
+        def is_enabled():
+            return tracing
+
+        def __enter__(self):
+            if broken:
+                raise RuntimeError("the profiler is gone")
+            calls.append(("enter",) + self.what)
+
+        def __exit__(self, *exc):
+            calls.append(("exit",) + self.what)
+
+    fake = types.ModuleType("jax.profiler")
+    fake.TraceAnnotation = FakeAnnotation
+    monkeypatch.setitem(sys.modules, "jax.profiler", fake)
+
+
+def test_phase_enters_a_trace_annotation_when_jax_is_loaded(monkeypatch):
+    calls = []
+    _fake_profiler(monkeypatch, calls)
+    t = PhaseTimer()
+    with t.phase("session_submit", batch=5):
+        with t.phase("plan_s"):
+            pass
+    assert calls == [("enter", "session_submit", {"batch": 5}),
+                     ("enter", "plan_s", {"batch": 5}),
+                     ("exit", "plan_s", {"batch": 5}),
+                     ("exit", "session_submit", {"batch": 5})]
+    # and with the real one (jax is loaded in this process by conftest)
+    monkeypatch.undo()
+    import jax.profiler  # noqa: F401
+
+    with t.phase("session_submit", batch=6):
+        pass
+    assert t.counts["session_submit"] == 2
+
+
+def test_phase_builds_no_annotation_while_nobody_traces(monkeypatch):
+    calls = []
+    _fake_profiler(monkeypatch, calls, tracing=False)
+    t = PhaseTimer()
+    with t.phase("session_submit", batch=5):
+        pass
+    assert calls == [] and t.counts == {"session_submit": 1}
+
+
+def test_a_span_that_cannot_enter_leaves_the_stack_balanced(monkeypatch):
+    from kme_tpu.telemetry import trace
+
+    t = PhaseTimer()
+    with t.phase("outer", batch=1):
+        _fake_profiler(monkeypatch, [], broken=True)
+        with pytest.raises(RuntimeError):
+            with t.phase("inner", batch=2):
+                raise AssertionError("the body must not run")
+        assert trace._open.stack == [{"batch": 1}]
+        monkeypatch.undo()
+        # a later span on the thread takes the batch of its real
+        # parent, not of the one that failed to open
+        rec = TraceRecorder()
+        install(rec)
+        try:
+            with t.phase("after"):
+                pass
+        finally:
+            install(None)
+        ev = [e for e in rec.trace_events() if e.get("name") == "after"]
+        assert ev[0]["args"] == {"batch": 1}
+    assert trace._open.stack == []
+
+
+# ---------------------------------------------------------------------------
+# the serve loop: every gauge in the first heartbeat, the spans partition
+# the loop
+
+
+def _perf_clock():
+    """The clock injected into the service: the spans' own
+    (perf_counter), so heartbeat times and span totals share one."""
+    import time
+
+    from kme_tpu.bridge.clock import WallClock
+
+    class PerfClock(WallClock):
+        def time(self):
+            return time.perf_counter()
+
+    return PerfClock()
+
+
+def _feed(broker, msgs):
+    for m in msgs:
+        broker.produce(TOPIC_IN, None, dumps_order(m))
+    return len(msgs)
+
+
+def _span_gauges(svc):
+    names = list(MatchService.LOOP_SPANS + MatchService.INNER_SPANS)
+    names += list(getattr(svc._session, "SPANS", ()))
+    out = []
+    for n in names:
+        base = n[:-2] if n.endswith("_s") else n
+        out += [base + "_s", base + "_n"]
+    return out + ["host_path_s", "serve_loop_s", "xla_compile_s",
+                  "startup_import_s", "startup_backend_s",
+                  "startup_session_s", "first_batch_s",
+                  "left_device_at_offset"]
+
+
+def _run_with_heartbeats(svc, n, path, monkeypatch):
+    """run() the service over n fed messages; returns every heartbeat
+    written, in order."""
+    beats = []
+    write = MatchService._write_heartbeat_locked
+
+    def keep(self, p, seen, tick, closing):
+        write(self, p, seen, tick, closing)
+        with open(p) as f:
+            beats.append(json.load(f))
+
+    monkeypatch.setattr(MatchService, "_write_heartbeat_locked", keep)
+    assert svc.run(max_messages=n, health_file=path,
+                   health_every=0.2) == n
+    return beats
+
+
+def _check_partition(svc, beats):
+    first, last = beats[0], beats[-1]
+    assert last["closing"] is True
+    g0, g1 = first["metrics"]["gauges"], last["metrics"]["gauges"]
+    missing = [k for k in _span_gauges(svc) if k not in g0]
+    assert not missing, f"not in the FIRST heartbeat: {missing}"
+    for c in ("lane_switches", "xla_compiles"):
+        assert c in first["metrics"]["counters"]
+    wall = g1["serve_loop_s"] - g0["serve_loop_s"]
+    covered = sum(g1[f"{n}_s"] - g0[f"{n}_s"]
+                  for n in MatchService.LOOP_SPANS)
+    assert wall > 0 and covered <= wall * 1.001
+    assert covered >= 0.9 * wall, (covered, wall, g1)
+    # every span the loop records is a listed one (and so in the
+    # first heartbeat): one name for one interval
+    assert set(svc._ptimer.totals) <= set(
+        MatchService.LOOP_SPANS + MatchService.INNER_SPANS)
+    # the loop's wall is the heartbeats' own, on the injected clock
+    assert wall <= last["time"] - first["time"] + 0.5
+    return g1
+
+
+def test_pipelined_seq_service_spans(tmp_path, monkeypatch):
+    msgs = list(zipf_symbol_stream(192, 8, 64, seed=3))
+    br = InProcessBroker()
+    provision(br)
+    n = _feed(br, msgs)
+    svc = MatchService(br, engine="seq", compat="fixed", batch=64,
+                       symbols=8, accounts=128, slots=128, max_fills=16,
+                       pipeline=2, checkpoint_dir=str(tmp_path / "ck"),
+                       checkpoint_every=128, clock=_perf_clock())
+    assert svc.pipeline == 2
+    beats = _run_with_heartbeats(svc, n, str(tmp_path / "hb.json"),
+                                 monkeypatch)
+    g = _check_partition(svc, beats)
+    nb = -(-n // 64)
+    assert g["session_submit_n"] == g["session_collect_n"] == nb
+    assert g["produce_buffer_n"] == g["publish_batch_n"] == nb
+    assert g["parse_batch_n"] == nb and g["poll_wait_n"] >= nb
+    assert g["dispatch_n"] == g["fetch_n"] == g["stage_n"] == nb
+    assert g["checkpoint_n"] == g["snapshot_save_n"] >= 1
+    assert g["snapshot_export_n"] == g["snapshot_write_n"] \
+        == g["snapshot_save_n"]
+    assert g["engine_refresh_n"] == g["session_metrics_n"] \
+        == g["metrics_export_n"] == g["metrics_count_n"] >= 1
+    assert g["metrics_export_s"] + g["metrics_count_s"] \
+        <= g["session_metrics_s"] <= g["engine_refresh_s"]
+    assert g["first_batch_s"] > 0 and g["startup_session_s"] > 0
+    assert g["host_path_s"] == pytest.approx(
+        g["plan_s"] + g["recon_s"], abs=2e-6)
+    assert beats[-1]["metrics"]["counters"]["xla_compiles"] > 0
+    # books in VMEM at this size: no lane switch to count
+    assert beats[-1]["metrics"]["counters"]["lane_switches"] == 0
+    svc.close()
+
+
+def test_serial_java_service_spans(tmp_path, monkeypatch):
+    msgs = harness_stream(192, seed=4, num_accounts=6, num_symbols=3)
+    br = InProcessBroker()
+    provision(br)
+    n = _feed(br, msgs)
+    svc = MatchService(br, engine="seq", compat="java", batch=64,
+                       symbols=8, accounts=128, slots=128, max_fills=16,
+                       checkpoint_dir=str(tmp_path / "ck"),
+                       checkpoint_every=128, clock=_perf_clock())
+    beats = _run_with_heartbeats(svc, n, str(tmp_path / "hb.json"),
+                                 monkeypatch)
+    g = _check_partition(svc, beats)
+    assert svc.engine_in_effect() == "seq"
+    assert g["process_wire_n"] == g["produce_lines_n"] \
+        == g["parse_batch_n"] == g["publish_batch_n"] == -(-n // 64)
+    assert g["session_submit_n"] == g["produce_buffer_n"] == 0
+    assert g["process_wire_s"] > 0
+    assert g["left_device_at_offset"] == -1
+    assert g["snapshot_export_n"] == g["snapshot_save_n"] >= 1
+    svc.close()
+
+
+# ---------------------------------------------------------------------------
+# metrics(): the split changed nothing it returns
+
+
+def _metrics_as_before(ses):
+    """SeqSession.metrics() as it stood before the split (PR 24)."""
+    from kme_tpu.engine import seq as SQ
+
+    counters = dict(zip(SQ.METRIC_NAMES, ses._metrics.tolist()))
+    if ses.cfg.compat == "java":
+        j = SQ.export_java(ses.cfg, ses.state)
+        used = j["slot_size"] > 0
+        counters.update({
+            "open_orders": int(used.sum()),
+            "books": int(j["book_exists"].sum()),
+            "accounts": int(j["bal_used"].sum()),
+            "positions": len(j["positions"]),
+            "max_book_depth": int(used.sum(axis=2).max())
+            if used.size else 0,
+        })
+    else:
+        canon = SQ.export_canonical(ses.cfg, ses.state)
+        used = canon["slot_used"]
+        depth = used.sum(axis=2)
+        counters.update({
+            "open_orders": int(used.sum()),
+            "books": int(canon["book_exists"].sum()),
+            "accounts": int(canon["bal_used"].sum()),
+            "positions": int((canon["pos_amt"] != 0).sum()),
+            "max_book_depth": int(depth.max()) if depth.size else 0,
+        })
+    return counters
+
+
+@pytest.mark.parametrize("compat", ["fixed", "java"])
+def test_metrics_returns_what_it_did(compat):
+    from kme_tpu.engine import seq as SQ
+    from kme_tpu.runtime.seqsession import SeqSession
+
+    ses = SeqSession(SQ.SeqConfig(lanes=8, slots=128, accounts=128,
+                                  max_fills=16, compat=compat))
+    if compat == "java":
+        msgs = harness_stream(150, seed=2, num_accounts=5, num_symbols=3)
+    else:
+        msgs = list(zipf_symbol_stream(150, 8, 32, seed=2))
+    ses.process_wire(msgs)
+    got, want = ses.metrics(), _metrics_as_before(ses)
+    assert list(got.items()) == list(want.items())
+    assert got["msgs"] > 0 and got["open_orders"] > 0
+    assert ses.timer.counts["session_metrics"] == 1
+    assert ses.timer.counts["metrics_export"] == 1
+    assert ses.timer.counts["metrics_count"] == 1
+
+
+# ---------------------------------------------------------------------------
+# lane_switches: the host's count against the kernel's rule, spelled out
+
+
+def _lane_switches_by_the_kernels_rule(act, lane):
+    """engine/seq.py: `needs_books = is_trade | is_cancel | is_barrier`,
+    a switch where `needs_books & (lane != cur_lane)`, `cur_lane` -1 at
+    the start of every kernel call (one row of the planes)."""
+    from kme_tpu.engine import seq as SQ
+
+    books = {SQ.L_BUY, SQ.L_SELL, SQ.L_CANCEL, SQ.L_PAYOUT_YES,
+             SQ.L_PAYOUT_NO, SQ.L_REMOVE_SYMBOL}
+    n = 0
+    for row_act, row_lane in zip(act.tolist(), lane.tolist()):
+        cur = -1
+        for a, ln in zip(row_act, row_lane):
+            if a in books and ln != cur:
+                n += 1
+                cur = ln
+    return n
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_lane_switches_match_a_plain_loop(seed):
+    from kme_tpu.engine import seq as SQ
+    from kme_tpu.runtime.seqsession import count_lane_switches
+
+    rng = random.Random(seed)
+    K, B = rng.choice([(1, 128), (3, 128), (4, 256)])
+    cfg = SQ.SeqConfig(lanes=8, slots=128, accounts=128, batch=B,
+                       hbm_books=True)
+    acts = list(range(10))          # L_NOP .. L_REMOVE_SYMBOL
+    act = np.array([[rng.choice(acts) for _ in range(B)]
+                    for _ in range(K)], np.int32)
+    lane = np.array([[rng.randrange(3 if seed % 2 else 8)
+                      for _ in range(B)] for _ in range(K)], np.int32)
+    if seed == 0:
+        act[:, B // 2:] = SQ.L_NOP      # padding, as the plan leaves it
+    if seed == 1:
+        # the same lane across a chunk boundary still loads again
+        act[:, :] = SQ.L_BUY
+        lane[:, :] = 5
+        assert count_lane_switches(cfg, {"act": act, "lane": lane}) == K
+    want = _lane_switches_by_the_kernels_rule(act, lane)
+    assert count_lane_switches(cfg, {"act": act, "lane": lane}) == want
+    vmem = SQ.SeqConfig(lanes=8, slots=128, accounts=128, batch=B)
+    assert count_lane_switches(vmem, {"act": act, "lane": lane}) == 0
+    none = np.full((K, B), SQ.L_CREATE, np.int32)
+    assert count_lane_switches(cfg, {"act": none, "lane": lane}) == 0
+
+
+def test_session_counts_lane_switches_over_its_plans(monkeypatch):
+    from kme_tpu.engine import seq as SQ
+    from kme_tpu.runtime.seqsession import SeqSession
+
+    ses = SeqSession(SQ.SeqConfig(lanes=8, slots=128, accounts=128,
+                                  max_fills=16, batch=128,
+                                  hbm_books=True))
+    planes = []
+    plan = ses._plan
+
+    def recording(msgs):
+        r = plan(msgs)
+        planes.append((np.array(r[2]["act"]), np.array(r[2]["lane"])))
+        return r
+
+    monkeypatch.setattr(ses, "_plan", recording)
+    msgs = list(zipf_symbol_stream(300, 8, 32, seed=9))
+    ses.process_wire(msgs)              # 300 messages: three kernel calls
+    assert planes[0][0].shape[0] >= 3
+    want = sum(_lane_switches_by_the_kernels_rule(a, ln)
+               for a, ln in planes)
+    assert ses.lane_switches == want > 3
+
+
+# ---------------------------------------------------------------------------
+# xla_compiles: JAX's own compile events
+
+
+def test_xla_compiles_counts_a_cold_sessions_programs():
+    from kme_tpu import _jaxsetup
+    from kme_tpu.engine import seq as SQ
+    from kme_tpu.runtime.seqsession import SeqSession
+
+    finished = []
+
+    class Count(logging.Handler):
+        def emit(self, record):
+            if "Finished XLA compilation" in record.getMessage():
+                finished.append(record.getMessage())
+
+    log = logging.getLogger("jax._src.dispatch")
+    handler, level = Count(), log.level
+    log.addHandler(handler)
+    log.setLevel(logging.DEBUG)
+    try:
+        n0 = _jaxsetup.compiles["n"]
+        s0 = _jaxsetup.compiles["seconds"]
+        # a shape no other test of this process uses: a cold session
+        ses = SeqSession(SQ.SeqConfig(lanes=8, slots=256, accounts=384,
+                                      max_fills=8, batch=256))
+        msgs = list(zipf_symbol_stream(400, 8, 32, seed=5))
+        ses.process_wire(msgs[:200])
+        cold = _jaxsetup.compiles["n"] - n0
+        assert cold == len(finished) > 0
+        assert _jaxsetup.compiles["seconds"] > s0
+        ses.process_wire(msgs[200:400])     # the same shapes again
+        assert _jaxsetup.compiles["n"] - n0 == cold, finished[cold:]
+        assert len(finished) == cold
+    finally:
+        log.removeHandler(handler)
+        log.setLevel(level)
+
+
+# ---------------------------------------------------------------------------
+# the heartbeat: one writer at a time, the closing one last
+
+
+def test_fifty_closings_against_a_fast_beater(tmp_path):
+    msgs = harness_stream(40, seed=1, num_accounts=4, num_symbols=2,
+                          payout_opcode_bug=False, validate=True)
+    br = InProcessBroker()
+    provision(br)
+    svc = MatchService(br, engine="oracle", compat="fixed", batch=8)
+    path = str(tmp_path / "health.json")
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for k in range(50):
+            if k < len(msgs):
+                br.produce(TOPIC_IN, None, dumps_order(msgs[k]))
+            svc.run(idle_exit=0.003, poll_timeout=0.001,
+                    health_file=path, health_every=0.0001)
+            with open(path) as f:
+                hb = json.load(f)       # whole, never torn
+            assert hb["closing"] is True, k
+    finally:
+        sys.setswitchinterval(old)
+    assert threading.active_count() < 50
+    svc.close()
+
+
+def test_device_ms_gauge_says_what_it_is():
+    br = InProcessBroker()
+    provision(br)
+    msgs = harness_stream(20, seed=1, num_accounts=4, num_symbols=2,
+                          payout_opcode_bug=False, validate=True)
+    n = _feed(br, msgs)
+    svc = MatchService(br, engine="oracle", compat="fixed", batch=8)
+    assert svc.run(max_messages=n) == n
+    text = svc.telemetry.prometheus_text()
+    line = next(ln for ln in text.splitlines()
+                if ln.startswith("# HELP device_ms_per_batch"))
+    assert "waited" in line and "--pipeline" in line
+    svc.close()

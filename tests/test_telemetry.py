@@ -409,4 +409,4 @@ def test_serve_emits_flow_arrows_per_batch(tmp_path):
             [e["id"] for e in finishes])
     # arrows bind to real spans: engine + produce phase slices exist
     names = {e["name"] for e in evs if e["ph"] == "X"}
-    assert "serve_engine" in names and "serve_produce" in names
+    assert "process_wire" in names and "produce_lines" in names
