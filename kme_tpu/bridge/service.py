@@ -1513,6 +1513,12 @@ class MatchService:
             # loop spent OFF the device (plan + reconstruction)
             gauges["host_path_s"] = round(
                 gauges.get("plan_s", 0.0) + gauges.get("recon_s", 0.0), 6)
+            # bytes the session's metrics() brought device -> host:
+            # 20 a refresh (SeqSession's narrow read); only a session
+            # that counts them has the gauge
+            fetched = getattr(self._session, "metrics_fetch_bytes", None)
+            if fetched is not None:
+                gauges["metrics_fetch_bytes"] = fetched
         gauges["serve_loop_s"] = round(_t.perf_counter() - self._loop_t0, 6)
         t.counter("lane_switches",
                   "HBM book-cache lane switches the seq kernel made "

@@ -1815,6 +1815,48 @@ def export_canonical(cfg: SeqConfig, state) -> dict:
     }
 
 
+# what build_seq_occupancy's vector holds, in order
+OCCUPANCY_NAMES = ("open_orders", "books", "accounts", "positions",
+                   "max_book_depth")
+
+
+@functools.lru_cache(maxsize=None)
+def build_seq_occupancy(cfg: SeqConfig):
+    """The narrow read of SeqSession.metrics(): ONE jitted reduction,
+    device state -> the (5,) i32 vector of OCCUPANCY_NAMES, so that a
+    refresh fetches 20 bytes and not every plane. Each count is taken
+    on the plane and by the rule the whole-state exports above use
+    (tests/test_spans.py holds them equal): a slot is used where
+    `bs > 0`; a position counts where export_canonical's scatter leaves
+    a non-zero amount (fixed) or export_java keeps the entry (java)."""
+    S, A, NR = cfg.lanes, cfg.accounts, cfg.nr
+
+    def count(mask):
+        return jnp.sum(mask, dtype=I32)
+
+    def occupancy(state):
+        # rows of a book plane run (lane, side, NR): planes2slot's
+        # view, counted row by row first — a (S, 2, NR * 128) view of
+        # the plane itself makes XLA lay all of it out again.
+        # slots % 128 == 0, so no lane of a row is padding
+        used = jnp.sum(state["bs"] > 0, axis=1, dtype=I32)
+        depth = jnp.sum(used.reshape(2 * S, NR), axis=1, dtype=I32)
+        if cfg.compat == "java":
+            positions = count(state["hstate"] == 1)
+        else:
+            positions = count((state["hk"] != 0)
+                              & ((state["ha_lo"] | state["ha_hi"]) != 0))
+        return jnp.stack([
+            jnp.sum(depth, dtype=I32),
+            count(state["bex"].reshape(-1)[:S] != 0),
+            count(state["bal_u"].reshape(-1)[:A] != 0),
+            positions,
+            jnp.max(depth),
+        ])
+
+    return jax.jit(occupancy)
+
+
 # the replicated balance planes (account a -> row a>>7, lane a&127):
 # the only cross-shard-coupled state the seqmesh async dispatcher
 # forwards point-to-point and select-merges at barriers

@@ -471,6 +471,13 @@ class SeqSession:
         # count over each plan, count_lane_switches); the serve loop
         # publishes it as counter `lane_switches`
         self.lane_switches = 0
+        # metrics()' narrow read, compiled here and not at the first
+        # refresh: a compile inside a served batch is a stall
+        self._occupancy = SQ.build_seq_occupancy(cfg)
+        self._occupancy(self.state)
+        # bytes metrics() has brought device -> host (cumulative; the
+        # serve loop publishes it as gauge `metrics_fetch_bytes`)
+        self.metrics_fetch_bytes = 0
 
     # ------------------------------------------------------------------
 
@@ -995,35 +1002,21 @@ class SeqSession:
             self._publish(counters)
         return counters
 
-    def _export_for_metrics(self) -> dict:
-        """The device -> host fetch of metrics(): the whole state."""
+    def _export_for_metrics(self) -> np.ndarray:
+        """The device -> host fetch of metrics(): SQ.OCCUPANCY_NAMES'
+        five integers, reduced on the device. The state is not donated
+        to the scan, so this reads it behind whatever is in flight.
+        (This method and _count_for_metrics keep their names: each is a
+        span target of benchmark/spans/.)"""
         with self.timer.phase("metrics_export"):
-            if self.cfg.compat == "java":
-                return SQ.export_java(self.cfg, self.state)
-            return SQ.export_canonical(self.cfg, self.state)
+            five = np.asarray(self._occupancy(self.state))
+            self.metrics_fetch_bytes += five.nbytes
+            return five
 
-    def _count_for_metrics(self, ex: dict) -> Dict[str, int]:
-        """The numpy reductions of metrics() over that export."""
+    def _count_for_metrics(self, five: np.ndarray) -> Dict[str, int]:
+        """Names what _export_for_metrics fetched."""
         with self.timer.phase("metrics_count"):
-            if self.cfg.compat == "java":
-                used = ex["slot_size"] > 0
-                return {
-                    "open_orders": int(used.sum()),
-                    "books": int(ex["book_exists"].sum()),
-                    "accounts": int(ex["bal_used"].sum()),
-                    "positions": len(ex["positions"]),
-                    "max_book_depth": int(used.sum(axis=2).max())
-                    if used.size else 0,
-                }
-            used = ex["slot_used"]
-            depth = used.sum(axis=2)
-            return {
-                "open_orders": int(used.sum()),
-                "books": int(ex["book_exists"].sum()),
-                "accounts": int(ex["bal_used"].sum()),
-                "positions": int((ex["pos_amt"] != 0).sum()),
-                "max_book_depth": int(depth.max()) if depth.size else 0,
-            }
+            return dict(zip(SQ.OCCUPANCY_NAMES, five.tolist()))
 
     def histograms(self) -> Dict[str, list]:
         """Device-accumulated distribution histograms (HIST_NAMES ->

@@ -14,11 +14,12 @@ import types
 import numpy as np
 import pytest
 
+import kme_tpu.opcodes as op
 from kme_tpu.bridge.broker import InProcessBroker
 from kme_tpu.bridge.provision import provision
 from kme_tpu.bridge.service import TOPIC_IN, MatchService
 from kme_tpu.telemetry import PhaseTimer, TraceRecorder, install
-from kme_tpu.wire import dumps_order
+from kme_tpu.wire import OrderMsg, WireBatch, dumps_order
 from kme_tpu.workload import harness_stream, zipf_symbol_stream
 
 # ---------------------------------------------------------------------------
@@ -205,7 +206,7 @@ def _span_gauges(svc):
     return out + ["host_path_s", "serve_loop_s", "xla_compile_s",
                   "startup_import_s", "startup_backend_s",
                   "startup_session_s", "first_batch_s",
-                  "left_device_at_offset"]
+                  "left_device_at_offset", "metrics_fetch_bytes"]
 
 
 def _run_with_heartbeats(svc, n, path, monkeypatch):
@@ -272,6 +273,8 @@ def test_pipelined_seq_service_spans(tmp_path, monkeypatch):
         == g["metrics_export_n"] == g["metrics_count_n"] >= 1
     assert g["metrics_export_s"] + g["metrics_count_s"] \
         <= g["session_metrics_s"] <= g["engine_refresh_s"]
+    # the narrow read engaged: five int32 a refresh, not the state
+    assert g["metrics_fetch_bytes"] == 20 * g["metrics_export_n"]
     assert g["first_batch_s"] > 0 and g["startup_session_s"] > 0
     assert g["host_path_s"] == pytest.approx(
         g["plan_s"] + g["recon_s"], abs=2e-6)
@@ -299,16 +302,19 @@ def test_serial_java_service_spans(tmp_path, monkeypatch):
     assert g["session_submit_n"] == g["produce_buffer_n"] == 0
     assert g["process_wire_s"] > 0
     assert g["left_device_at_offset"] == -1
+    assert g["metrics_fetch_bytes"] == 20 * g["metrics_export_n"] > 0
     assert g["snapshot_export_n"] == g["snapshot_save_n"] >= 1
     svc.close()
 
 
 # ---------------------------------------------------------------------------
-# metrics(): the split changed nothing it returns
+# metrics(): the narrow read (five integers reduced on the device) returns
+# what the whole-state exports did
 
 
 def _metrics_as_before(ses):
-    """SeqSession.metrics() as it stood before the split (PR 24)."""
+    """SeqSession.metrics() as it stood before the split (PR 24): the
+    oracle, counted on the host over the whole-state exports."""
     from kme_tpu.engine import seq as SQ
 
     counters = dict(zip(SQ.METRIC_NAMES, ses._metrics.tolist()))
@@ -337,24 +343,115 @@ def _metrics_as_before(ses):
     return counters
 
 
-@pytest.mark.parametrize("compat", ["fixed", "java"])
-def test_metrics_returns_what_it_did(compat):
+def _occupancy_stream(compat):
+    """Resting orders on several lanes, non-zero positions, and a
+    position that came back to zero: in fixed mode the zipf stream
+    holds one; the java harness never deletes a key in 150 messages,
+    so a tail deletes one (Q11: two fills leave a value-as-key entry
+    (5, 5), a fill back to zero pops it)."""
+    if compat == "fixed":
+        return list(zipf_symbol_stream(150, 8, 32, seed=2))
+    a, b, sid = 101, 102, 7
+    msgs = harness_stream(150, seed=2, num_accounts=5, num_symbols=3)
+    for aid in (a, b):
+        msgs += [OrderMsg(action=op.CREATE_BALANCE, aid=aid),
+                 OrderMsg(action=op.TRANSFER, aid=aid, size=10**6)]
+    msgs.append(OrderMsg(action=op.ADD_SYMBOL, sid=sid))
+    oid = 10**9
+    for buyer, seller, size in ((a, b, 5), (a, b, 3), (b, a, 5)):
+        msgs += [OrderMsg(action=op.BUY, oid=oid, aid=buyer, sid=sid,
+                          price=50, size=size),
+                 OrderMsg(action=op.SELL, oid=oid + 1, aid=seller,
+                          sid=sid, price=50, size=size)]
+        oid += 2
+    return msgs
+
+
+def _occupancy_session(compat, hbm_books, **kw):
     from kme_tpu.engine import seq as SQ
     from kme_tpu.runtime.seqsession import SeqSession
 
-    ses = SeqSession(SQ.SeqConfig(lanes=8, slots=128, accounts=128,
-                                  max_fills=16, compat=compat))
-    if compat == "java":
-        msgs = harness_stream(150, seed=2, num_accounts=5, num_symbols=3)
-    else:
-        msgs = list(zipf_symbol_stream(150, 8, 32, seed=2))
-    ses.process_wire(msgs)
+    return SeqSession(SQ.SeqConfig(lanes=8, slots=128, accounts=128,
+                                   max_fills=16, compat=compat,
+                                   hbm_books=hbm_books, **kw))
+
+
+def _same_as_before(ses):
     got, want = ses.metrics(), _metrics_as_before(ses)
+    # keys, order and values
     assert list(got.items()) == list(want.items())
-    assert got["msgs"] > 0 and got["open_orders"] > 0
+    return got
+
+
+@pytest.mark.parametrize("hbm_books", [False, True])
+@pytest.mark.parametrize("compat", ["fixed", "java"])
+def test_metrics_returns_what_it_did(compat, hbm_books):
+    ses = _occupancy_session(compat, hbm_books)
+    ses.process_wire(_occupancy_stream(compat))
+    got = _same_as_before(ses)
+    assert got["msgs"] > 0 and got["positions"] > 0
+    assert got["max_book_depth"] > 1
+    st = {k: np.asarray(v) for k, v in ses.state.items()}
+    # the stream reached every rule of the reduction
+    resting = (st["bs"].reshape(8, -1) > 0).any(axis=1)
+    assert resting.sum() >= 3 and got["open_orders"] > resting.sum()
+    if compat == "java":
+        gone = st["hstate"] == 2
+    else:
+        gone = (st["hk"] != 0) & ((st["ha_lo"] | st["ha_hi"]) == 0)
+    assert gone.any(), "no position came back to zero"
     assert ses.timer.counts["session_metrics"] == 1
     assert ses.timer.counts["metrics_export"] == 1
     assert ses.timer.counts["metrics_count"] == 1
+
+
+def test_metrics_between_batches_in_flight():
+    """metrics() reads the state behind whatever is dispatched: with
+    one and two batches in flight, between the collects and after the
+    drain it is what the whole-state export of the same moment gives."""
+    from kme_tpu.engine import seq as SQ
+
+    ses = _occupancy_session("fixed", True, batch=128)
+    msgs = _occupancy_stream("fixed")
+    empty = _same_as_before(ses)
+    assert empty["open_orders"] == empty["positions"] == 0
+    h1 = ses.submit(WireBatch.from_msgs(msgs[:80]))
+    one = _same_as_before(ses)
+    h2 = ses.submit(WireBatch.from_msgs(msgs[80:]))
+    two = _same_as_before(ses)
+    # the counters are the host's, added at collect; the occupancy is
+    # the device's, as of the last dispatch
+    assert two["msgs"] == 0 and two["open_orders"] > one["open_orders"] > 0
+    ses.collect(h1)
+    assert _same_as_before(ses)["msgs"] > 0
+    ses.collect(h2)
+    drained = _same_as_before(ses)
+    serial = _occupancy_session("fixed", True, batch=128)
+    serial.process_wire(msgs[:80])
+    serial.process_wire(msgs[80:])
+    assert drained == serial.metrics()
+    assert [two[k] for k in SQ.OCCUPANCY_NAMES] \
+        == [drained[k] for k in SQ.OCCUPANCY_NAMES]
+
+
+@pytest.mark.parametrize("compat", ["fixed", "java"])
+def test_metrics_fetches_five_integers_not_the_state(compat, monkeypatch):
+    from kme_tpu.engine import seq as SQ
+
+    ses = _occupancy_session(compat, compat == "fixed")
+    ses.process_wire(_occupancy_stream(compat))
+    want = _metrics_as_before(ses)
+
+    def whole_state(*a, **kw):
+        raise AssertionError("metrics() exported the whole state")
+
+    monkeypatch.setattr(SQ, "export_canonical", whole_state)
+    monkeypatch.setattr(SQ, "export_java", whole_state)
+    assert ses.metrics_fetch_bytes == 0
+    for n in (1, 2, 3):
+        assert ses.metrics() == want
+        assert ses.metrics_fetch_bytes == 20 * n
+    assert ses.timer.counts["metrics_export"] == 3
 
 
 # ---------------------------------------------------------------------------
