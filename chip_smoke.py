@@ -16,8 +16,15 @@ bytes of the durable MatchOut log against the native oracle.
   C  java-harness: --compat java, fed by `kme-loadgen --connections 8
      --binary` (the stock exchange_test.js stream); must still be on
      the device session at the end.
-  D  fixed-vmem-default: kme-serve's default shape (books in VMEM),
-     --pipeline 0, JSON wire from kme-loadgen.
+  D  kme-serve's default shape (books in VMEM, positions in HBM),
+     --pipeline 0, JSON wire from kme-loadgen: 20,000 events of the
+     validated harness stream over 1024 symbols x 2048 accounts. A
+     start-up proof, not the deployment: that stream is half refusals
+     (52% of its trades in the first 49k messages, 89% by 300k: once-
+     funded accounts, 513 of the 1024 symbols created) and ends long
+     before the position store matters. The deployment is the
+     benchmark's configuration fixed-vmem-default (uniform
+     zipf_symbol_stream, cell vmem-default-sat).
   (--chips 4)  `kme-bench --suite shards` on four chips: parity at
      shards 1/2/4, four distinct devices, the lockstep shard_map leg.
 
@@ -486,7 +493,8 @@ def main(argv=None) -> int:
         c.update(verify_log("C", c["state"], NativeOracleEngine("java")))
         done(c)
 
-        # ---- D: kme-serve's default shape, serial, JSON wire
+        # ---- D: kme-serve's default shape, serial, JSON wire (a start-up
+        # proof on a stream that is half refusals; see the docstring)
         d = serve_phase(
             "D", "D", kids, out, env, ["--pipeline", "0"],
             loadgen_feeder(kids, out, "D", env,

@@ -1150,8 +1150,7 @@ def bench_shards(events: int = 4000, symbols: int = 8,
     want = [r.wire() for m in msgs for r in oracle.process(m.copy())]
     cfg = SQ.SeqConfig(lanes=symbols, slots=slots,
                        accounts=-(-max(accounts, 128) // 128) * 128,
-                       max_fills=max_fills, pos_cap=1 << 10,
-                       probe_max=8)
+                       max_fills=max_fills)
 
     def run(shards, rebalance, mode=dispatch, wall_feed=False):
         ses = SeqMeshSession(cfg, shards, rebalance=rebalance,

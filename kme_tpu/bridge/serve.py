@@ -14,7 +14,9 @@ import argparse
 import sys
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
+    """kme-serve's options; their defaults ARE the flagless deployment
+    (benchmark configuration fixed-vmem-default writes them out)."""
     p = argparse.ArgumentParser(prog="kme-serve", description=__doc__)
     p.add_argument("--listen", default="127.0.0.1:9092", metavar="HOST:PORT")
     p.add_argument("--kafka", default=None, metavar="BOOTSTRAP",
@@ -237,7 +239,11 @@ def main(argv=None) -> int:
                         "naming each rejected order's rej_* reason "
                         "code (the IN/OUT stream stays byte-identical "
                         "to the reference)")
-    args = p.parse_args(argv)
+    return p
+
+
+def main(argv=None) -> int:
+    args = build_parser().parse_args(argv)
 
     import os
 
@@ -357,7 +363,7 @@ def main(argv=None) -> int:
                                args.slo_min_records_per_sec}))
     print("kme-serve: " + " ".join(f"{k}={v}" for k, v in {
         "engine": svc.engine_in_effect(), "pipeline": svc.pipeline,
-        **svc.runs_on}.items()), file=sys.stderr)
+        **svc.runs_on, **svc.state_homes()}.items()), file=sys.stderr)
     msrv = None
     if args.metrics_port is not None:
         from kme_tpu.telemetry import start_metrics_server

@@ -755,7 +755,8 @@ class MatchService:
             "startup_import_s": 0.0, "startup_backend_s": 0.0,
             **(jaxsetup.startup if jaxsetup is not None else {}),
             "startup_session_s": round(self._startup_session_s, 3),
-            "first_batch_s": 0.0})
+            "first_batch_s": 0.0, **self.state_homes()})
+        self._publish_pos_store({})
         t.gauge("left_device_at_offset",
                 "input offset of the batch at which a java-mode seq "
                 "service left the device for the native engine "
@@ -1524,6 +1525,10 @@ class MatchService:
                   "HBM book-cache lane switches the seq kernel made "
                   "(host count over each plan, by the kernel's "
                   "rule)").set(getattr(self._session, "lane_switches", 0))
+        t.counter("pos_probe_tiles",
+                  "tiles of the position store the seq kernel brought "
+                  "in from HBM (the kernel's own count)"
+                  ).set(getattr(self._session, "pos_probe_tiles", 0))
         # host engines never load jax: nothing compiles
         jaxsetup = sys.modules.get("kme_tpu._jaxsetup")
         compiles = (jaxsetup.compiles if jaxsetup is not None
@@ -1613,7 +1618,8 @@ class MatchService:
         t = self.telemetry
         with self._span("engine_refresh"):
             if self._session is not None:
-                self._session.metrics()   # publishes counters + gauges
+                # publishes counters + gauges
+                self._publish_pos_store(self._session.metrics())
                 self._session.histograms()  # publishes bucket counts
             if self.slo is not None:
                 # SLO degradation rides the same heartbeat channel as
@@ -1877,6 +1883,29 @@ class MatchService:
         if self._session is not None:
             return self.engine_kind
         return "native" if self._native is not None else "oracle"
+
+    def state_homes(self) -> dict:
+        """Where the seq kernel keeps its books, as the deployment's
+        depth decided it (1: in HBM, one lane at a time in VMEM; 0:
+        VMEM-resident) — on the start-up line and as a gauge. The
+        fixed-mode position store is in HBM at every size. {} for
+        every other engine."""
+        cfg = getattr(self._session, "cfg", None)
+        if not hasattr(cfg, "hbm_books"):
+            return {}
+        return {"books_in_hbm": int(cfg.hbm_books)}
+
+    def _publish_pos_store(self, counters: dict) -> None:
+        """The seq engine's position store under its load, of the same
+        read as the engine's gauges: live entries (`positions`) over
+        what the configuration's store holds (SeqConfig.pos_capacity)."""
+        cap = getattr(getattr(self._session, "cfg", None),
+                      "pos_capacity", None)
+        if cap:
+            live = counters.get("positions", 0)
+            self.telemetry.publish_gauges({
+                "pos_live": live, "pos_capacity": cap,
+                "pos_load_pct": round(100.0 * live / cap, 4)})
 
     def metrics(self) -> Optional[dict]:
         """On-device counters+gauges (lanes engine; None for oracle)."""

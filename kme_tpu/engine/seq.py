@@ -30,14 +30,22 @@ are recombined only in scalar emulation helpers inside the kernel):
 - book planes (2*S*NR, 128), row = lane*2*NR + side*NR + r, side 0 =
   buy, N = NR*128 slots/side: oid lo/hi, aid, price, size, seq.
   A slot is occupied iff size > 0 (no used flag).
-- positions: an open-addressing HASH TABLE of (CAP,) entries in
-  (CAP/128, 128) planes [key, amt lo/hi, avail lo/hi]; key =
-  lane*A + acc + 1 (0 = empty). Entries are NEVER deleted — a live
-  position has amt != 0 (the delete-at-zero invariant the lanes engine
-  already uses), so lookups need no tombstones; probing is
-  tile-granular linear (scan 128-wide rows from the home tile until
-  key or an empty slot appears). The dense (S, A) alternative is 33MB
-  — VMEM is ~16MB/core, the hash is ~2.6MB at CAP=2^17.
+- positions (fixed mode): a DENSE direct-indexed store, one entry per
+  (lane, account) pair — what the deployment can hold, so no stream
+  of any length exhausts it (the canonical snapshot has always been
+  dense in S*A). ONE plane `pos` of 8-row tiles: tile lane*PTL +
+  (acc >> 8) holds 256 accounts of one lane, rows [amt lo, amt hi,
+  avail lo, avail hi] for accounts 0..127 of the tile and the same
+  four for 128..255, so a lane's positions are PTL = ceil(A/256)
+  consecutive tiles (a payout scans those, not the store) and one
+  4 KB DMA moves whole entries. An absent position is all zeros (the
+  delete-at-zero invariant the lanes engine already uses). The plane
+  lives in HBM at every size (1024 x 2048 is 33 MB) and the kernel
+  keeps ONE tile in a VMEM scratch, written back when dirty and
+  replaced on a miss — the `hbm_books` idiom; a miss costs well under
+  a microsecond on the v5e (PERF.md, PR 29), so a small store has no
+  VMEM-resident home of its own. Java mode keeps its own value-keyed
+  hash (Q11).
 - balances (A/128, 128) lo/hi/used planes.
 - per-lane seq counters and book-exists flags as (ceil(S/128), 128)
   planes.
@@ -82,6 +90,9 @@ from kme_tpu.engine.lanes import (  # noqa: F401 (re-exported act codes)
 # is one masked where (no lane rotate). Lanes 2..13 hold the 12 metric
 # deltas, so the window starts right after them.
 HIST_LANE0 = 2 + N_METRICS
+# the lane after the histograms: position tiles the call brought in
+# from HBM (fixed mode; java has no such store and leaves it 0)
+POS_TILES_LANE = HIST_LANE0 + N_HIST * N_HIST_BUCKETS
 
 # barrier acts (device-executed, unlike the lanes engine where barriers
 # are separate settle calls): mode mapping matches barrier_ops.settle
@@ -89,7 +100,7 @@ L_PAYOUT_YES = 7
 L_PAYOUT_NO = 8
 L_REMOVE_SYMBOL = 9
 
-LERR_HASH_FULL = 4   # position hash exhausted (pos_cap knob)
+LERR_HASH_FULL = 4   # java mode's position hash exhausted (pos_cap)
 LERR_JAVA_DOMAIN = 5   # java mode: price/size outside the device domain
 LERR_JAVA_CAP = 6      # java mode: slots/max_fills device bound exceeded
                        # (the reference's stores are unbounded; hitting
@@ -103,7 +114,11 @@ LN = 128
 
 _STATE_KEYS = ("bo_lo", "bo_hi", "ba", "bp", "bs", "bq",
                "seqc", "bex", "bal_lo", "bal_hi", "bal_u",
-               "hk", "ha_lo", "ha_hi", "hv_lo", "hv_hi", "dep", "err")
+               "pos", "dep", "err")
+
+# one tile of the position plane: 8 rows = 256 accounts x 4 values
+POS_TILE_ROWS = 8
+POS_TILE_ACCOUNTS = 256
 
 # java mode: Q11 positions are keyed by 128-bit pairs — real keys
 # (aid, sid), garbage keys (amount, available) — with true deletion
@@ -134,9 +149,13 @@ class SeqConfig:
     accounts: int = 2048       # A dense account capacity (mult of 128)
     max_fills: int = 16        # E makers swept per taker (H3 envelope)
     batch: int = 4096          # B messages per kernel call (mult of 128)
-    pos_cap: int = 1 << 17     # position hash capacity (pow2 mult of 128)
+    # java mode only (its keys are VALUES, Q11, so accounts x symbols
+    # does not bound them): hash capacity (pow2 mult of 128) and the
+    # most tiles probed before HASH_FULL. Fixed mode reads neither: its
+    # position store is sized by lanes x accounts (pos_capacity)
+    pos_cap: int = 1 << 17
     fill_cap: int = 1 << 15    # fill entries per call (mult of 128)
-    probe_max: int = 64        # max hash tiles probed before HASH_FULL
+    probe_max: int = 64
     # compat='java' replicates the reference quirk-for-quirk ON DEVICE
     # (Q1 merged sid-0 book, Q2 ghost trades, Q9, Q11 value-as-key
     # positions with a 128-bit-key tombstoned hash) for the stock wire
@@ -186,6 +205,24 @@ class SeqConfig:
     def caprows(self):
         return self.pos_cap // LN
 
+    @property
+    def pos_capacity(self):
+        """Positions the store can hold. Fixed mode: every (lane,
+        account) pair the configuration has, so it cannot fill; java
+        mode: the hash's pos_cap."""
+        if self.compat == "java":
+            return self.pos_cap
+        return self.lanes * self.accounts
+
+    @property
+    def pos_tiles_per_lane(self):
+        return -(-self.accounts // POS_TILE_ACCOUNTS)
+
+    @property
+    def pos_rows(self):
+        """Rows of the fixed-mode `pos` plane."""
+        return self.lanes * self.pos_tiles_per_lane * POS_TILE_ROWS
+
 
 def make_seq_state(cfg: SeqConfig):
     S, NR = cfg.lanes, cfg.nr
@@ -209,9 +246,7 @@ def make_seq_state(cfg: SeqConfig):
         })
     else:
         common.update({
-            "hk": z(cfg.caprows),
-            "ha_lo": z(cfg.caprows), "ha_hi": z(cfg.caprows),
-            "hv_lo": z(cfg.caprows), "hv_hi": z(cfg.caprows),
+            "pos": z(cfg.pos_rows),
             # per-lane occupied-slot count (both sides), maintained
             # incrementally for the book-depth histogram: a both-plane
             # reduction per message would dwarf the message cost
@@ -331,7 +366,7 @@ def build_seq_step(cfg: SeqConfig):
     out_plane: (out_rows, 128) int32 — see unpack_out.
     """
     S, NR, E, B = cfg.lanes, cfg.nr, cfg.max_fills, cfg.batch
-    A, CAPR, FB = cfg.accounts, cfg.caprows, cfg.fill_cap
+    CAPR, FB = cfg.caprows, cfg.fill_cap
     BR, FR = B // LN, FB // LN
     NROWS = out_rows(cfg)
     PROBE = min(cfg.probe_max, CAPR)
@@ -339,6 +374,7 @@ def build_seq_step(cfg: SeqConfig):
 
     HBM = cfg.hbm_books
     JAVA = cfg.compat == "java"
+    PTL = cfg.pos_tiles_per_lane
     KEYS = state_keys(cfg)
     NSMEM = 12 if JAVA else 7
     BOOK_KEYS = ("bo_lo", "bo_hi", "ba", "bp", "bs", "bq")
@@ -348,7 +384,8 @@ def build_seq_step(cfg: SeqConfig):
         # + out plane, then scratch: an SMEM scalar row (cross-section
         # results — the heavy sections run under pl.when branches so
         # non-trade messages skip the trade machinery entirely), then
-        # (hbm_books) 6 VMEM scratch planes + a DMA semaphore array.
+        # (hbm_books) 6 VMEM scratch planes + a DMA semaphore array,
+        # then (fixed mode) one position tile + its DMA semaphore.
         (act_s, oidlo_s, oidhi_s, aid_s, price_s, size_s,
          lane_s) = args[:7]
         if JAVA:
@@ -360,9 +397,13 @@ def build_seq_step(cfg: SeqConfig):
         out = outs[nst]
         sm = refs[nst + nst + 1]
         vr = refs[nst + nst + 2]
+        extra = list(refs[nst + nst + 3:])
         if HBM:
-            scr = dict(zip(BOOK_KEYS, refs[nst + nst + 3:nst + nst + 9]))
-            dsem = refs[nst + nst + 9]
+            scr = dict(zip(BOOK_KEYS, extra[:6]))
+            dsem = extra[6]
+            del extra[:7]
+        if not JAVA:
+            pscr, psem = extra
 
         ci = jax.lax.broadcasted_iota(I32, (1, LN), 1)
         # flat slot index over an (NR, 128) side block
@@ -420,148 +461,60 @@ def build_seq_step(cfg: SeqConfig):
             put(st["bal_lo"], r, l, nlo)
             put(st["bal_hi"], r, l, nhi)
 
-        # -------- position hash ---------------------------------------
-        def h_home(key):
-            # Fibonacci hash, tile-granular
-            return ((key * _i(-1640531527)) >> _i(7)) & (CAPMASK >> _i(7))
+        # -------- positions (fixed mode): the dense store -------------
+        # sm[16] the tile the scratch holds (-1: none), sm[17] whether
+        # it was written, sm[18] tiles brought in from HBM this call
+        def pos_copy(tile, flush):
+            hbm = st["pos"].at[pl.ds(tile * _i(POS_TILE_ROWS),
+                                     POS_TILE_ROWS)]
+            src, dst = (pscr, hbm) if flush else (hbm, pscr)
+            cp = pltpu.make_async_copy(src, dst, psem.at[_i(0)])
+            cp.start()
+            cp.wait()
 
-        def h_find(key):
-            """-> (flat entry index or -1, err_flag). Scans tiles from
-            the home tile until the key or an empty slot appears. The
-            FIRST tile probes straight-line (the enforced <=50% load
-            factor makes one tile the overwhelmingly common case —
-            and merely entering a while_loop costs ~0.9us on this
-            Mosaic, scripts/exp_loopbody.py); the loop is entered only
-            when tile 0 is full with no hit."""
-            t0 = h_home(key)
-            krow = st["hk"][pl.ds(t0, 1), :]
-            hit = krow == key
-            hidx = jnp.min(jnp.where(hit, ci, BIG))
-            empty = jnp.min(jnp.where(krow == _i(0), ci, BIG))
-            found = hidx < BIG
-            stop0 = found | (empty < BIG) | (_i(1) >= _i(PROBE))
-            sm[14] = jnp.where(found, t0 * _i(LN) + hidx, _i(-1))
-            sm[15] = ((~found) & (_i(1) >= _i(PROBE))).astype(I32)
+        def pos_bring(tile):
+            """make the scratch hold `tile` of the HBM plane."""
+            cur = sm[16]
 
-            @pl.when(~stop0)
+            @pl.when(tile != cur)
             def _():
-                def body(c):
-                    t, probes, res, done = c
-                    kr = st["hk"][pl.ds(t, 1), :]
-                    ht = kr == key
-                    hx = jnp.min(jnp.where(ht, ci, BIG))
-                    em = jnp.min(jnp.where(kr == _i(0), ci, BIG))
-                    fnd = hx < BIG
-                    stop = (fnd | (em < BIG)
-                            | (probes + _i(1) >= _i(PROBE)))
-                    res = jnp.where(fnd, t * _i(LN) + hx, res)
-                    return ((t + _i(1)) & (CAPMASK >> _i(7)),
-                            probes + _i(1), res, stop)
+                @pl.when(sm[17] != _i(0))
+                def _():
+                    pos_copy(cur, True)
 
-                _, probes, res, _ = jax.lax.while_loop(
-                    lambda c: ~c[3], body,
-                    ((t0 + _i(1)) & (CAPMASK >> _i(7)), _i(1),
-                     _i(-1), False))
-                sm[14] = res
-                sm[15] = ((res < _i(0))
-                          & (probes >= _i(PROBE))).astype(I32)
+                pos_copy(tile, False)
+                sm[16] = tile
+                sm[17] = _i(0)
+                sm[18] = sm[18] + _i(1)
 
-            return sm[14], sm[15] != _i(0)
-
-        def h_claim(key):
-            """find-or-insert -> (flat index, err_flag). First tile
-            straight-line, loop only on a full missless tile 0 (see
-            h_find)."""
-            t0 = h_home(key)
-            krow = st["hk"][pl.ds(t0, 1), :]
-            hit = krow == key
-            hidx = jnp.min(jnp.where(hit, ci, BIG))
-            empty = jnp.min(jnp.where(krow == _i(0), ci, BIG))
-            found = hidx < BIG
-            can_ins = ~found & (empty < BIG)
-            res0 = jnp.where(found, t0 * _i(LN) + hidx, _i(-1))
-            res0 = jnp.where(can_ins, t0 * _i(LN) + empty, res0)
-            sm[14] = res0
-
-            @pl.when(can_ins)
-            def _():
-                put(st["hk"], t0, empty, key)
-
-            stop0 = found | can_ins | (_i(1) >= _i(PROBE))
-
-            @pl.when(~stop0)
-            def _():
-                def body(c):
-                    t, probes, res, done = c
-                    kr = st["hk"][pl.ds(t, 1), :]
-                    ht = kr == key
-                    hx = jnp.min(jnp.where(ht, ci, BIG))
-                    em = jnp.min(jnp.where(kr == _i(0), ci, BIG))
-                    fnd = hx < BIG
-                    ins = ~fnd & (em < BIG)
-                    res = jnp.where(fnd, t * _i(LN) + hx, res)
-                    res = jnp.where(ins, t * _i(LN) + em, res)
-
-                    @pl.when(ins)
-                    def _():
-                        put(st["hk"], t, em, key)
-
-                    stop = fnd | ins | (probes + _i(1) >= _i(PROBE))
-                    return ((t + _i(1)) & (CAPMASK >> _i(7)),
-                            probes + _i(1), res, stop)
-
-                _, probes, res, _ = jax.lax.while_loop(
-                    lambda c: ~c[3], body,
-                    ((t0 + _i(1)) & (CAPMASK >> _i(7)), _i(1),
-                     _i(-1), False))
-                sm[14] = res
-
-            resv = sm[14]
-            return resv, resv < _i(0)
-
-        def pos_key(lane, acc):
-            return lane * _i(A) + acc + _i(1)
+        def pos_at(lane, acc):
+            """bring in the pair's tile -> (row of amt lo, lane) of its
+            entry in the scratch."""
+            pos_bring(lane * _i(PTL) + (acc >> _i(8)))
+            return ((acc >> _i(7)) & _i(1)) * _i(4), acc & _i(127)
 
         def pos_get(lane, acc):
             """-> (amt lo, hi, avail lo, hi); zeros when absent."""
-            e, _err = h_find(pos_key(lane, acc))
-            r, l = e >> _i(7), e & _i(127)
-            there = e >= _i(0)
-            rr = jnp.where(there, r, _i(0))
-            z = _i(0)
-            alo = jnp.where(there, rget(st["ha_lo"], rr, l), z)
-            ahi = jnp.where(there, rget(st["ha_hi"], rr, l), z)
-            vlo = jnp.where(there, rget(st["hv_lo"], rr, l), z)
-            vhi = jnp.where(there, rget(st["hv_hi"], rr, l), z)
-            return alo, ahi, vlo, vhi
+            r, l = pos_at(lane, acc)
+            return tuple(rget(pscr, r + _i(k_), l) for k_ in range(4))
 
         def pos_set(lane, acc, alo, ahi, vlo, vhi):
-            """write a position (claiming a slot if new) -> err_flag."""
-            e, err = h_claim(pos_key(lane, acc))
-            r, l = jnp.where(e >= _i(0), e >> _i(7), _i(0)), e & _i(127)
-
-            @pl.when(e >= _i(0))
-            def _():
-                put(st["ha_lo"], r, l, alo)
-                put(st["ha_hi"], r, l, ahi)
-                put(st["hv_lo"], r, l, vlo)
-                put(st["hv_hi"], r, l, vhi)
-
-            return err
+            r, l = pos_at(lane, acc)
+            for k_, v in enumerate((alo, ahi, vlo, vhi)):
+                put(pscr, r + _i(k_), l, v)
+            sm[17] = _i(1)
 
         def fill_one(lane, acc, sgn_fill):
             """fillOrder's position half (KProcessor.java:276-287),
             fixed mode: create == update-from-(0,0); delete-at-zero
-            writes (0,0). sgn_fill: signed i32 size. -> err_flag."""
+            writes (0,0). sgn_fill: signed i32 size."""
             alo, ahi, vlo, vhi = pos_get(lane, acc)
             nalo, nahi = _add64(alo, ahi, *_sx(sgn_fill))
             nvlo, nvhi = _add64(vlo, vhi, *_sx(sgn_fill))
             dead = (nalo == _i(0)) & (nahi == _i(0))
             z = _i(0)
-            return pos_set(lane, acc,
-                           nalo, nahi,
-                           jnp.where(dead, z, nvlo),
-                           jnp.where(dead, z, nvhi))
+            pos_set(lane, acc, nalo, nahi,
+                    jnp.where(dead, z, nvlo), jnp.where(dead, z, nvhi))
 
         # -------- java (Q11) position hash: 128-bit keys, tombstones --
         if JAVA:
@@ -821,19 +774,10 @@ def build_seq_step(cfg: SeqConfig):
             rel_lo, rel_hi = _muls64(signed + adjlo, unit)
             adj_nz = (adjlo != _i(0)) | (adjhi != _i(0))
 
-            err = _i(0)
-
             @pl.when(adj_nz)
             def _():
                 nvlo, nvhi = _add64(vlo, vhi, adjlo, adjhi)
-                e = pos_set(lane, acc, alo, ahi, nvlo, nvhi)
-                # adj_nz requires an existing position (amt != 0 or
-                # avail != 0 implies the entry exists), so pos_set can
-                # only fail if the hash itself is broken — fold into
-                # the sticky error anyway via the out-of-band plane
-                @pl.when(e)
-                def _():
-                    set_err(_i(LERR_HASH_FULL))
+                pos_set(lane, acc, alo, ahi, nvlo, nvhi)
 
             return rel_lo, rel_hi
 
@@ -1155,11 +1099,7 @@ def build_seq_step(cfg: SeqConfig):
                             jwrite(e_actor, a_rlo, a_rhi, s_rlo, s_rhi,
                                    palo, pahi, nvlo, nvhi)
                         else:
-                            e = pos_set(lane, acc, palo, pahi, nvlo, nvhi)
-
-                            @pl.when(e)
-                            def _():
-                                set_err(_i(LERR_HASH_FULL))
+                            pos_set(lane, acc, palo, pahi, nvlo, nvhi)
 
                     # maker size writeback (size==0 deletes the slot)
                     side_put("bs", lane, opp, wsize)
@@ -1197,16 +1137,16 @@ def build_seq_step(cfg: SeqConfig):
                             m_rhi = rget(st["araw_hi"], mr, ml)
                             me = jfill_one(m_rlo, m_rhi, s_rlo, s_rhi, msz)
                             te = jfill_one(a_rlo, a_rhi, s_rlo, s_rhi, tsz)
+
+                            @pl.when(me | te)
+                            def _():
+                                set_err(_i(LERR_HASH_FULL))
                         else:
-                            me = fill_one(lane, maid, msz)
-                            te = fill_one(lane, acc, tsz)
+                            fill_one(lane, maid, msz)
+                            fill_one(lane, acc, tsz)
                         # taker credit: int*int wraps at i32 before the
                         # long add (KProcessor.java:286); maker credit is 0
                         bal_add(acc, *_sx(tsz * (limit - mprice)))
-
-                        @pl.when(me | te)
-                        def _():
-                            set_err(_i(LERR_HASH_FULL))
 
                         return _c
 
@@ -1374,64 +1314,57 @@ def build_seq_step(cfg: SeqConfig):
                 put(st["bex"], lr, ll, _i(0))
 
                 # payout: credit (YES) / just delete (NO) the lane's
-                # positions — hash scan; entries keep their keys, a
-                # zeroed amt/avail IS deletion (the absence invariant)
+                # positions — its PTL consecutive tiles of the store;
+                # zeros ARE deletion (the absence invariant)
                 is_payout = act != _i(L_REMOVE_SYMBOL)
                 do_credit = act == _i(L_PAYOUT_YES)
 
                 @pl.when(is_payout)
                 def _():
-                    klo = lane * _i(A) + _i(1)
+                    def scan_tile(b, _c):
+                        pos_bring(lane * _i(PTL) + b)
 
-                    def scan_row(tr, _c):
-                        krow = st["hk"][pl.ds(tr, 1), :]
-                        mine = (krow >= klo) & (krow < klo + _i(A))
-                        arow_lo = st["ha_lo"][pl.ds(tr, 1), :]
-                        arow_hi = st["ha_hi"][pl.ds(tr, 1), :]
-                        live = mine & ((arow_lo != _i(0))
-                                       | (arow_hi != _i(0)))
+                        def credit_half(half):
+                            arow_lo = pscr[4 * half:4 * half + 1, :]
+                            arow_hi = pscr[4 * half + 1:4 * half + 2, :]
+                            live = (arow_lo != _i(0)) | (arow_hi != _i(0))
+                            acc0 = (b * _i(POS_TILE_ACCOUNTS)
+                                    + _i(LN * half))
 
-                        @pl.when(do_credit
-                                 & (jnp.max(jnp.where(live, _i(1), _i(0)))
-                                    == _i(1)))
+                            @pl.when(jnp.max(jnp.where(live, _i(1), _i(0)))
+                                     == _i(1))
+                            def _():
+                                def credit_one(c):
+                                    rem, done = c
+                                    l2 = jnp.min(jnp.where(
+                                        rem > _i(0), ci, BIG))
+                                    anyl = l2 < BIG
+                                    lc = jnp.where(anyl, l2, _i(0))
+
+                                    @pl.when(anyl)
+                                    def _():
+                                        plo, phi = _mul64(
+                                            pick(arow_lo, lc),
+                                            pick(arow_hi, lc), *_sx(size))
+                                        bal_add(acc0 + lc, plo, phi)
+
+                                    rem = jnp.where(ci == lc, _i(0), rem)
+                                    return rem, ~anyl
+
+                                jax.lax.while_loop(
+                                    lambda c: ~c[1], credit_one,
+                                    (jnp.where(live, _i(1), _i(0)), False))
+
+                        @pl.when(do_credit)
                         def _():
-                            def credit_one(c):
-                                rem, done = c
-                                l2 = jnp.min(jnp.where(
-                                    rem > _i(0), ci, BIG))
-                                anyl = l2 < BIG
-                                lc = jnp.where(anyl, l2, _i(0))
+                            credit_half(0)
+                            credit_half(1)
 
-                                @pl.when(anyl)
-                                def _():
-                                    a2lo = pick(arow_lo, lc)
-                                    a2hi = pick(arow_hi, lc)
-                                    acc2 = pick(krow, lc) - klo
-                                    plo, phi = _mul64(a2lo, a2hi,
-                                                      *_sx(size))
-                                    bal_add(acc2, plo, phi)
-
-                                rem = jnp.where(ci == lc, _i(0), rem)
-                                return rem, ~anyl
-
-                            jax.lax.while_loop(
-                                lambda c: ~c[1], credit_one,
-                                (jnp.where(live, _i(1), _i(0)), False))
-
-                        # delete: zero amt + avail where mine
-                        st["ha_lo"][pl.ds(tr, 1), :] = jnp.where(
-                            mine, _i(0), arow_lo)
-                        st["ha_hi"][pl.ds(tr, 1), :] = jnp.where(
-                            mine, _i(0), arow_hi)
-                        vr_lo = st["hv_lo"][pl.ds(tr, 1), :]
-                        vr_hi = st["hv_hi"][pl.ds(tr, 1), :]
-                        st["hv_lo"][pl.ds(tr, 1), :] = jnp.where(
-                            mine, _i(0), vr_lo)
-                        st["hv_hi"][pl.ds(tr, 1), :] = jnp.where(
-                            mine, _i(0), vr_hi)
+                        pscr[...] = jnp.zeros((POS_TILE_ROWS, LN), I32)
+                        sm[17] = _i(1)
                         return _c
 
-                    _fori32(CAPR, scan_row, _i(0))
+                    _fori32(PTL, scan_tile, _i(0))
 
             # ---------------- outputs + metrics -----------------------
             t_ok = sm[0] != _i(0)
@@ -1504,6 +1437,9 @@ def build_seq_step(cfg: SeqConfig):
         # per-call histogram deltas accumulate in the scratch row,
         # pre-offset to their final scalar-row lanes
         vr[NR + 2:NR + 3, :] = jnp.zeros((1, LN), I32)
+        sm[16] = _i(-1)
+        sm[17] = _i(0)
+        sm[18] = _i(0)
         met0 = tuple(_i(0) for _ in range(N_METRICS))
         fill_total, cur_lane, met = _fori32(
             B, one, (_i(0), _i(-1), met0))
@@ -1511,6 +1447,10 @@ def build_seq_step(cfg: SeqConfig):
             @pl.when(cur_lane >= _i(0))
             def _():
                 books_flush(cur_lane)
+        if not JAVA:
+            @pl.when(sm[17] != _i(0))
+            def _():
+                pos_copy(sm[16], True)
 
         # batch occupancy: ONE observation per non-empty kernel call
         # (met[0] = this call's non-NOP message count)
@@ -1518,7 +1458,8 @@ def build_seq_step(cfg: SeqConfig):
 
         # scalar row: lane0 err, lane1 fill_total, lanes 2.. metrics,
         # lanes HIST_LANE0.. the histogram deltas (already in place in
-        # the scratch row)
+        # the scratch row), lane POS_TILES_LANE the position tiles
+        # this call brought in from HBM
         errv = pick(st["err"][0:1, :], _i(0))
         scal = jnp.where(ci == _i(0), errv, _i(0))
         scal = jnp.where(ci == _i(1), fill_total, scal)
@@ -1528,6 +1469,7 @@ def build_seq_step(cfg: SeqConfig):
         scal = jnp.where(
             (ci >= _i(HIST_LANE0))
             & (ci < _i(HIST_LANE0 + N_HIST * N_HIST_BUCKETS)), hr, scal)
+        scal = jnp.where(ci == _i(POS_TILES_LANE), sm[18], scal)
         out[0:1, :] = scal
 
     nstate = len(KEYS)
@@ -1536,14 +1478,16 @@ def build_seq_step(cfg: SeqConfig):
                               "sidr_hi", "flags") if JAVA else ())
 
     def _spec(key):
-        if cfg.hbm_books and key in BOOK_KEYS:
+        if (HBM and key in BOOK_KEYS) or key == "pos":
             return pl.BlockSpec(memory_space=pl.ANY)
         return pl.BlockSpec(memory_space=pltpu.VMEM)
 
-    scratches = [pltpu.SMEM((16,), I32),
+    scratches = [pltpu.SMEM((24,), I32),
                  pltpu.VMEM((NR + 3, LN), I32)] \
         + ([pltpu.VMEM((2 * NR, LN), I32)] * 6
-           + [pltpu.SemaphoreType.DMA((6,))] if cfg.hbm_books else [])
+           + [pltpu.SemaphoreType.DMA((6,))] if HBM else []) \
+        + ([pltpu.VMEM((POS_TILE_ROWS, LN), I32),
+            pltpu.SemaphoreType.DMA((1,))] if not JAVA else [])
 
     def raw_call(state, msgs):
         outs = pl.pallas_call(
@@ -1684,6 +1628,7 @@ def unpack_hdr(cfg: SeqConfig, hdr: np.ndarray, n: int) -> dict:
         "err": int(scal[0]),
         "fill_total": int(scal[1]),
         "metrics": scal[2:2 + N_METRICS].astype(np.int64),
+        "pos_tiles": int(scal[POS_TILES_LANE]),
         "hist": scal[HIST_LANE0:HIST_LANE0 + N_HIST * N_HIST_BUCKETS]
         .astype(np.int64).reshape(N_HIST, N_HIST_BUCKETS),
     }
@@ -1760,6 +1705,39 @@ def export_java(cfg: SeqConfig, state) -> dict:
 # ---------------------------------------------------------------------------
 # canonical (lanes-style) state import/export for checkpoint parity
 
+def _pos_views(cfg: SeqConfig, pos, both):
+    """The same words seen from both sides: `pos` (pos_rows, 128) i32 as
+    [lane, tile, half, value, column], and `both` (2, S, PTL*256) i64
+    [amount | available, lane, account] as its i32 words [.., lo | hi]
+    (little-endian). Value plane k of `pos` (amt lo, amt hi, avail lo,
+    avail hi) IS word k & 1 of value k >> 1 — so each way is four
+    strided copies and no pass widens, shifts or ors (at 1024 x 4096 a
+    snapshot moves 64 MiB, and that arithmetic is five passes over
+    twice as much)."""
+    S, PTL = cfg.lanes, cfg.pos_tiles_per_lane
+    rows = pos.reshape(S, PTL, 2, 4, LN)
+    words = both.view(np.int32).reshape(2, S, PTL, 2, LN, 2)
+    return [(rows[:, :, :, k, :], words[k >> 1, ..., k & 1])
+            for k in range(4)]
+
+
+def pos_to_values(cfg: SeqConfig, pos: np.ndarray) -> np.ndarray:
+    """`pos` plane -> (2, S, PTL*256) i64 [amount, available]."""
+    both = np.empty((2, cfg.lanes,
+                     cfg.pos_tiles_per_lane * POS_TILE_ACCOUNTS), np.int64)
+    for plane, word in _pos_views(cfg, pos, both):
+        word[...] = plane
+    return both
+
+
+def values_to_pos(cfg: SeqConfig, both: np.ndarray) -> np.ndarray:
+    """Inverse of pos_to_values."""
+    pos = np.empty((cfg.pos_rows, LN), np.int32)
+    for plane, word in _pos_views(cfg, pos, both):
+        plane[...] = word
+    return pos
+
+
 def export_canonical(cfg: SeqConfig, state) -> dict:
     """Device planes -> the canonical snapshot layout the lanes engine
     checkpoints use (slot_* (S,2,N) i64/i32/bool, flat positions s64,
@@ -1783,17 +1761,8 @@ def export_canonical(cfg: SeqConfig, state) -> dict:
 
     slot_size = planes2slot(h["bs"]).astype(np.int32)
     used = slot_size > 0
-    pos_amt = np.zeros(S * A, np.int64)
-    pos_avail = np.zeros(S * A, np.int64)
-    hk = h["hk"].reshape(-1)
-    live = hk != 0
-    keys = hk[live] - 1
-    amt = ((h["ha_lo"].reshape(-1)[live].astype(np.int64) & 0xFFFFFFFF)
-           | (h["ha_hi"].reshape(-1)[live].astype(np.int64) << 32))
-    avail = ((h["hv_lo"].reshape(-1)[live].astype(np.int64) & 0xFFFFFFFF)
-             | (h["hv_hi"].reshape(-1)[live].astype(np.int64) << 32))
-    pos_amt[keys] = amt
-    pos_avail[keys] = avail
+    pos_amt, pos_avail = (v[:, :A].reshape(-1)
+                          for v in pos_to_values(cfg, h["pos"]))
     seqc = h["seqc"].reshape(-1)[:S].astype(np.int32)
     bal = ((h["bal_lo"].reshape(-1)[:A].astype(np.int64) & 0xFFFFFFFF)
            | (h["bal_hi"].reshape(-1)[:A].astype(np.int64) << 32))
@@ -1827,8 +1796,8 @@ def build_seq_occupancy(cfg: SeqConfig):
     refresh fetches 20 bytes and not every plane. Each count is taken
     on the plane and by the rule the whole-state exports above use
     (tests/test_spans.py holds them equal): a slot is used where
-    `bs > 0`; a position counts where export_canonical's scatter leaves
-    a non-zero amount (fixed) or export_java keeps the entry (java)."""
+    `bs > 0`; a position counts where export_canonical gives a
+    non-zero amount (fixed) or export_java keeps the entry (java)."""
     S, A, NR = cfg.lanes, cfg.accounts, cfg.nr
 
     def count(mask):
@@ -1844,8 +1813,10 @@ def build_seq_occupancy(cfg: SeqConfig):
         if cfg.compat == "java":
             positions = count(state["hstate"] == 1)
         else:
-            positions = count((state["hk"] != 0)
-                              & ((state["ha_lo"] | state["ha_hi"]) != 0))
+            # whole tiles (a free reshape): amt lo | hi of each half
+            t = state["pos"].reshape(-1, POS_TILE_ROWS, LN)
+            positions = (count((t[:, 0] | t[:, 1]) != 0)
+                         + count((t[:, 4] | t[:, 5]) != 0))
         return jnp.stack([
             jnp.sum(depth, dtype=I32),
             count(state["bex"].reshape(-1)[:S] != 0),
@@ -1887,8 +1858,8 @@ def import_canonical(cfg: SeqConfig, canon: dict):
     """Inverse of export_canonical (numpy -> device plane dict). The
     snapshot's slot depth and account capacity may be SMALLER than the
     config's (elastic restore into deeper books / wider account space —
-    position hash keys are recomputed with the new stride); shrinking
-    either is a state migration, not a restore, and raises."""
+    positions are laid out again at the new stride); shrinking either
+    is a state migration, not a restore, and raises."""
     S, N, A, NR = cfg.lanes, cfg.slots, cfg.accounts, cfg.nr
     S0 = np.asarray(canon["slot_oid"]).shape[0]
     if S0 != S:
@@ -1925,62 +1896,12 @@ def import_canonical(cfg: SeqConfig, canon: dict):
         a[:len(v)] = v
         return a.reshape(rows, LN)
 
-    pos_amt = np.asarray(canon["pos_amt"]).reshape(S, A0)
-    pos_avail = np.asarray(canon["pos_avail"]).reshape(S, A0)
-    live2 = np.nonzero(pos_amt != 0)
-    # re-key (lane, acc) with the CONFIG's stride (A may exceed A0)
-    live = live2[0].astype(np.int64) * A + live2[1].astype(np.int64)
-    pos_amt = {int(k): int(pos_amt[l, a])
-               for k, l, a in zip(live, live2[0], live2[1])}
-    pos_avail = {int(k): int(pos_avail[l, a])
-                 for k, l, a in zip(live, live2[0], live2[1])}
-    if len(live) > cfg.pos_cap // 2:
-        raise ValueError(
-            f"{len(live)} live positions exceed half the hash capacity "
-            f"{cfg.pos_cap} — raise pos_cap")
-    hk = np.zeros(cfg.pos_cap, np.int32)
-    halo = np.zeros(cfg.pos_cap, np.int32)
-    hahi = np.zeros(cfg.pos_cap, np.int32)
-    hvlo = np.zeros(cfg.pos_cap, np.int32)
-    hvhi = np.zeros(cfg.pos_cap, np.int32)
-    capr = cfg.caprows
-    tilemask = capr - 1
-    # the kernel's h_find/h_claim stop after min(probe_max, capr) tiles;
-    # an entry the import places beyond that bound would be silently
-    # INVISIBLE to the device (pos_get returns zeros), so the host probe
-    # is bounded identically and overflow is a loud error
-    probe_lim = min(cfg.probe_max, capr)
-    for k in live:
-        key = int(k) + 1
-        # home tile = the kernel's Fibonacci hash (h_home) in int32 wrap
-        # arithmetic: ((key * -1640531527) >> 7) & tilemask
-        h = (key * -1640531527) & 0xFFFFFFFF
-        if h >= 1 << 31:
-            h -= 1 << 32
-        t = (h >> 7) & tilemask
-        placed = False
-        for p in range(probe_lim):
-            base = ((t + p) & tilemask) * LN
-            row = hk[base:base + LN]
-            empt = np.nonzero(row == 0)[0]
-            if len(empt):
-                j = base + empt[0]
-                def _lo(v):
-                    lo = int(v) & 0xFFFFFFFF
-                    return np.int32(lo - (1 << 32) if lo >= (1 << 31)
-                                    else lo)
-
-                hk[j] = np.int32(key)
-                halo[j] = _lo(pos_amt[int(k)])
-                hahi[j] = np.int32(int(pos_amt[int(k)]) >> 32)
-                hvlo[j] = _lo(pos_avail[int(k)])
-                hvhi[j] = np.int32(int(pos_avail[int(k)]) >> 32)
-                placed = True
-                break
-        if not placed:
-            raise ValueError(
-                "position hash import overflow: entry unreachable within "
-                "probe_max tiles — raise pos_cap or probe_max")
+    # at the CONFIG's account stride (A may exceed A0)
+    both = np.zeros((2, S, cfg.pos_tiles_per_lane * POS_TILE_ACCOUNTS),
+                    np.int64)
+    both[0, :, :A0] = np.asarray(canon["pos_amt"]).reshape(S, A0)
+    both[1, :, :A0] = np.asarray(canon["pos_avail"]).reshape(S, A0)
+    pos = values_to_pos(cfg, both)
 
     bal = np.asarray(canon["bal"]).reshape(-1)
     return {
@@ -1999,11 +1920,7 @@ def import_canonical(cfg: SeqConfig, canon: dict):
                                        cfg.arows)),
         "bal_u": jnp.asarray(padplane(
             np.asarray(canon["bal_used"]).astype(np.int32), cfg.arows)),
-        "hk": jnp.asarray(hk.reshape(capr, LN)),
-        "ha_lo": jnp.asarray(halo.reshape(capr, LN)),
-        "ha_hi": jnp.asarray(hahi.reshape(capr, LN)),
-        "hv_lo": jnp.asarray(hvlo.reshape(capr, LN)),
-        "hv_hi": jnp.asarray(hvhi.reshape(capr, LN)),
+        "pos": jnp.asarray(pos),
         # dep is derived state (occupied slots per lane, both sides) —
         # recomputed here so canonical snapshots stay engine-agnostic
         "dep": jnp.asarray(padplane(

@@ -326,8 +326,7 @@ class SeqMeshSession(SeqSession):
         self.local_cfg = SQ.SeqConfig(
             lanes=cfg.lanes // shards, slots=cfg.slots,
             accounts=cfg.accounts, max_fills=cfg.max_fills,
-            batch=WINDOW_CAP, pos_cap=cfg.pos_cap,
-            fill_cap=cfg.fill_cap, probe_max=cfg.probe_max)
+            batch=WINDOW_CAP, fill_cap=cfg.fill_cap)
         self.S_local = cfg.lanes // shards
         self.state = make_mesh_state(self.local_cfg, shards)
         self.router = make_seq_router(cfg.lanes, cfg.accounts)
@@ -339,6 +338,9 @@ class SeqMeshSession(SeqSession):
         self.phases = self.timer.totals   # cumulative across batches
         self._use_native_wire = True
         self._ghint = 8
+        # position tiles the shards' kernels brought in from HBM (each
+        # call's own count, added at collect as in SeqSession)
+        self.pos_probe_tiles = 0
         # elastic placement: global lane -> global slot; shard of a
         # lane is perm[lane] // S_local, its kernel row perm[lane] %
         # S_local. Identity == the pre-elastic static layout.
@@ -588,6 +590,7 @@ class SeqMeshSession(SeqSession):
                     mets += res["metrics"]
                     hists += res["hist"]
                     self._hist_shard[s] += res["hist"]
+                    self.pos_probe_tiles += res["pos_tiles"]
             self._metrics += mets
             self._hist += hists
             self._publish_shard_telemetry(
@@ -948,6 +951,7 @@ class SeqMeshSession(SeqSession):
                 mets += res["metrics"]
                 hists += res["hist"]
                 self._hist_shard[s] += res["hist"]
+                self.pos_probe_tiles += res["pos_tiles"]
         self._metrics += mets
         self._hist += hists
         fills_parts = []

@@ -34,7 +34,7 @@ from kme_tpu.wire import (OrderMsg, OutRecord, WireBatch, order_json,
 # register the seq-specific sticky-error name so LaneEngineError renders
 # it (the code space is shared with the lanes engine's LERR_*)
 _session._LERR_NAMES[SQ.LERR_HASH_FULL] = \
-    "position hash exhausted (pos_cap knob)"
+    "java-mode position hash exhausted (SeqConfig.pos_cap)"
 _session._LERR_NAMES[SQ.LERR_JAVA_DOMAIN] = \
     "java mode: price/size outside the device domain (the reference " \
     "runs unvalidated fields; this stream needs the native engine)"
@@ -471,6 +471,11 @@ class SeqSession:
         # count over each plan, count_lane_switches); the serve loop
         # publishes it as counter `lane_switches`
         self.lane_switches = 0
+        # tiles of the position store the kernel brought in from HBM
+        # (its own count, in each call's scalar row; added as a batch is
+        # fetched; java mode has no such store: 0); the serve loop
+        # publishes it as counter `pos_probe_tiles`
+        self.pos_probe_tiles = 0
         # metrics()' narrow read, compiled here and not at the first
         # refresh: a compile inside a served batch is a stall
         self._occupancy = SQ.build_seq_occupancy(cfg)
@@ -574,6 +579,7 @@ class SeqSession:
             results.append(res)
             mets += res["metrics"]
             hists += res["hist"]
+            self.pos_probe_tiles += res["pos_tiles"]
         gneed = [-(-max(r["fill_total"], 1) // 128) for r in results]
         self._ghint = max(self._ghint, *gneed)
         over = [ci for ci in range(K) if gneed[ci] > ghint]

@@ -175,39 +175,55 @@ def test_seq_canonical_roundtrip_and_resume():
     assert got_head + got_tail == want
 
 
-def test_seq_hash_full_error():
-    cfg = SQ.SeqConfig(lanes=8, slots=128, accounts=128, max_fills=8,
-                       batch=128, pos_cap=128, fill_cap=1 << 12,
-                       probe_max=1)
-    msgs = [OrderMsg(action=op.CREATE_BALANCE, aid=0),
-            OrderMsg(action=op.TRANSFER, aid=0, size=10**9)]
+def _positions_stream():
+    """A preamble and eight batches that open >128 distinct (lane,
+    account) positions: one batch a symbol, 32 crossing pairs each."""
+    pre = [OrderMsg(action=op.CREATE_BALANCE, aid=0),
+           OrderMsg(action=op.TRANSFER, aid=0, size=10**9)]
     for a in range(1, 100):
-        msgs.append(OrderMsg(action=op.CREATE_BALANCE, aid=a))
-        msgs.append(OrderMsg(action=op.TRANSFER, aid=a, size=10**9))
+        pre.append(OrderMsg(action=op.CREATE_BALANCE, aid=a))
+        pre.append(OrderMsg(action=op.TRANSFER, aid=a, size=10**9))
     for s in range(8):
-        msgs.append(OrderMsg(action=op.ADD_SYMBOL, sid=s))
+        pre.append(OrderMsg(action=op.ADD_SYMBOL, sid=s))
     oid = 1000
-    # >128 distinct (lane, account) positions at probe_max=1 must trip
-    # the sticky HASH_FULL error eventually
+    batches = []
+    for s in range(8):
+        batch = []
+        for a in range(32):
+            batch.append(OrderMsg(action=op.SELL, oid=oid, aid=a % 99,
+                                  sid=s, price=50, size=1))
+            oid += 1
+            batch.append(OrderMsg(action=op.BUY, oid=oid,
+                                  aid=(a + 1) % 99, sid=s, price=55,
+                                  size=1))
+            oid += 1
+        batches.append(pre + batch if s == 0 else batch)
+    return batches
+
+
+def test_seq_hash_full_error():
+    """LERR_HASH_FULL is java mode's guard (its keys are values, so no
+    configuration bounds them): >128 positions at pos_cap=128,
+    probe_max=1 trip the sticky error. The fixed store is sized by
+    lanes x accounts and takes the same stream whole, whatever
+    pos_cap says."""
     from kme_tpu.runtime.session import LaneEngineError
-    ses = SeqSession(cfg)
-    try:
-        for s in range(8):
-            batch = []
-            for a in range(32):
-                batch.append(OrderMsg(action=op.SELL, oid=oid, aid=a % 99,
-                                      sid=s, price=50, size=1))
-                oid += 1
-                batch.append(OrderMsg(action=op.BUY, oid=oid,
-                                      aid=(a + 1) % 99, sid=s, price=55,
-                                      size=1))
-                oid += 1
-            ses.process_wire(msgs + batch if s == 0 else batch)
-        raised = False
-    except LaneEngineError as e:
-        raised = True
-        assert e.code == SQ.LERR_HASH_FULL
-    assert raised
+    kw = dict(lanes=8, slots=128, accounts=128, max_fills=8, batch=128,
+              pos_cap=128, fill_cap=1 << 12, probe_max=1)
+    ses = SeqSession(SQ.SeqConfig(compat="java", **kw))
+    with pytest.raises(LaneEngineError) as e:
+        for batch in _positions_stream():
+            ses.process_wire(batch)
+    assert e.value.code == SQ.LERR_HASH_FULL
+    ses = SeqSession(SQ.SeqConfig(**kw))
+    orc = OracleEngine("fixed", book_slots=128, max_fills=8)
+    for batch in _positions_stream():
+        got = ses.process_wire(batch)
+        assert got == [[r.wire() for r in orc.process(m.copy())]
+                       for m in batch]
+    touched = {(m.sid, m.aid) for b in _positions_stream() for m in b
+               if m.action in (op.BUY, op.SELL)}
+    assert len(touched) > 128 > ses.metrics()["positions"] > 0
 
 
 def test_seq_native_wire_equivalence():
