@@ -157,7 +157,7 @@ def saturate(prod: Producer, cons: Consumer, traffic: dict, seconds: float,
             first = max(warm, cons.begun + 1)
         if t_open is None and first is not None and cons.begun > first:
             t_open = cons.last_t[first - 1]
-            on_open()
+            on_open(t_open)
         if t_open is not None and time.monotonic() >= t_open + seconds:
             break
         alive()
@@ -214,7 +214,7 @@ def paced(prod: Producer, cons: Consumer, traffic: dict, seconds: float,
     due = due_offsets(traffic, seconds)
     have = prod.stream.wait_for(warm + len(due)) - warm
     t_open = time.monotonic() + 0.05
-    on_open()
+    on_open(t_open)
     late = []
     j = 0
     while j < have:

@@ -5,7 +5,7 @@ program's generators without changing the yardstick's traffic.
 
 They yield lazily: the harness sends the preamble while the rest of the
 stream is still being drawn. Same seed, same messages as the originals
-(checked by benchmark/test_generators.py). A traffic file names a
+(checked by benchmark/test_yardstick.py). A traffic file names a
 generator either by its name here or as `module:function` (any
 generator of the program that takes `num_events`, `seed` and keyword
 parameters and returns the messages)."""
@@ -32,7 +32,8 @@ class WorkloadGen:
         self.rake = rake
         self.rng = random.Random(seed)
         self.payout_opcode_bug = payout_opcode_bug
-        # validate clamps prices/sizes into the fixed-mode domain
+        # validate clamps a trade's price and size into the device
+        # domain (fixed mode's, and java mode's on the chip)
         self.validate = validate
         self.open_orders: dict[int, int] = {}
         # sorted oid pool: cancels select by sorted position
@@ -104,10 +105,16 @@ class WorkloadGen:
 
 
 def harness_stream(num_events, seed=0, num_accounts=10, num_symbols=3,
-                   rake=3) -> Iterator[OrderMsg]:
+                   rake=3, validate=False) -> Iterator[OrderMsg]:
     """The upstream harness workload (exchange_test.js:18-36): preamble,
-    then `num_events` random events with its mix per mille."""
-    gen = WorkloadGen(num_accounts, num_symbols, rake, seed)
+    then `num_events` random events with its mix per mille. `validate`
+    is `kme-loadgen --validate`: a trade's price goes to min(125,
+    max(0, p)) and its size to max(1, s) after every draw has been made,
+    so the random sequence, every oid and every other message are the
+    stock stream's, and a trade inside the java device domain (0 <=
+    price < 126, size > 0) is untouched."""
+    gen = WorkloadGen(num_accounts, num_symbols, rake, seed,
+                      validate=validate)
     for aid in range(num_accounts):
         yield gen.create_account(aid)
         yield gen.create_transfer(aid, gen.normal_param(500 * 100,

@@ -7,8 +7,9 @@ metric by adding a file (and its `BENCHMARK.json` entry); a reader that
 finds nothing to read returns None and the metric is left out.
 
 Sources a `read` can name with `from`:
-  heartbeat  two snapshots of the server's heartbeat file, the newest at
-             the window's opening (a) and at its close (b); `key` is a
+  heartbeat  two snapshots of the server's heartbeat file, the first
+             written after the window opened (a) and the newest at its
+             close (b), so both lie inside the window; `key` is a
              dotted path under its `metrics` section
              (`counters.service_batches`, `gauges.plan_s`,
              `latencies.lat_produce`)

@@ -31,12 +31,14 @@ import re          # noqa: E402
 import shutil      # noqa: E402
 import subprocess  # noqa: E402
 import sys         # noqa: E402
+import threading   # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 IDLE_EXIT_S = 5.0       # the server ends itself after this much silence
 SERVER_START_S = 600    # a cold first run builds the native library too
 DRAIN_S = 240
+HB_OPEN_WAIT_S = 10.0   # the server writes a heartbeat every second
 
 
 class RunFailure(Exception):
@@ -98,6 +100,40 @@ def read_json_or_none(path: str, tries: int = 1):
             if k + 1 < tries:
                 time.sleep(0.02)
     return None
+
+
+class OpeningHeartbeat(threading.Thread):
+    """Waits, beside the feeding loop, for the first whole heartbeat
+    stamped at or after `t_wall` on its own clock (`"time"`: the
+    server's wall clock). The file as it stands when the window opens is
+    up to a heartbeat period old, so it may hold less than the server
+    had done by then: by the first batch and its compilation, in a cell
+    whose warm-up is short."""
+
+    def __init__(self, path: str, t_wall: float, stop: threading.Event,
+                 wait_s: float = HB_OPEN_WAIT_S):
+        super().__init__(daemon=True)
+        self.path, self.t_wall, self.stop = path, t_wall, stop
+        self.wait_s, self.found = wait_s, None
+        self.start()
+
+    def run(self):
+        deadline = time.monotonic() + self.wait_s
+        while time.monotonic() < deadline:
+            hb = read_json_or_none(self.path)
+            if hb and hb.get("time", 0.0) >= self.t_wall:
+                self.found = hb
+                return
+            if self.stop.wait(0.05):    # the run has ended
+                return
+
+    def get(self) -> dict:
+        """The heartbeat; a run in which none came does not stand."""
+        self.join()
+        if self.found is None:
+            raise RunFailure(f"no heartbeat stamped after the window "
+                             f"opened came within {self.wait_s:g} s")
+        return self.found
 
 
 def percentile(sorted_values: list, q: float) -> float:
@@ -234,6 +270,7 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool,
         proc = subprocess.Popen(cmd, stdout=log, stderr=log, env=env,
                                 cwd=ROOT)
     cons = prod = None
+    hb_stop = threading.Event()
     try:
         def alive():
             if proc.poll() is not None:
@@ -283,11 +320,15 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool,
         # that after the preamble's batch, and the run was lost)
         window = {}
 
-        def on_open():
-            window["hb_a"] = read_json_or_none(hb_path, 3)
+        def on_open(t_open):
+            # the opening snapshot is the first heartbeat written after
+            # the window opened; a thread waits for it, so that the
+            # feeding loop is not held up
             window["log_at"] = os.path.getsize(log_path)
             if trace:
                 open(flag_path, "w").close()
+            window["opening"] = OpeningHeartbeat(
+                hb_path, time.time() + (t_open - time.monotonic()), hb_stop)
 
         kind = client.KINDS[traffic["kind"]]
         facts = kind(prod, cons, traffic, seconds, alive, on_open)
@@ -296,6 +337,11 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool,
         t_open = facts["t_open"]
         if t_open is None:
             raise RunFailure("the window never opened")
+        window["hb_a"] = window["opening"].get()
+        say("heartbeats read, seconds after the window opened: "
+            + ", ".join("none" if hb is None else
+                        f"{hb['time'] - window['opening'].t_wall:.2f}"
+                        for hb in (window["hb_a"], window["hb_b"])))
         setup_s = t_open - T_PROCESS
         marks["stream generated"] = stream.done_t
         marks["first MatchOut record"] = cons.first_t
@@ -315,6 +361,7 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool,
     except (BrokerError, OSError) as e:
         raise RunFailure(f"feeding failed ({e!r})\n{tail(log_path)}")
     finally:
+        hb_stop.set()
         if proc.poll() is None:
             proc.terminate()
             try:
@@ -424,6 +471,10 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool,
         result["rehearsal"] = "cpu"
         result["metrics"] = {f"cpu_rehearsal.{k}": v
                              for k, v in result["metrics"].items()}
+    # every number compared beside its limit, last in the line: what a
+    # record of a run that read `correct: false` keeps of it
+    result["compared"] = {what: {"value": value, "limit": limit}
+                          for what, value, limit, _ok in checks}
     return result
 
 
@@ -464,6 +515,9 @@ def main(argv=None) -> int:
     if "jax" in sys.modules:
         print("benchmark: FAILED: the parent imported jax", file=sys.stderr)
         return 1
+    for what, c in result["compared"].items():
+        print(f"compared {what}: {c['value']!r} (limit {c['limit']!r})",
+              file=sys.stderr)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -7,7 +7,12 @@
    timed path broken underneath (one MatchOut record altered where the
    serve loop produces it) must report `correct: false`; the same run
    on the sound host reports true, and under `--control` false.
-3. A stream drawn more slowly than the server's patience (it ends
+3. The seed whose stock stream holds a trade outside the java device
+   domain (2147483736, message 14,330) stays on the device session with
+   the configuration's `validate` and reads `correct: true`; the same
+   run on the stock stream leaves it and reads false: the check guards
+   the program now, not the dice.
+4. A stream drawn more slowly than the server's patience (it ends
    itself when its input stays silent) does not lose the run: the
    server is fed while the stream is drawn, and the window opens only
    after the last message exists."""
@@ -39,9 +44,9 @@ def test_control_reference_fails_the_comparison(name, seed):
     assert judge.differing(flat(ctrl), flat(want)) > 0
 
 
-def rehearse(tmp_path, **kw):
-    return run.run_cell(CELL, seed=11, seconds=3, trace=False,
-                        allow_cpu=True, events=60000,
+def rehearse(tmp_path, seed=11, seconds=3, events=60000, **kw):
+    return run.run_cell(CELL, seed=seed, seconds=seconds, trace=False,
+                        allow_cpu=True, events=events,
                         out=str(tmp_path / "run"), **kw)
 
 
@@ -54,6 +59,36 @@ def test_sound_run_is_correct(tmp_path):
 
 def test_control_run_is_not_correct(tmp_path):
     assert rehearse(tmp_path, control=True)["correct"] is False
+
+
+LEAVING_SEED, LEAVES_AT = 2147483736, 14330
+
+
+def rehearse_leaving_seed(tmp_path, capsys):
+    """-> (result, what the run printed); long enough a window that the
+    message at LEAVES_AT is served"""
+    result = rehearse(tmp_path, seed=LEAVING_SEED, seconds=8, events=20000)
+    traffic, _config = run.load_cell(CELL)
+    assert result["attempted"] + traffic["warmup_messages"] > LEAVES_AT
+    return result, capsys.readouterr().out
+
+
+def test_the_stream_stays_on_the_device_session(tmp_path, capsys):
+    result, said = rehearse_leaving_seed(tmp_path, capsys)
+    assert result["correct"] is True and result["failed"] == 0
+    assert "check ok   left the device session: 0 (limit 0)" in said
+
+
+def test_the_stock_stream_leaves_the_device_session(tmp_path, capsys,
+                                                    monkeypatch):
+    traffic, config = run.load_cell(CELL)
+    config["stream"]["params"]["validate"] = False
+    monkeypatch.setattr(run, "load_cell", lambda cell: (traffic, config))
+    result, said = rehearse_leaving_seed(tmp_path, capsys)
+    assert result["correct"] is False
+    assert "check MISS left the device session: 1 (limit 0)" in said
+    assert "check MISS engine in effect: 'native' (limit 'seq')" in said
+    assert said.count("check MISS") == 2    # byte-exact all the same
 
 
 def test_broken_timed_path_is_not_correct(tmp_path):

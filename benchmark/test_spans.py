@@ -1,8 +1,10 @@
-"""The layer metrics and spans PR 26 added, as data: every new metric
-file reads a number from a recorded pair of heartbeats of the finished
-program, `loop_other_ms_per_batch.sat` subtracts exactly the spans that
-partition a loop iteration, every span file resolves, and every file
-has its `BENCHMARK.json` entry.
+"""The layer metrics and spans added since PR 26, as data: every metric
+file of a cell the recording can feed reads a number from a recorded
+pair of heartbeats of the finished program (a file that lists only
+later cells is read too, and may find nothing),
+`loop_other_ms_per_batch.sat` subtracts exactly the spans that partition
+a loop iteration, every span file resolves, and every file has its
+`BENCHMARK.json` entry.
 
 `testdata/zipf1k-sat.heartbeats.json` is the pair (`hb_a` at the
 window's opening, `hb_b` at its close) that a CPU rehearsal of
@@ -41,6 +43,14 @@ def metric_files():
 
 
 NEW = sorted(set(metric_files()) - BEFORE)
+# the cells that stood when the pair was recorded: a metric file that
+# lists one of them (or lists none) was written to read a number from
+# these heartbeats. A later cell's files are read all the same, and
+# must not raise; what the recording cannot feed gives nothing
+RECORDED_CELLS = {"zipf1k-sat", "zipf1k-paced", "java-harness-sat"}
+FED = [n for n in NEW
+       if RECORDED_CELLS & set(metric_files()[n].get("cells",
+                                                   RECORDED_CELLS))]
 
 
 @pytest.fixture(scope="module")
@@ -52,7 +62,7 @@ def ctx():
 
 
 def test_there_are_new_metrics():
-    assert len(NEW) == 21
+    assert len(FED) >= 21       # PR 26's; later PRs add, none takes away
 
 
 @pytest.mark.parametrize("name", NEW)
@@ -60,8 +70,9 @@ def test_new_metric_reads_a_number(name, ctx):
     spec = metric_files()[name]
     assert spec["name"] == name
     value = layers.read(spec["read"], ctx)
-    assert isinstance(value, (int, float)), (name, value)
-    assert value >= 0 or name.startswith(("loop_other", "left_device"))
+    if name in FED or value is not None:
+        assert isinstance(value, (int, float)), (name, value)
+        assert value >= 0 or name.startswith(("loop_other", "left_device"))
     # and nothing, without raising, from a program that has no such
     # span or counter (the parent's heartbeats)
     bare = {k: (dict(ctx[k], metrics={"counters": dict(

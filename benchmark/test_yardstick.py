@@ -2,9 +2,10 @@
 program's, the kernel's byte count against `SeqConfig`, the layer-metric
 reductions, the peaks table, the paced schedule."""
 
-import itertools
 import json
 import os
+import threading
+import time
 
 import pytest
 
@@ -22,8 +23,47 @@ def test_generators_are_the_programs(seed):
         {"num_symbols": 64, "num_accounts": 128, "zipf_a": 1.2}))
     assert mine == workload.zipf_symbol_stream(3000, 64, 128, seed=seed,
                                                zipf_a=1.2)
-    mine = list(generators.open_stream("harness_stream", 3000, seed, {}))
-    assert mine == workload.harness_stream(3000, seed=seed)
+    for validate in (False, True):
+        mine = list(generators.open_stream("harness_stream", 3000, seed,
+                                           {"validate": validate}))
+        assert mine == workload.harness_stream(3000, seed=seed,
+                                               validate=validate)
+
+
+def outside_java_device_domain(m) -> bool:
+    from kme_tpu import opcodes as op
+
+    return m.action in (op.BUY, op.SELL) and not (
+        0 <= m.price < 126 and m.size > 0)
+
+
+def test_java_harness_stream_stays_in_the_java_device_domain():
+    """Seed 2147483736 draws one trade that `--compat java` does not
+    keep on the device (message 14,330 of the whole stream, its 23
+    messages of preamble included: SELL price 58 size -1). With
+    `validate`, which the configuration asks for, that one message is
+    clamped and every other is the stock stream's."""
+    from kme_tpu import workload
+
+    config = run.load_json(os.path.join(HERE, "configs",
+                                        "java-harness.json"))
+    spec = config["stream"]
+    assert spec["params"]["validate"] is True and config["reduced"] == []
+    seed, events = 2147483736, 20000
+    mine = list(generators.open_stream(spec["generator"], events, seed,
+                                       spec["params"]))
+    stock = list(generators.open_stream(
+        spec["generator"], events, seed,
+        dict(spec["params"], validate=False)))
+    assert mine == workload.harness_stream(events, seed=seed, validate=True)
+    assert len(mine) == len(stock) == events + 23
+    assert not any(outside_java_device_domain(m) for m in mine)
+    assert [k for k, m in enumerate(stock)
+            if outside_java_device_domain(m)] == [14330]
+    assert [k for k, (a, b) in enumerate(zip(mine, stock))
+            if a != b] == [14330]
+    assert (stock[14330].price, stock[14330].size) == (58, -1)
+    assert (mine[14330].price, mine[14330].size) == (58, 1)
 
 
 def test_program_generator_by_name():
@@ -129,6 +169,36 @@ def test_every_listed_metric_has_its_file():
             == {m["name"] for m in bench["per_layer"]
                 if w["name"] in m.get("workloads", [w["name"]])
                 and m["moves"] in reports}
+
+
+def write_heartbeat(path, t):
+    tmp = str(path) + ".tmp"
+    with open(tmp, "w") as f:
+        json.dump({"time": t, "metrics": {}}, f)
+    os.replace(tmp, path)
+
+
+def test_opening_heartbeat_is_one_stamped_after_the_window_opened(tmp_path):
+    path, t_open, stop = tmp_path / "health.json", 1000.0, threading.Event()
+    write_heartbeat(path, t_open - 0.4)     # the newest file at the opening
+    later = threading.Timer(0.3, write_heartbeat, (path, t_open + 0.6))
+    later.start()
+    t = time.monotonic()
+    hb = run.OpeningHeartbeat(str(path), t_open, stop, wait_s=5.0).get()
+    later.join()
+    assert hb["time"] == t_open + 0.6 and time.monotonic() - t < 2.0
+    # none stamped after the opening: the run fails, it never falls back
+    # to the stale file; a torn file is no heartbeat either
+    for text in (json.dumps({"time": t_open - 0.4}), '{"time": 10'):
+        path.write_text(text)
+        with pytest.raises(run.RunFailure, match="no heartbeat stamped"):
+            run.OpeningHeartbeat(str(path), t_open, stop, wait_s=0.3).get()
+    # a run that ended meanwhile stops the wait
+    stop.set()
+    t = time.monotonic()
+    with pytest.raises(run.RunFailure):
+        run.OpeningHeartbeat(str(path), t_open, stop, wait_s=5.0).get()
+    assert time.monotonic() - t < 1.0
 
 
 def test_paced_schedules_keep_the_mean_rate():
