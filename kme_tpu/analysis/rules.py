@@ -84,8 +84,12 @@ HOT_SCOPES: Dict[str, Set[str]] = {
     # flush via _flush_log_lines (deliberately un-scoped: it is the
     # sanctioned batched exit point) — per-record blocking I/O
     # reappearing inside the loop is exactly the JSON-ingress tax
-    # this path exists to remove
-    "kme_tpu/bridge/broker.py": {"produce_frames"},
+    # this path exists to remove. produce_stamped is its egress twin
+    # (one stamped run of MatchOut records a call) with the same one
+    # exit, and _stamped_rows builds that run's rows: a write or flush
+    # per record inside either is the same tax on the serve loop
+    "kme_tpu/bridge/broker.py": {"produce_frames", "produce_stamped",
+                                 "_stamped_rows"},
 }
 
 # Replay scopes: functions whose outputs must be bit-identical when a
@@ -110,7 +114,8 @@ REPLAY_SCOPES: Dict[str, Set[str]] = {
                                   # MatchOut/Xfer split and the stamp
                                   # assignment must regenerate
                                   # identically on crash-replay
-                                  "_produce_out", "_produce_xfer"},
+                                  "_produce_out", "_produce_xfer",
+                                  "_produce_records", "_produce_run"},
     # the split IS the transfer regeneration path: a crash-replay
     # re-runs route_line over the MatchIn prefix and must emit the
     # byte-identical injected legs (same grants, same xids)
@@ -216,8 +221,8 @@ FEED_SCOPES: Dict[str, Set[str]] = {
 CLOCK_SCOPES: Dict[str, Set[str]] = {
     "kme_tpu/bridge/service.py": {
         "step", "_step_pipelined", "_process_batch", "_produce_retry",
-        "_publish_batch", "_write_heartbeat"},
-    "kme_tpu/bridge/broker.py": {"produce", "fetch"},
+        "_broker_retry", "_publish_batch", "_write_heartbeat"},
+    "kme_tpu/bridge/broker.py": {"produce", "produce_stamped", "fetch"},
     "kme_tpu/bridge/replica.py": {"fetch", "run", "_write_heartbeat",
                                   "_promote"},
     "kme_tpu/bridge/tcp.py": {"_ats_for"},

@@ -405,13 +405,25 @@ def simulate_overload(values: List[str], windows, controller:
 
 
 def _flush_log_lines(logfile, lines: List[str]) -> None:
-    """The batched durable-write exit point for produce_frames: ONE
-    write + flush for a whole admitted prefix. Deliberately outside
-    the produce_frames lint hot-scope — this is the sanctioned place
-    for the blocking I/O, so anything blocking reappearing inside the
-    per-record loop fails KME-H001."""
+    """The batched durable-write exit point for produce_frames and
+    produce_stamped: ONE write + flush for a whole admitted prefix.
+    Deliberately outside their lint hot-scope — this is the sanctioned
+    place for the blocking I/O, so anything blocking reappearing inside
+    a per-record loop fails KME-H002."""
     logfile.write("".join(lines))
     logfile.flush()
+
+
+def _stamped_rows(records, epoch: int, seq0: int) -> List[str]:
+    """The durable rows of one stamped run, byte-equal to what
+    produce() writes record by record (json.dumps of ``[key, value,
+    epoch, out_seq]`` with ``(",", ":")`` separators, plus the
+    newline) without building an encoder per record: a row of two
+    strings and two ints is its escaped strings and the ints' digits."""
+    enc = json.encoder.encode_basestring_ascii
+    return [f"[{'null' if key is None else enc(key)},{enc(value)},"
+            f"{epoch},{out_seq}]\n"
+            for out_seq, (key, value) in enumerate(records, seq0)]
 
 
 class InProcessBroker:
@@ -746,6 +758,16 @@ class InProcessBroker:
                 self._data.notify_all()
         if overload_msg is None and shed_detail is None:
             return appended, last_off
+        self._raise_overload(topic, overload_msg, shed_detail, appended)
+
+    def _raise_overload(self, topic: str, overload_msg: Optional[str],
+                        shed_detail: Optional[dict],
+                        admitted: int) -> None:
+        """The mid-batch refusal of produce_frames / produce_stamped,
+        raised OUTSIDE the broker lock: the BrokerOverload carries
+        `.admitted` (the prefix that stays appended) and, for a
+        controller shed, the backoff hint and the detail the shed
+        observer was shown."""
         if shed_detail is not None:
             obs = self.shed_observer
             if obs is not None:
@@ -762,8 +784,79 @@ class InProcessBroker:
             exc.detail = shed_detail
         else:
             exc = BrokerOverload(overload_msg)
-        exc.admitted = appended
+        exc.admitted = admitted
         raise exc
+
+    def produce_stamped(self, topic: str, records, epoch: int,
+                        seq0: int) -> int:
+        """Stamped batch append for output records — the egress twin of
+        produce_frames: `records` is the list of one run's ``(key,
+        value)`` pairs in order, record i carries ``out_seq = seq0 +
+        i``. Record for record the semantics are produce()'s: the
+        `broker.produce` fault point is asked once, before anything is
+        appended; a stale epoch raises BrokerFenced with nothing
+        appended; records at or below the topic's durable watermark
+        are suppressed and counted (the stamps of a run are dense and
+        rising, so a replayed tail is a prefix of it); on a `max_lag` /
+        controller refusal the admitted prefix STAYS appended and the
+        BrokerOverload carries `.admitted`, as in produce_frames. The
+        rows are the bytes produce() writes, through ONE write + flush
+        (_flush_log_lines) and ONE notify_all, all under one hold of
+        the data lock: no consumer can fetch a record that is not yet
+        flushed. One admission stamp (`ats`) for the run. Returns how
+        many records were appended."""
+        if faults.should("broker.produce"):
+            raise BrokerError("injected fault: broker.produce")
+        # the stamps are known before the lock, so the rows are built
+        # outside it (produce_frames parses before it locks)
+        rows = (_stamped_rows(records, epoch, seq0)
+                if self._persist_dir is not None else None)
+        ats = self._clock.time_us()
+        shed_detail = overload_msg = None
+        with self._data:
+            t = self._topics.get(topic)
+            if t is None:
+                raise BrokerError(f"unknown topic {topic!r}")
+            if epoch < self._fence_epoch:
+                self.fenced_produces += 1
+                raise BrokerFenced(
+                    f"fenced: produce to {topic!r} from stale epoch "
+                    f"{epoch} < fence {self._fence_epoch}")
+            self._fence_epoch = epoch
+            first = min(len(records), max(0, t.max_out_seq + 1 - seq0))
+            self.dup_suppressed += first
+            bounded = topic in self._commits
+            log = t.log
+            end = first
+            for key, value in records[first:]:
+                if bounded:
+                    backlog = len(log) - self._commits[topic]
+                    if (self._max_lag is not None
+                            and backlog >= self._max_lag):
+                        self.overload_rejects += 1
+                        overload_msg = (
+                            f"rej_overload: topic {topic!r} backlog "
+                            f"{backlog} >= max_lag {self._max_lag}")
+                        break
+                    if self.overload is not None:
+                        ok, shed_detail = self.overload.admit(
+                            value, backlog)
+                        if not ok:
+                            self.overload_rejects += 1
+                            break
+                    self.wire_json_records += 1
+                log.append(Record(len(log), key, value, epoch,
+                                  seq0 + end, ats))
+                end += 1
+            appended = end - first
+            if appended:
+                t.max_out_seq = seq0 + end - 1
+                if t.logfile is not None:
+                    _flush_log_lines(t.logfile, rows[first:end])
+                self._data.notify_all()
+        if overload_msg is None and shed_detail is None:
+            return appended
+        self._raise_overload(topic, overload_msg, shed_detail, appended)
 
     def fence(self, epoch: int) -> None:
         """Advance the fence so every produce stamped below `epoch` is
@@ -818,14 +911,15 @@ class InProcessBroker:
             return len(t.log)
 
     def sync(self) -> None:
-        """fsync every topic log to stable storage. `produce` only
-        flush()es (process-crash durability); callers that are about to
-        commit an offset DERIVED from these records (MatchService
-        checkpoints) call sync() first so an fsync'd snapshot offset can
-        never address records the OS lost in a power failure. The
-        persist directory is fsync'd too: a freshly created topic log is
-        a new directory entry, and POSIX only makes those durable after
-        a directory fsync."""
+        """fsync every topic log to stable storage. `produce`,
+        `produce_frames` and `produce_stamped` only flush() — once a
+        record or once a batch (process-crash durability); callers that
+        are about to commit an offset DERIVED from these records
+        (MatchService checkpoints) call sync() first so an fsync'd
+        snapshot offset can never address records the OS lost in a
+        power failure. The persist directory is fsync'd too: a freshly
+        created topic log is a new directory entry, and POSIX only
+        makes those durable after a directory fsync."""
         with self._lock:
             any_file = False
             for t in self._topics.values():

@@ -207,6 +207,12 @@ class MatchService:
                       file=sys.stderr)
         self.epoch: Optional[int] = None  # leader fencing token
         self.out_seq = 0                  # next MatchOut produce stamp
+        # output-stream records handed to the broker and the calls that
+        # took them (counters matchout_records / matchout_produce_calls)
+        self._out_calls = self._out_records = 0
+        # the run of organic records _produce_records is gathering for
+        # one produce_stamped call; None where records go out one by one
+        self._run = None
         if exactly_once and checkpoint_dir is None:
             raise ValueError("exactly_once needs checkpoint_dir (the "
                              "leader-epoch lease lives there)")
@@ -1462,9 +1468,10 @@ class MatchService:
         return out
 
     def _produce_buffer(self, buf, line_off, ordinal=None) -> None:
-        """Produce a reconstructed record buffer line by line — the
-        collect-side twin of _produce_lines (same stamping, retry and
-        flow-arrow semantics)."""
+        """Produce a reconstructed record buffer — the collect-side
+        twin of _produce_lines: both hand the batch's lines to
+        _produce_records (same stamping, retry and flow-arrow
+        semantics)."""
         import time as _t
 
         t0 = _t.perf_counter()
@@ -1472,9 +1479,8 @@ class MatchService:
             self._flow("f", ordinal)
             text = buf.decode("ascii")
             lo = line_off.tolist()
-            for i in range(len(lo) - 1):
-                key, _, value = text[lo[i]:lo[i + 1]].partition(" ")
-                self._produce_out(key, value)
+            self._produce_records(
+                [text[lo[i]:lo[i + 1]] for i in range(len(lo) - 1)])
         self._last_produce_s += _t.perf_counter() - t0
 
     def _publish_batch(self, nrecs: int, ndropped: int) -> None:
@@ -1529,6 +1535,13 @@ class MatchService:
                   "tiles of the position store the seq kernel brought "
                   "in from HBM (the kernel's own count)"
                   ).set(getattr(self._session, "pos_probe_tiles", 0))
+        t.counter("matchout_produce_calls",
+                  "broker calls made for output-stream records: one a "
+                  "run on a broker with produce_stamped, one a record "
+                  "elsewhere").set(self._out_calls)
+        t.counter("matchout_records",
+                  "output-stream records handed to the broker, by "
+                  "either path").set(self._out_records)
         # host engines never load jax: nothing compiles
         jaxsetup = sys.modules.get("kme_tpu._jaxsetup")
         compiles = (jaxsetup.compiles if jaxsetup is not None
@@ -1694,22 +1707,28 @@ class MatchService:
         for promotion). BrokerFenced is never retried — a newer leader
         owns the stream and this process must die so its supervisor
         restarts it under a fresh epoch."""
+        stamped = stamp and self.epoch is not None
+        if stamped:
+            self._broker_retry(self.broker.produce, topic, key, value,
+                               epoch=self.epoch, out_seq=self.out_seq)
+        else:
+            self._broker_retry(self.broker.produce, topic, key, value)
+        if stamp:
+            self._out_calls += 1
+            self._out_records += 1
+            if stamped or (self.follower and self.exactly_once):
+                self.out_seq += 1
+
+    def _broker_retry(self, send, topic: str, *args, **kw) -> None:
+        """One broker call under _produce_retry's bounded backoff: five
+        retries of a BrokerError, 0.05 s doubling to 1 s, each counted
+        in `broker_retries`; BrokerFenced goes straight up."""
         from kme_tpu.bridge.broker import BrokerError, BrokerFenced
 
-        stamped = stamp and self.epoch is not None
-        counted = stamp and (stamped
-                             or (self.follower and self.exactly_once))
         delay = 0.05
         for attempt in range(6):
             try:
-                if stamped:
-                    self.broker.produce(topic, key, value,
-                                        epoch=self.epoch,
-                                        out_seq=self.out_seq)
-                else:
-                    self.broker.produce(topic, key, value)
-                if counted:
-                    self.out_seq += 1
+                send(topic, *args, **kw)
                 return
             except BrokerFenced:
                 raise
@@ -1723,6 +1742,46 @@ class MatchService:
                 self.clock.sleep(delay)
                 delay = min(delay * 2, 1.0)
 
+    def _produce_records(self, lines) -> None:
+        """Produce one batch's "KEY value" output lines in order — the
+        one walk behind _produce_buffer and _produce_lines; every line
+        is routed by _produce_out. How many records a broker call
+        takes is read off the broker object: a stamping leader whose
+        broker has `produce_stamped` (InProcessBroker) gathers each
+        run of consecutive organic lines and sends it in ONE call —
+        one lock hold, one log write + flush, one wake of the
+        consumers; an Xfer-marked line closes the run and goes out in
+        its place, so the stamp stream is the one the per-record walk
+        writes. Everything else — a follower's discarding broker, a
+        remote or Kafka broker, the unstamped at-least-once path —
+        hands each record over as it is routed."""
+        if (self.epoch is not None
+                and getattr(self.broker, "produce_stamped", None)
+                is not None):
+            self._run = []
+        try:
+            for ln in lines:
+                key, _, value = ln.partition(" ")
+                self._produce_out(key, value)
+            self._produce_run()
+        finally:
+            self._run = None
+
+    def _produce_run(self) -> None:
+        """Send the gathered run, if any, in one stamped batch produce
+        under _produce_retry's backoff: a BrokerError retries the whole
+        run from the same `seq0`, and the broker's dedup makes that
+        idempotent."""
+        run = self._run
+        if not run:
+            return
+        self._broker_retry(self.broker.produce_stamped, self.topic_out,
+                           run, self.epoch, self.out_seq)
+        self._out_calls += 1
+        self._out_records += len(run)
+        self.out_seq += len(run)
+        self._run = []
+
     def _produce_out(self, key, value) -> None:
         """Route one output line: organic records go to this group's
         MatchOut stream; front-injected cross-shard lines (the
@@ -1731,9 +1790,14 @@ class MatchService:
         per-group Xfer topic instead, so every applied transfer leg
         leaves one fenced `(epoch, out_seq)` row of durable dedup
         evidence. Both paths consume the same out_seq cursor, keeping
-        the stamp stream deterministic across crash-replay."""
+        the stamp stream deterministic across crash-replay. Where
+        _produce_records gathers a run, an organic record joins it and
+        an Xfer line sends it first."""
         if self._xfer_mark is not None and self._xfer_mark in value:
+            self._produce_run()
             self._produce_xfer(key, value)
+        elif self._run is not None:
+            self._run.append((key, value))
         else:
             self._produce_retry(self.topic_out, key, value, stamp=True)
 
@@ -1783,15 +1847,15 @@ class MatchService:
                     track="serve")
 
     def _produce_lines(self, out) -> None:
+        """Produce a batch's per-message line lists — the serial
+        path's twin of _produce_buffer, through the same
+        _produce_records."""
         import time as _t
 
         t0 = _t.perf_counter()
         with self._span("produce_lines"):
             self._flow("f")
-            for lines in out:
-                for ln in lines:
-                    key, _, value = ln.partition(" ")
-                    self._produce_out(key, value)
+            self._produce_records([ln for lines in out for ln in lines])
         # accumulates across the branch paths that produce more than
         # once per step (native partial + REJ annotations)
         self._last_produce_s += _t.perf_counter() - t0

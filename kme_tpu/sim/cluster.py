@@ -18,9 +18,10 @@ Fault vocabulary (see ``schedule.py``):
   (broker errors, torn/bitflipped checkpoints, link partitions/delays/
   reorder-dups, clock skew);
 - ``crash`` events model SIGKILL of a group leader by DROPPING its
-  service and broker objects (``produce`` flushes per record, so the
-  on-disk logs are exactly what a kill -9 leaves) and letting the
-  supervisor actor restart it through the ordinary recovery path;
+  service and broker objects (the broker flushes inside every produce
+  call, a record or a stamped run at a time, so the on-disk logs are
+  exactly what a kill -9 leaves) and letting the supervisor actor
+  restart it through the ordinary recovery path;
 - ``reshard`` events drain the cluster at a stream barrier, close the
   generation, run the coordinator, and reopen services over the new
   topology with the settle-phase resume cursors.
@@ -159,7 +160,7 @@ class _Leader:
 
     def crash(self) -> None:
         """kill -9 at the object layer: no close(), no final flush
-        beyond what produce() already did per record."""
+        beyond what each produce call already did."""
         self.crashes += 1
         self.svc = None
         self.broker = None

@@ -52,6 +52,21 @@ class SeqSession:
     def _fetch_outputs(self):
         self.journal_f.flush()
 """),
+    # the stamped batch produce: a per-record flush coming back into
+    # its loop gates; _flush_log_lines is the one sanctioned exit
+    ("KME-H002", "kme_tpu/bridge/broker.py", """
+class Broker:
+    def produce_stamped(self, topic, records, epoch, seq0):
+        for key, value in records:
+            self.logfile.flush()
+""", """
+def _flush_log_lines(logfile, lines):
+    logfile.write("".join(lines))
+    logfile.flush()
+class Broker:
+    def produce_stamped(self, topic, records, epoch, seq0):
+        _flush_log_lines(self.logfile, list(records))
+"""),
     ("KME-D001", "kme_tpu/bridge/broker.py", """
 import time
 class Broker:

@@ -53,6 +53,31 @@ def test_validate_mode_bounds_domain():
             assert 0 <= m.price <= 125 and m.size >= 1
 
 
+def test_validate_clamps_the_one_trade_outside_the_java_device_domain():
+    """Seed 2147483736 draws one trade that `--engine seq --compat
+    java` does not keep on the device (0 <= price < 126, size > 0):
+    message 14,330 of the whole stream, preamble included, SELL 58 x
+    -1. With `validate` that one message reads SELL 58 x 1 and every
+    other is the stock stream's — the stream the benchmark's
+    java-harness-sat cell serves (PERF.md section 4)."""
+    def outside(m):
+        return m.action in (op.BUY, op.SELL) and not (
+            0 <= m.price < 126 and m.size > 0)
+
+    seed, events = 2147483736, 20000
+    mine = harness_stream(events, seed=seed, validate=True)
+    stock = harness_stream(events, seed=seed)
+    assert len(mine) == len(stock) == events + 23
+    assert not any(outside(m) for m in mine)
+    assert [k for k, m in enumerate(stock) if outside(m)] == [14330]
+    assert [k for k, (a, b) in enumerate(zip(mine, stock))
+            if a != b] == [14330]
+    assert (stock[14330].action, stock[14330].price,
+            stock[14330].size) == (op.SELL, 58, -1)
+    assert (mine[14330].action, mine[14330].price,
+            mine[14330].size) == (op.SELL, 58, 1)
+
+
 def test_oracle_survives_harness_distribution_java():
     e = OracleEngine("java")
     n = 0
