@@ -25,8 +25,8 @@ bytes of the durable MatchOut log against the native oracle.
      before the position store matters. The deployment is the
      benchmark's configuration fixed-vmem-default (uniform
      zipf_symbol_stream, cell vmem-default-sat).
-  (--chips 4)  `kme-bench --suite shards` on four chips: parity at
-     shards 1/2/4, four distinct devices, the lockstep shard_map leg.
+  (--chips 4)  seqmesh.shard_proof on four chips: parity at shards
+     1/2/4, four distinct devices, the lockstep shard_map leg.
 
 This parent never imports jax. Stdout: one JSON line per phase, one
 summary line (versions, cache directory, per-phase set-up facts), and
@@ -344,34 +344,40 @@ def loadgen_feeder(kids, out, name, env, args):
 
 
 def shards_phase(kids, out, env):
-    """Four chips: the only entry that reaches SeqMeshSession. The
-    suite clamps itself to 8 symbols x 128 accounts, VMEM books."""
-    log_path = os.path.join(out, "S.bench.log")
-    p = kids.spawn([sys.executable, "-m", "kme_tpu.cli", "bench",
-                    "--suite", "shards"], log_path, env)
-    wait_exit(p, "S: bench --suite shards", log_path)
-    detail = None
+    """Four chips: the only entry that reaches SeqMeshSession. A child
+    runs seqmesh.shard_proof (8 symbols x 128 accounts x 128 slots,
+    VMEM books), which raises unless the bytes match the oracle at
+    shards 1/2/4 with migrations above one shard, the four shard
+    states sit on four devices, and the lockstep leg matches too."""
+    log_path = os.path.join(out, "S.proof.log")
+    p = kids.spawn([sys.executable, "-c",
+                    "import json; from kme_tpu.parallel.seqmesh import "
+                    "shard_proof; print(json.dumps(shard_proof()))"],
+                   log_path, env)
+    wait_exit(p, "S: shard_proof", log_path)
+    proof = None
     with open(log_path, errors="replace") as f:
         for line in f:
-            if line.startswith("{") and '"suite": "shards"' in line:
-                detail = json.loads(line)
-    check(detail is not None, "S: no shards detail line")
-    check(detail.get("backend") == "tpu",
-          f"S: shards suite ran on {detail.get('backend')!r}")
-    devs = [d[0] for d in detail.get("shard_devices", [])]
+            if line.startswith('{"backend"'):
+                proof = json.loads(line)
+    check(proof is not None, "S: shard_proof printed no result")
+    check(proof["backend"] == "tpu",
+          f"S: shard_proof ran on {proof['backend']!r}")
+    devs = proof["shard_devices"]
     check(len(devs) == 4 and len(set(devs)) == 4,
           f"S: shard states not on four distinct devices: {devs}")
-    check(detail.get("dispatch") == "async"
-          and [r["parity"] for r in detail["per_shards"]]
-          == ["byte-exact"] * 3
-          and "lockstep_wall_s" in detail,
-          "S: async parity at shards 1/2/4 or the lockstep leg missing")
+    check(proof["dispatch"] == "async"
+          and proof["shard_counts"] == [1, 2, 4]
+          and proof["parity"] == proof["lockstep_parity"] == "byte-exact",
+          f"S: async parity at shards 1/2/4 or the lockstep leg "
+          f"missing: {proof}")
     return {"phase": "S", "shard_devices": devs,
-            "device_kind": detail.get("device_kind"),
-            "shard_counts": detail["shard_counts"],
-            "parity": "byte-exact", "dispatch": detail["dispatch"],
-            "lockstep_leg": "compiled and byte-exact",
-            "size": "8 symbols x 128 accounts x 128 slots (suite clamp)"}
+            "device_kind": proof["device_kind"],
+            "shard_counts": proof["shard_counts"],
+            "parity": proof["parity"], "dispatch": proof["dispatch"],
+            "migrations": proof["migrations"],
+            "lockstep_leg": proof["lockstep_parity"],
+            "size": "8 symbols x 128 accounts x 128 slots"}
 
 
 def main(argv=None) -> int:

@@ -30,8 +30,8 @@ Package layout:
   bridge/    transport edge speaking the reference's Kafka wire contract:
              broker core with durable logs, TCP process boundary, and the
              MatchIn -> engine -> MatchOut service + CLIs
-  wire/workload/opcodes/benchmarks/cli  byte-exact serde, seeded harness
-             workloads, protocol constants, bench suite, entry points
+  wire/workload/opcodes/cli  byte-exact serde, seeded harness
+             workloads, protocol constants, entry points
 
 Compatibility envelope and mode matrix: COMPAT.md at the repo root.
 The top-level package is import-light: the pure-Python layers (wire,

@@ -52,8 +52,8 @@ RULES: Dict[str, str] = {
 #
 # Hot scopes: the submit half of the double-buffered pipeline — between
 # a batch's fetch and its device dispatch, any host sync or blocking
-# I/O serializes the pipeline and shows up as measured_overlap_frac
-# collapse. Collect-side functions (_collect_one, collect,
+# I/O serializes the pipeline: the collect wall stops hiding under
+# device execution. Collect-side functions (_collect_one, collect,
 # _fetch_outputs) legitimately sync and are NOT listed.
 HOT_SCOPES: Dict[str, Set[str]] = {
     "kme_tpu/bridge/service.py": {"_step_pipelined", "_parse_batch"},

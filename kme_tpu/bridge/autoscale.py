@@ -13,7 +13,7 @@ consuming exactly the signals the serving side already exports:
 and deriving `shard_imbalance` (max/mean lag) from them. Decisions are
 doubling/halving proposals (N→2N split, N→N/2 merge) because the
 rendezvous assignment moves the minimal key fraction for any target —
-the move-cost the multihost bench gates — and a power-of-two ladder
+the move-cost tests/test_reshard.py pins — and a power-of-two ladder
 keeps repeated decisions composable.
 
 Hysteresis is explicit and threefold, so the policy cannot flap:

@@ -1527,8 +1527,8 @@ def run_reshard_storm(args, run_dir: str, report_path: str) -> int:
         "old_fenced": probes,
         "migration_pause_s": (round(pause, 3)
                               if pause is not None else None),
-        # flat perfgate-scrapeable gauges: reshard_pause_ms decomposed
-        # by phase (perfgate.ADVISORY_METRICS — wall clocks, advisory)
+        # reshard_pause_ms decomposed by phase (wall clocks: reported,
+        # never enforced)
         "reshard_pause_ms": (round(pause * 1000.0, 3)
                              if pause is not None else None),
         "reshard_drain_ms": walls_ms["drain"],

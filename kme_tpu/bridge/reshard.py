@@ -74,7 +74,7 @@ def plan_reshard(n: int, m: int, symbols: Sequence[int],
                  accounts: Sequence[int]) -> dict:
     """Deterministic re-split plan over explicit key universes: which
     symbols change their book's group, which accounts change custody,
-    and the headline `moved_key_frac` the multihost bench gates against
+    and the headline `moved_key_frac` tests/test_reshard.py holds to
     the rendezvous-minimal expectation (a consistent-hashing regression
     — e.g. a salt drift remapping everything — shows up here as
     moved_key_frac ≈ 1)."""

@@ -96,8 +96,8 @@ class MatchService:
             raise ValueError(f"unknown compat {compat!r}")
         if engine == "seq" and shards != 1:
             # the served seq engine is one SeqSession on one device; the
-            # sharded SeqMeshSession is reachable from `bench --suite
-            # shards` only until it is wired in here
+            # sharded SeqMeshSession is reachable from seqmesh's
+            # shard_proof only until it is wired in here
             raise ValueError(
                 f"engine='seq' serves on one device; shards={shards} is "
                 f"not wired into kme-serve (use engine='lanes' for the "
@@ -572,8 +572,8 @@ class MatchService:
     def _init_watch(self, resumed: bool) -> None:
         """Live watchpoint wiring (ISSUE 17). Predicates evaluate
         inline at the batch barrier — directly against the serving
-        OracleEngine when that IS the engine (zero-derivation, the
-        kme-bench prof 3% budget), else against an auditor-shaped
+        OracleEngine when that IS the engine (zero-derivation: an
+        armed watchpoint is free), else against an auditor-shaped
         shadow ledger fed from the batch's own (untampered) output
         lines. Both are pure functions of exported state, so two
         seeded runs fire identical (offset, predicate) hit sets. Hits

@@ -358,15 +358,6 @@ def oracle_main(argv=None) -> int:
     return 0
 
 
-def bench_main(argv=None) -> int:
-    """Benchmark harness (bench.py at the repo root drives the same code)."""
-    try:
-        from kme_tpu.benchmarks import main as _main
-    except ImportError:
-        return _not_yet("the benchmark suite")
-    return _main(argv)
-
-
 def serve_main(argv=None) -> int:
     """Engine service speaking the reference Kafka wire contract."""
     try:
@@ -681,7 +672,7 @@ def prof_main(argv=None) -> int:
     (kme-serve --tsdb and friends): list/plot/export metric series,
     verify segment digests, inspect the transfer-vs-compute artifact,
     and attribute a regression to a pipeline stage with --diff between
-    two history windows or recorded BENCH artifacts."""
+    two history windows."""
     p = argparse.ArgumentParser(prog="kme-prof",
                                 description=prof_main.__doc__)
     p.add_argument("store", nargs="?", default=None, metavar="DIR",
@@ -710,9 +701,7 @@ def prof_main(argv=None) -> int:
     p.add_argument("--diff", nargs=2, default=None,
                    metavar=("BASE", "CUR"),
                    help="stage-level regression attribution between "
-                        "two TSDB stores (window summaries) or two "
-                        "recorded BENCH/driver artifacts — each "
-                        "operand may be either")
+                        "the window summaries of two TSDB stores")
     p.add_argument("--captures", default=None, metavar="DIR",
                    help="list and pretty-print the capture_NNN.json "
                         "trigger captures in DIR (kme-serve "
@@ -720,7 +709,6 @@ def prof_main(argv=None) -> int:
                         "kme-xray watchpoint hits share the format)")
     args = p.parse_args(argv)
     import json
-    import os
 
     from kme_tpu.telemetry import tsdb
 
@@ -758,24 +746,21 @@ def prof_main(argv=None) -> int:
         print(json.dumps(doc, indent=1, sort_keys=True))
         return 0
     if args.diff is not None:
-        from kme_tpu import perfgate
-
-        def _metrics(operand: str):
-            if os.path.isdir(operand):
-                return tsdb.window_summary(operand,
-                                           source=args.source)
-            return perfgate.load_artifact(operand)["metrics"]
-
-        base, cur = (_metrics(x) for x in args.diff)
+        try:
+            base, cur = (tsdb.window_summary(x, source=args.source)
+                         for x in args.diff)
+        except ValueError as e:
+            print(f"kme-prof: {e}", file=sys.stderr)
+            return 2
         if not base or not cur:
             print("kme-prof: no metrics on one side of --diff",
                   file=sys.stderr)
             return 2
-        att = perfgate.attribute_regression(base, cur)
+        att = tsdb.attribute_regression(base, cur)
         if args.json:
             print(json.dumps(att, indent=1))
         else:
-            print(perfgate.format_attribution(att))
+            print(tsdb.format_attribution(att))
         return 0
     if args.store is None:
         p.error("give a store dir (or --artifact / --diff)")
@@ -822,9 +807,9 @@ def prof_main(argv=None) -> int:
 
 def trace_main(argv=None) -> int:
     """Flight-recorder query tool: reconstruct one order's or account's
-    lifecycle from a journal written by kme-serve --journal-out (or
-    kme-bench --journal-out), verify a journal against the reference
-    oracle replay, or replay an audit violation repro dump."""
+    lifecycle from a journal written by kme-serve --journal-out,
+    verify a journal against the reference oracle replay, or replay an
+    audit violation repro dump."""
     p = argparse.ArgumentParser(prog="kme-trace",
                                 description=trace_main.__doc__)
     p.add_argument("journal", nargs="?", default=None,
@@ -1275,7 +1260,7 @@ def events_main(argv=None) -> int:
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(prog="python -m kme_tpu.cli")
     p.add_argument("command", choices=(
-        "loadgen", "oracle", "bench", "serve", "consume", "provision",
+        "loadgen", "oracle", "serve", "consume", "provision",
         "supervise", "standby", "trace", "chaos", "top", "lint",
         "front", "agg", "feed", "reshard", "prof", "xray", "sim",
         "events"))
@@ -1283,7 +1268,7 @@ def main(argv=None) -> int:
     try:
         return {
             "loadgen": loadgen_main, "oracle": oracle_main,
-            "bench": bench_main, "serve": serve_main,
+            "serve": serve_main,
             "consume": consume_main, "provision": provision_main,
             "supervise": supervise_main, "standby": standby_main,
             "trace": trace_main, "chaos": chaos_main,

@@ -39,7 +39,7 @@ preserves the global application order and the per-window
 account-disjointness invariant above yields byte-identical MatchOut —
 which is what lets the planner rebalance aggressively and the tests
 gate on oracle parity WITH migrations observed
-(tests/test_shard_elastic.py, kme-bench --suite shards).
+(tests/test_shard_elastic.py, shard_proof below).
 
 PER-CHIP ASYNC DISPATCH (this round): the shard_map scan above is
 LOCKSTEP — one dispatch, every shard waits for the slowest shard at
@@ -670,8 +670,8 @@ class SeqMeshSession(SeqSession):
           dependency fetch, a full-merge collective at barriers and
           batch-end only; lockstep — every window is a global barrier
           AND a full collective, so T += max-shard cost + S·merge per
-          window. chip_stall_frac derives from this schedule, so the
-          perfgate metric is replay-stable and backend-independent.
+          window. chip_stall_frac derives from this schedule, so it is
+          replay-stable and backend-independent.
         """
         acts = cols["act"]
         aids = cols["aid"]
@@ -1302,3 +1302,77 @@ class SeqMeshSession(SeqSession):
         """Oracle-comparable host dict view, both dispatch modes: the
         stitched global canon through SeqSession's shared mapping."""
         return self._canon_to_export(self.export_canonical_global())
+
+
+def shard_proof(events: int = 4000, shard_counts=(1, 2, 4)) -> dict:
+    """The proof chip_smoke.py's four-chip phase runs (and
+    tests/test_seqmesh.py rehearses on the virtual CPU mesh): the
+    zipf-hot stream (seed 0, 8 symbols x 128 accounts x 128 slots, VMEM
+    books) through SeqMeshSession at every shard count, byte-compared
+    with the scalar fixed-mode oracle. It is fed in slices of 500
+    because rebalancing happens between process_wire calls only, and
+    above one shard migrations are REQUIRED — a placement that never
+    moved proves nothing about _migrate. At the top count every
+    shard's state must sit on a device of its own (async dispatch is
+    parallel only then), and a lockstep control run must produce the
+    same bytes. Raises AssertionError on the first check that fails."""
+    from kme_tpu.oracle import OracleEngine
+    from kme_tpu.workload import zipf_hot_stream
+
+    top = max(shard_counts)
+    if len(jax.devices()) < top:
+        raise RuntimeError(
+            f"shard_proof needs {top} devices, found "
+            f"{len(jax.devices())} (a virtual CPU mesh: XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={top})")
+    msgs = zipf_hot_stream(events, num_symbols=8, num_accounts=128,
+                           seed=0)
+    oracle = OracleEngine("fixed", book_slots=128, max_fills=16)
+    want = [r.wire() for m in msgs for r in oracle.process(m.copy())]
+    cfg = SQ.SeqConfig(lanes=8, slots=128, accounts=128, max_fills=16)
+
+    def run(shards, dispatch):
+        ses = SeqMeshSession(cfg, shards, dispatch=dispatch)
+        got = []
+        for lo in range(0, len(msgs), 500):
+            for per in ses.process_wire(msgs[lo:lo + 500]):
+                got.extend(per)
+        if got != want:
+            raise AssertionError(
+                f"shards={shards} dispatch={ses.dispatch}: MatchOut "
+                f"diverged from the single-chip oracle "
+                f"({sum(a != b for a, b in zip(got, want))} lines + "
+                f"{abs(len(got) - len(want))} length delta)")
+        return ses
+
+    migrations = []
+    top_ses = None
+    for shards in shard_counts:
+        ses = run(shards, "auto")
+        if ses.dispatch != "async":
+            raise AssertionError(
+                f"shards={shards}: dispatch resolved to "
+                f"{ses.dispatch!r}, not async")
+        moved = ses.shard_stats()["migrations"]
+        if shards > 1 and moved <= 0:
+            raise AssertionError(
+                f"shards={shards}: no migrations on the skewed stream "
+                f"— the elastic planner never fired")
+        migrations.append(moved)
+        if shards == top:
+            top_ses = ses
+    placed = [sorted(str(d) for d in st["err"].devices())
+              for st in top_ses._shard_states]
+    if (any(len(p) != 1 for p in placed)
+            or len({p[0] for p in placed}) != top):
+        raise AssertionError(
+            f"shards={top}: per-shard states are not on {top} distinct "
+            f"devices: {placed}")
+    run(top, "lockstep")
+    return {"backend": jax.devices()[0].platform,
+            "device_kind": jax.devices()[0].device_kind,
+            "events": len(msgs), "shard_counts": list(shard_counts),
+            "dispatch": top_ses.dispatch, "parity": "byte-exact",
+            "migrations": migrations,
+            "shard_devices": [p[0] for p in placed],
+            "lockstep_parity": "byte-exact"}

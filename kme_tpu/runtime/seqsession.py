@@ -653,7 +653,7 @@ class SeqSession:
         self._h2d_total_s += dt_st
         if self._n_submit > self._n_collect:
             self._h2d_overlap_s += dt_st
-        # advisory gauges (never perfgate-enforced: pure wall time):
+        # advisory gauges (pure wall time, never enforced):
         # cumulative host cost of the async staging enqueues + the
         # fraction of it hidden under in-flight device compute
         self.telemetry.publish_gauges(

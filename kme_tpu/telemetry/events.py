@@ -48,8 +48,8 @@ journal phase ordinal), so the re-run's duplicate emission deduplicates
 instead of double-counting.
 
 Event emission is always-on but can be globally disabled with
-``KME_EVENTS=0`` (the MatchOut byte-parity escape hatch the prof suite
-exercises); a disabled log swallows emissions without touching disk.
+``KME_EVENTS=0`` (the MatchOut byte-parity escape hatch); a disabled
+log swallows emissions without touching disk.
 """
 
 from __future__ import annotations

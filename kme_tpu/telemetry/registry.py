@@ -113,8 +113,8 @@ class Histogram:
 # Fixed log-spaced buckets: 1 µs doubling up to ~67 s, one overflow
 # bucket. 27 boundaries + overflow = 28 counts; a full histogram is a
 # few hundred bytes, so every stage of the serving pipeline can afford
-# one that is ALWAYS on (the bench's sort-all-samples percentiles need
-# the whole sample vector; this needs O(1) memory and O(1) observe).
+# one that is ALWAYS on (sort-all-samples percentiles need the whole
+# sample vector; this needs O(1) memory and O(1) observe).
 
 LAT_N_BUCKETS = 28
 LAT_BOUNDS = tuple(1e-6 * (1 << i) for i in range(LAT_N_BUCKETS - 1))

@@ -320,9 +320,6 @@ def render(view: dict, width: int = 78) -> list:
         lines.append(
             f"  slo={'OK' if slo_ok else 'BREACH'}"
             + (f" burn={_fmt(burn, 2)}x" if burn is not None else ""))
-    if _gauge(lead, "pipeline_warning"):
-        lines.append("  pipeline_warning: speedup < 1.0 "
-                     "(see measured_overlap_s)")
 
     # degradation row (adaptive overload controller, kme-serve
     # --overload-high-lag): only rendered when the controller is

@@ -7,7 +7,7 @@ attribute (same object, assigned once), and — unlike the old code —
 totals ACCUMULATE across batches; callers snapshot/reset explicitly.
 
 When a TraceRecorder is installed (module-global via install(), as
-`kme-serve --trace-out` and `bench --trace-out` do), every phase span
+`kme-serve --trace-out` does), every phase span
 is also emitted as a Chrome trace event; save() writes the standard
 {"traceEvents": [...]} JSON that chrome://tracing / Perfetto load
 directly.

@@ -433,6 +433,73 @@ def _is_monotonic_name(name: str) -> bool:
             or name.startswith("service_"))
 
 
+# -- stage-level regression attribution (kme-prof --diff) ------------------
+
+# stage -> the window_summary series that witness it: the service's
+# per-stage latency quantiles (lat_*.p99_ms), the host sampling
+# profiler's stage fractions (prof_stage_frac_*) and the per-batch
+# device gauge. A series missing on either side is skipped — the
+# verdict is built from whatever evidence both windows share.
+STAGE_ATTRIBUTION: Dict[str, tuple] = {
+    "parse": ("lat_ingress.p99_ms", "prof_stage_frac_parse"),
+    "plan": ("lat_plan.p99_ms", "prof_stage_frac_plan"),
+    "device": ("lat_device.p99_ms", "prof_stage_frac_dispatch",
+               "prof_stage_frac_collect", "device_ms_per_batch"),
+    "produce": ("lat_produce.p99_ms", "prof_stage_frac_produce"),
+    "e2e": ("lat_e2e.p99_ms",),
+}
+
+
+def attribute_regression(base: Dict[str, float],
+                         cur: Dict[str, float]) -> Dict:
+    """Rank pipeline stages by how much their evidence degraded
+    between two window summaries. Returns {"stages": [...worst
+    first...], "suspect": <stage name or None>}; a stage's score is the
+    worst relative increase among its shared series (1.0 = unchanged)."""
+    stages: List[dict] = []
+    for stage, names in STAGE_ATTRIBUTION.items():
+        evidence = []
+        score = 1.0
+        for name in names:
+            b, c = base.get(name), cur.get(name)
+            if b is None or c is None or b <= 0:
+                continue
+            ratio = c / b
+            evidence.append({"name": name, "baseline": b,
+                             "current": c, "ratio": round(ratio, 4)})
+            score = max(score, ratio)
+        if evidence:
+            stages.append({"stage": stage, "score": round(score, 4),
+                           "evidence": evidence})
+    stages.sort(key=lambda s: -s["score"])
+    # "e2e" restates the symptom, never the cause: only name it when
+    # no concrete stage moved with it
+    suspect = None
+    for s in stages:
+        if s["score"] > 1.05 and s["stage"] != "e2e":
+            suspect = s["stage"]
+            break
+    if suspect is None and stages and stages[0]["score"] > 1.05:
+        suspect = stages[0]["stage"]
+    return {"stages": stages, "suspect": suspect}
+
+
+def format_attribution(att: Dict) -> str:
+    lines = []
+    for s in att["stages"]:
+        mark = "!" if s["stage"] == att["suspect"] else " "
+        ev = ", ".join(f"{e['name']} x{e['ratio']}"
+                       for e in s["evidence"][:3])
+        lines.append(f"{mark} stage {s['stage']:<8s} "
+                     f"x{s['score']:<8g} {ev}")
+    if att["suspect"]:
+        lines.append(f"! attribution: the {att['suspect']} stage moved "
+                     f"the most")
+    else:
+        lines.append("attribution: no stage moved beyond 5%")
+    return "\n".join(lines)
+
+
 # -- digest sidecars --------------------------------------------------------
 
 

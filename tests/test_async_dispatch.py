@@ -142,8 +142,7 @@ def test_wall_feed_parity(cpu_devices):
 def test_h2d_overlap_pipelined_single_chip(cpu_devices):
     """Depth-2 pipelined submit/collect on the single-chip session:
     most H2D staging must land while an earlier batch is still in
-    flight (h2d_overlap_frac >= 0.5 — the serve-path gauge the bench
-    reports advisory-up)."""
+    flight (h2d_overlap_frac >= 0.5 — an advisory serve-path gauge)."""
     from kme_tpu.native import load_library
 
     if load_library() is None:

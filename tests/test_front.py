@@ -223,6 +223,34 @@ def test_cross_account_workload_parity():
     assert rep["ok"], rep["mismatches"][:1]
 
 
+# -- what cross-group routing costs ------------------------------------
+
+
+@pytest.mark.parametrize("ngroups,transfers", [(2, 1250), (4, 2087)])
+def test_cross_shard_transfer_frac(ngroups, transfers):
+    """Transfers the front door injects into the zipf stream (20,000
+    events, 1,024 symbols x 256 accounts, seed 0, prefund 8), from the
+    split alone: router arithmetic with no clock and no RNG, so the
+    counts are exact. 2,087 over 17,999 orders is the 0.116
+    transfers/order a four-group deployment pays; a change to
+    the rendezvous hash, the home-account rule or the chunked
+    reserve->settle policy moves it."""
+    msgs = zipf_symbol_stream(20_000, num_symbols=1024,
+                              num_accounts=256, seed=0)
+    orders = sum(1 for m in msgs if m.action in (op.BUY, op.SELL))
+    assert orders == 17_999
+    per, router = front.split_lines([dumps_order(m) for m in msgs],
+                                    ngroups, prefund=8)
+    assert router.counters["cross_shard_transfers_total"] == transfers
+    assert router.counters["transfer_shortfall_total"] == 0
+    # every counted transfer is a debit leg and a credit leg in the
+    # substreams
+    legs = sum(1 for sub in per for ln in sub
+               if front.is_internal_line(ln)
+               and parse_order(ln).action == op.TRANSFER)
+    assert legs == 2 * transfers
+
+
 # -- transfer dedup under duplicate delivery ---------------------------
 
 

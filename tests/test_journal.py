@@ -206,7 +206,7 @@ def test_fsync_batch_mode_writes_through(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# pipeline-window math (the bench's measured_overlap_s)
+# pipeline-window math (measured_overlap_s)
 
 
 def test_measured_overlap_full_and_none():

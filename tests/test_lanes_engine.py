@@ -193,3 +193,82 @@ def test_lane_transfer_int_min_negation_wraps():
     ]
     ses, ora = assert_lane_parity(msgs)
     assert ora.balances[1] == -(2**31)
+
+
+def test_capacity_envelope_book_full_rejects_per_message(cpu_devices):
+    """H2 policy: overflowing a book side rejects THAT message only —
+    the batch continues and stays oracle-exact (no sticky poison)."""
+    slots = 4
+    cfg = LaneConfig(lanes=2, slots=slots, accounts=8, max_fills=8, steps=8)
+    msgs = [OrderMsg(action=op.CREATE_BALANCE, aid=1),
+            OrderMsg(action=op.TRANSFER, aid=1, size=10_000_000),
+            OrderMsg(action=op.CREATE_BALANCE, aid=2),
+            OrderMsg(action=op.TRANSFER, aid=2, size=10_000_000),
+            OrderMsg(action=op.ADD_SYMBOL, sid=0)]
+    # 6 non-crossing buys on one side: slots 5 and 6 must reject
+    for i in range(slots + 2):
+        msgs.append(OrderMsg(action=op.BUY, oid=100 + i, aid=1, sid=0,
+                             price=10 + i, size=5))
+    # the book still works afterwards: a crossing sell fills the best buy
+    msgs.append(OrderMsg(action=op.SELL, oid=200, aid=2, sid=0,
+                         price=10, size=5))
+
+    ora = OracleEngine("fixed", book_slots=slots, max_fills=8)
+    want = [[r.wire() for r in ora.process(m.copy())] for m in msgs]
+    ses = LaneSession(cfg)
+    got = [[r.wire() for r in recs] for recs in ses.process(msgs)]
+    assert got == want
+    # the overflowing buys were rejected, and only those
+    flat = [ln for recs in got for ln in recs]
+    rejects = [ln for ln in flat if ln.startswith('OUT {"action":7')]
+    assert len(rejects) == 2
+    # the final sell produced fills (stream survived the overflow)
+    assert any(ln.startswith('OUT {"action":5') for ln in flat)
+
+
+def test_capacity_envelope_max_fills_rejects_per_message(cpu_devices):
+    """H3 policy: a taker that would sweep more than max_fills makers is
+    rejected as a unit; makers stay untouched."""
+    E = 2
+    cfg = LaneConfig(lanes=2, slots=16, accounts=8, max_fills=E, steps=8)
+    msgs = [OrderMsg(action=op.CREATE_BALANCE, aid=1),
+            OrderMsg(action=op.TRANSFER, aid=1, size=10_000_000),
+            OrderMsg(action=op.CREATE_BALANCE, aid=2),
+            OrderMsg(action=op.TRANSFER, aid=2, size=10_000_000),
+            OrderMsg(action=op.ADD_SYMBOL, sid=0)]
+    for i in range(E + 1):  # 3 resting sells at one level
+        msgs.append(OrderMsg(action=op.SELL, oid=100 + i, aid=1, sid=0,
+                             price=50, size=1))
+    # sweeping all 3 exceeds max_fills=2 -> reject
+    msgs.append(OrderMsg(action=op.BUY, oid=200, aid=2, sid=0,
+                         price=50, size=3))
+    # sweeping 2 is inside the envelope -> fills
+    msgs.append(OrderMsg(action=op.BUY, oid=201, aid=2, sid=0,
+                         price=50, size=2))
+
+    ora = OracleEngine("fixed", book_slots=16, max_fills=E)
+    want = [[r.wire() for r in ora.process(m.copy())] for m in msgs]
+    ses = LaneSession(cfg)
+    got = [[r.wire() for r in recs] for recs in ses.process(msgs)]
+    assert got == want
+    flat = [ln for recs in got for ln in recs]
+    assert sum(1 for ln in flat if ln.startswith('OUT {"action":7')) == 1
+    assert sum(1 for ln in flat if ln.startswith('OUT {"action":6')) == 2
+
+
+def test_capacity_envelope_zipf_stream_parity(cpu_devices):
+    """A skewed stream that actually overflows small books stays
+    byte-exact vs the enveloped oracle (the BENCH_r02 failure class)."""
+    slots = 8
+    msgs = zipf_symbol_stream(800, num_symbols=4, num_accounts=16, seed=7,
+                              zipf_a=1.5)
+    cfg = LaneConfig(lanes=4, slots=slots, accounts=32, max_fills=16,
+                     steps=16)
+    ora = OracleEngine("fixed", book_slots=slots, max_fills=16)
+    want = [[r.wire() for r in ora.process(m.copy())] for m in msgs]
+    ses = LaneSession(cfg)
+    got = [[r.wire() for r in recs] for recs in ses.process(msgs)]
+    assert got == want
+    flat = [ln for recs in got for ln in recs]
+    # the point of the scenario: overflow actually happened
+    assert any(ln.startswith('OUT {"action":7') for ln in flat)

@@ -15,7 +15,8 @@ from kme_tpu.bridge.broker import (CLS_ADMIN, CLS_DRAIN, CLS_ORDER,
 from kme_tpu.bridge.provision import provision
 from kme_tpu.bridge.service import TOPIC_IN, TOPIC_OUT
 from kme_tpu.wire import (REJ_OVERLOAD, OrderMsg, dumps_order,
-                          rej_record_json)
+                          parse_order, rej_record_json)
+from kme_tpu.workload import STORM_PROFILES, storm_stream, storm_windows
 
 
 def _order(aid=1, oid=100, action=2):
@@ -163,8 +164,6 @@ def test_aimd_backoff_grows_on_shed_halves_in_normal():
 
 
 def test_simulate_overload_deterministic_and_sheds():
-    from kme_tpu.workload import storm_stream, storm_windows
-
     lines = [dumps_order(m) for m in storm_stream(
         "flash-crowd", 1500, num_symbols=8, num_accounts=16, seed=0)]
     wins = storm_windows("flash-crowd", 1500, num_symbols=8,
@@ -176,12 +175,48 @@ def test_simulate_overload_deterministic_and_sheds():
     assert a["admitted"] + a["shed"] == a["total"] == len(lines)
 
 
+# profile -> (symbols, accounts, shed_frac): what the deterministic
+# overload replay sheds of each storm at 4,000 events,
+# seed 0, high_lag 32, drain 2.0 per message — a scale at which every
+# profile's burst overwhelms the modeled drain. No wall clock and no
+# RNG enters, so only a change to the admission policy, the priority
+# classing or a profile's generator can move a figure.
+STORM_SHED = {
+    "payout-storm-wide": (64, 32, 0.015),
+    "flash-crowd": (32, 32, 0.1938),
+    "cancel-storm": (16, 32, 0.0659),
+    "hot-book": (8, 32, 0.1461),
+    "liquidation-cascade": (32, 32, 0.0959),
+}
+
+
+@pytest.mark.parametrize("profile", sorted(STORM_PROFILES))
+def test_storm_profile_shed_frac(profile):
+    from kme_tpu.oracle import OracleEngine
+
+    symbols, accounts, frac = STORM_SHED[profile]
+    lines = [dumps_order(m) for m in storm_stream(
+        profile, 4000, num_symbols=symbols, num_accounts=accounts,
+        seed=0)]
+    wins = storm_windows(profile, 4000, num_symbols=symbols,
+                         num_accounts=accounts)
+    a, b = (simulate_overload(lines, wins,
+                              OverloadController(high_lag=32),
+                              drain_per_msg=2.0) for _ in range(2))
+    assert a["admitted_idx"] == b["admitted_idx"]
+    assert a["shed"] > 0
+    assert round(a["shed_frac"], 4) == frac
+    # shedding is a pure input filter: what survives must still be a
+    # stream the engine can process
+    eng = OracleEngine("fixed")
+    for i in a["admitted_idx"]:
+        eng.process(parse_order(lines[i]))
+
+
 def test_simulate_cancels_shed_strictly_less_than_orders():
     # the acceptance criterion: under a cancel-storm / flash-crowd
     # style mix that sheds, class-0 (cancel/payout) shed rate is
     # STRICTLY below class-2 (new order) shed rate
-    from kme_tpu.workload import storm_stream, storm_windows
-
     for name in ("cancel-storm", "flash-crowd"):
         lines = [dumps_order(m) for m in storm_stream(
             name, 2000, num_symbols=8, num_accounts=16, seed=0)]

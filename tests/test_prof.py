@@ -2,8 +2,8 @@
 TSDB (roundtrip, rotation + sha256 prune, torn-tail recovery, restart
 dedup via sample_seq), the sampling stage profiler, trigger captures
 with kme-trace-resolvable exemplars, the per-backend transfer artifact,
-and stage-level regression attribution (kme-prof --diff / kme-perfgate
---attribute naming a planted slowdown).
+stage-level regression attribution (kme-prof --diff naming a planted
+slowdown), and the planes' invisibility to the MatchOut bytes.
 """
 
 import json
@@ -13,14 +13,15 @@ import time
 
 import pytest
 
-from kme_tpu import perfgate
 from kme_tpu.bridge.broker import InProcessBroker
 from kme_tpu.bridge.provision import provision
-from kme_tpu.bridge.service import TOPIC_IN, MatchService
+from kme_tpu.bridge.service import TOPIC_IN, TOPIC_OUT, MatchService
 from kme_tpu.telemetry.profiler import (StageProfiler, TriggerCapture,
                                         read_transfer_artifact,
                                         write_transfer_artifact)
-from kme_tpu.telemetry.tsdb import (MAGIC, REC_SIZE, TSDB, iter_samples,
+from kme_tpu.telemetry.tsdb import (MAGIC, REC_SIZE, TSDB,
+                                    attribute_regression,
+                                    format_attribution, iter_samples,
                                     query, read_samples, verify_store,
                                     window_summary)
 from kme_tpu.wire import dumps_order
@@ -398,17 +399,17 @@ def _window(p99_device=2.0, p99_e2e=5.0, frac_dispatch=0.3):
 def test_attribution_names_planted_device_regression():
     """Plant a 2x device-stage slowdown (which also moves e2e): the
     verdict must name `device`, never the e2e symptom."""
-    att = perfgate.attribute_regression(
+    att = attribute_regression(
         _window(), _window(p99_device=4.0, p99_e2e=8.5, frac_dispatch=0.55))
     assert att["suspect"] == "device"
     assert att["stages"][0]["stage"] == "device"
     ev = {e["name"]: e["ratio"] for e in att["stages"][0]["evidence"]}
     assert ev["lat_device.p99_ms"] == 2.0
-    txt = perfgate.format_attribution(att)
+    txt = format_attribution(att)
     assert "the device stage moved the most" in txt
 
     # unchanged windows: nobody accused
-    att = perfgate.attribute_regression(_window(), _window())
+    att = attribute_regression(_window(), _window())
     assert att["suspect"] is None
 
 
@@ -433,24 +434,6 @@ def test_kme_prof_diff_names_planted_regression(tmp_path, capsys):
     assert prof_main(["--diff", base, cur, "--json"]) == 0
     att = json.loads(capsys.readouterr().out)
     assert att["suspect"] == "produce"
-
-
-def test_perfgate_attribute_cli_over_bench_artifacts(tmp_path):
-    """kme-perfgate BASELINE CURRENT --attribute over recorded bench
-    detail files: exit 1 + suspect named when a stage moved."""
-    base, cur = str(tmp_path / "b.json"), str(tmp_path / "c.json")
-    for path, dev in ((base, 2.0), (cur, 5.0)):
-        with open(path, "w") as f:
-            json.dump({"metric": "orders_per_sec", "value": 1.0,
-                       "detail": {"device_ms_per_batch": dev,
-                                  "p99_ms": 3.0 + dev,
-                                  "plan_s": 0.1}}, f)
-    rep = str(tmp_path / "att.json")
-    assert perfgate.main([base, cur, "--attribute", "--report", rep]) == 1
-    att = json.load(open(rep))
-    assert att["suspect"] == "device"
-    # clean pair: exit 0, no suspect
-    assert perfgate.main([base, base, "--attribute"]) == 0
 
 
 # ---------------------------------------------------------------------------
@@ -497,22 +480,44 @@ def test_kme_top_history_lines(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# overhead ceiling: the real gate runs in CI at full size
-# (`kme-bench --suite prof`, 3% ceiling); here the same code path runs
-# small with the ceiling relaxed — parity + artifact asserts stay hard
+# the observability planes are invisible to the matched stream
 
 
-def test_bench_prof_smoke(tmp_path, cpu_devices):
-    from kme_tpu.benchmarks import bench_prof
+def test_prof_planes_leave_matchout_byte_identical(tmp_path):
+    """One stream served twice, bare and with every always-on plane
+    (host sampling profiler, heartbeat + TSDB history, transfer
+    artifact, an armed watchpoint): the MatchOut records must be
+    byte-identical (COMPAT.md: the wire contract does not move), and
+    the history must really have been written."""
+    lines = [dumps_order(m) for m in harness_stream(
+        1500, seed=7, num_accounts=64, num_symbols=16, validate=True)]
 
-    rec = bench_prof(events=1500, seed=7, batch=256, repeats=1,
-                     overhead_ceiling=10.0)
-    # byte parity + artifact round-trip are hard asserts INSIDE the
-    # suite; reaching here means both held
-    assert rec["metric"] == "orders_per_sec" and rec["value"] > 0
-    d = rec["detail"]
-    assert d["suite"] == "prof"
-    assert d["tsdb_samples"] > 0
-    assert 0.0 <= d["prof_overhead_frac"] <= 10.0
-    assert set(d["prof_stage_fracs"]) == {
-        "parse", "plan", "dispatch", "collect", "produce"}
+    def serve(**planes):
+        broker = InProcessBroker()
+        provision(broker)
+        for ln in lines:
+            broker.produce(TOPIC_IN, None, ln)
+        svc = MatchService(broker, engine="oracle", compat="fixed",
+                           batch=256, **planes)
+        assert svc.run(
+            max_messages=len(lines), idle_exit=5.0, health_every=0.05,
+            health_file=(str(tmp_path / "serve.health") if planes
+                         else None)) == len(lines)
+        svc.close()
+        out, off = [], 0
+        while True:
+            recs = broker.fetch(TOPIC_OUT, off, 4096)
+            if not recs:
+                return out
+            out.extend((r.key, r.value) for r in recs)
+            off = recs[-1].offset + 1
+
+    store = str(tmp_path / "tsdb")
+    bare = serve()
+    observed = serve(tsdb=store, profile=True,
+                     profile_artifact=str(tmp_path / "xfer.json"),
+                     watch=["balance[1]<0"],
+                     capture_dir=str(tmp_path / "captures"))
+    assert len(bare) > len(lines)
+    assert observed == bare, "profiling altered the MatchOut records"
+    assert sum(1 for _ in read_samples(store, source="serve")) > 0

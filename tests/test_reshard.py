@@ -4,8 +4,7 @@ Pins the contracts the reshard-under-storm drill stands on, all
 in-process so they run in tier-1 time:
 
 - the plan is deterministic and rendezvous-minimal (growing 2→4 only
-  moves keys onto NEW group ids — the moved_key_frac the multihost
-  bench gates);
+  moves keys onto NEW group ids — moved_key_frac);
 - `partition_engines` + settlement legs + a resharded router reproduce
   the single-leader oracle byte-for-byte across the barrier
   (verify_groups_reshard);

@@ -16,7 +16,9 @@ from kme_tpu.engine import seq as SQ
 from kme_tpu.oracle import OracleEngine
 from kme_tpu.runtime.seqsession import SeqSession
 from kme_tpu.wire import OrderMsg
-from kme_tpu.workload import harness_stream, zipf_symbol_stream
+from kme_tpu.workload import (STORM_PROFILES, cancel_heavy_stream,
+                              harness_stream, storm_stream,
+                              zipf_symbol_stream)
 
 CFG = SQ.SeqConfig(lanes=8, slots=128, accounts=128, max_fills=32,
                    batch=128, pos_cap=1 << 11, fill_cap=1 << 12,
@@ -149,6 +151,24 @@ def test_seq_harness_stream_parity():
 
 def test_seq_zipf_stream_parity():
     msgs = zipf_symbol_stream(500, num_symbols=6, num_accounts=24, seed=3)
+    assert_seq_parity(msgs, SQ.SeqConfig(
+        lanes=8, slots=128, accounts=128, max_fills=64, batch=256,
+        pos_cap=1 << 11, fill_cap=1 << 13, probe_max=16))
+
+
+@pytest.mark.parametrize("stream",
+                         ["cancel-heavy"] + sorted(STORM_PROFILES))
+def test_seq_workload_parity(stream):
+    """The served engine on the streams its steady-state tests never
+    send: the cancel/replace mix (about half the events are cancels of
+    resting orders) and the five adversarial storms — PAYOUT+re-ADD
+    barrier bursts, a flooder clique on one symbol, mass cancels, one
+    deep hot book, forced liquidations."""
+    if stream == "cancel-heavy":
+        msgs = cancel_heavy_stream(500, 6, 24, seed=5)
+    else:
+        msgs = storm_stream(stream, 500, num_symbols=8,
+                            num_accounts=24, seed=5)
     assert_seq_parity(msgs, SQ.SeqConfig(
         lanes=8, slots=128, accounts=128, max_fills=64, batch=256,
         pos_cap=1 << 11, fill_cap=1 << 13, probe_max=16))

@@ -13,7 +13,7 @@ import pytest
 
 from kme_tpu.engine import seq as SQ
 from kme_tpu.oracle import OracleEngine
-from kme_tpu.parallel.seqmesh import SeqMeshSession
+from kme_tpu.parallel.seqmesh import SeqMeshSession, shard_proof
 from kme_tpu.runtime.seqsession import SeqSession
 from kme_tpu.workload import zipf_symbol_stream
 
@@ -87,3 +87,13 @@ def test_seqmesh_window_invariant(cpu_devices):
                 a = int(cols["aid"][k])
                 assert seen.setdefault(a, s) == s, \
                     f"account {a} on two shards in window {w}"
+
+
+def test_shard_proof_cpu(cpu_devices):
+    """The proof chip_smoke.py --chips 4 runs on the chips, here at
+    shards 1/2 on a short stream: it raises on any divergence, a
+    placement that never migrated, shard states sharing a device or a
+    lockstep leg that differs."""
+    proof = shard_proof(600, (1, 2))
+    assert proof["shard_counts"] == [1, 2]
+    assert proof["migrations"][1] > 0

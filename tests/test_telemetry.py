@@ -142,7 +142,7 @@ def test_trace_recorder(tmp_path):
 
 # ---------------------------------------------------------------------------
 # session integration: the legacy phase keys are load-bearing
-# (benchmarks.py, tests/test_bench_smoke.py) and must ACCUMULATE across
+# (bridge/service.py's stage attribution) and must ACCUMULATE across
 # batches — the bug this PR fixes was SeqSession overwriting them
 
 

@@ -93,8 +93,7 @@ def test_render_shows_stages_slo_and_supervisor():
     view = build_view({
         "t": 1.0,
         "leader": _node(records=10,
-                        gauges={"slo_ok": 0, "slo_burn_rate": 3.5,
-                                "pipeline_warning": 1},
+                        gauges={"slo_ok": 0, "slo_burn_rate": 3.5},
                         lats=lats,
                         hb={"epoch": 2, "offset": 9,
                             "degraded": "slo burn 3.5x"}),
@@ -107,7 +106,6 @@ def test_render_shows_stages_slo_and_supervisor():
     assert "epoch=2" in text and "offset=9" in text
     assert "DEGRADED: slo burn 3.5x" in text
     assert "slo=BREACH burn=3.50x" in text
-    assert "pipeline_warning" in text
     assert "e2e" in text and "ingress" in text and "9.500" in text
     assert "applied=8" in text and "lag=1" in text
     assert "restarts=1" in text and "kind=leader" in text
