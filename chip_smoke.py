@@ -268,6 +268,22 @@ def serve_phase(name, state_name, kids, out, env, serve_args, feed,
             "wall_s": round(wall, 3), "state": state, "log": log}
 
 
+def snapshot_file(state, offset):
+    """What the snapshot at `offset` is on disk: its bytes and, from
+    its meta, the version and the sections written by their live
+    entries (runtime/checkpoint.py:save_seq_session)."""
+    import numpy as np
+
+    path = os.path.join(state, f"ckpt-{offset}.npz")
+    with np.load(path) as z:
+        meta = json.loads(bytes(z["meta"]).decode())
+    layout = meta.get("layout", {})
+    return {"bytes": os.path.getsize(path), "version": meta["version"],
+            "sparse": layout.get("sparse", []),
+            "live_slots": layout.get("live_slots"),
+            "live_positions": layout.get("live_positions")}
+
+
 def verify_log(name, state, oracle, expect_in=None):
     """The durable MatchOut log against the oracle run over the durable
     MatchIn log — bytes, order, stamps."""
@@ -455,6 +471,8 @@ def main(argv=None) -> int:
               f"{len(scans_a)} scan entries")
         done(a)
 
+        # read before B's own snapshots prune it
+        a_snapshot = snapshot_file(a["state"], cut)
         b = serve_phase("B", "AB", kids, out, env, ab_args,
                         frame_feeder(msgs, cut, len(msgs)), 2,
                         args.allow_cpu)
@@ -463,6 +481,10 @@ def main(argv=None) -> int:
               not in b["log"],
               "B: did not resume from A's snapshot (started over?)")
         b["resumed_at"] = int(m.group(1))
+        b["resumed_from"] = a_snapshot
+        check(b["resumed_from"]["sparse"] == ["books", "positions"],
+              f"B: A's snapshot was not written by its live entries "
+              f"({b['resumed_from']})")
         check(b["resumed_at"] == cut > 0,
               f"B: resumed at {b['resumed_at']}, A committed {cut}")
         check(b["offset"] == len(msgs),
@@ -546,7 +568,8 @@ def main(argv=None) -> int:
         "wall_s": round(time.monotonic() - T_START, 1),
         "phases": {p["phase"]: {k: p.get(k) for k in (
             "messages", "records", "parity", "start_to_first_matchout_s",
-            "wall_s", "resumed_at", "scan_cache", "rej_capacity",
+            "wall_s", "resumed_at", "resumed_from", "scan_cache",
+            "rej_capacity",
             "max_book_depth", "duplicate_stamps", "shard_devices")
             if p.get(k) is not None} for p in phases},
     }

@@ -1526,6 +1526,9 @@ class MatchService:
             fetched = getattr(self._session, "metrics_fetch_bytes", None)
             if fetched is not None:
                 gauges["metrics_fetch_bytes"] = fetched
+            # the newest snapshot's size and live counts, from the
+            # first one a fixed-mode SeqSession writes
+            gauges.update(getattr(self._session, "snapshot_gauges", {}))
         gauges["serve_loop_s"] = round(_t.perf_counter() - self._loop_t0, 6)
         t.counter("lane_switches",
                   "HBM book-cache lane switches the seq kernel made "

@@ -1659,6 +1659,10 @@ def unpack_out(cfg: SeqConfig, plane: np.ndarray, n: int) -> dict:
     return res
 
 
+def _j64(lo, hi):
+    return (lo.astype(np.int64) & 0xFFFFFFFF) | (hi.astype(np.int64) << 32)
+
+
 def export_java(cfg: SeqConfig, state) -> dict:
     """Host view of a JAVA-mode state: positions keyed by the 128-bit
     (ka, kb) pairs exactly as the java oracle's dict (real keys
@@ -1676,19 +1680,15 @@ def export_java(cfg: SeqConfig, state) -> dict:
                 | (hi.reshape(S, 2, NR * LN)[:, :, :N].astype(np.int64)
                    << 32))
 
-    def j64(lo, hi):
-        return ((lo.astype(np.int64) & 0xFFFFFFFF)
-                | (hi.astype(np.int64) << 32))
-
     live = h["hstate"].reshape(-1) == 1
-    ka = j64(h["hka_lo"].reshape(-1), h["hka_hi"].reshape(-1))[live]
-    kb = j64(h["hkb_lo"].reshape(-1), h["hkb_hi"].reshape(-1))[live]
-    amt = j64(h["ha_lo"].reshape(-1), h["ha_hi"].reshape(-1))[live]
-    av = j64(h["hv_lo"].reshape(-1), h["hv_hi"].reshape(-1))[live]
+    ka = _j64(h["hka_lo"].reshape(-1), h["hka_hi"].reshape(-1))[live]
+    kb = _j64(h["hkb_lo"].reshape(-1), h["hkb_hi"].reshape(-1))[live]
+    amt = _j64(h["ha_lo"].reshape(-1), h["ha_hi"].reshape(-1))[live]
+    av = _j64(h["hv_lo"].reshape(-1), h["hv_hi"].reshape(-1))[live]
     positions = {(int(a), int(b)): (int(x), int(y))
                  for a, b, x, y in zip(ka, kb, amt, av)}
     A = cfg.accounts
-    bal = j64(h["bal_lo"].reshape(-1)[:A], h["bal_hi"].reshape(-1)[:A])
+    bal = _j64(h["bal_lo"].reshape(-1)[:A], h["bal_hi"].reshape(-1)[:A])
     return {
         "positions": positions,
         "bal": bal,
@@ -1738,6 +1738,41 @@ def values_to_pos(cfg: SeqConfig, both: np.ndarray) -> np.ndarray:
     return pos
 
 
+def _small_sections(cfg: SeqConfig, h: dict) -> dict:
+    """The canonical sections that are S or A long."""
+    S, A = cfg.lanes, cfg.accounts
+    return {
+        "seq": h["seqc"].reshape(-1)[:S].astype(np.int32),
+        "book_exists": h["bex"].reshape(-1)[:S] != 0,
+        "bal": _j64(h["bal_lo"].reshape(-1)[:A], h["bal_hi"].reshape(-1)[:A]),
+        "bal_used": h["bal_u"].reshape(-1)[:A] != 0,
+        "err": np.int32(h["err"].reshape(-1)[0]),
+    }
+
+
+def _dense_books(cfg: SeqConfig, h: dict) -> dict:
+    S, N, NR = cfg.lanes, cfg.slots, cfg.nr
+
+    def planes2slot(v):
+        return v.reshape(S, 2, NR * LN)[:, :, :N]
+
+    slot_size = planes2slot(h["bs"]).astype(np.int32)
+    return {
+        "slot_oid": _j64(planes2slot(h["bo_lo"]), planes2slot(h["bo_hi"])),
+        "slot_aid": planes2slot(h["ba"]).astype(np.int32),
+        "slot_price": planes2slot(h["bp"]).astype(np.int32),
+        "slot_size": slot_size,
+        "slot_seq": planes2slot(h["bq"]).astype(np.int32),
+        "slot_used": slot_size > 0,
+    }
+
+
+def _dense_positions(cfg: SeqConfig, h: dict) -> dict:
+    pos_amt, pos_avail = (v[:, :cfg.accounts].reshape(-1)
+                          for v in pos_to_values(cfg, h["pos"]))
+    return {"pos_amt": pos_amt, "pos_avail": pos_avail}
+
+
 def export_canonical(cfg: SeqConfig, state) -> dict:
     """Device planes -> the canonical snapshot layout the lanes engine
     checkpoints use (slot_* (S,2,N) i64/i32/bool, flat positions s64,
@@ -1748,40 +1783,113 @@ def export_canonical(cfg: SeqConfig, state) -> dict:
         raise ValueError(
             "java-mode state has no fixed-layout canonical export — "
             "snapshot via runtime/javasnap.export_seqjava")
-    S, N, A, NR = cfg.lanes, cfg.slots, cfg.accounts, cfg.nr
     h = {k: np.asarray(state[k]) for k in _STATE_KEYS}
-
-    def planes2slot(lo, hi=None):
-        v = lo.reshape(S, 2, NR * LN)[:, :, :N]
-        if hi is None:
-            return v
-        return ((v.astype(np.int64) & 0xFFFFFFFF)
-                | (hi.reshape(S, 2, NR * LN)[:, :, :N].astype(np.int64)
-                   << 32))
-
-    slot_size = planes2slot(h["bs"]).astype(np.int32)
-    used = slot_size > 0
-    pos_amt, pos_avail = (v[:, :A].reshape(-1)
-                          for v in pos_to_values(cfg, h["pos"]))
-    seqc = h["seqc"].reshape(-1)[:S].astype(np.int32)
-    bal = ((h["bal_lo"].reshape(-1)[:A].astype(np.int64) & 0xFFFFFFFF)
-           | (h["bal_hi"].reshape(-1)[:A].astype(np.int64) << 32))
     return {
-        "slot_oid": planes2slot(h["bo_lo"], h["bo_hi"]),
-        "slot_aid": planes2slot(h["ba"]).astype(np.int32),
-        "slot_price": planes2slot(h["bp"]).astype(np.int32),
-        "slot_size": slot_size,
-        "slot_seq": planes2slot(h["bq"]).astype(np.int32),
-        "slot_used": used,
-        "seq": seqc,
-        "book_exists": h["bex"].reshape(-1)[:S] != 0,
-        "pos_amt": pos_amt,
-        "pos_avail": pos_avail,
-        "bal": bal,
-        "bal_used": h["bal_u"].reshape(-1)[:A] != 0,
-        "err": np.int32(h["err"].reshape(-1)[0]),
+        **_dense_books(cfg, h),
+        **_small_sections(cfg, h),
+        **_dense_positions(cfg, h),
         "metrics": None,  # counters are host-accumulated in SeqSession
     }
+
+
+# the two sections of the canonical layout that scale with capacity,
+# and the keys each holds when it is written by its live entries: an
+# index into the dense section's flat space, then one value per entry
+SPARSE_SECTIONS = {
+    "books": ("slot_idx", "slot_oid", "slot_aid", "slot_price",
+              "slot_size", "slot_seq"),
+    "positions": ("pos_idx", "pos_amt", "pos_avail"),
+}
+# bytes of one entry, dense and live (an i64 index more, no `used` flag)
+_SLOT_DENSE_B, _SLOT_LIVE_B = 8 + 4 * 4 + 1, 8 + 8 + 4 * 4
+_POS_DENSE_B, _POS_LIVE_B = 2 * 8, 8 + 2 * 8
+
+
+def export_snapshot(cfg: SeqConfig, state):
+    """Device planes -> (canon, layout): export_canonical's state with
+    each SPARSE_SECTIONS section given by its LIVE entries where that
+    takes fewer bytes than the dense section, and densely (as
+    export_canonical gives it) where it does not — the occupancy
+    decides, section by section. `layout` names the sparse sections,
+    the dense shapes densify_canonical restores them to, and the live
+    counts. A slot is live where `bs > 0` (the rule import_canonical
+    and build_seq_occupancy apply; what a freed slot last held is not
+    state); a position where ANY of its four words is non-zero (an
+    amount of 0 with an available balance is state). No dense canonical
+    array is built for a sparse section: 16.7 M slots holding 60,000
+    orders cost one pass over `bs` and six gathers of 60,000."""
+    if cfg.compat != "fixed":
+        raise ValueError("java-mode state snapshots via "
+                         "runtime/javasnap.export_seqjava")
+    S, N, A = cfg.lanes, cfg.slots, cfg.accounts
+    h = jax.device_get({k: state[k] for k in _STATE_KEYS if k != "dep"})
+    canon = _small_sections(cfg, h)
+    layout = {"slot_shape": [S, 2, N], "pos_size": S * A, "sparse": []}
+
+    # books: slots % 128 == 0, so a plane's flat order (lane, side, row,
+    # column) IS the canonical (S, 2, N) flat order and no row is padding
+    idx = np.flatnonzero(h["bs"].reshape(-1) > 0)
+    layout["live_slots"] = int(idx.size)
+    if idx.size * _SLOT_LIVE_B < S * 2 * N * _SLOT_DENSE_B:
+        def at(k):
+            return h[k].reshape(-1)[idx]
+
+        canon.update(slot_idx=idx, slot_oid=_j64(at("bo_lo"), at("bo_hi")),
+                     slot_aid=at("ba"), slot_price=at("bp"),
+                     slot_size=at("bs"), slot_seq=at("bq"))
+        layout["sparse"].append("books")
+    else:
+        canon.update(_dense_books(cfg, h))
+
+    # positions: `pos` is [lane, tile, half, value, column]; account
+    # a of a lane is (tile, half, column) = (a >> 8, a >> 7 & 1, a & 127)
+    # and, where A is no multiple of a tile's 256, the lane's last
+    # tile ends in padding
+    PTL = cfg.pos_tiles_per_lane
+    rows = h["pos"].reshape(S, PTL, 2, 4, LN)
+    lane, acct = np.divmod(np.flatnonzero(rows.any(axis=3)),
+                           PTL * POS_TILE_ACCOUNTS)
+    lane, acct = lane[acct < A], acct[acct < A]
+    layout["live_positions"] = int(lane.size)
+    if lane.size * _POS_LIVE_B < S * A * _POS_DENSE_B:
+        def word(k):
+            return rows[lane, acct >> 8, (acct >> 7) & 1, k, acct & (LN - 1)]
+
+        canon.update(pos_idx=lane * A + acct,
+                     pos_amt=_j64(word(0), word(1)),
+                     pos_avail=_j64(word(2), word(3)))
+        layout["sparse"].append("positions")
+    else:
+        canon.update(_dense_positions(cfg, h))
+    return canon, layout
+
+
+def densify_canonical(canon: dict, layout: dict) -> dict:
+    """Inverse of export_snapshot's encoding: every section `layout`
+    names as sparse scattered into zeros of its dense shape (and
+    `slot_used` set at the live slots), so that what comes out is the
+    canonical dict import_canonical and the lanes engine read."""
+    out = {k: v for k, v in canon.items()
+           if k not in ("slot_idx", "pos_idx")}
+
+    def scatter(idx, size, key, shape):
+        full = np.zeros(size, canon[key].dtype)
+        full[idx] = canon[key]
+        return full.reshape(shape)
+
+    if "books" in layout["sparse"]:
+        shape = tuple(layout["slot_shape"])
+        size, idx = int(np.prod(shape)), np.asarray(canon["slot_idx"])
+        for key in SPARSE_SECTIONS["books"][1:]:
+            out[key] = scatter(idx, size, key, shape)
+        used = np.zeros(size, bool)
+        used[idx] = True
+        out["slot_used"] = used.reshape(shape)
+    if "positions" in layout["sparse"]:
+        size, idx = int(layout["pos_size"]), np.asarray(canon["pos_idx"])
+        for key in SPARSE_SECTIONS["positions"][1:]:
+            out[key] = scatter(idx, size, key, (size,))
+    return out
 
 
 # what build_seq_occupancy's vector holds, in order

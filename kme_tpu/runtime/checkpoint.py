@@ -213,10 +213,14 @@ def _prune(ckpt_dir: str, pattern, keep: Optional[int] = None) -> None:
 
 
 def _load_file(path: str):
-    data = np.load(path)
-    if "digest" in data.files:
+    """(arrays, meta) of one .npz snapshot, digest-verified over the
+    file as written; the arrays in the DENSE canonical layout whatever
+    the file's encoding, so every reader sees one form."""
+    with np.load(path) as npz:
+        data = {k: npz[k] for k in npz.files}
+    if "digest" in data:
         want = bytes(data["digest"]).decode()
-        got = _payload_digest({k: data[k] for k in data.files})
+        got = _payload_digest(data)
         if got != want:
             raise ValueError(
                 f"content digest mismatch in {path} (stored "
@@ -227,9 +231,15 @@ def _load_file(path: str):
     # and restore into EITHER engine (cross-engine restore); "seqjava"
     # is the java-mode canonical form (runtime/javasnap.py), restorable
     # into SeqSession(compat='java') and convertible to/from the native
-    # engine's dump
-    if meta.get("version") != 1 or meta.get("kind") not in (
-            "lanes", "seq", "seqjava"):
+    # engine's dump. Version 2 is a "seq" snapshot with a section given
+    # by its live entries (meta "layout"): a binary that knows only
+    # version 1 refuses it here and falls back to an older file
+    version, kind = meta.get("version"), meta.get("kind")
+    if version == 2 and kind == "seq":
+        from kme_tpu.engine import seq as SQ
+
+        data = SQ.densify_canonical(data, meta["layout"])
+    elif version != 1 or kind not in ("lanes", "seq", "seqjava"):
         raise ValueError(f"unsupported snapshot {path}")
     return data, meta
 
@@ -288,7 +298,7 @@ def _restore_one(path: str, shards: Optional[int], width: Optional[int]):
             state[k] = v  # recreated empty (drained at snapshot)
             continue
         if k == "metrics":
-            if k not in data.files:
+            if k not in data:
                 state[k] = v  # pure observability counter: pre-metrics
                 continue      # snapshots restore with fresh zeros
             arr = np.asarray(data[k])
@@ -303,7 +313,7 @@ def _restore_one(path: str, shards: Optional[int], width: Optional[int]):
                         if isinstance(v, tuple) else jnp.asarray(arr))
             continue
         if k == "hist":
-            if k not in data.files:
+            if k not in data:
                 state[k] = v  # pure observability: pre-histogram
                 continue      # snapshots restore with fresh zeros
             arr = np.asarray(data[k])
@@ -365,17 +375,24 @@ def _restore_one(path: str, shards: Optional[int], width: Optional[int]):
     return ses
 
 
-def _snapshot_export(session) -> dict:
+def _snapshot_export(session):
     """The device -> host half of a seq snapshot (span
-    `snapshot_export` of the session's timer)."""
+    `snapshot_export` of the session's timer): (arrays, layout), with
+    `layout` None where the arrays are dense throughout."""
+    from kme_tpu.runtime.seqsession import SeqSession
+
     with session.timer.phase("snapshot_export"):
         if session.cfg.compat == "java":
             from kme_tpu.runtime.javasnap import export_seqjava
 
-            return export_seqjava(session)
+            return export_seqjava(session), None
         from kme_tpu.engine import seq as SQ
 
-        return SQ.export_canonical(session.cfg, session.state)
+        if type(session) is SeqSession:
+            return SQ.export_snapshot(session.cfg, session.state)
+        # a subclass keeps its state elsewhere (SeqMeshSession: sharded
+        # across devices) and its dense export
+        return SQ.export_canonical(session.cfg, session.state), None
 
 
 def save_seq_session(ckpt_dir: str, session, offset: int,
@@ -384,15 +401,20 @@ def save_seq_session(ckpt_dir: str, session, offset: int,
     """Snapshot a SeqSession at input offset `offset` in the SAME
     canonical layout as lanes snapshots (slot_* / flat s64 positions /
     bal), so snapshots restore across ENGINES as well as across
-    shard/width topologies."""
+    shard/width topologies. The books and the positions are each
+    written by their live entries where that is the smaller encoding
+    (engine/seq.py:export_snapshot; _load_file densifies), so a file's
+    size follows what is live and not the configured capacity."""
     if session.cfg.compat == "java":
         return _save_seqjava(ckpt_dir, session, offset, keep=keep,
                              extra=extra)
     os.makedirs(ckpt_dir, exist_ok=True)
-    canon = _snapshot_export(session)
+    canon, layout = _snapshot_export(session)
+    sparse = layout["sparse"] if layout else []
     r = session.router
     meta = {
-        "version": 1,
+        # a file with no sparse section IS a version-1 file
+        "version": 2 if sparse else 1,
         "kind": "seq",
         "offset": int(offset),
         "cfg": dataclasses.asdict(session.cfg),
@@ -405,6 +427,8 @@ def save_seq_session(ckpt_dir: str, session, offset: int,
         "width": 0,
         "shards": 1,
     }
+    if sparse:
+        meta["layout"] = layout
     if extra:
         meta["extra"] = dict(extra)
     payload = {k: v for k, v in canon.items()
@@ -415,7 +439,15 @@ def save_seq_session(ckpt_dir: str, session, offset: int,
     payload["meta"] = np.frombuffer(
         json.dumps(meta).encode(), dtype=np.uint8)
     with session.timer.phase("snapshot_write"):
-        return _atomic_savez(ckpt_dir, offset, payload, keep=keep)
+        path = _atomic_savez(ckpt_dir, offset, payload, keep=keep)
+    if layout:
+        session.snapshot_gauges = {
+            "snapshot_bytes": os.path.getsize(path),
+            "snapshot_live_slots": layout["live_slots"],
+            "snapshot_live_positions": layout["live_positions"],
+            "snapshot_sparse_sections": len(sparse),
+        }
+    return path
 
 
 def _save_seqjava(ckpt_dir: str, session, offset: int,
@@ -427,7 +459,7 @@ def _save_seqjava(ckpt_dir: str, session, offset: int,
     orders with direction tags and bucket seq, balances, and the
     router id maps."""
     os.makedirs(ckpt_dir, exist_ok=True)
-    snap = _snapshot_export(session)
+    snap, _ = _snapshot_export(session)
     meta = {
         "version": 1,
         "kind": "seqjava",
@@ -450,7 +482,7 @@ def _save_seqjava(ckpt_dir: str, session, offset: int,
 
 
 def _seqjava_snap_from_file(data, meta) -> dict:
-    snap = {k: np.asarray(data[k]) for k in data.files if k != "meta"}
+    snap = {k: v for k, v in data.items() if k != "meta"}
     snap["aid_idx"] = {int(k): int(v) for k, v in meta["aid_idx"]}
     snap["sid_lane"] = {int(k): int(v) for k, v in meta["sid_lane"]}
     snap["oid_sid"] = {int(k): int(v) for k, v in meta["oid_sid"]}
@@ -533,7 +565,7 @@ def _restore_seq(data, meta, cfg):
                 accounts=-(-int(mc["accounts"]) // 128) * 128,
                 max_fills=int(mc["max_fills"]),
                 hbm_books=slots > 512)
-    canon = {k: np.asarray(data[k]) for k in data.files if k != "meta"}
+    canon = {k: v for k, v in data.items() if k != "meta"}
     canon.setdefault("err", np.int32(0))
     if explicit_cfg:
         # service resume: the matching ENVELOPE must not change across

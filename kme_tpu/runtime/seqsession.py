@@ -483,6 +483,12 @@ class SeqSession:
         # bytes metrics() has brought device -> host (cumulative; the
         # serve loop publishes it as gauge `metrics_fetch_bytes`)
         self.metrics_fetch_bytes = 0
+        # what the newest fixed-mode snapshot held (set by
+        # runtime/checkpoint.py:save_seq_session; the serve loop
+        # publishes them as gauges): `snapshot_bytes` of the file,
+        # `snapshot_live_slots` / `snapshot_live_positions` in it, and
+        # `snapshot_sparse_sections` (0-2) written by their live entries
+        self.snapshot_gauges: Dict[str, int] = {}
 
     # ------------------------------------------------------------------
 

@@ -709,3 +709,352 @@ def test_oldest_retained_offset_tracks_pruning(tmp_path):
     assert ck.oldest_retained_offset(d) == 32
     ck.save_oracle(d, ora, 192, keep=2)                # prunes 64
     assert ck.oldest_retained_offset(d) == 32          # npz untouched
+
+
+# ---------------------------------------------------------------------------
+# fixed-mode seq snapshots: the books and the positions each written by
+# their live entries where that is the smaller encoding
+# (engine/seq.py:export_snapshot), densified by the one loader
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+SMALL = dict(lanes=8, slots=128, accounts=128, max_fills=16)
+
+
+def _seq_session(state=None, **shape):
+    from kme_tpu.engine import seq as SQ
+    from kme_tpu.runtime.seqsession import SeqSession
+
+    ses = SeqSession(SQ.SeqConfig(**shape))
+    if state is not None:
+        ses.state = SQ.import_canonical(ses.cfg, state)
+    return ses
+
+
+def _random_canon(shape, book_load, pos_load, amount=True, seed=5):
+    """A canonical state with those shares of its slots and positions
+    live — and something in EVERY word of every dead slot, as a slot
+    freed by a fill or a cancel keeps what it held."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    S, N, A = shape["lanes"], shape["slots"], shape["accounts"]
+    used = rng.random((S, 2, N)) < book_load
+    live = rng.random(S * A) < pos_load
+
+    def words(hi, shape, dtype):
+        return rng.integers(1, hi, shape).astype(dtype)
+
+    return {
+        "slot_oid": words(1 << 53, (S, 2, N), np.int64),
+        "slot_aid": words(A, (S, 2, N), np.int32),
+        "slot_price": words(126, (S, 2, N), np.int32),
+        "slot_size": words(1000, (S, 2, N), np.int32),
+        "slot_seq": words(1 << 20, (S, 2, N), np.int32),
+        "slot_used": used,
+        "seq": words(1 << 20, S, np.int32),
+        "book_exists": rng.random(S) < 0.7,
+        # an amount of 0 beside an available balance is a position too
+        "pos_amt": np.where(live & amount, rng.integers(-10**12, 10**12,
+                                                        S * A), 0),
+        "pos_avail": np.where(live, words(10**12, S * A, np.int64), 0),
+        "bal": rng.integers(-10**15, 10**15, A),
+        "bal_used": rng.random(A) < 0.5,
+        "err": np.int32(0),
+    }
+
+
+# name -> (shape, state or None for a fresh session, sparse sections)
+SPARSE_CASES = {
+    "hbm-books": (dict(lanes=4, slots=1024, accounts=256, max_fills=16,
+                       hbm_books=True), (0.02, 0.05), 2),
+    # 128 accounts in tiles of 256: half of every position tile is
+    # padding (a book plane has none: slots % 128 == 0)
+    "vmem-books-padded-tiles": (SMALL, (0.1, 0.1), 2),
+    "empty": (SMALL, None, 2),
+    "amount-0-available-not": (SMALL, (0.1, 0.1, False), 2),
+    "past-the-break-even": (SMALL, (0.9, 0.8), 0),
+    "full-books-thin-positions": (SMALL, (1.0, 0.01), 1),
+}
+
+
+@pytest.mark.parametrize("case", SPARSE_CASES)
+def test_sparse_snapshot_loads_as_the_canonical_state(case, tmp_path):
+    """Whatever the encoding, _load_file hands on export_canonical's
+    state: equal on every live slot and position, zero on every dead
+    one; a section past its break-even comes out dense, as the parent
+    wrote it (version 1, readable by an older binary)."""
+    import json
+
+    import numpy as np
+
+    from kme_tpu.engine import seq as SQ
+
+    shape, loads, n_sparse = SPARSE_CASES[case]
+    state = None if loads is None else _random_canon(shape, *loads)
+    ses = _seq_session(state, **shape)
+    path = ck.save_seq_session(str(tmp_path), ses, 7)
+    want = SQ.export_canonical(ses.cfg, ses.state)
+    used = want["slot_used"]
+    if loads is not None:       # what a dense file would have carried
+        assert want["slot_oid"][~used].all() and used.any()
+        assert want["pos_avail"].any()
+    if case == "amount-0-available-not":
+        assert not want["pos_amt"].any()
+
+    with np.load(path) as z:
+        raw = {k: z[k] for k in z.files}
+    meta = json.loads(bytes(raw["meta"]).decode())
+    sparse = meta.get("layout", {}).get("sparse", [])
+    assert len(sparse) == n_sparse
+    assert meta["version"] == (2 if sparse else 1)
+    assert ("slot_idx" in raw) == ("books" in sparse) \
+        == ("slot_used" not in raw)
+    assert ("pos_idx" in raw) == ("positions" in sparse)
+    if "books" in sparse:
+        assert raw["slot_idx"].tolist() == np.flatnonzero(used).tolist()
+        assert all(raw[k].shape == raw["slot_idx"].shape
+                   for k in SQ.SPARSE_SECTIONS["books"])
+    if "positions" in sparse:
+        assert raw["pos_idx"].tolist() == np.flatnonzero(
+            (want["pos_amt"] != 0) | (want["pos_avail"] != 0)).tolist()
+
+    data, meta = ck._load_file(path)
+    for k, v in want.items():
+        if v is None:
+            continue
+        v = np.asarray(v)
+        assert data[k].shape == v.shape and data[k].dtype == v.dtype, k
+        if k.startswith("slot_") and "books" in sparse:
+            assert np.array_equal(data[k][used], v[used]), k
+            assert not data[k][~used].any(), k
+        else:
+            assert np.array_equal(data[k], v), k
+    assert ses.snapshot_gauges == {
+        "snapshot_bytes": os.path.getsize(path),
+        "snapshot_live_slots": int(used.sum()),
+        "snapshot_live_positions": int(
+            ((want["pos_amt"] != 0) | (want["pos_avail"] != 0)).sum()),
+        "snapshot_sparse_sections": n_sparse}
+
+
+def _reuse_stream():
+    """Books that fill, thin out by cancels and fills, and fill again:
+    `cut` is a point where every side has freed slots that still hold
+    the dead order's words; what follows rests new orders in those
+    slots at the old makers' prices (time priority across the cut),
+    sweeps old and new makers together and cancels live, dead and
+    unknown oids."""
+    import kme_tpu.opcodes as op
+    from kme_tpu.wire import OrderMsg
+
+    accounts, symbols = 16, 4
+    msgs = []
+    for a in range(accounts):
+        msgs += [OrderMsg(action=op.CREATE_BALANCE, aid=a),
+                 OrderMsg(action=op.TRANSFER, aid=a, size=10**7)]
+    msgs += [OrderMsg(action=op.ADD_SYMBOL, sid=s) for s in range(symbols)]
+    oid = 1000
+    resting = {s: [] for s in range(symbols)}
+
+    def order(action, aid, sid, price, size):
+        nonlocal oid
+        oid += 1
+        msgs.append(OrderMsg(action=action, oid=oid, aid=aid % accounts,
+                             sid=sid, price=price, size=size))
+        return oid, aid % accounts
+
+    def cancel(orders):
+        msgs.extend(OrderMsg(action=op.CANCEL, oid=o, aid=a)
+                    for o, a in orders)
+
+    def rest(s, k, base):
+        for i in range(k):
+            resting[s].append(order(op.SELL, base + i, s, 60 + i % 30, 5))
+            resting[s].append(order(op.BUY, base + i + 1, s, 40 - i % 30,
+                                    5))
+
+    def thin(s):
+        cancel(resting[s][::3])
+        order(op.BUY, 3, s, 63, 37)      # sweeps 60..63, one partly
+        order(op.SELL, 5, s, 38, 23)
+
+    for s in range(symbols):
+        rest(s, 45, s)
+        thin(s)
+    cut = len(msgs)
+    for s in range(symbols):
+        rest(s, 30, s + 7)               # into the freed slots
+        order(op.BUY, 9, s, 70, 160)     # old and new makers, in order
+        order(op.SELL, 11, s, 30, 160)
+        cancel(resting[s][:12])          # live, cancelled and filled
+        cancel([(7, 0), resting[s][13][::-1]])      # unknown; not its own
+        thin(s)
+    return msgs, cut
+
+
+def _exactly_once(tmp_path, name, msgs):
+    broker = InProcessBroker(persist_dir=str(tmp_path / (name + "-log")))
+    provision(broker)
+    for m in msgs:
+        broker.produce(TOPIC_IN, None, dumps_order(m))
+    kw = dict(engine="seq", compat="fixed", symbols=8, accounts=128,
+              batch=128, checkpoint_dir=str(tmp_path / (name + "-ck")),
+              exactly_once=True, checkpoint_every=10**9,
+              **{k: SMALL[k] for k in ("slots", "max_fills")})
+    return broker, kw
+
+
+def test_resume_from_sparse_snapshot_reuses_freed_slots(tmp_path):
+    """A dense file carried what a freed slot last held and the sparse
+    one restores zeros there: served past a restore through orders that
+    take those slots, MatchOut is byte-equal to the uninterrupted run
+    and to the oracle, with no stamp twice."""
+    import numpy as np
+
+    from kme_tpu.bridge.consume import DedupRing
+    from kme_tpu.bridge.service import TOPIC_OUT
+    from kme_tpu.engine import seq as SQ
+    from kme_tpu.native.oracle import NativeOracleEngine
+
+    msgs, cut = _reuse_stream()
+    b0, kw0 = _exactly_once(tmp_path, "whole", msgs)
+    assert MatchService(b0, **kw0).run(max_messages=len(msgs)) == len(msgs)
+    whole = list(consume_lines(b0, follow=False))
+    ref = NativeOracleEngine("fixed", book_slots=128, max_fills=16)
+    assert whole == [ln for g in ref.process_wire(
+        [m.copy() for m in msgs]) for ln in g]
+
+    b1, kw = _exactly_once(tmp_path, "cut", msgs)
+    svc = MatchService(b1, **kw)
+    cut = svc.run(max_messages=cut)             # whole batches
+    assert cut == svc.offset < len(msgs) - 128
+    svc.checkpoint()
+    canon = SQ.export_canonical(svc._session.cfg, svc._session.state)
+    dead = ~canon["slot_used"]
+    # freed slots keep the dead order's words, on every side in use
+    assert (canon["slot_oid"][dead] != 0).sum() >= 6 * 15
+    _, meta = ck._load_file(ck.snapshot_path(kw["checkpoint_dir"], cut))
+    assert meta["version"] == 2 and len(meta["layout"]["sparse"]) == 2
+    assert svc.run(max_messages=128) == 128     # past the snapshot...
+    del svc                                     # ...and killed
+
+    b2 = InProcessBroker(persist_dir=str(tmp_path / "cut-log"))
+    svc2 = MatchService(b2, **kw)
+    assert svc2.offset == cut and svc2.epoch == 2
+    restored = SQ.export_canonical(svc2._session.cfg, svc2._session.state)
+    assert not restored["slot_oid"][dead].any()
+    assert np.array_equal(restored["slot_oid"][~dead],
+                          canon["slot_oid"][~dead])
+    rest = len(msgs) - cut
+    assert svc2.run(max_messages=rest) == rest
+    assert list(consume_lines(b2, follow=False)) == whole
+    ring = DedupRing(capacity=1 << 20)
+    recs = b2.fetch(TOPIC_OUT, 0, 10**7)
+    assert b2.dup_suppressed > 0
+    assert not any(ring.is_dup(r.epoch, r.out_seq) for r in recs)
+
+
+@pytest.mark.parametrize("name,offset,seed", [
+    ("seq_pre_pr29.npz", 700, 11),      # hash planes in its meta
+    ("seq_dense_pr34.npz", 600, 12),    # written by the parent, ef872a6
+])
+def test_dense_files_of_older_writers_restore(name, offset, seed, tmp_path):
+    """A dense version-1 file — `git archive ef872a6`'s save_seq_session
+    wrote seq_dense_pr34.npz at offset 600 of the stream below —
+    restores under the loader that also reads sparse ones, and the
+    session finishes the stream byte-exact."""
+    import shutil
+
+    from kme_tpu.engine import seq as SQ
+    from kme_tpu.native.oracle import NativeOracleEngine
+
+    msgs = list(zipf_symbol_stream(900, 8, 64, seed=seed, zipf_a=0.0))
+    shutil.copy(os.path.join(HERE, "data", name),
+                ck.snapshot_path(str(tmp_path), offset))
+    _, meta = ck._load_file(ck.snapshot_path(str(tmp_path), offset))
+    assert meta["version"] == 1 and "layout" not in meta
+    want = NativeOracleEngine("fixed", book_slots=128, max_fills=16
+                              ).process_wire([m.copy() for m in msgs])
+    for cfg in (None, SQ.SeqConfig(**SMALL)):
+        ses, off = ck.load_seq_session(str(tmp_path), cfg)
+        assert off == offset
+        assert ses.process_wire([m.copy() for m in msgs[offset:]]) \
+            == want[offset:]
+
+
+def _two_sparse_snapshots(tmp_path, at=(400, 600)):
+    msgs = list(zipf_symbol_stream(900, 8, 64, seed=12, zipf_a=0.0))
+    ses, done = _seq_session(**SMALL), 0
+    for off in at:
+        ses.process_wire([m.copy() for m in msgs[done:off]])
+        ck.save_seq_session(str(tmp_path), ses, off)
+        assert ses.snapshot_gauges["snapshot_sparse_sections"] == 2
+        done = off
+    return msgs
+
+
+def test_sparse_seq_snapshot_restores_into_the_lanes_engine(tmp_path):
+    """Cross-engine: the canonical layout _load_file hands on is the
+    lanes engine's own, whichever way the seq engine wrote it."""
+    msgs = _two_sparse_snapshots(tmp_path)
+    ora = OracleEngine("fixed", book_slots=128, max_fills=16)
+    per_msg = [[r.wire() for r in ora.process(m.copy())] for m in msgs]
+    ses, off = ck.load_session(str(tmp_path))
+    assert isinstance(ses, LaneSession) and off == 600
+    assert ses.process_wire([m.copy() for m in msgs[off:]]) == per_msg[off:]
+    exp = ses.export_state()
+    assert exp["balances"] == dict(ora.balances)
+    assert exp["positions"] == dict(ora.positions)
+
+
+@pytest.mark.parametrize("damage", ["bitflip", "torn"])
+def test_damaged_sparse_snapshot_falls_back(damage, tmp_path):
+    """The digest is over the file as written: one value of one live
+    slot altered, or the file cut short, and the loader takes the
+    snapshot before it."""
+    import numpy as np
+
+    from kme_tpu.engine import seq as SQ
+
+    msgs = _two_sparse_snapshots(tmp_path)
+    path = ck.snapshot_path(str(tmp_path), 600)
+    if damage == "torn":
+        with open(path, "r+b") as f:
+            f.truncate(os.path.getsize(path) // 2)
+    else:
+        with np.load(path) as z:
+            data = {k: z[k].copy() for k in z.files}
+        data["slot_price"][0] ^= 1          # digest array kept STALE
+        with open(path, "wb") as f:
+            np.savez(f, **data)
+        with pytest.raises(ValueError, match="digest mismatch"):
+            ck._load_file(path)
+    ses, off = ck.load_seq_session(str(tmp_path), SQ.SeqConfig(**SMALL))
+    assert off == 400
+    ora = OracleEngine("fixed", book_slots=128, max_fills=16)
+    per_msg = [[r.wire() for r in ora.process(m.copy())] for m in msgs]
+    assert ses.process_wire([m.copy() for m in msgs[off:]]) == per_msg[off:]
+
+
+def test_snapshot_gauges_read_what_the_file_holds(tmp_path):
+    """The serve loop publishes the newest snapshot's size and live
+    counts with the batch's other gauges; none before the first
+    snapshot."""
+    import numpy as np
+
+    msgs, cut = _reuse_stream()
+    broker, kw = _exactly_once(tmp_path, "g", msgs)
+    svc = MatchService(broker, **kw)
+    cut = svc.run(max_messages=cut)
+    assert "snapshot_bytes" not in svc.telemetry.snapshot()["gauges"]
+    svc.checkpoint()
+    svc._publish_spans()
+    gauges = svc.telemetry.snapshot()["gauges"]
+    path = ck.snapshot_path(kw["checkpoint_dir"], cut)
+    with np.load(path) as z:
+        assert gauges["snapshot_live_slots"] == len(z["slot_idx"]) \
+            == svc.metrics()["open_orders"] > 0
+        assert gauges["snapshot_live_positions"] == len(z["pos_idx"]) > 0
+    assert gauges["snapshot_bytes"] == os.path.getsize(path)
+    assert gauges["snapshot_sparse_sections"] == 2
+    assert gauges["snapshot_export_n"] == gauges["snapshot_write_n"] == 1
