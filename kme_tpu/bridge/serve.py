@@ -37,7 +37,13 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--compat", choices=("java", "fixed"), default="fixed")
     p.add_argument("--batch", type=int, default=1024,
                    help="max records per engine micro-batch")
-    p.add_argument("--symbols", type=int, default=1024)
+    p.add_argument("--symbols", type=int, default=1024,
+                   help="symbols listed AT A TIME. The seq engine "
+                        "(fixed mode) hands a symbol's lane back when "
+                        "its PAYOUT has settled it, so any number of "
+                        "ids are served over a leader's life; the "
+                        "lanes engine and java mode bind an id for "
+                        "ever")
     p.add_argument("--accounts", type=int, default=4096)
     p.add_argument("--slots", type=int, default=128)
     p.add_argument("--max-fills", type=int, default=16)

@@ -52,6 +52,24 @@ _DEVICE_MS_HELP = ("what the host waited on the device for the last "
                    "work the pipeline hid is not in it")
 
 
+_LIFECYCLE_COUNTERS = {
+    "symbols_listed": "ADD_SYMBOLs the seq router routed to a lane "
+                      "that held no book (the device accepts those)",
+    "symbols_settled": "PAYOUTs the seq router routed to a listed "
+                       "symbol: its books wiped, its positions paid out",
+    "lanes_released": "lanes that went back to the router's pool, one "
+                      "a settled symbol",
+    "lanes_reused": "new symbol ids that took a lane another id had "
+                    "held before",
+    "unlisted_rejects": "trades, cancels and barriers host-rejected "
+                        "because their symbol id holds no lane",
+    "barrier_wiped_orders": "resting orders the seq kernel's barrier "
+                            "section took off the books (its own count)",
+    "barrier_credited_positions": "positions a YES payout credited in "
+                                  "the seq kernel (its own count)",
+}
+
+
 class MatchService:
     # the spans that PARTITION one iteration of the serve loop (names of
     # its PhaseTimer): what is left of the loop's wall after their sum
@@ -1538,6 +1556,17 @@ class MatchService:
                   "tiles of the position store the seq kernel brought "
                   "in from HBM (the kernel's own count)"
                   ).set(getattr(self._session, "pos_probe_tiles", 0))
+        # the symbol lifecycle: the router's counts as of the newest
+        # batch collected and the kernel's own of its barrier section
+        # (0 from an engine that has no lanes to hand back)
+        routed = getattr(self._session, "router_stats", {})
+        for name, what in _LIFECYCLE_COUNTERS.items():
+            t.counter(name, what).set(routed.get(
+                name, getattr(self._session, name, 0)))
+        bound = routed.get("lanes_bound", 0)
+        gauges["lanes_bound"] = bound
+        gauges["lanes_free"] = (self._session.cfg.lanes - bound
+                                if routed else 0)
         t.counter("matchout_produce_calls",
                   "broker calls made for output-stream records: one a "
                   "run on a broker with produce_stamped, one a record "

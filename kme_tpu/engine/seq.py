@@ -93,6 +93,11 @@ HIST_LANE0 = 2 + N_METRICS
 # the lane after the histograms: position tiles the call brought in
 # from HBM (fixed mode; java has no such store and leaves it 0)
 POS_TILES_LANE = HIST_LANE0 + N_HIST * N_HIST_BUCKETS
+# and the two after it, the barrier section's work in this call (fixed
+# mode): resting orders `wipe_side` took off, one loop turn each, and
+# positions a YES payout credited
+WIPED_LANE = POS_TILES_LANE + 1
+CREDITED_LANE = POS_TILES_LANE + 2
 
 # barrier acts (device-executed, unlike the lanes engine where barriers
 # are separate settle calls): mode mapping matches barrier_ops.settle
@@ -464,6 +469,8 @@ def build_seq_step(cfg: SeqConfig):
         # -------- positions (fixed mode): the dense store -------------
         # sm[16] the tile the scratch holds (-1: none), sm[17] whether
         # it was written, sm[18] tiles brought in from HBM this call
+        # (sm[19], sm[20]: orders wiped and positions credited by the
+        # barrier section this call)
         def pos_copy(tile, flush):
             hbm = st["pos"].at[pl.ds(tile * _i(POS_TILE_ROWS),
                                      POS_TILE_ROWS)]
@@ -1303,6 +1310,7 @@ def build_seq_step(cfg: SeqConfig):
                                 lane, o_aid, wside == _i(0),
                                 o_price, o_size)
                             bal_add(o_aid, rlo, rhi)
+                            sm[19] = sm[19] + _i(1)
 
                         return _k + _i(1), ~anyu
 
@@ -1347,6 +1355,7 @@ def build_seq_step(cfg: SeqConfig):
                                             pick(arow_lo, lc),
                                             pick(arow_hi, lc), *_sx(size))
                                         bal_add(acc0 + lc, plo, phi)
+                                        sm[20] = sm[20] + _i(1)
 
                                     rem = jnp.where(ci == lc, _i(0), rem)
                                     return rem, ~anyl
@@ -1440,6 +1449,8 @@ def build_seq_step(cfg: SeqConfig):
         sm[16] = _i(-1)
         sm[17] = _i(0)
         sm[18] = _i(0)
+        sm[19] = _i(0)
+        sm[20] = _i(0)
         met0 = tuple(_i(0) for _ in range(N_METRICS))
         fill_total, cur_lane, met = _fori32(
             B, one, (_i(0), _i(-1), met0))
@@ -1459,7 +1470,8 @@ def build_seq_step(cfg: SeqConfig):
         # scalar row: lane0 err, lane1 fill_total, lanes 2.. metrics,
         # lanes HIST_LANE0.. the histogram deltas (already in place in
         # the scratch row), lane POS_TILES_LANE the position tiles
-        # this call brought in from HBM
+        # this call brought in from HBM, then the barrier section's
+        # wiped orders and credited positions
         errv = pick(st["err"][0:1, :], _i(0))
         scal = jnp.where(ci == _i(0), errv, _i(0))
         scal = jnp.where(ci == _i(1), fill_total, scal)
@@ -1470,6 +1482,8 @@ def build_seq_step(cfg: SeqConfig):
             (ci >= _i(HIST_LANE0))
             & (ci < _i(HIST_LANE0 + N_HIST * N_HIST_BUCKETS)), hr, scal)
         scal = jnp.where(ci == _i(POS_TILES_LANE), sm[18], scal)
+        scal = jnp.where(ci == _i(WIPED_LANE), sm[19], scal)
+        scal = jnp.where(ci == _i(CREDITED_LANE), sm[20], scal)
         out[0:1, :] = scal
 
     nstate = len(KEYS)
@@ -1629,6 +1643,8 @@ def unpack_hdr(cfg: SeqConfig, hdr: np.ndarray, n: int) -> dict:
         "fill_total": int(scal[1]),
         "metrics": scal[2:2 + N_METRICS].astype(np.int64),
         "pos_tiles": int(scal[POS_TILES_LANE]),
+        "wiped": int(scal[WIPED_LANE]),
+        "credited": int(scal[CREDITED_LANE]),
         "hist": scal[HIST_LANE0:HIST_LANE0 + N_HIST * N_HIST_BUCKETS]
         .astype(np.int64).reshape(N_HIST, N_HIST_BUCKETS),
     }

@@ -236,6 +236,12 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
                                       None),
         "kme_router_import_routes": ([c.c_void_p, c.c_int64, P64, P64],
                                      None),
+        # the symbol lifecycle (SeqRouter's): counts and delisted ids
+        "kme_router_stats": ([c.c_void_p, P64, P64], None),
+        "kme_router_n_delisted": ([c.c_void_p], c.c_int64),
+        "kme_router_export_delisted": ([c.c_void_p, P64], None),
+        "kme_router_import_delisted": ([c.c_void_p, c.c_int64, P64],
+                                       None),
         # consistent-hash group assignment (kme_router.cpp, stateless)
         "kme_group_assign": ([c.c_int64, P64, c.c_int32, c.c_int64,
                               P32], None),
@@ -247,13 +253,6 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         "kme_recon_n_lines": ([c.c_void_p], c.c_int64),
         "kme_recon_line_off": ([c.c_void_p], P64),
         "kme_recon_msg_lines": ([c.c_void_p], P32),
-        "kme_recon_wire": ([c.c_int64] + [P64] * 6
-                           + [P64, c.POINTER(c.c_uint8)] * 2
-                           + [c.POINTER(c.c_uint8), P32,
-                              c.POINTER(c.c_uint8), P32, P64, P64, P64,
-                              c.POINTER(c.c_uint8), P64]
-                           + [c.c_int64] + [P64] * 4 + [c.c_void_p],
-                           c.c_int32),
         # native batch plan + H2D pack (kme_host.cpp kme_pack_*)
         "kme_pack_new": ([], c.c_void_p),
         "kme_pack_free": ([c.c_void_p], None),
@@ -267,10 +266,10 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         # native one-pass batch reconstruction (kme_wire.cpp)
         "kme_recon_batch": ([c.c_int64] + [P64] * 6
                             + [P64, c.POINTER(c.c_uint8)] * 2
-                            + [c.c_int64, P64, P32, P32]
+                            + [c.c_int64, P64, P32]
                             + [c.POINTER(c.c_uint8), P64, P64, P64,
                                c.POINTER(c.c_uint8)]
-                            + [c.c_int64, P64, c.c_int64, P64]
+                            + [c.c_int64, P64]
                             + [c.c_int64] + [P64] * 4 + [c.c_void_p],
                             c.c_int32),
         # native wire parsing (kme_wire.cpp kme_parse_*)

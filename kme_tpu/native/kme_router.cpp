@@ -6,11 +6,20 @@
 // costs ~2us/message (~0.8s on the 400k soak); this does the same work
 // over columnar int64 arrays in ~tens of ns/message. Semantics
 // authority: SeqRouter.route (runtime/seqsession.py); equality pinned
-// by tests/test_seq_engine.py.
+// by tests/test_seq_engine.py and tests/test_symbol_lifecycle.py.
+//
+// The symbol lifecycle is SeqRouter's (its docstring): a lane is bound
+// by an ADD_SYMBOL, released by an accepted PAYOUT, the lowest free
+// lane goes to the next new id, and a trade, cancel or barrier naming
+// an id that holds no lane is host-rejected and takes none.
 
+#include <algorithm>
+#include <chrono>
 #include <climits>
 #include <cstdint>
+#include <functional>
 #include <unordered_map>
+#include <unordered_set>
 #include <vector>
 
 namespace {
@@ -51,6 +60,17 @@ struct Router {
     return idx;
   }
 
+  // the symbol lifecycle (SeqRouter's twin): bound ids whose book a
+  // REMOVE_SYMBOL took away, the released lanes below the high-water
+  // mark `hw` as a min-heap, and the cumulative counts in
+  // ROUTER_STATS' order (stats[7], the bound lanes, is read off the map)
+  std::unordered_set<int64_t> delisted;
+  std::vector<int32_t> free_lanes;
+  int32_t hw = 0;
+  int64_t stats[7] = {0, 0, 0, 0, 0, 0, 0};
+  enum { LISTED, SETTLED, RELEASED, REUSED, UNLISTED, PURGE_NS, PURGE_N };
+
+  // the lane of `sid`, binding the lowest free one to a new id
   int32_t lane(int64_t sid, bool* ok) {
     auto it = sid_lane.find(sid);
     if (it != sid_lane.end()) return it->second;
@@ -59,9 +79,54 @@ struct Router {
       err_value = sid;
       return 0;
     }
-    int32_t l = (int32_t)sid_lane.size();
+    int32_t l;
+    if (!free_lanes.empty()) {
+      std::pop_heap(free_lanes.begin(), free_lanes.end(),
+                    std::greater<int32_t>());
+      l = free_lanes.back();
+      free_lanes.pop_back();
+      stats[REUSED]++;
+    } else {
+      l = hw++;
+    }
     sid_lane.emplace(sid, l);
     return l;
+  }
+
+  void release(int64_t sid, int32_t l) {
+    sid_lane.erase(sid);
+    free_lanes.push_back(l);
+    std::push_heap(free_lanes.begin(), free_lanes.end(),
+                   std::greater<int32_t>());
+    stats[SETTLED]++;
+    stats[RELEASED]++;
+  }
+
+  // a wholesale import of sid_lane: the pool is rebuilt from the map
+  void rebuild_pool() {
+    delisted.clear();
+    hw = 0;
+    for (auto& kv : sid_lane) hw = std::max(hw, kv.second + 1);
+    std::vector<bool> bound(hw, false);
+    for (auto& kv : sid_lane) bound[kv.second] = true;
+    free_lanes.clear();
+    for (int32_t l = 0; l < hw; l++)
+      if (!bound[l]) free_lanes.push_back(l);  // ascending: a min-heap
+  }
+
+  // resting-oid routes die with the wipe
+  void purge(int64_t s) {
+    auto t0 = std::chrono::steady_clock::now();
+    for (auto it = oid_sid.begin(); it != oid_sid.end();) {
+      if (it->second == s)
+        it = oid_sid.erase(it);
+      else
+        ++it;
+    }
+    stats[PURGE_NS] += std::chrono::duration_cast<std::chrono::nanoseconds>(
+                           std::chrono::steady_clock::now() - t0)
+                           .count();
+    stats[PURGE_N]++;
   }
 };
 
@@ -150,30 +215,39 @@ int32_t kme_router_route(void* p, int64_t n, const int64_t* action,
     r.o_lane.push_back(ln);
     r.o_oid.push_back(oid[i]);
   };
+  auto unlisted = [&](int64_t i) {
+    r.o_rej.push_back(i);
+    r.stats[Router::UNLISTED]++;
+  };
   for (int64_t i = 0; i < n; i++) {
     int64_t a = action[i];
     if (a == OP_BUY || a == OP_SELL) {
-      // mutation ORDER matches the Python authority (lane, then
-      // oid_sid, then acct) so partial map state after a CapacityError
-      // is identical either way (ADVICE r4)
-      int32_t ln = r.lane(sid[i], &ok);
-      if (!ok) return RT_CAP_SYMBOLS;
+      // mutation ORDER matches the Python authority (oid_sid, then
+      // acct) so partial map state after a CapacityError is identical
+      // either way (ADVICE r4)
+      auto sl = r.sid_lane.find(sid[i]);
+      if (sl == r.sid_lane.end()) {
+        unlisted(i);
+        continue;
+      }
       r.oid_sid[oid[i]] = sid[i];
       int32_t ai = r.acct(aid[i], &ok);
       if (!ok) return RT_CAP_ACCOUNTS;
-      emit(i, a == OP_BUY ? L_BUY : L_SELL, ai, ln);
+      emit(i, a == OP_BUY ? L_BUY : L_SELL, ai, sl->second);
     } else if (a == OP_CANCEL) {
       auto it = r.oid_sid.find(oid[i]);
       if (it == r.oid_sid.end()) {
         r.o_rej.push_back(i);
         continue;
       }
-      // Python evaluates _acct before _lane here (argument order)
+      auto sl = r.sid_lane.find(it->second);
+      if (sl == r.sid_lane.end()) {  // only an imported map can say so
+        unlisted(i);
+        continue;
+      }
       int32_t ai = r.acct(aid[i], &ok);
       if (!ok) return RT_CAP_ACCOUNTS;
-      int32_t ln = r.lane(it->second, &ok);
-      if (!ok) return RT_CAP_SYMBOLS;
-      emit(i, L_CANCEL, ai, ln);
+      emit(i, L_CANCEL, ai, sl->second);
     } else if (a == OP_CREATE_BALANCE) {
       int32_t ai = r.acct(aid[i], &ok);
       if (!ok) return RT_CAP_ACCOUNTS;
@@ -187,34 +261,37 @@ int32_t kme_router_route(void* p, int64_t n, const int64_t* action,
         r.o_rej.push_back(i);
         continue;
       }
+      bool fresh = r.sid_lane.find(sid[i]) == r.sid_lane.end();
       int32_t ln = r.lane(sid[i], &ok);
       if (!ok) return RT_CAP_SYMBOLS;
+      // the device accepts it where the book does not exist
+      if (fresh || r.delisted.erase(sid[i])) r.stats[Router::LISTED]++;
       emit(i, L_ADD_SYMBOL, 0, ln);
     } else if (a == OP_REMOVE_SYMBOL || a == OP_PAYOUT) {
       // abs(INT64_MIN) = 2^63 can never be a (wrapped) Java-long map
       // key, so the Python authority host-rejects it — and negating it
       // here would be signed-overflow UB (same guard as kme_host.cpp)
-      if (sid[i] == INT64_MIN) {
-        r.o_rej.push_back(i);
-        continue;
+      int64_t s = 0;
+      auto it = r.sid_lane.end();
+      if (sid[i] != INT64_MIN) {
+        s = sid[i] < 0 ? -sid[i] : sid[i];
+        it = r.sid_lane.find(s);
       }
-      int64_t s = sid[i] < 0 ? -sid[i] : sid[i];
-      auto it = r.sid_lane.find(s);
       if (it == r.sid_lane.end()) {
-        r.o_rej.push_back(i);
+        unlisted(i);
         continue;
       }
+      int32_t ln = it->second;
       int32_t act = a == OP_REMOVE_SYMBOL
                         ? L_REMOVE_SYMBOL
                         : (sid[i] >= 0 ? L_PAYOUT_YES : L_PAYOUT_NO);
-      emit(i, act, 0, it->second);
-      // resting-oid routes die with the wipe
-      for (auto it2 = r.oid_sid.begin(); it2 != r.oid_sid.end();) {
-        if (it2->second == s)
-          it2 = r.oid_sid.erase(it2);
-        else
-          ++it2;
-      }
+      emit(i, act, 0, ln);
+      r.purge(s);
+      if (r.delisted.count(s)) continue;  // no book: the device rejects
+      if (a == OP_REMOVE_SYMBOL)
+        r.delisted.insert(s);  // its positions stay, so the lane does
+      else
+        r.release(s, ln);  // books wiped, positions zeroed
     } else {
       r.o_rej.push_back(i);
     }
@@ -230,6 +307,17 @@ int64_t kme_router_n_rejects(void* p) {
 }
 int64_t kme_router_err_value(void* p) {
   return static_cast<Router*>(p)->err_value;
+}
+// ROUTER_STATS (runtime/seqsession.py), cumulative; `add` (7 values or
+// null) is folded in first: what a call routed by the Python twin
+// counted
+void kme_router_stats(void* p, const int64_t* add, int64_t* out) {
+  Router& r = *static_cast<Router*>(p);
+  for (int k = 0; k < 7; k++) {
+    if (add) r.stats[k] += add[k];
+    out[k] = r.stats[k];
+  }
+  out[7] = (int64_t)r.sid_lane.size();
 }
 const int64_t* kme_router_o_msg(void* p) {
   return static_cast<Router*>(p)->o_msg.data();
@@ -298,9 +386,23 @@ void kme_router_import_accounts(void* p, int64_t n, const int64_t* keys,
 }
 void kme_router_import_symbols(void* p, int64_t n, const int64_t* keys,
                                const int32_t* vals) {
-  auto& m = static_cast<Router*>(p)->sid_lane;
-  m.clear();
-  for (int64_t i = 0; i < n; i++) m.emplace(keys[i], vals[i]);
+  Router& r = *static_cast<Router*>(p);
+  r.sid_lane.clear();
+  for (int64_t i = 0; i < n; i++) r.sid_lane.emplace(keys[i], vals[i]);
+  r.rebuild_pool();
+}
+// the bound ids whose book is gone (after an import of the symbols)
+int64_t kme_router_n_delisted(void* p) {
+  return (int64_t)static_cast<Router*>(p)->delisted.size();
+}
+void kme_router_export_delisted(void* p, int64_t* keys) {
+  int64_t i = 0;
+  for (int64_t s : static_cast<Router*>(p)->delisted) keys[i++] = s;
+}
+void kme_router_import_delisted(void* p, int64_t n, const int64_t* keys) {
+  auto& d = static_cast<Router*>(p)->delisted;
+  d.clear();
+  d.insert(keys, keys + n);
 }
 void kme_router_import_routes(void* p, int64_t n, const int64_t* keys,
                               const int64_t* vals) {

@@ -95,116 +95,33 @@ const int32_t* kme_recon_msg_lines(void* p) {
   return static_cast<Recon*>(p)->msg_lines;
 }
 
-// Returns 0 on success. All per-message arrays are in arrival order.
-// d_* arrays are valid where d_isdev != 0; trades carry d_sid (the
-// lane's symbol) and their fills live at f_*[d_off .. d_off+d_nfill).
-int32_t kme_recon_wire(
-    int64_t nmsg, const int64_t* m_action, const int64_t* m_oid,
-    const int64_t* m_aid, const int64_t* m_sid, const int64_t* m_price,
-    const int64_t* m_size, const int64_t* m_next, const uint8_t* m_has_next,
-    const int64_t* m_prev, const uint8_t* m_has_prev,
-    const uint8_t* d_isdev, const int32_t* d_act, const uint8_t* d_ok,
-    const int32_t* d_nfill, const int64_t* d_off, const int64_t* d_residual,
-    const int64_t* d_prev_oid, const uint8_t* d_append, const int64_t* d_sid,
-    int64_t nfills, const int64_t* f_oid, const int64_t* f_aid,
-    const int64_t* f_price, const int64_t* f_size, void* handle) {
-  Recon& r = *static_cast<Recon*>(handle);
-  // worst-case line budget: IN + OUT per message + 2 lines per fill.
-  // Longest line: "OUT " (4) + 65 bytes of JSON scaffolding + 8 fields
-  // of up to 20 chars (int64 min) = 229; 240 leaves slack.
-  int64_t lines = 2 * nmsg + 2 * nfills;
-  int64_t need = 240 * lines + 64;
-  if (r.cap < need) {
-    delete[] r.buf;
-    r.buf = new char[need];
-    r.cap = need;
-  }
-  if (r.lines_cap < lines) {
-    delete[] r.line_off;
-    r.line_off = new int64_t[lines];
-    r.lines_cap = lines;
-  }
-  if (r.nmsg_cap < nmsg) {
-    delete[] r.msg_lines;
-    r.msg_lines = new int32_t[nmsg];
-    r.nmsg_cap = nmsg;
-  }
-  r.len = 0;
-  r.n_lines = 0;
-
-  for (int64_t i = 0; i < nmsg; i++) {
-    int64_t lines0 = r.n_lines;
-    start_line(r, "IN ", 3);
-    put_order(r, m_action[i], m_oid[i], m_aid[i], m_sid[i], m_price[i],
-              m_size[i], m_has_next[i], m_next[i], m_has_prev[i],
-              m_prev[i]);
-    bool isdev = d_isdev[i] != 0;
-    bool ok = isdev && d_ok[i] != 0;
-    if (!ok) {
-      start_line(r, "OUT ", 4);
-      put_order(r, OP_REJECT, m_oid[i], m_aid[i], m_sid[i], m_price[i],
-                m_size[i], m_has_next[i], m_next[i], m_has_prev[i],
-                m_prev[i]);
-    } else {
-      int32_t act = d_act[i];
-      bool is_trade = act == L_BUY || act == L_SELL;
-      if (is_trade) {
-        int64_t sid = d_sid[i];
-        int64_t mk = act == L_BUY ? OP_SOLD : OP_BOUGHT;
-        int64_t tk = act == L_BUY ? OP_BOUGHT : OP_SOLD;
-        int64_t o0 = d_off[i];
-        for (int32_t e = 0; e < d_nfill[i]; e++) {
-          start_line(r, "OUT ", 4);
-          put_order(r, mk, f_oid[o0 + e], f_aid[o0 + e], sid, 0,
-                    f_size[o0 + e], false, 0, false, 0);
-          start_line(r, "OUT ", 4);
-          put_order(r, tk, m_oid[i], m_aid[i], sid,
-                    m_price[i] - f_price[o0 + e], f_size[o0 + e],
-                    false, 0, false, 0);
-        }
-        start_line(r, "OUT ", 4);
-        bool app = d_append[i] != 0;
-        put_order(r, m_action[i], m_oid[i], m_aid[i], m_sid[i],
-                  m_price[i], d_residual[i], m_has_next[i], m_next[i],
-                  app || m_has_prev[i], app ? d_prev_oid[i] : m_prev[i]);
-      } else {
-        start_line(r, "OUT ", 4);
-        put_order(r, m_action[i], m_oid[i], m_aid[i], m_sid[i],
-                  m_price[i], m_size[i], m_has_next[i], m_next[i],
-                  m_has_prev[i], m_prev[i]);
-      }
-    }
-    r.msg_lines[i] = static_cast<int32_t>(r.n_lines - lines0);
-  }
-  return 0;
-}
-
 // One-pass reconstruction straight from the engine's routed/host arrays
-// (the D2H half of the native host path). kme_recon_wire needs ~10
-// per-message scatter arrays built in numpy first; this entry absorbs
-// that: routed rows arrive in ascending msg-index order (the router
-// emits at most one row per message, in order), so a single merge walk
-// recovers isdev/act/ok/fill-window per message, translates lane -> sid
-// and fill account-index -> aid through the two LUTs, and emits through
-// the same line builders. Fill windows are the running sum of h_nfill
-// over ALL routed rows (failed rows carry nfill 0), matching the numpy
-// cumsum. Returns 0 on success, 1 on an out-of-range lane / account
-// index / fill offset (the Python caller raises; numpy would IndexError
-// on the same input).
+// (the D2H half of the native host path): routed rows arrive in
+// ascending msg-index order (the router emits at most one row per
+// message, in order), so a single merge walk
+// recovers isdev/act/ok/fill-window per message, translates a fill's
+// account-index -> aid through the LUT (its symbol id is the taker
+// message's own: a lane may name another id by the time the batch is
+// collected), and emits through the same line builders. Fill windows
+// are the running sum of h_nfill over ALL routed rows (failed rows
+// carry nfill 0), matching the numpy cumsum. Returns 0 on success, 1 on
+// an out-of-range account index / fill offset (the Python caller
+// raises; numpy would IndexError on the same input).
 int32_t kme_recon_batch(
     int64_t nmsg, const int64_t* m_action, const int64_t* m_oid,
     const int64_t* m_aid, const int64_t* m_sid, const int64_t* m_price,
     const int64_t* m_size, const int64_t* m_next, const uint8_t* m_has_next,
     const int64_t* m_prev, const uint8_t* m_has_prev,
     int64_t nr, const int64_t* r_msg, const int32_t* r_act,
-    const int32_t* r_lane,
     const uint8_t* h_ok, const int64_t* h_nfill, const int64_t* h_resid,
     const int64_t* h_prev, const uint8_t* h_append,
-    int64_t nlanes, const int64_t* lane_sid,
     int64_t nacct, const int64_t* idx2aid,
     int64_t nfills, const int64_t* f_oid, const int64_t* f_aidx,
     const int64_t* f_price, const int64_t* f_size, void* handle) {
   Recon& r = *static_cast<Recon*>(handle);
+  // worst-case line budget: IN + OUT per message + 2 lines per fill.
+  // Longest line: "OUT " (4) + 65 bytes of JSON scaffolding + 8 fields
+  // of up to 20 chars (int64 min) = 229; 240 leaves slack.
   int64_t lines = 2 * nmsg + 2 * nfills;
   int64_t need = 240 * lines + 64;
   if (r.cap < need) {
@@ -243,8 +160,9 @@ int32_t kme_recon_batch(
     } else {
       int32_t act = r_act[k];
       if (act == L_BUY || act == L_SELL) {
-        if (r_lane[k] < 0 || r_lane[k] >= nlanes) return 1;
-        int64_t sid = lane_sid[r_lane[k]];
+        // a fill is in the taker's book: the id its message was
+        // routed under, whatever a lane -> id table says by now
+        int64_t sid = m_sid[i];
         int64_t mk = act == L_BUY ? OP_SOLD : OP_BOUGHT;
         int64_t tk = act == L_BUY ? OP_BOUGHT : OP_SOLD;
         for (int64_t e = 0; e < h_nfill[k]; e++) {

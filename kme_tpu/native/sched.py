@@ -267,8 +267,8 @@ def slice_windows(wins: dict, win_idx, shard: int, shards: int,
 # The serve/bench hot loop's host work — envelope check + route + H2D
 # staging pack on the way in, output-array -> byte-stream reconstruction
 # on the way out — as single C calls (kme_plan_batch / kme_recon_batch).
-# Both return None when the loaded library predates the entry points so
-# callers fall back to the numpy implementations, which remain the
+# plan_batch returns None when the loaded library predates its entry
+# point, so its caller falls back to the numpy pack, which remains the
 # semantics authority (parity pinned by tests/test_host_path.py).
 
 
@@ -357,15 +357,11 @@ def collect_plan(lib, router, pack, K, B, price, size):
     return cols, host_rejects, stacked, cnts, K
 
 
-def recon_batch(lib, handle, batch, cols, host, fills, lane_sid,
-                idx2aid):
+def recon_batch(lib, handle, batch, cols, host, fills, idx2aid):
     """One-pass native reconstruction (kme_recon_batch): batch columns
     + routed rows + device results -> the byte-exact record stream,
-    without the ~10 per-message numpy scatter arrays kme_recon_wire
-    needs. Returns (buf, line_off, msg_lines) like
-    SeqSession.process_wire_buffer, or None when unavailable."""
-    if not hasattr(lib, "kme_recon_batch"):
-        return None
+    in one merge walk, no per-message numpy scatter. Returns (buf,
+    line_off, msg_lines) like SeqSession.process_wire_buffer."""
     c = ctypes
     P64 = c.POINTER(c.c_int64)
     P32 = c.POINTER(c.c_int32)
@@ -387,19 +383,16 @@ def recon_batch(lib, handle, batch, cols, host, fills, lane_sid,
                      np.uint8, nmsg)
     r_msg = i64(cols["msg_index"])
     r_act = np.ascontiguousarray(cols["act"], np.int32)
-    r_lane = np.ascontiguousarray(cols["lane"], np.int32)
     h_ok = np.ascontiguousarray(host["ok"], np.uint8)
     h_append = np.ascontiguousarray(host["append"], np.uint8)
     h_nfill, h_resid, h_prev = (i64(host[k]) for k in
                                 ("nfill", "residual", "prev_oid"))
-    for nm, a in (("cols.act", r_act), ("cols.lane", r_lane)):
-        check_buffer(f"recon_batch.{nm}", a, np.int32, nr)
+    check_buffer("recon_batch.cols.act", r_act, np.int32, nr)
     for nm, a in (("host.ok", h_ok), ("host.append", h_append)):
         check_buffer(f"recon_batch.{nm}", a, np.uint8, nr)
     for nm, a in (("host.nfill", h_nfill), ("host.residual", h_resid),
                   ("host.prev_oid", h_prev)):
         check_buffer(f"recon_batch.{nm}", a, np.int64, nr)
-    check_buffer("recon_batch.lane_sid", lane_sid, np.int64)
     check_buffer("recon_batch.idx2aid", idx2aid, np.int64)
     if fills.ndim != 2 or fills.shape[0] != 4:
         raise BoundaryError(
@@ -414,10 +407,9 @@ def recon_batch(lib, handle, batch, cols, host, fills, lane_sid,
         pp(batch.size, P64), pp(batch.next, P64),
         pp(batch.hnext, PU8), pp(batch.prev, P64),
         pp(batch.hprev, PU8),
-        nr, pp(r_msg, P64), pp(r_act, P32), pp(r_lane, P32),
+        nr, pp(r_msg, P64), pp(r_act, P32),
         pp(h_ok, PU8), pp(h_nfill, P64), pp(h_resid, P64),
         pp(h_prev, P64), pp(h_append, PU8),
-        len(lane_sid), pp(lane_sid, P64),
         len(idx2aid), pp(idx2aid, P64),
         fills.shape[1], pp(f_oid, P64), pp(f_aidx, P64),
         pp(f_price, P64), pp(f_size, P64), handle)

@@ -596,6 +596,9 @@ def _restore_seq(data, meta, cfg):
     r.aid_idx = {int(k): int(i) for k, i in meta["aid_idx"]}
     r.sid_lane = {int(k): int(l) for k, l in meta["sid_lane"]}
     r.oid_sid = {int(k): int(s) for k, s in meta["oid_sid"]}
+    # the router's pool of free lanes is rebuilt from `sid_lane` by its
+    # setter; which bound ids hold no book, from the restored books
+    r.set_listed(np.asarray(canon["book_exists"]).reshape(-1))
     return ses
 
 

@@ -16,6 +16,8 @@ every separate np.asarray is a blocking device->host round trip.
 
 from __future__ import annotations
 
+import heapq
+from time import perf_counter_ns
 from typing import Dict, List
 
 import numpy as np
@@ -57,12 +59,35 @@ class UnsupportedJavaOp(RuntimeError):
     engines (COMPAT.md)."""
 
 
+# the routers' cumulative lifecycle counts, in the order both export
+# them (SeqRouter.stats / kme_router_stats); `lanes_bound` is a level,
+# the rest only grow. `route_purge_ns` / `route_purge_n`: time spent
+# dropping a wiped symbol's oid routes, and how many such purges
+ROUTER_STATS = ("symbols_listed", "symbols_settled", "lanes_released",
+                "lanes_reused", "unlisted_rejects", "route_purge_ns",
+                "route_purge_n", "lanes_bound")
+
+
 class SeqRouter:
     """Arrival-order ID routing (no conflict analysis). Mirrors the
     sequencer's id spaces and host-reject edge semantics. compat='java'
     additionally emits the raw Java-long aid/sid columns and the Q1
     merged-book flag the kernel needs, and REFUSES the opcodes outside
-    the java device surface."""
+    the java device surface.
+
+    Fixed mode, the symbol lifecycle: a lane is bound to a symbol id by
+    the first routed ADD_SYMBOL of it and goes back to the pool when an
+    accepted PAYOUT has emptied it (the kernel wipes the books, clears
+    `bex` and zeroes the lane's positions), so `S` is how many symbols
+    are listed AT A TIME. Whether the device will accept a barrier is
+    known here, at route time: it accepts one exactly where the book
+    exists, and every operation that makes or unmakes a book passes
+    through this router in order — `delisted` holds the bound ids whose
+    book a REMOVE_SYMBOL took away (their positions stay, so the lane
+    does). A new id takes the LOWEST free lane, a function of
+    `sid_lane` alone, so a replay and a restore choose as the first run
+    did. A trade, cancel or barrier naming an id that holds no lane is
+    host-rejected and takes none (the reference rejects each)."""
 
     def __init__(self, num_lanes: int, num_accounts: int,
                  compat: str = "fixed") -> None:
@@ -70,8 +95,36 @@ class SeqRouter:
         self.A = num_accounts
         self.compat = compat
         self.aid_idx: Dict[int, int] = {}
-        self.sid_lane: Dict[int, int] = {}
         self.oid_sid: Dict[int, int] = {}
+        self.delisted: set = set()
+        self.counts = dict.fromkeys(ROUTER_STATS[:-1], 0)
+        self.sid_lane = {}
+
+    @property
+    def sid_lane(self) -> Dict[int, int]:
+        return self._sid_lane
+
+    @sid_lane.setter
+    def sid_lane(self, d: Dict[int, int]) -> None:
+        """A wholesale import (construction, checkpoint restore): the
+        pool of free lanes is rebuilt from the map — every lane below
+        the highest bound one that no id holds, and all above it."""
+        self._sid_lane = dict(d)
+        self.delisted = set()
+        self._hw = max(self._sid_lane.values(), default=-1) + 1
+        self._free = sorted(set(range(self._hw))
+                            - set(self._sid_lane.values()))
+
+    def set_listed(self, book_exists) -> None:
+        """After an import of `sid_lane`: the bound ids whose lane holds
+        no book (`book_exists[lane]` of the restored device state) are
+        the delisted ones."""
+        self.delisted = {sid for sid, lane in self.sid_lane.items()
+                         if not book_exists[lane]}
+
+    def stats(self) -> tuple:
+        """ROUTER_STATS, cumulative."""
+        return (*self.counts.values(), len(self._sid_lane))
 
     def _acct(self, aid: int) -> int:
         idx = self.aid_idx.get(aid)
@@ -84,14 +137,29 @@ class SeqRouter:
         return idx
 
     def _lane(self, sid: int) -> int:
-        lane = self.sid_lane.get(sid)
+        """The lane of `sid`, binding the lowest free one to a new id."""
+        lane = self._sid_lane.get(sid)
         if lane is None:
-            if len(self.sid_lane) >= self.S:
+            if len(self._sid_lane) >= self.S:
                 raise CapacityError(
                     f"symbol capacity {self.S} exhausted (sid={sid})")
-            lane = len(self.sid_lane)
-            self.sid_lane[sid] = lane
+            if self._free:
+                lane = heapq.heappop(self._free)
+                self.counts["lanes_reused"] += 1
+            else:
+                lane = self._hw
+                self._hw += 1
+            self._sid_lane[sid] = lane
         return lane
+
+    def _purge(self, sid: int) -> None:
+        """Resting-oid routes die with the wipe."""
+        t0 = perf_counter_ns()
+        dead = [o for o, s2 in self.oid_sid.items() if s2 == sid]
+        for o in dead:
+            del self.oid_sid[o]
+        self.counts["route_purge_ns"] += perf_counter_ns() - t0
+        self.counts["route_purge_n"] += 1
 
     def acct_of_idx(self) -> List[int]:
         out = [0] * len(self.aid_idx)
@@ -127,6 +195,10 @@ class SeqRouter:
                 cols["sid_raw"].append(sid)
                 cols["flags"].append(1 if sid == 0 else 0)
 
+        def unlisted(i):
+            host_rejects.add(i)
+            self.counts["unlisted_rejects"] += 1
+
         # envelope-check the WHOLE batch up front so an EnvelopeError
         # leaves the id maps untouched (the native router's contract;
         # native/sched.py documents the same for the scheduler)
@@ -159,7 +231,13 @@ class SeqRouter:
                 # mutation order (lane, oid_sid, acct) is the authority
                 # contract: the native router replicates it exactly so
                 # partial map state after a CapacityError is identical
-                lane = self._lane(sid)
+                if java:
+                    lane = self._lane(sid)
+                else:
+                    lane = self._sid_lane.get(sid)
+                    if lane is None:
+                        unlisted(i)
+                        continue
                 self.oid_sid[oid] = sid
                 emit(i, _TRADE_ACTS[a], self._acct(aid), lane, m, oid,
                      aid, sid)
@@ -168,8 +246,15 @@ class SeqRouter:
                 if rsid is None:
                     host_rejects.add(i)
                     continue
-                emit(i, SQ.L_CANCEL, self._acct(aid), self._lane(rsid),
-                     m, oid, aid, rsid)
+                if java:
+                    emit(i, SQ.L_CANCEL, self._acct(aid),
+                         self._lane(rsid), m, oid, aid, rsid)
+                    continue
+                lane = self._sid_lane.get(rsid)
+                if lane is None:    # only an imported map can say so
+                    unlisted(i)
+                    continue
+                emit(i, SQ.L_CANCEL, self._acct(aid), lane, m, oid)
             elif a == op.CREATE_BALANCE:
                 emit(i, SQ.L_CREATE, self._acct(aid), 0, m, oid, aid, 0)
             elif a == op.TRANSFER:
@@ -183,8 +268,13 @@ class SeqRouter:
                 if sid < 0:
                     host_rejects.add(i)
                     continue
-                emit(i, SQ.L_ADD_SYMBOL, 0, self._lane(sid), m, oid,
-                     aid, sid)
+                fresh = sid not in self._sid_lane
+                lane = self._lane(sid)
+                if fresh or sid in self.delisted:
+                    # the device accepts it: the book does not exist
+                    self.delisted.discard(sid)
+                    self.counts["symbols_listed"] += 1
+                emit(i, SQ.L_ADD_SYMBOL, 0, lane, m, oid, aid, sid)
             elif a in (op.REMOVE_SYMBOL, op.PAYOUT):
                 if java:
                     raise UnsupportedJavaOp(
@@ -192,18 +282,26 @@ class SeqRouter:
                         f"in java mode — Q3-Q6 barrier paths are outside "
                         f"the device surface; use the native engine")
                 s = abs(sid)
-                if s not in self.sid_lane:
-                    host_rejects.add(i)
+                lane = self._sid_lane.get(s)
+                if lane is None:
+                    unlisted(i)
                     continue
-                lane = self.sid_lane[s]
                 if a == op.REMOVE_SYMBOL:
                     act = SQ.L_REMOVE_SYMBOL
                 else:
                     act = SQ.L_PAYOUT_YES if sid >= 0 else SQ.L_PAYOUT_NO
                 emit(i, act, 0, lane, m, oid)
-                dead = [o for o, s2 in self.oid_sid.items() if s2 == s]
-                for o in dead:
-                    del self.oid_sid[o]
+                self._purge(s)
+                if s in self.delisted:
+                    continue        # no book: the device rejects it
+                if a == op.REMOVE_SYMBOL:
+                    self.delisted.add(s)    # its positions stay
+                else:
+                    # books wiped, positions zeroed: the lane is empty
+                    del self._sid_lane[s]
+                    heapq.heappush(self._free, lane)
+                    self.counts["symbols_settled"] += 1
+                    self.counts["lanes_released"] += 1
             else:
                 host_rejects.add(i)
         out = {
@@ -239,8 +337,8 @@ class NativeSeqRouter:
         self._h = lib.kme_router_new(num_lanes, num_accounts)
         self._fin = weakref.finalize(self, lib.kme_router_free, self._h)
         # bumped on every wholesale map import (checkpoint restore):
-        # SeqSession's recon-LUT cache keys on (map sizes, epoch), and
-        # sizes alone can collide across an import
+        # SeqSession's recon-LUT cache keys on (map size, epoch), and
+        # the size alone can collide across an import
         self._map_epoch = 0
 
     # -- map views (checkpoint save/load reads+writes these) -----------
@@ -287,6 +385,40 @@ class NativeSeqRouter:
     @sid_lane.setter
     def sid_lane(self, d):
         self._import(self._lib.kme_router_import_symbols, d, np.int32)
+
+    @property
+    def delisted(self) -> set:
+        import ctypes
+
+        lib = self._lib
+        keys = np.empty(lib.kme_router_n_delisted(self._h), np.int64)
+        lib.kme_router_export_delisted(
+            self._h, keys.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)))
+        return set(keys.tolist())
+
+    @delisted.setter
+    def delisted(self, d) -> None:
+        import ctypes
+
+        keys = np.fromiter(d, np.int64, len(d))
+        self._lib.kme_router_import_delisted(
+            self._h, len(d),
+            keys.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)))
+
+    set_listed = SeqRouter.set_listed
+
+    def stats(self, add=None) -> tuple:
+        """ROUTER_STATS, cumulative (`add`: counts to fold in first)."""
+        import ctypes
+
+        P64 = ctypes.POINTER(ctypes.c_int64)
+        out = np.empty(len(ROUTER_STATS), np.int64)
+        if add is not None:
+            add = np.asarray(add, np.int64)
+        self._lib.kme_router_stats(
+            self._h, None if add is None else add.ctypes.data_as(P64),
+            out.ctypes.data_as(P64))
+        return tuple(out.tolist())
 
     @property
     def oid_sid(self):
@@ -337,13 +469,15 @@ class NativeSeqRouter:
             # a field beyond int64: the columnar path cannot carry it
             py = SeqRouter(self.S, self.A)
             py.aid_idx = self.aid_idx
-            py.sid_lane = self.sid_lane
+            py.sid_lane, py.delisted = self.sid_lane, self.delisted
             py.oid_sid = self.oid_sid
-            cols, rejects = py.route(msgs)
-            self.aid_idx = py.aid_idx
-            self.sid_lane = py.sid_lane
-            self.oid_sid = py.oid_sid
-            return cols, rejects
+            try:
+                return py.route(msgs)
+            finally:    # whatever it left, a CapacityError's too
+                self.aid_idx = py.aid_idx
+                self.sid_lane, self.delisted = py.sid_lane, py.delisted
+                self.oid_sid = py.oid_sid
+                self.stats(add=py.stats()[:-1])
         bad = ((raw["price"] < -(2**31)) | (raw["price"] >= 2**31)
                | (raw["size"] < -(2**31)) | (raw["size"] >= 2**31))
         if bad.any():
@@ -476,6 +610,16 @@ class SeqSession:
         # fetched; java mode has no such store: 0); the serve loop
         # publishes it as counter `pos_probe_tiles`
         self.pos_probe_tiles = 0
+        # the barrier section's work, likewise the kernel's own counts:
+        # resting orders `wipe_side` took off (one loop turn each) and
+        # positions a YES payout credited
+        self.barrier_wiped_orders = 0
+        self.barrier_credited_positions = 0
+        # the router's ROUTER_STATS as of the newest batch COLLECTED
+        # (the router itself runs `pipeline` batches ahead); the serve
+        # loop publishes them as counters, `lanes_bound` and what is
+        # left of cfg.lanes as gauges, the purge as span `route_purge`
+        self.router_stats = dict.fromkeys(ROUTER_STATS, 0)
         # metrics()' narrow read, compiled here and not at the first
         # refresh: a compile inside a served batch is a stall
         self._occupancy = SQ.build_seq_occupancy(cfg)
@@ -551,6 +695,7 @@ class SeqSession:
         with self.timer.phase("plan_s"):
             cols, host_rejects, stacked, cnts, K = self._plan(msgs)
         self.lane_switches += count_lane_switches(self.cfg, stacked)
+        self._note_router(self.router.stats())
         with self.timer.phase("dispatch_s"):
             self.state, outp = SQ.build_seq_scan(self.cfg, K)(
                 self.state, stacked)
@@ -586,6 +731,8 @@ class SeqSession:
             mets += res["metrics"]
             hists += res["hist"]
             self.pos_probe_tiles += res["pos_tiles"]
+            self.barrier_wiped_orders += res["wiped"]
+            self.barrier_credited_positions += res["credited"]
         gneed = [-(-max(r["fill_total"], 1) // 128) for r in results]
         self._ghint = max(self._ghint, *gneed)
         over = [ci for ci in range(K) if gneed[ci] > ghint]
@@ -639,6 +786,7 @@ class SeqSession:
         # added at collect(), with the batch's other counters: a reader
         # of two heartbeats then finds the same batches in each
         switches = count_lane_switches(self.cfg, stacked)
+        routed = self.router.stats()
         with self.timer.phase("stage_s"):
             # explicit async H2D staging: device_put enqueues the copy
             # of batch N+1's input planes while the device still runs
@@ -673,7 +821,20 @@ class SeqSession:
         self.windows.append(("submit", self._n_submit, t0,
                              perf_counter()))
         self._n_submit += 1
-        return (msgs, cols, host_rejects, outp, cnts, K, switches)
+        return (msgs, cols, host_rejects, outp, cnts, K, switches, routed)
+
+    def _note_router(self, stats: tuple) -> None:
+        """Take up the router's cumulative counts as of one batch, and
+        fold what its purges took since the last into the timer as span
+        `route_purge` (timed inside the router: in C++ on the native
+        path)."""
+        new = dict(zip(ROUTER_STATS, stats))
+        old, self.router_stats = self.router_stats, new
+        t = self.timer
+        t.totals["route_purge"] = t.totals.get("route_purge", 0.0) + 1e-9 * (
+            new["route_purge_ns"] - old["route_purge_ns"])
+        t.counts["route_purge"] = t.counts.get("route_purge", 0) + (
+            new["route_purge_n"] - old["route_purge_n"])
 
     @property
     def h2d_overlap_frac(self) -> float:
@@ -691,8 +852,9 @@ class SeqSession:
         from time import perf_counter
 
         t0 = perf_counter()
-        batch, cols, host_rejects, outp, cnts, K, switches = handle
+        batch, cols, host_rejects, outp, cnts, K, switches, routed = handle
         self.lane_switches += switches
+        self._note_router(routed)
         with self.timer.phase("fetch_s"):
             host, fills = self._fetch_outputs(outp, cnts, K)
         with self.timer.phase("recon_s"):
@@ -737,41 +899,32 @@ class SeqSession:
                                    fills)
         return r
 
-    def _recon_luts(self):
-        """lane -> sid and account-idx -> aid LUTs for reconstruction,
-        cached against the router's id-map sizes: the maps only grow
-        (REMOVE_SYMBOL wipes books, not the lane mapping), and
-        exporting them was O(accounts) dict traffic per batch on the
-        hot path. Wholesale imports (checkpoint restore) bump
-        _map_epoch, so same-size-different-content restores can never
-        serve a stale cache; Python routers are uncached (their dicts
-        mutate without a hook)."""
+    def _idx2aid(self):
+        """account-idx -> aid LUT for reconstruction, cached against
+        the router's account-map size: that map only grows, and
+        exporting it was O(accounts) dict traffic per batch on the hot
+        path. Wholesale imports (checkpoint restore) bump _map_epoch,
+        so same-size-different-content restores can never serve a stale
+        cache; Python routers are uncached (their dicts mutate without
+        a hook). (There is no lane -> symbol table beside it: a lane
+        names one id after another, and the router runs ahead of the
+        batch being collected, so a record's id is its message's own.)"""
         r = self.router
         key = None
         if isinstance(r, NativeSeqRouter):
-            key = (int(r._lib.kme_router_n_symbols(r._h)),
-                   int(r._lib.kme_router_n_accounts(r._h)),
-                   r._map_epoch)
+            key = (int(r._lib.kme_router_n_accounts(r._h)), r._map_epoch)
             cached = getattr(self, "_lut_cache", None)
             if cached is not None and cached[0] == key:
-                return cached[1], cached[2]
-        lut = np.zeros(self.cfg.lanes, np.int64)
-        for lane, sid in r.sid_of_lane().items():
-            lut[lane] = sid
+                return cached[1]
         idx2aid = np.array(r.acct_of_idx() or [0], np.int64)
         if key is not None:
-            self._lut_cache = (key, lut, idx2aid)
-        return lut, idx2aid
+            self._lut_cache = (key, idx2aid)
+        return idx2aid
 
     def _recon_buffer(self, batch, cols, host_rejects, host, fills):
         """Columnar inputs + device results -> the byte-exact record
-        stream via the native C++ reconstructor (kme_wire.cpp).
-        Prefers the one-pass kme_recon_batch entry (a single merge
-        walk in C++, no numpy scatter); the kme_recon_wire scatter
-        path below remains as the fallback for libraries built from
-        older sources."""
-        import ctypes
-
+        stream via the native C++ reconstructor (kme_wire.cpp:
+        kme_recon_batch, a single merge walk, no numpy scatter)."""
         from kme_tpu.native import load_library
         from kme_tpu.native.sched import recon_batch
 
@@ -793,75 +946,8 @@ class SeqSession:
             # a finalizer survives interpreter-shutdown ordering)
             self._recon_fin = weakref.finalize(
                 self, lib.kme_recon_free, self._recon)
-        lane_sid, idx2aid = self._recon_luts()
-        r = recon_batch(lib, self._recon, batch, cols, host, fills,
-                        lane_sid, idx2aid)
-        if r is not None:
-            return r
-        m_action, m_oid, m_aid = batch.action, batch.oid, batch.aid
-        m_sid, m_price, m_size = batch.sid, batch.price, batch.size
-        m_next, m_hnext = batch.next, batch.hnext
-        m_prev, m_hprev = batch.prev, batch.hprev
-
-        mi = cols["msg_index"]
-        d_isdev = np.zeros(nmsg, np.uint8)
-        d_isdev[mi] = 1
-        d_act = np.zeros(nmsg, np.int32)
-        d_act[mi] = cols["act"]
-        d_ok = np.zeros(nmsg, np.uint8)
-        d_nfill = np.zeros(nmsg, np.int32)
-        d_off = np.zeros(nmsg, np.int64)
-        d_resid = np.zeros(nmsg, np.int64)
-        d_prev = np.zeros(nmsg, np.int64)
-        d_append = np.zeros(nmsg, np.uint8)
-        d_sid = np.zeros(nmsg, np.int64)
-        if len(mi):
-            d_ok[mi] = host["ok"].astype(np.uint8)
-            d_nfill[mi] = host["nfill"].astype(np.int32)
-            offs = np.cumsum(host["nfill"]) - host["nfill"]
-            d_off[mi] = offs
-            d_resid[mi] = host["residual"]
-            d_prev[mi] = host["prev_oid"]
-            d_append[mi] = host["append"].astype(np.uint8)
-            lut = np.zeros(self.cfg.lanes, np.int64)
-            for lane, sid in self.router.sid_of_lane().items():
-                lut[lane] = sid
-            d_sid[mi] = lut[cols["lane"]]
-        idx2aid = np.array(self.router.acct_of_idx() or [0], np.int64)
-        f_aid = (idx2aid[fills[1]] if fills.shape[1]
-                 else np.zeros(0, np.int64))
-        f_oid = np.ascontiguousarray(fills[0])
-        f_aid = np.ascontiguousarray(f_aid)
-        f_price = np.ascontiguousarray(fills[2])
-        f_size = np.ascontiguousarray(fills[3])
-
-        c = ctypes
-        P64 = c.POINTER(c.c_int64)
-        P32 = c.POINTER(c.c_int32)
-        PU8 = c.POINTER(c.c_uint8)
-        pp = lambda a, t: a.ctypes.data_as(t)
-        rc = lib.kme_recon_wire(
-            nmsg, pp(m_action, P64), pp(m_oid, P64), pp(m_aid, P64),
-            pp(m_sid, P64), pp(m_price, P64), pp(m_size, P64),
-            pp(m_next, P64), pp(m_hnext, PU8), pp(m_prev, P64),
-            pp(m_hprev, PU8),
-            pp(d_isdev, PU8), pp(d_act, P32), pp(d_ok, PU8),
-            pp(d_nfill, P32), pp(d_off, P64), pp(d_resid, P64),
-            pp(d_prev, P64), pp(d_append, PU8), pp(d_sid, P64),
-            fills.shape[1], pp(f_oid, P64), pp(f_aid, P64),
-            pp(f_price, P64), pp(f_size, P64), self._recon)
-        if rc != 0:
-            raise RuntimeError(f"kme_recon_wire failed rc={rc}")
-        blen = lib.kme_recon_len(self._recon)
-        nlines = lib.kme_recon_n_lines(self._recon)
-        buf = c.string_at(lib.kme_recon_buf(self._recon), blen)
-        line_off = np.empty(nlines + 1, np.int64)
-        line_off[:nlines] = np.ctypeslib.as_array(
-            lib.kme_recon_line_off(self._recon), (nlines,))
-        line_off[nlines] = blen
-        msg_lines = np.ctypeslib.as_array(
-            lib.kme_recon_msg_lines(self._recon), (nmsg,)).copy()
-        return buf, line_off, msg_lines
+        return recon_batch(lib, self._recon, batch, cols, host, fills,
+                           self._idx2aid())
 
     def process_wire(self, msgs) -> List[List[str]]:
         if getattr(self, "_use_native_wire", True):
@@ -879,8 +965,9 @@ class SeqSession:
         if isinstance(msgs, WireBatch):
             msgs = msgs.msgs()
         cols, host_rejects, host, fills = self._run(msgs)
+        from kme_tpu.oracle.javalong import jlong
+
         idx_to_aid = self.router.acct_of_idx()
-        lane_to_sid = self.router.sid_of_lane()
 
         nmsg = len(msgs)
         self.last_reasons = reject_reason_codes(
@@ -893,7 +980,6 @@ class SeqSession:
         prev_of = [0] * nmsg
         append_of = [False] * nmsg
         act_of = [0] * nmsg
-        lane_of = [0] * nmsg
         mis = cols["msg_index"].tolist()
         offs = (np.cumsum(host["nfill"]) - host["nfill"]).tolist() \
             if len(mis) else []
@@ -905,11 +991,9 @@ class SeqSession:
             for k, mi in enumerate(mis):
                 dst[mi] = vals[k]
         acts = cols["act"].tolist()
-        lanes_l = cols["lane"].tolist()
         for k, mi in enumerate(mis):
             off_of[mi] = offs[k]
             act_of[mi] = acts[k]
-            lane_of[mi] = lanes_l[k]
         f_oid, f_aid, f_price, f_size = (fills[c].tolist() for c in range(4))
 
         out: List[List[str]] = []
@@ -925,7 +1009,10 @@ class SeqSession:
                 lane_act = act_of[i]
                 is_trade = lane_act in (SQ.L_BUY, SQ.L_SELL)
                 if is_trade:
-                    sid = lane_to_sid[lane_of[i]]
+                    # a fill is in the taker's book: the id the
+                    # message was routed under (the router's map may
+                    # name another for its lane by now)
+                    sid = jlong(m.sid)
                     is_buy = lane_act == SQ.L_BUY
                     mk_act = op.SOLD if is_buy else op.BOUGHT
                     tk_act = op.BOUGHT if is_buy else op.SOLD
@@ -952,9 +1039,10 @@ class SeqSession:
     def process(self, msgs) -> List[List[OutRecord]]:
         if isinstance(msgs, WireBatch):
             msgs = msgs.msgs()
+        from kme_tpu.oracle.javalong import jlong
+
         cols, host_rejects, host, fills = self._run(msgs)
         idx_to_aid = self.router.acct_of_idx()
-        lane_to_sid = self.router.sid_of_lane()
         nmsg = len(msgs)
         self.last_reasons = reject_reason_codes(
             nmsg, cols["msg_index"], cols["act"], host["ok"],
@@ -978,7 +1066,7 @@ class SeqSession:
                 lane_act = int(cols["act"][k])
                 is_trade = lane_act in (SQ.L_BUY, SQ.L_SELL)
                 if is_trade and ok:
-                    sid = lane_to_sid[int(cols["lane"][k])]
+                    sid = jlong(m.sid)
                     is_buy = lane_act == SQ.L_BUY
                     o0 = int(offs[k])
                     for e in range(int(host["nfill"][k])):

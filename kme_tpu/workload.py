@@ -328,6 +328,73 @@ def payout_storm_stream(num_events: int, num_symbols: int,
     return msgs
 
 
+def market_lifecycle_stream(num_events: int, num_symbols: int,
+                            num_accounts: int, seed: int = 0,
+                            zipf_a: float = 1.2,
+                            deposit: int = 10_000_000
+                            ) -> Iterator[OrderMsg]:
+    """A prediction market that lists, trades, settles and is never
+    relisted: `num_symbols` binary contracts are open at any time, and
+    every settlement is followed at once by the listing of the next
+    contract under a FRESH symbol id (a settled contract names an event
+    that has happened; ids only grow, so a long run names many more ids
+    than are ever listed together).
+
+    Preamble as zipf_symbol_stream (accounts created and funded, ids
+    0..num_symbols-1 listed, rank r <-> id r). Then per event the
+    upstream's draw e = uniform(1000) and its mix (exchange_test.js:
+    106-117) with one slot added:
+      e == 0      settlement: rank r ~ Zipf(zipf_a) -- the law trades
+                  follow: a market is busiest as it resolves --
+                  PAYOUT(+-sid[r], 100 - rake), YES/NO by a coin as
+                  create_payout, then ADD_SYMBOL(next id) into rank r
+      e == 1      a late order: a BUY/SELL (coin) naming the id most
+                  recently paid out (it raced its market's close);
+                  before the first settlement an ordinary one
+      e in 2, 3   TRANSFER floor(N(0, 12500)) to a uniform account
+      4..335      BUY,  336..667 SELL on sid[rank ~ Zipf], uniform
+                  account, price and size floor(N(50, 10)) clamped
+                  into the device domain
+      668..999    create_cancel()
+    Lazy (a generator): a long stream's preamble can be served while
+    the rest is drawn. Seed-deterministic."""
+    gen = WorkloadGen(num_accounts, num_symbols, seed=seed, validate=True,
+                      payout_opcode_bug=False)
+    yield from _storm_preamble(gen, num_accounts, num_symbols, deposit)
+    cdf = _zipf_cdf(num_symbols, zipf_a)
+    sid_of = list(range(num_symbols))       # rank -> the id listed there
+    next_id = num_symbols
+    settled = None                          # the id paid out last
+
+    def trade(buy: bool, sid: int) -> OrderMsg:
+        make = gen.create_buy if buy else gen.create_sell
+        return make(gen._uniform(num_accounts), sid,
+                    gen._normal_param(50, 10), gen._normal_param(50, 10))
+
+    def rank() -> int:
+        return bisect.bisect_left(cdf, gen.rng.random())
+
+    for _ in range(num_events):
+        e = gen._uniform(1000)
+        if e == 0:
+            r = rank()
+            settled = sid_of[r]
+            yield gen.create_payout(settled, gen._uniform(2) == 0)
+            yield gen.create_symbol(next_id)
+            sid_of[r] = next_id
+            next_id += 1
+        elif e == 1:
+            buy = gen._uniform(2) == 0
+            yield trade(buy, sid_of[rank()] if settled is None else settled)
+        elif e <= 3:
+            yield gen.create_transfer(gen._uniform(num_accounts),
+                                      gen._normal_param(0, 125 * 100))
+        elif e <= 667:
+            yield trade(e <= 335, sid_of[rank()])
+        else:
+            yield gen.create_cancel()
+
+
 def cancel_heavy_stream(num_events: int, num_symbols: int, num_accounts: int,
                         seed: int = 0, cancel_ratio: float = 0.8,
                         deposit: int = 10_000_000) -> List[OrderMsg]:

@@ -5,6 +5,8 @@ exchange_test.js:33-36, SURVEY.md §4)."""
 
 import collections
 
+import pytest
+
 from kme_tpu import opcodes as op
 from kme_tpu.oracle import OracleEngine
 from kme_tpu.workload import WorkloadGen, cancel_heavy_stream, harness_stream, \
@@ -231,3 +233,70 @@ def test_adversarial_streams_survive_oracle():
                                  num_accounts=24, seed=2):
         e2.process(m)
     assert all(b >= 0 for b in e2.balances.values())
+
+
+# -- market_lifecycle_stream (PR 36): a market that lists, trades,
+# settles and is never relisted
+
+_LIFE = dict(num_symbols=1024, num_accounts=2048)
+
+
+def _lifecycle(events, seed):
+    from kme_tpu.workload import market_lifecycle_stream
+
+    return list(market_lifecycle_stream(events, seed=seed, **_LIFE))
+
+
+@pytest.mark.parametrize("seed", [3, 2 ** 31 + 5])
+def test_lifecycle_stream_is_seed_deterministic(seed):
+    a, b = _lifecycle(3000, seed), _lifecycle(3000, seed)
+    assert a == b and a != _lifecycle(3000, seed + 1)
+    # the preamble is zipf_symbol_stream's
+    assert a[:5120] == zipf_symbol_stream(0, 1024, 2048, seed=seed)
+
+
+def test_lifecycle_stream_never_repeats_an_id_and_holds_1024_listed():
+    msgs = _lifecycle(200_000, seed=7)
+    listed, ever, settled = set(), [], []
+    for m in msgs:
+        if m.action == op.ADD_SYMBOL:
+            assert m.sid not in ever
+            ever.append(m.sid)
+            listed.add(m.sid)
+        elif m.action == op.PAYOUT:
+            listed.remove(abs(m.sid))   # paid out once, while listed
+            settled.append(abs(m.sid))
+        elif m.action in (op.BUY, op.SELL) and m.sid not in listed:
+            # only the late order names an id that is not listed: the
+            # one paid out last
+            assert m.sid == settled[-1]
+        assert len(listed) in (1023, 1024) or len(ever) < 1024
+    assert ever == list(range(len(ever))) and len(ever) > 1024 + 150
+    assert len(listed) == 1024
+    # a settlement is followed at once by the next listing
+    for i, m in enumerate(msgs):
+        if m.action == op.PAYOUT:
+            assert msgs[i + 1].action == op.ADD_SYMBOL
+            assert m.size == 97
+
+
+def test_lifecycle_stream_reads_the_upstreams_mix_per_mille():
+    """exchange_test.js:106-117 with one slot added: per mille of EVENTS
+    1 settlement (a PAYOUT and its ADD_SYMBOL), 1 late order, 2
+    transfers, 332 buys, 332 sells, 332 cancels."""
+    n = 200_000
+    msgs = _lifecycle(n, seed=11)[5120:]
+    c = collections.Counter(m.action for m in msgs)
+    assert c[op.PAYOUT] == c[op.ADD_SYMBOL] and len(msgs) == n + c[op.PAYOUT]
+    per_mille = {k: 1000 * v / n for k, v in c.items()}
+    assert 0.7 < per_mille[op.PAYOUT] < 1.3
+    assert 1.6 < per_mille[op.TRANSFER] < 2.4
+    assert 329 < per_mille[op.CANCEL] < 335
+    # the late order is a buy or a sell by a coin
+    assert 329.5 < per_mille[op.BUY] < 335.5
+    assert 329.5 < per_mille[op.SELL] < 335.5
+    yes = sum(1 for m in msgs if m.action == op.PAYOUT and m.sid >= 0)
+    assert 0.35 < yes / c[op.PAYOUT] < 0.65
+    # trades stay inside the device domain, as `validate` clamps them
+    assert all(0 <= m.price <= 125 and m.size >= 1 for m in msgs
+               if m.action in (op.BUY, op.SELL))
