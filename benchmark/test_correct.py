@@ -66,8 +66,9 @@ LEAVING_SEED, LEAVES_AT = 2147483736, 14330
 
 def rehearse_leaving_seed(tmp_path, capsys):
     """-> (result, what the run printed); long enough a window that the
-    message at LEAVES_AT is served"""
-    result = rehearse(tmp_path, seed=LEAVING_SEED, seconds=8, events=20000)
+    message at LEAVES_AT is served, long enough a stream that it
+    outlasts half the window (20,000 events drained 2.5-3.3 s into it)"""
+    result = rehearse(tmp_path, seed=LEAVING_SEED, seconds=8, events=30000)
     traffic, _config = run.load_cell(CELL)
     assert result["attempted"] + traffic["warmup_messages"] > LEAVES_AT
     return result, capsys.readouterr().out
