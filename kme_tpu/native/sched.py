@@ -23,6 +23,7 @@ import numpy as np
 from kme_tpu.native import BoundaryError, check_buffer, load_library
 from kme_tpu.runtime.sequencer import (
     Barrier, EnvelopeError, CapacityError, HostReject, Schedule,
+    sorted_routes,
 )
 from kme_tpu.wire import OrderMsg
 
@@ -168,24 +169,37 @@ class NativeScheduler:
             keys.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
             vals.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)))
 
-    @property
-    def oid_sid(self) -> Dict[int, int]:
+    def routes_arrays(self):
+        """`oid_sid` as a snapshot carries it (sorted_routes), straight
+        from the C++ map: no dict in between."""
         n = self._lib.kme_sched_n_routes(self._h)
         keys = np.zeros(n, np.int64)
         vals = np.zeros(n, np.int64)
         self._lib.kme_sched_export_routes(
             self._h, keys.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
             vals.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)))
+        return sorted_routes(keys, vals)
+
+    def import_routes(self, keys, vals) -> None:
+        keys = np.ascontiguousarray(keys, np.int64)
+        vals = np.ascontiguousarray(vals, np.int64)
+        if keys.shape != vals.shape or keys.ndim != 1:
+            raise ValueError(f"routes: {keys.shape} keys for "
+                             f"{vals.shape} values")
+        self._lib.kme_sched_import_routes(
+            self._h, len(keys),
+            keys.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+            vals.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)))
+
+    @property
+    def oid_sid(self) -> Dict[int, int]:
+        keys, vals = self.routes_arrays()
         return dict(zip(keys.tolist(), vals.tolist()))
 
     @oid_sid.setter
     def oid_sid(self, d: Dict[int, int]) -> None:
-        keys = np.fromiter(d.keys(), np.int64, len(d))
-        vals = np.fromiter(d.values(), np.int64, len(d))
-        self._lib.kme_sched_import_routes(
-            self._h, len(d),
-            keys.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-            vals.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)))
+        self.import_routes(np.fromiter(d.keys(), np.int64, len(d)),
+                           np.fromiter(d.values(), np.int64, len(d)))
 
     @property
     def _rr_lane(self) -> int:

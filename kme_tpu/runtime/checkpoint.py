@@ -21,9 +21,14 @@ re-produces with the SAME stamps and the broker suppresses it.
 
 Snapshots are self-describing single files: every state array plus a
 JSON `meta` blob (config, compaction width, shard count, input offset,
-scheduler id-maps) in one .npz, written atomically (tmp + rename) and
+the small id maps) in one .npz, written atomically (tmp + rename) and
 named ckpt-<offset>.npz so the latest valid one wins; a torn or corrupt
-file falls back to the previous snapshot.
+file falls back to the previous snapshot. The router's oid -> sid
+routes — every oid ever routed that no payout or removal dropped, the
+one id map that grows with the stream — are two int64 arrays of the
+payload, `route_oid` ascending and `route_sid` in its order (version 3;
+older files list them in the meta, and `_load_file` hands both on as
+the arrays).
 
 The device fill log is intentionally NOT saved: at a batch boundary it
 has been drained to the host and rewound (filloff == 0), so restore
@@ -72,6 +77,19 @@ _SKIP_KEYS = ("fillbuf",)
 _LANE_KEYS = ("slot_oid", "slot_aid", "slot_price", "slot_size",
               "slot_seq", "slot_used", "seq", "book_exists")
 _POS_KEYS = ("pos_amt", "pos_avail")  # flat (S*A,) lane-major
+
+
+# the version every .npz writer here gives its files: the routes as the
+# payload arrays `route_oid` / `route_sid` (versions 1 and 2 list them
+# in the meta as `oid_sid`; 2 is a "seq" file with a sparse section)
+_VERSION = 3
+
+
+def _routes_payload(router) -> dict:
+    """The router's oid -> sid routes as a snapshot carries them:
+    straight from where they live, no dict and no text in between."""
+    route_oid, route_sid = router.routes_arrays()
+    return {"route_oid": route_oid, "route_sid": route_sid}
 
 
 def snapshot_path(ckpt_dir: str, offset: int) -> str:
@@ -151,7 +169,7 @@ def save_session(ckpt_dir: str, session, offset: int,
                          "(call at a batch boundary)")
     sch = session.scheduler
     meta = {
-        "version": 1,
+        "version": _VERSION,
         "kind": "lanes",
         "offset": int(offset),
         "cfg": dataclasses.asdict(session.cfg),
@@ -159,14 +177,13 @@ def save_session(ckpt_dir: str, session, offset: int,
         "shards": int(session.shards),
         "aid_idx": sorted(sch.aid_idx.items()),
         "sid_lane": sorted(sch.sid_lane.items()),
-        "oid_sid": sorted(sch.oid_sid.items()),
         "rr_lane": sch._rr_lane,
     }
     if extra:
         meta["extra"] = dict(extra)
     S = session.cfg.lanes  # canonical lane count (no scrap row)
     A = session.cfg.accounts
-    payload = {}
+    payload = _routes_payload(sch)
     for k, v in state.items():
         if k in _SKIP_KEYS:
             continue
@@ -232,15 +249,27 @@ def _load_file(path: str):
     # is the java-mode canonical form (runtime/javasnap.py), restorable
     # into SeqSession(compat='java') and convertible to/from the native
     # engine's dump. Version 2 is a "seq" snapshot with a section given
-    # by its live entries (meta "layout"): a binary that knows only
-    # version 1 refuses it here and falls back to an older file
+    # by its live entries (meta "layout"); version 3 (any kind) carries
+    # the routes as arrays, and a "seq" one may have such sections too.
+    # A binary that does not know a file's version refuses it HERE and
+    # falls back to an older file
     version, kind = meta.get("version"), meta.get("kind")
-    if version == 2 and kind == "seq":
+    if (kind not in ("lanes", "seq", "seqjava") or version not in (1, 2, 3)
+            or (version == 2 and kind != "seq")):
+        raise ValueError(f"unsupported snapshot {path}")
+    if kind == "seq" and "layout" in meta:
         from kme_tpu.engine import seq as SQ
 
         data = SQ.densify_canonical(data, meta["layout"])
-    elif version != 1 or kind not in ("lanes", "seq", "seqjava"):
-        raise ValueError(f"unsupported snapshot {path}")
+    if version < 3:
+        pairs = np.array(meta.pop("oid_sid"), np.int64).reshape(-1, 2)
+        data["route_oid"] = np.ascontiguousarray(pairs[:, 0])
+        data["route_sid"] = np.ascontiguousarray(pairs[:, 1])
+    routes = [data.get("route_oid"), data.get("route_sid")]
+    if any(r is None or r.dtype != np.int64 or r.ndim != 1
+           for r in routes) or len(routes[0]) != len(routes[1]):
+        raise ValueError(f"snapshot {path}: no int64 route_oid / "
+                         f"route_sid arrays of one length")
     return data, meta
 
 
@@ -370,7 +399,7 @@ def _restore_one(path: str, shards: Optional[int], width: Optional[int]):
     sch = ses.scheduler
     sch.aid_idx = {int(k): int(i) for k, i in meta["aid_idx"]}
     sch.sid_lane = {int(k): int(l) for k, l in meta["sid_lane"]}
-    sch.oid_sid = {int(k): int(s) for k, s in meta["oid_sid"]}
+    sch.import_routes(data["route_oid"], data["route_sid"])
     sch._rr_lane = int(meta["rr_lane"])
     return ses
 
@@ -383,9 +412,9 @@ def _snapshot_export(session):
 
     with session.timer.phase("snapshot_export"):
         if session.cfg.compat == "java":
-            from kme_tpu.runtime.javasnap import export_seqjava
+            from kme_tpu.runtime.javasnap import export_seqjava_device
 
-            return export_seqjava(session), None
+            return export_seqjava_device(session), None
         from kme_tpu.engine import seq as SQ
 
         if type(session) is SeqSession:
@@ -393,6 +422,52 @@ def _snapshot_export(session):
         # a subclass keeps its state elsewhere (SeqMeshSession: sharded
         # across devices) and its dense export
         return SQ.export_canonical(session.cfg, session.state), None
+
+
+def _snapshot_payload(session, kind: str, offset: int,
+                      extra: Optional[dict], arrays: dict,
+                      layout: Optional[dict] = None) -> dict:
+    """The host half of a seq snapshot between the fetch and the write
+    (span `snapshot_meta` of the session's timer): the file's payload —
+    `arrays`, the routes' two (_routes_payload) and the meta JSON, which
+    holds all that is not an array, the two small id maps included
+    (<= `accounts` and <= `lanes` entries)."""
+    with session.timer.phase("snapshot_meta"):
+        r = session.router
+        meta = {
+            "version": _VERSION,
+            "kind": kind,
+            "offset": int(offset),
+            "cfg": dataclasses.asdict(session.cfg),
+            "metrics": [int(x) for x in session._metrics],
+            "hist": [[int(x) for x in row] for row in session._hist],
+            "aid_idx": sorted(r.aid_idx.items()),
+            "sid_lane": sorted(r.sid_lane.items()),
+        }
+        if kind == "seq":   # lanes-session cross-restore compatibility
+            meta.update(rr_lane=0, width=0, shards=1)
+        if layout and layout["sparse"]:
+            meta["layout"] = layout
+        if extra:
+            meta["extra"] = dict(extra)
+        payload = dict(arrays)
+        payload.update(_routes_payload(r))
+        payload["meta"] = np.frombuffer(
+            json.dumps(meta).encode(), dtype=np.uint8)
+        return payload
+
+
+def _snapshot_write(ckpt_dir: str, session, offset: int, payload: dict,
+                    keep: Optional[int]) -> str:
+    """The durable half of a seq snapshot (span `snapshot_write`), and
+    what the file holds as the session's `snapshot_gauges`."""
+    with session.timer.phase("snapshot_write"):
+        path = _atomic_savez(ckpt_dir, offset, payload, keep=keep)
+    session.snapshot_gauges = {
+        "snapshot_bytes": os.path.getsize(path),
+        "snapshot_routes": len(payload["route_oid"]),
+    }
+    return path
 
 
 def save_seq_session(ckpt_dir: str, session, offset: int,
@@ -404,49 +479,28 @@ def save_seq_session(ckpt_dir: str, session, offset: int,
     shard/width topologies. The books and the positions are each
     written by their live entries where that is the smaller encoding
     (engine/seq.py:export_snapshot; _load_file densifies), so a file's
-    size follows what is live and not the configured capacity."""
+    size follows what is live and not the configured capacity. Three
+    spans of the session's timer split the call: `snapshot_export` (the
+    device -> host fetch), `snapshot_meta` (the meta and the routes'
+    arrays) and `snapshot_write`."""
     if session.cfg.compat == "java":
         return _save_seqjava(ckpt_dir, session, offset, keep=keep,
                              extra=extra)
     os.makedirs(ckpt_dir, exist_ok=True)
     canon, layout = _snapshot_export(session)
-    sparse = layout["sparse"] if layout else []
-    r = session.router
-    meta = {
-        # a file with no sparse section IS a version-1 file
-        "version": 2 if sparse else 1,
-        "kind": "seq",
-        "offset": int(offset),
-        "cfg": dataclasses.asdict(session.cfg),
-        "metrics": [int(x) for x in session._metrics],
-        "hist": [[int(x) for x in row] for row in session._hist],
-        "aid_idx": sorted(r.aid_idx.items()),
-        "sid_lane": sorted(r.sid_lane.items()),
-        "oid_sid": sorted(r.oid_sid.items()),
-        "rr_lane": 0,   # lanes-session cross-restore compatibility
-        "width": 0,
-        "shards": 1,
-    }
-    if sparse:
-        meta["layout"] = layout
-    if extra:
-        meta["extra"] = dict(extra)
-    payload = {k: v for k, v in canon.items()
-               if k != "metrics" and v is not None}
-    payload["err"] = np.asarray(canon["err"])
+    arrays = {k: v for k, v in canon.items()
+              if k != "metrics" and v is not None}
+    arrays["err"] = np.asarray(canon["err"])
     # lanes-session cross-restore expects the drained fill-log cursor
-    payload["filloff"] = np.zeros(1, np.int64)
-    payload["meta"] = np.frombuffer(
-        json.dumps(meta).encode(), dtype=np.uint8)
-    with session.timer.phase("snapshot_write"):
-        path = _atomic_savez(ckpt_dir, offset, payload, keep=keep)
+    arrays["filloff"] = np.zeros(1, np.int64)
+    path = _snapshot_write(
+        ckpt_dir, session, offset, _snapshot_payload(
+            session, "seq", offset, extra, arrays, layout), keep)
     if layout:
-        session.snapshot_gauges = {
-            "snapshot_bytes": os.path.getsize(path),
-            "snapshot_live_slots": layout["live_slots"],
-            "snapshot_live_positions": layout["live_positions"],
-            "snapshot_sparse_sections": len(sparse),
-        }
+        session.snapshot_gauges.update(
+            snapshot_live_slots=layout["live_slots"],
+            snapshot_live_positions=layout["live_positions"],
+            snapshot_sparse_sections=len(layout["sparse"]))
     return path
 
 
@@ -460,32 +514,16 @@ def _save_seqjava(ckpt_dir: str, session, offset: int,
     router id maps."""
     os.makedirs(ckpt_dir, exist_ok=True)
     snap, _ = _snapshot_export(session)
-    meta = {
-        "version": 1,
-        "kind": "seqjava",
-        "offset": int(offset),
-        "cfg": dataclasses.asdict(session.cfg),
-        "metrics": [int(x) for x in session._metrics],
-        "hist": [[int(x) for x in row] for row in session._hist],
-        "aid_idx": sorted(snap["aid_idx"].items()),
-        "sid_lane": sorted(snap["sid_lane"].items()),
-        "oid_sid": sorted(snap["oid_sid"].items()),
-    }
-    if extra:
-        meta["extra"] = dict(extra)
-    payload = {k: np.asarray(v) for k, v in snap.items()
-               if k not in ("aid_idx", "sid_lane", "oid_sid")}
-    payload["meta"] = np.frombuffer(
-        json.dumps(meta).encode(), dtype=np.uint8)
-    with session.timer.phase("snapshot_write"):
-        return _atomic_savez(ckpt_dir, offset, payload, keep=keep)
+    arrays = {k: np.asarray(v) for k, v in snap.items()}
+    return _snapshot_write(
+        ckpt_dir, session, offset, _snapshot_payload(
+            session, "seqjava", offset, extra, arrays), keep)
 
 
 def _seqjava_snap_from_file(data, meta) -> dict:
     snap = {k: v for k, v in data.items() if k != "meta"}
     snap["aid_idx"] = {int(k): int(v) for k, v in meta["aid_idx"]}
     snap["sid_lane"] = {int(k): int(v) for k, v in meta["sid_lane"]}
-    snap["oid_sid"] = {int(k): int(v) for k, v in meta["oid_sid"]}
     return snap
 
 
@@ -595,7 +633,7 @@ def _restore_seq(data, meta, cfg):
     r = ses.router
     r.aid_idx = {int(k): int(i) for k, i in meta["aid_idx"]}
     r.sid_lane = {int(k): int(l) for k, l in meta["sid_lane"]}
-    r.oid_sid = {int(k): int(s) for k, s in meta["oid_sid"]}
+    r.import_routes(data["route_oid"], data["route_sid"])
     # the router's pool of free lanes is rebuilt from `sid_lane` by its
     # setter; which bound ids hold no book, from the restored books
     r.set_listed(np.asarray(canon["book_exists"]).reshape(-1))

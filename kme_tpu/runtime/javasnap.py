@@ -14,7 +14,11 @@ snapshot stores the SEMANTIC content, not the physical layout:
 - resting orders: (oid, aidx, is_buy, price, size, seq, lane) in
   (lane, side, slot) order. Slot POSITIONS are not semantic (the kernel
   orders by (price, seq)); within-bucket seq order is;
-- balances / book-exists / seq counters / router id maps.
+- balances / book-exists / seq counters / router id maps; of these the
+  oid -> sid routes are two int64 arrays (`route_oid` ascending,
+  `route_sid` in its order: a map that holds every oid ever routed is
+  not copied into a dict to be carried), `aid_idx` / `sid_lane` plain
+  dicts.
 
 Cross-engine: `to_native_dump` emits the native engine's checkpoint
 text (kme_oracle.cpp dump_state grammar: B/P/K/U/O lines) with bucket
@@ -66,6 +70,17 @@ def _jhome(ka: int, kb: int, tilemask: int) -> int:
 def export_seqjava(session) -> dict:
     """SeqSession(compat='java') -> canonical snapshot dict (numpy
     arrays + plain dicts; see module docstring)."""
+    r = session.router
+    route_oid, route_sid = r.routes_arrays()
+    return {**export_seqjava_device(session),
+            "aid_idx": dict(r.aid_idx), "sid_lane": dict(r.sid_lane),
+            "route_oid": route_oid, "route_sid": route_sid}
+
+
+def export_seqjava_device(session) -> dict:
+    """The device's half of the canonical form: the fetch and its
+    repack (all of the checkpoint's span `snapshot_export`; the
+    router's half is under `snapshot_meta` there)."""
     from kme_tpu.engine import seq as SQ
 
     cfg = session.cfg
@@ -89,7 +104,6 @@ def export_seqjava(session) -> dict:
                         int(j["slot_price"][lane, side, nn]),
                         int(j["slot_size"][lane, side, nn]),
                         int(slot_seq[lane, side, nn]), lane))
-    r = session.router
     return {
         "pos_ka": np.array([k[0] for k in keys], np.int64),
         "pos_kb": np.array([k[1] for k in keys], np.int64),
@@ -103,9 +117,6 @@ def export_seqjava(session) -> dict:
         "bal": np.asarray(j["bal"], np.int64),
         "bal_used": j["bal_used"].astype(np.int32),
         "err": np.int32(j["err"]),
-        "aid_idx": dict(r.aid_idx),
-        "sid_lane": dict(r.sid_lane),
-        "oid_sid": dict(r.oid_sid),
     }
 
 
@@ -263,7 +274,7 @@ def import_seqjava(cfg, snap) -> "SeqSession":
     r = ses.router
     r.aid_idx = aid_idx
     r.sid_lane = sid_lane
-    r.oid_sid = {int(k): int(v) for k, v in snap["oid_sid"].items()}
+    r.import_routes(snap["route_oid"], snap["route_sid"])
     return ses
 
 
@@ -421,6 +432,13 @@ def from_native_dump(text: str) -> dict:
     for lane, c in seqc.items():
         seqc_arr[lane] = c
     lane_sid = {v: k for k, v in sid_lane.items()}
+    from kme_tpu.runtime.sequencer import sorted_routes
+
+    # resting oids route to their symbol; non-resting oids need no
+    # route (a device REJECT and a host REJECT are the same bytes)
+    route_oid, route_sid = sorted_routes(
+        np.array([r[0] for r in rest], np.int64),
+        np.array([lane_sid[r[6]] for r in rest], np.int64))
     return {
         "pos_ka": np.array([p[0] for p in positions], np.int64),
         "pos_kb": np.array([p[1] for p in positions], np.int64),
@@ -434,7 +452,5 @@ def from_native_dump(text: str) -> dict:
         "err": np.int32(0),
         "aid_idx": aid_idx,
         "sid_lane": sid_lane,
-        # resting oids route to their symbol; non-resting oids need no
-        # route (a device REJECT and a host REJECT are the same bytes)
-        "oid_sid": {int(r[0]): int(lane_sid[int(r[6])]) for r in rest},
+        "route_oid": route_oid, "route_sid": route_sid,
     }

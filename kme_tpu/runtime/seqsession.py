@@ -28,7 +28,8 @@ from kme_tpu import opcodes as op
 from kme_tpu.engine import seq as SQ
 from kme_tpu.runtime import session as _session
 from kme_tpu.runtime.session import LaneEngineError
-from kme_tpu.runtime.sequencer import CapacityError, EnvelopeError
+from kme_tpu.runtime.sequencer import (CapacityError, DictRoutes,
+                                        EnvelopeError, sorted_routes)
 from kme_tpu.telemetry import PhaseTimer, Registry
 from kme_tpu.wire import (OrderMsg, OutRecord, WireBatch, order_json,
                           reject_reason_codes)
@@ -68,7 +69,7 @@ ROUTER_STATS = ("symbols_listed", "symbols_settled", "lanes_released",
                 "route_purge_n", "lanes_bound")
 
 
-class SeqRouter:
+class SeqRouter(DictRoutes):
     """Arrival-order ID routing (no conflict analysis). Mirrors the
     sequencer's id spaces and host-reject edge semantics. compat='java'
     additionally emits the raw Java-long aid/sid columns and the Q1
@@ -342,7 +343,8 @@ class NativeSeqRouter:
         self._map_epoch = 0
 
     # -- map views (checkpoint save/load reads+writes these) -----------
-    def _export(self, nfn, efn, vdt):
+    def _export_arrays(self, nfn, efn, vdt):
+        """(keys, values) of one C++ map, in the map's own order."""
         import ctypes
 
         n = nfn(self._h)
@@ -352,19 +354,30 @@ class NativeSeqRouter:
         PV = ctypes.POINTER(
             ctypes.c_int32 if vdt == np.int32 else ctypes.c_int64)
         efn(self._h, keys.ctypes.data_as(P64), vals.ctypes.data_as(PV))
+        return keys, vals
+
+    def _export(self, nfn, efn, vdt):
+        keys, vals = self._export_arrays(nfn, efn, vdt)
         return dict(zip(keys.tolist(), vals.tolist()))
 
-    def _import(self, ifn, d, vdt):
+    def _import_arrays(self, ifn, keys, vals, vdt):
         import ctypes
 
         self._map_epoch += 1
-        keys = np.fromiter(d.keys(), np.int64, len(d))
-        vals = np.fromiter(d.values(), vdt, len(d))
+        keys = np.ascontiguousarray(keys, np.int64)
+        vals = np.ascontiguousarray(vals, vdt)
+        if keys.shape != vals.shape or keys.ndim != 1:
+            raise ValueError(f"id map: {keys.shape} keys for "
+                             f"{vals.shape} values")
         P64 = ctypes.POINTER(ctypes.c_int64)
         PV = ctypes.POINTER(
             ctypes.c_int32 if vdt == np.int32 else ctypes.c_int64)
-        ifn(self._h, len(d), keys.ctypes.data_as(P64),
+        ifn(self._h, len(keys), keys.ctypes.data_as(P64),
             vals.ctypes.data_as(PV))
+
+    def _import(self, ifn, d, vdt):
+        self._import_arrays(ifn, np.fromiter(d.keys(), np.int64, len(d)),
+                            np.fromiter(d.values(), vdt, len(d)), vdt)
 
     @property
     def aid_idx(self):
@@ -429,6 +442,18 @@ class NativeSeqRouter:
     @oid_sid.setter
     def oid_sid(self, d):
         self._import(self._lib.kme_router_import_routes, d, np.int64)
+
+    def routes_arrays(self):
+        """`oid_sid` as a snapshot carries it (sorted_routes), straight
+        from the C++ map: no dict in between."""
+        lib = self._lib
+        return sorted_routes(*self._export_arrays(
+            lib.kme_router_n_routes, lib.kme_router_export_routes,
+            np.int64))
+
+    def import_routes(self, keys, vals) -> None:
+        self._import_arrays(self._lib.kme_router_import_routes, keys,
+                            vals, np.int64)
 
     def acct_of_idx(self) -> List[int]:
         m = self.aid_idx
@@ -569,7 +594,7 @@ class SeqSession:
     # heartbeat, so that a reader of two snapshots finds it in both
     SPANS = ("plan_s", "stage_s", "dispatch_s", "fetch_s", "recon_s",
              "session_metrics", "metrics_export", "metrics_count",
-             "snapshot_export", "snapshot_write")
+             "snapshot_export", "snapshot_meta", "snapshot_write")
 
     def __init__(self, cfg: SQ.SeqConfig) -> None:
         self.cfg = cfg

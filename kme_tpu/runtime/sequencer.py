@@ -109,6 +109,32 @@ class Schedule:
 _TRADE_ACTS = {op.BUY: L.L_BUY, op.SELL: L.L_SELL}
 
 
+def sorted_routes(keys: np.ndarray, vals: np.ndarray):
+    """The oid -> sid routes a snapshot carries (runtime/checkpoint.py):
+    two int64 arrays, keys ascending and values in the keys' order — a
+    map's own order is not reproducible, and two snapshots of one state
+    must carry one digest."""
+    order = np.argsort(keys)
+    return keys[order], vals[order]
+
+
+class DictRoutes:
+    """The snapshot's view of a Python router's `oid_sid` dict (the
+    native twins answer the same two calls from their C++ maps)."""
+
+    oid_sid: Dict[int, int]
+
+    def routes_arrays(self):
+        """`oid_sid` as a snapshot carries it (sorted_routes)."""
+        d = self.oid_sid
+        return sorted_routes(np.fromiter(d.keys(), np.int64, len(d)),
+                             np.fromiter(d.values(), np.int64, len(d)))
+
+    def import_routes(self, keys, vals) -> None:
+        self.oid_sid = dict(zip(np.asarray(keys).tolist(),
+                                np.asarray(vals).tolist()))
+
+
 def make_scheduler(num_lanes: int, num_accounts: int, width: int = 0):
     """The native C++ scheduler when the toolchain/library is available
     (KME_NATIVE=0 disables), else this module's Python implementation —
@@ -126,7 +152,7 @@ def make_scheduler(num_lanes: int, num_accounts: int, width: int = 0):
     return Scheduler(num_lanes, num_accounts, width)
 
 
-class Scheduler:
+class Scheduler(DictRoutes):
     def __init__(self, num_lanes: int, num_accounts: int,
                  width: int = 0) -> None:
         if width < 0:

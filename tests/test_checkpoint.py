@@ -781,8 +781,8 @@ SPARSE_CASES = {
 def test_sparse_snapshot_loads_as_the_canonical_state(case, tmp_path):
     """Whatever the encoding, _load_file hands on export_canonical's
     state: equal on every live slot and position, zero on every dead
-    one; a section past its break-even comes out dense, as the parent
-    wrote it (version 1, readable by an older binary)."""
+    one; a section past its break-even comes out dense. Every file is
+    version 3 (the routes are arrays), with or without a `layout`."""
     import json
 
     import numpy as np
@@ -806,7 +806,7 @@ def test_sparse_snapshot_loads_as_the_canonical_state(case, tmp_path):
     meta = json.loads(bytes(raw["meta"]).decode())
     sparse = meta.get("layout", {}).get("sparse", [])
     assert len(sparse) == n_sparse
-    assert meta["version"] == (2 if sparse else 1)
+    assert meta["version"] == 3 and ("layout" in meta) == bool(sparse)
     assert ("slot_idx" in raw) == ("books" in sparse) \
         == ("slot_used" not in raw)
     assert ("pos_idx" in raw) == ("positions" in sparse)
@@ -831,6 +831,7 @@ def test_sparse_snapshot_loads_as_the_canonical_state(case, tmp_path):
             assert np.array_equal(data[k], v), k
     assert ses.snapshot_gauges == {
         "snapshot_bytes": os.path.getsize(path),
+        "snapshot_routes": 0,
         "snapshot_live_slots": int(used.sum()),
         "snapshot_live_positions": int(
             ((want["pos_amt"] != 0) | (want["pos_avail"] != 0)).sum()),
@@ -934,7 +935,7 @@ def test_resume_from_sparse_snapshot_reuses_freed_slots(tmp_path):
     # freed slots keep the dead order's words, on every side in use
     assert (canon["slot_oid"][dead] != 0).sum() >= 6 * 15
     _, meta = ck._load_file(ck.snapshot_path(kw["checkpoint_dir"], cut))
-    assert meta["version"] == 2 and len(meta["layout"]["sparse"]) == 2
+    assert meta["version"] == 3 and len(meta["layout"]["sparse"]) == 2
     assert svc.run(max_messages=128) == 128     # past the snapshot...
     del svc                                     # ...and killed
 
@@ -1058,3 +1059,320 @@ def test_snapshot_gauges_read_what_the_file_holds(tmp_path):
     assert gauges["snapshot_bytes"] == os.path.getsize(path)
     assert gauges["snapshot_sparse_sections"] == 2
     assert gauges["snapshot_export_n"] == gauges["snapshot_write_n"] == 1
+
+
+# ---------------------------------------------------------------------------
+# the routes as two int64 arrays of the payload (version 3): no writer
+# copies `oid_sid` into a dict, sorts its pairs or prints them as text
+
+def _raw(path):
+    """(arrays, meta) of a file as written, nothing converted."""
+    import json
+
+    import numpy as np
+
+    with np.load(path) as z:
+        raw = {k: z[k] for k in z.files}
+    return raw, json.loads(bytes(raw["meta"]).decode())
+
+
+def _router(ses):
+    return ses.scheduler if isinstance(ses, LaneSession) else ses.router
+
+
+def _writer(kind, monkeypatch=None):
+    """-> (fresh session, its stream, save, load) of one .npz writer."""
+    from kme_tpu.native import load_library
+    from kme_tpu.runtime import seqsession
+
+    if kind == "lanes":
+        return (LaneSession(CFG), list(_stream(600, seed=21)),
+                ck.save_session, ck.load_session)
+    if kind == "java":
+        from kme_tpu.runtime.seqsession import SeqSession
+
+        return (SeqSession(_java_cfg()), _java_stream(n=600),
+                ck.save_seq_session, ck.load_seq_session)
+    if kind == "fixed-python":
+        monkeypatch.setattr(
+            seqsession, "make_seq_router",
+            lambda lanes, accounts, compat="fixed":
+            seqsession.SeqRouter(lanes, accounts, compat))
+    elif load_library() is None:
+        pytest.skip("native host runtime unavailable")
+    ses = _seq_session(**SMALL)
+    want = seqsession.SeqRouter if kind == "fixed-python" \
+        else seqsession.NativeSeqRouter
+    assert type(ses.router) is want
+    return (ses, list(zipf_symbol_stream(900, 8, 64, seed=12, zipf_a=0.0)),
+            ck.save_seq_session, ck.load_seq_session)
+
+
+WRITERS = ["fixed-native", "fixed-python", "java", "lanes"]
+
+
+@pytest.mark.parametrize("kind", WRITERS)
+def test_routes_round_trip_as_two_sorted_arrays(kind, tmp_path,
+                                                monkeypatch):
+    """Every writer puts the routes in the payload, keys ascending and
+    values in their order, and none in the meta; the restored router
+    holds the same map and serves the same continuation."""
+    import numpy as np
+
+    ses, msgs, save, load = _writer(kind, monkeypatch)
+    cut = 400
+    ses.process_wire([m.copy() for m in msgs[:cut]])
+    want = dict(_router(ses).oid_sid)
+    assert len(want) > 100
+    raw, meta = _raw(save(str(tmp_path), ses, cut))
+    assert meta["version"] == 3 and "oid_sid" not in meta
+    for k in ("route_oid", "route_sid"):
+        assert raw[k].dtype == np.int64 and raw[k].shape == (len(want),)
+    assert raw["route_oid"].tolist() == sorted(want)
+    assert raw["route_sid"].tolist() == [want[k] for k in sorted(want)]
+    back, off = load(str(tmp_path))
+    assert off == cut and type(_router(back)) is type(_router(ses))
+    assert dict(_router(back).oid_sid) == want
+    assert back.process_wire([m.copy() for m in msgs[cut:]]) \
+        == ses.process_wire([m.copy() for m in msgs[cut:]])
+    assert dict(_router(back).oid_sid) == dict(_router(ses).oid_sid)
+
+
+@pytest.mark.parametrize("src", ["lanes", "fixed-native"])
+def test_routes_cross_the_engines(src, tmp_path):
+    """lanes -> seq and seq -> lanes: the one loader hands both the
+    same two arrays, and a cancel of an order that rested before the
+    cut still finds its book."""
+    ses, msgs, save, _ = _writer(src)
+    cut = 400
+    ses.process_wire([m.copy() for m in msgs[:cut]])
+    want = dict(_router(ses).oid_sid)
+    save(str(tmp_path), ses, cut)
+    load = ck.load_seq_session if src == "lanes" else ck.load_session
+    back, off = load(str(tmp_path))
+    assert off == cut and isinstance(back, LaneSession) == (src != "lanes")
+    assert dict(_router(back).oid_sid) == want
+    ora = OracleEngine("fixed", book_slots=back.cfg.slots,
+                       max_fills=back.cfg.max_fills)
+    per_msg = [[r.wire() for r in ora.process(m.copy())] for m in msgs]
+    assert back.process_wire([m.copy() for m in msgs[cut:]]) == per_msg[cut:]
+
+
+RECORDED = ["seq_pre_pr29.npz", "seq_dense_pr34.npz", "seq_sparse_pr35.npz",
+            "seq_routes_json_pr39.npz"]
+
+
+@pytest.mark.parametrize("name", RECORDED)
+def test_recorded_files_restore_the_routes_their_meta_lists(name, tmp_path):
+    """Every file written before version 3 lists its routes in the meta
+    (`oid_sid`, sorted pairs): the loader hands them on as the arrays a
+    new file carries, and both engines' routers hold that map."""
+    import shutil
+
+    src = os.path.join(HERE, "data", name)
+    raw, meta = _raw(src)
+    assert meta["version"] in (1, 2) and "route_oid" not in raw
+    want = {int(k): int(v) for k, v in meta["oid_sid"]}
+    assert len(want) > 100
+    path = ck.snapshot_path(str(tmp_path), meta["offset"])
+    shutil.copy(src, path)
+    data, loaded = ck._load_file(path)
+    assert "oid_sid" not in loaded
+    assert data["route_oid"].tolist() == sorted(want)
+    assert data["route_sid"].tolist() == [want[k] for k in sorted(want)]
+    ses, off = ck.load_seq_session(str(tmp_path))
+    assert off == meta["offset"] and dict(ses.router.oid_sid) == want
+    lanes, _ = ck.load_session(str(tmp_path))
+    assert dict(lanes.scheduler.oid_sid) == want
+
+
+def test_the_parents_newest_file_restores_and_serves_on(tmp_path):
+    """seq_routes_json_pr39.npz — written by `git archive 89e385f`'s
+    save_seq_session (version 2, routes as JSON) at offset 600 of the
+    stream below, after a payout freed a lane and purged its routes:
+    the guard for the next change of format."""
+    import shutil
+
+    import kme_tpu.opcodes as op
+    from kme_tpu.native.oracle import NativeOracleEngine
+    from kme_tpu.wire import OrderMsg
+
+    msgs = list(zipf_symbol_stream(900, 8, 64, seed=13, zipf_a=0.0))
+    msgs.insert(500, OrderMsg(action=op.PAYOUT, sid=5, size=97))
+    msgs += [OrderMsg(action=op.ADD_SYMBOL, sid=100),
+             OrderMsg(action=op.BUY, oid=5, aid=1, sid=100, price=50,
+                      size=2)]
+    shutil.copy(os.path.join(HERE, "data", "seq_routes_json_pr39.npz"),
+                ck.snapshot_path(str(tmp_path), 600))
+    want = NativeOracleEngine("fixed", book_slots=128, max_fills=16
+                              ).process_wire([m.copy() for m in msgs])
+    ses, off = ck.load_seq_session(str(tmp_path))
+    assert off == 600 and 5 not in ses.router.sid_lane
+    assert 5 not in set(ses.router.oid_sid.values())
+    assert ses.process_wire([m.copy() for m in msgs[600:]]) == want[600:]
+    assert ses.router.sid_lane[100] == 5        # the lowest free lane
+
+
+@pytest.mark.parametrize("kind", ["fixed-native", "java", "lanes"])
+def test_a_session_that_routed_nothing_saves_zero_routes(kind, tmp_path):
+    import numpy as np
+
+    ses, _, save, load = _writer(kind)
+    raw, _ = _raw(save(str(tmp_path), ses, 0))
+    for k in ("route_oid", "route_sid"):
+        assert raw[k].dtype == np.int64 and raw[k].shape == (0,)
+    back, _ = load(str(tmp_path))
+    assert dict(_router(back).oid_sid) == {}
+
+
+def test_two_snapshots_of_one_state_carry_one_digest(tmp_path):
+    """An unordered_map's order follows its history: a router restored
+    from the sorted arrays and the one that routed the stream hold one
+    map in two orders, and their files are equal byte for byte in
+    every array."""
+    import numpy as np
+
+    ses, msgs, save, load = _writer("fixed-native")
+    ses.process_wire([m.copy() for m in msgs[:600]])
+    a, _ = _raw(save(str(tmp_path / "a"), ses, 600))
+    back, _ = load(str(tmp_path / "a"))
+    own = lambda r: r._export_arrays(r._lib.kme_router_n_routes,
+                                     r._lib.kme_router_export_routes,
+                                     np.int64)[0].tolist()
+    assert own(back.router) != own(ses.router)      # two orders...
+    assert sorted(own(back.router)) == sorted(own(ses.router))
+    b, _ = _raw(save(str(tmp_path / "b"), back, 600))
+    assert bytes(a["digest"]) == bytes(b["digest"])     # ...one file
+    assert a.keys() == b.keys()
+    assert all(np.array_equal(a[k], b[k]) for k in a)
+
+
+@pytest.mark.parametrize("kind", ["fixed-native", "lanes"])
+def test_a_native_routers_map_is_never_made_a_dict(kind, tmp_path,
+                                                   monkeypatch):
+    """Neither the save nor the restore touches the `oid_sid` dict
+    property of a native router: the arrays go C++ -> file -> C++."""
+    ses, msgs, save, load = _writer(kind)
+    ses.process_wire([m.copy() for m in msgs[:400]])
+    router = _router(ses)
+    if "Native" not in type(router).__name__:
+        pytest.skip("native host runtime unavailable")
+    want = dict(router.oid_sid)
+
+    def never(self, *a):
+        raise AssertionError("oid_sid copied into a dict")
+
+    with monkeypatch.context() as mp:
+        mp.setattr(type(router), "oid_sid", property(never, never))
+        raw, _ = _raw(save(str(tmp_path), ses, 400))
+        back, _ = load(str(tmp_path))
+    assert len(raw["route_oid"]) == len(want)
+    assert type(_router(back)) is type(router)
+    assert dict(_router(back).oid_sid) == want
+
+
+def test_a_bit_flipped_in_the_routes_fails_the_digest(tmp_path):
+    """The routes are under the content digest as arrays: one oid's sid
+    altered, and the loader takes the snapshot before it."""
+    import numpy as np
+
+    from kme_tpu.engine import seq as SQ
+
+    msgs = _two_sparse_snapshots(tmp_path)
+    path = ck.snapshot_path(str(tmp_path), 600)
+    with np.load(path) as z:
+        data = {k: z[k].copy() for k in z.files}
+    data["route_sid"][len(data["route_sid"]) // 2] ^= 1  # digest kept STALE
+    with open(path, "wb") as f:
+        np.savez(f, **data)
+    with pytest.raises(ValueError, match="digest mismatch"):
+        ck._load_file(path)
+    ses, off = ck.load_seq_session(str(tmp_path), SQ.SeqConfig(**SMALL))
+    assert off == 400
+    ora = OracleEngine("fixed", book_slots=128, max_fills=16)
+    per_msg = [[r.wire() for r in ora.process(m.copy())] for m in msgs]
+    assert ses.process_wire([m.copy() for m in msgs[off:]]) == per_msg[off:]
+
+
+def _version_rule_of_the_parent(meta):
+    """runtime/checkpoint.py:_load_file's version check as 89e385f has
+    it (the last binary that knows only versions 1 and 2)."""
+    version, kind = meta.get("version"), meta.get("kind")
+    if version == 2 and kind == "seq":
+        return
+    elif version != 1 or kind not in ("lanes", "seq", "seqjava"):
+        raise ValueError("unsupported snapshot")
+
+
+@pytest.mark.parametrize("kind", ["fixed-native", "java", "lanes"])
+def test_a_reader_of_versions_1_and_2_refuses_a_new_file(kind, tmp_path):
+    """The rule itself passes every recorded file, and stops a version-3
+    file of each kind before anything reads `meta["oid_sid"]`."""
+    for name in RECORDED:
+        _version_rule_of_the_parent(_raw(os.path.join(HERE, "data",
+                                                      name))[1])
+    ses, msgs, save, _ = _writer(kind)
+    ses.process_wire([m.copy() for m in msgs[:200]])
+    _, meta = _raw(save(str(tmp_path), ses, 200))
+    with pytest.raises(ValueError, match="unsupported snapshot"):
+        _version_rule_of_the_parent(meta)
+
+
+def test_a_file_of_a_later_version_is_refused_in_load_file(tmp_path,
+                                                           monkeypatch):
+    """The same rule looking forward: this binary refuses a version it
+    does not know in _load_file, where the loaders fall back, and not
+    with a KeyError in the unguarded restore."""
+    from kme_tpu.engine import seq as SQ
+
+    msgs = list(zipf_symbol_stream(900, 8, 64, seed=12, zipf_a=0.0))
+    ses = _seq_session(**SMALL)
+    ses.process_wire([m.copy() for m in msgs[:400]])
+    ck.save_seq_session(str(tmp_path), ses, 400)
+    ses.process_wire([m.copy() for m in msgs[400:600]])
+    monkeypatch.setattr(ck, "_VERSION", 4)
+    path = ck.save_seq_session(str(tmp_path), ses, 600)
+    with pytest.raises(ValueError, match="unsupported snapshot"):
+        ck._load_file(path)
+    back, off = ck.load_seq_session(str(tmp_path), SQ.SeqConfig(**SMALL))
+    assert off == 400
+    lanes, off = ck.load_session(str(tmp_path))
+    assert off == 400 and isinstance(lanes, LaneSession)
+
+
+@pytest.mark.parametrize("compat", ["fixed", "java"])
+def test_snapshot_meta_span_and_routes_gauge_are_published(compat,
+                                                           tmp_path):
+    """`snapshot_meta` splits `snapshot_save` with `snapshot_export`
+    and `snapshot_write`; `snapshot_routes` / `snapshot_bytes` say what
+    the newest file holds — in java mode too."""
+    if compat == "fixed":
+        msgs, cut = _reuse_stream()
+        broker, kw = _exactly_once(tmp_path, "m", msgs)
+    else:
+        msgs, cut = _java_stream(n=600), 512
+        broker = InProcessBroker(persist_dir=str(tmp_path / "m-log"))
+        provision(broker)
+        for m in msgs:
+            broker.produce(TOPIC_IN, None, dumps_order(m))
+        kw = dict(engine="seq", compat="java", symbols=8, accounts=128,
+                  slots=512, max_fills=128, batch=256,
+                  checkpoint_dir=str(tmp_path / "m-ck"),
+                  checkpoint_every=10**9)
+    svc = MatchService(broker, **kw)
+    cut = svc.run(max_messages=cut)
+    before = svc.telemetry.snapshot()["gauges"]
+    assert before["snapshot_meta_s"] == 0 == before["snapshot_meta_n"]
+    assert "snapshot_routes" not in before
+    svc.checkpoint()
+    svc._publish_spans()
+    gauges = svc.telemetry.snapshot()["gauges"]
+    path = ck.snapshot_path(kw["checkpoint_dir"], cut)
+    raw, _ = _raw(path)
+    assert gauges["snapshot_routes"] == len(raw["route_oid"]) \
+        == len(svc._session.router.oid_sid) > 100
+    assert gauges["snapshot_bytes"] == os.path.getsize(path)
+    for span in ("snapshot_export", "snapshot_meta", "snapshot_write"):
+        assert gauges[span + "_n"] == 1 and gauges[span + "_s"] > 0
+    assert gauges["snapshot_export_s"] + gauges["snapshot_meta_s"] \
+        + gauges["snapshot_write_s"] <= gauges["snapshot_save_s"]

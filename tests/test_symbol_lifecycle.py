@@ -16,8 +16,9 @@ What must hold:
 - a message naming an id that holds no lane is rejected and takes none;
   `REMOVE_SYMBOL` alone keeps the lane (its positions stay);
 - a snapshot taken between a settlement and the next listing restores
-  to the same continuation and the same lane choice; the snapshot's
-  format is the parent's (its files restore, both versions)."""
+  to the same continuation and the same lane choice; the parent's
+  files restore (both versions); a snapshot after payouts carries only
+  the routes that survived them."""
 
 import os
 import random
@@ -357,6 +358,35 @@ def test_snapshot_between_a_settlement_and_the_next_listing(stream,
         assert s.process_wire([m.copy() for m in tail]) == want[len(head):]
         assert_state(s, stores)
     assert back.router.sid_lane == ses.router.sid_lane
+
+
+@pytest.mark.parametrize("router", ["native", "python"])
+def test_a_snapshot_after_payouts_holds_only_the_surviving_routes(
+        stream, router, tmp_path):
+    """A settlement purges its symbol's routes from the router, and the
+    snapshot carries what the router holds, nothing thinned and nothing
+    kept back: the file's two arrays are the Python router's map over
+    the same messages, and none of them names a settled id."""
+    msgs, lines, _ = stream
+    payouts = [i for i, (m, g) in enumerate(zip(msgs, lines))
+               if m.action == op.PAYOUT and '"action":200' in g[-1]]
+    head = msgs[:payouts[20] + 1]
+    ses = SeqSession(CFG)
+    if router == "python":
+        ses.router = SeqRouter(LANES, 128)
+    elif load_library() is None:
+        pytest.skip("native host runtime unavailable")
+    ses.process_wire([m.copy() for m in head])
+    ref = SeqRouter(LANES, 128)
+    ref.route([m.copy() for m in head])
+    with np.load(ck.save_seq_session(str(tmp_path), ses, len(head))) as z:
+        got = dict(zip(z["route_oid"].tolist(), z["route_sid"].tolist()))
+        assert z["route_oid"].tolist() == sorted(got)
+    assert got == ref.oid_sid and got
+    traded = {m.oid for m in head if m.action in (op.BUY, op.SELL)}
+    assert len(got) < len(traded) / 2           # most went with a wipe
+    assert set(got.values()) <= set(ses.router.sid_lane)
+    assert ses.snapshot_gauges["snapshot_routes"] == len(got)
 
 
 def sid_listed(msgs):
