@@ -63,6 +63,13 @@ _LIFECYCLE_COUNTERS = {
                     "held before",
     "unlisted_rejects": "trades, cancels and barriers host-rejected "
                         "because their symbol id holds no lane",
+    "routes_made": "trades the seq router wrote an oid route for",
+    "routes_dropped": "oid routes the seq router dropped because their "
+                      "order left the book (refused, filled, cancelled)",
+    "cancels_routed": "cancels that found their order's route and went "
+                      "to the device",
+    "cancels_host_rejected": "cancels host-rejected because no route "
+                             "names their oid",
     "barrier_wiped_orders": "resting orders the seq kernel's barrier "
                             "section took off the books (its own count)",
     "barrier_credited_positions": "positions a YES payout credited in "
@@ -1567,6 +1574,9 @@ class MatchService:
         gauges["lanes_bound"] = bound
         gauges["lanes_free"] = (self._session.cfg.lanes - bound
                                 if routed else 0)
+        # oid routes the router holds as of the newest batch collected
+        # (it plans `pipeline` batches ahead: their routes are in)
+        gauges["routes_held"] = getattr(self._session, "routes_held", 0)
         t.counter("matchout_produce_calls",
                   "broker calls made for output-stream records: one a "
                   "run on a broker with produce_stamped, one a record "
@@ -2004,8 +2014,19 @@ class MatchService:
                 "pos_load_pct": round(100.0 * live / cap, 4)})
 
     def metrics(self) -> Optional[dict]:
-        """On-device counters+gauges (lanes engine; None for oracle)."""
-        return self._session.metrics() if self._session is not None else None
+        """On-device counters+gauges (lanes engine; None for oracle).
+        A seq session with nothing in flight adds `stale_routes`: the
+        oid routes it holds beyond the orders resting on the device."""
+        if self._session is None:
+            return None
+        met = self._session.metrics()
+        stale = getattr(self._session, "stale_routes", None)
+        if stale is not None and "open_orders" in met:
+            n = stale(met["open_orders"])
+            if n is not None:
+                met["stale_routes"] = n
+                self.telemetry.gauge("stale_routes").set(n)
+        return met
 
     def run(self, max_messages: Optional[int] = None,
             idle_exit: Optional[float] = None,

@@ -890,7 +890,10 @@ def build_seq_step(cfg: SeqConfig):
             # sm: 0 trade_ok, 1 trade_acc, 2 cap_reject, 3 append,
             #     4 residual echo, 5 nfill, 6/7 tail prev lo/hi,
             #     8 do_rest, 9 cancel_ok, 10 emptied-maker count (dep
-            #     plane decrement). The heavy sections below run
+            #     plane decrement), 11 whether the LAST maker of the
+            #     sweep was emptied (fixed mode: every maker before it
+            #     was, so the host knows which resting orders a taker
+            #     took off the book). The heavy sections below run
             #     under pl.when(act) branches (a NOP/CREATE message
             #     must not pay for hash probes or book reductions) and
             #     publish their scalar results here for the epilogue.
@@ -905,6 +908,7 @@ def build_seq_step(cfg: SeqConfig):
             sm[8] = _i(0)
             sm[9] = _i(0)
             sm[10] = _i(0)
+            sm[11] = _i(0)
 
             # ================ TRADE section (pl.when-gated) ===========
             @pl.when(is_trade)
@@ -1199,6 +1203,9 @@ def build_seq_step(cfg: SeqConfig):
                 sm[7] = tail_hi
                 sm[8] = do_rest.astype(I32)
                 sm[10] = jnp.where(trade_acc, nempt, _i(0))
+                if not JAVA:
+                    sm[11] = (trade_acc & (nfill > _i(0))
+                              & last_emptied).astype(I32)
 
             # ---------------- CANCEL ----------------------------------
             # (pl.when-gated: only cancels pay for the
@@ -1414,8 +1421,10 @@ def build_seq_step(cfg: SeqConfig):
                                                       is_barrier,
                                                       barrier_do,
                                                       act == _i(L_NOP)))))))
+            # bit 3 rides the flags region the fetch already brings: no
+            # plane grows for it
             flags = (ok.astype(I32) | (capr.astype(I32) << _i(1))
-                     | (appnd << _i(2)))
+                     | (appnd << _i(2)) | (sm[11] << _i(3)))
             out_put(_i(1), m, flags)
             out_put(_i(1 + BR), m, resid_v)
             out_put(_i(1 + 2 * BR), m, nf)
@@ -1633,6 +1642,9 @@ def unpack_hdr(cfg: SeqConfig, hdr: np.ndarray, n: int) -> dict:
         "ok": (flags & 1) != 0,
         "cap_reject": (flags & 2) != 0,
         "append": (flags & 4) != 0,
+        # fixed mode: the last maker of this taker's sweep was emptied
+        # (the makers before it always are)
+        "last_emptied": (flags & 8) != 0,
         "residual": flat[base + BR * LN:base + BR * LN + B][:n],
         "nfill": flat[base + 2 * BR * LN:base + 2 * BR * LN + B][:n],
         "prev_oid": ((flat[base + 3 * BR * LN:base + 3 * BR * LN + B][:n]

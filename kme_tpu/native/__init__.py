@@ -238,6 +238,13 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
                                      None),
         # the symbol lifecycle (SeqRouter's): counts and delisted ids
         "kme_router_stats": ([c.c_void_p, P64, P64], None),
+        # an order's route leaves when the order left the book
+        # (its seven array pointers go as plain addresses: the call is
+        # made once a batch on the serve loop, and building seven typed
+        # pointer objects cost more than the walk itself)
+        "kme_router_drop_batch": (
+            [c.c_void_p, c.c_int64] + [c.c_void_p] * 6
+            + [c.c_int64, c.c_void_p, c.c_int64, c.c_void_p], c.c_int32),
         "kme_router_n_delisted": ([c.c_void_p], c.c_int64),
         "kme_router_export_delisted": ([c.c_void_p, P64], None),
         "kme_router_import_delisted": ([c.c_void_p, c.c_int64, P64],

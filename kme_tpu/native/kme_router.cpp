@@ -12,6 +12,10 @@
 // by an ADD_SYMBOL, released by an accepted PAYOUT, the lowest free
 // lane goes to the next new id, and a trade, cancel or barrier naming
 // an id that holds no lane is host-rejected and takes none.
+//
+// An order's route (SeqRouter's docstring): written when its trade is
+// routed, stamped with that plan's ordinal, gone when the session says
+// the order left the book (kme_router_drop_batch) or its symbol is wiped.
 
 #include <algorithm>
 #include <chrono>
@@ -35,11 +39,18 @@ constexpr int32_t L_BUY = 1, L_SELL = 2, L_CANCEL = 3, L_CREATE = 4,
 
 constexpr int32_t RT_OK = 0, RT_CAP_ACCOUNTS = 1, RT_CAP_SYMBOLS = 2;
 
+// an order's symbol, and the plan that wrote the route (0: imported)
+struct Route {
+  int64_t sid, plan;
+};
+
 struct Router {
   int64_t S, A;
   std::unordered_map<int64_t, int32_t> aid_idx;
   std::unordered_map<int64_t, int32_t> sid_lane;
-  std::unordered_map<int64_t, int64_t> oid_sid;
+  std::unordered_map<int64_t, Route> oid_sid;
+  std::unordered_set<int64_t> dead;  // kme_router_drop_batch's scratch
+  int64_t routes_dropped = 0;
 
   // route outputs (valid until the next call)
   std::vector<int64_t> o_msg, o_oid;
@@ -63,12 +74,16 @@ struct Router {
   // the symbol lifecycle (SeqRouter's twin): bound ids whose book a
   // REMOVE_SYMBOL took away, the released lanes below the high-water
   // mark `hw` as a min-heap, and the cumulative counts in
-  // ROUTER_STATS' order (stats[7], the bound lanes, is read off the map)
+  // ROUTER_STATS' order (the level after them, the bound lanes, is
+  // read off the map)
   std::unordered_set<int64_t> delisted;
   std::vector<int32_t> free_lanes;
   int32_t hw = 0;
-  int64_t stats[7] = {0, 0, 0, 0, 0, 0, 0};
-  enum { LISTED, SETTLED, RELEASED, REUSED, UNLISTED, PURGE_NS, PURGE_N };
+  enum {
+    LISTED, SETTLED, RELEASED, REUSED, UNLISTED, PURGE_NS, PURGE_N,
+    ROUTES_MADE, CANCELS_ROUTED, CANCELS_HOST_REJECTED, PLANS, N_STATS
+  };
+  int64_t stats[N_STATS] = {};
 
   // the lane of `sid`, binding the lowest free one to a new id
   int32_t lane(int64_t sid, bool* ok) {
@@ -118,7 +133,7 @@ struct Router {
   void purge(int64_t s) {
     auto t0 = std::chrono::steady_clock::now();
     for (auto it = oid_sid.begin(); it != oid_sid.end();) {
-      if (it->second == s)
+      if (it->second.sid == s)
         it = oid_sid.erase(it);
       else
         ++it;
@@ -206,6 +221,7 @@ int32_t kme_router_route(void* p, int64_t n, const int64_t* action,
   r.o_rej.clear();
   r.o_msg.reserve(n);
   bool ok = true;
+  const int64_t plan = ++r.stats[Router::PLANS];
   auto emit = [&](int64_t i, int32_t act, int32_t aidx, int32_t ln) {
     r.o_msg.push_back(i);
     r.o_act.push_back(act);
@@ -230,7 +246,8 @@ int32_t kme_router_route(void* p, int64_t n, const int64_t* action,
         unlisted(i);
         continue;
       }
-      r.oid_sid[oid[i]] = sid[i];
+      r.oid_sid[oid[i]] = Route{sid[i], plan};
+      r.stats[Router::ROUTES_MADE]++;
       int32_t ai = r.acct(aid[i], &ok);
       if (!ok) return RT_CAP_ACCOUNTS;
       emit(i, a == OP_BUY ? L_BUY : L_SELL, ai, sl->second);
@@ -238,9 +255,11 @@ int32_t kme_router_route(void* p, int64_t n, const int64_t* action,
       auto it = r.oid_sid.find(oid[i]);
       if (it == r.oid_sid.end()) {
         r.o_rej.push_back(i);
+        r.stats[Router::CANCELS_HOST_REJECTED]++;
         continue;
       }
-      auto sl = r.sid_lane.find(it->second);
+      r.stats[Router::CANCELS_ROUTED]++;
+      auto sl = r.sid_lane.find(it->second.sid);
       if (sl == r.sid_lane.end()) {  // only an imported map can say so
         unlisted(i);
         continue;
@@ -308,16 +327,60 @@ int64_t kme_router_n_rejects(void* p) {
 int64_t kme_router_err_value(void* p) {
   return static_cast<Router*>(p)->err_value;
 }
-// ROUTER_STATS (runtime/seqsession.py), cumulative; `add` (7 values or
-// null) is folded in first: what a call routed by the Python twin
-// counted
+// ROUTER_STATS (runtime/seqsession.py), cumulative; `add` (N_STATS
+// values or null) is folded in first: what a call routed by the Python
+// twin counted
 void kme_router_stats(void* p, const int64_t* add, int64_t* out) {
   Router& r = *static_cast<Router*>(p);
-  for (int k = 0; k < 7; k++) {
+  for (int k = 0; k < Router::N_STATS; k++) {
     if (add) r.stats[k] += add[k];
     out[k] = r.stats[k];
   }
-  out[7] = (int64_t)r.sid_lane.size();
+  out[Router::N_STATS] = (int64_t)r.sid_lane.size();
+}
+// SeqRouter.drop_batch: what the collect of plan `plan` fetched, walked
+// in message order over its nr routed rows (route_events' rule): a
+// sweep's makers leave before their taker's own event, every maker but
+// the last emptied and the last by the kernel's word; a trade rests
+// where it was accepted with a residual; an accepted cancel takes its
+// order off. The last event of an oid decides, and an oid that ends
+// dead loses its route unless a plan after `plan` wrote it. out =
+// {routes dropped so far, routes held}; returns 1 where nfill runs
+// past the fills given (nothing is dropped then), else 0
+int32_t kme_router_drop_batch(void* p, int64_t nr, const int32_t* act,
+                              const int64_t* oid, const uint8_t* ok,
+                              const int32_t* resid, const int32_t* nfill,
+                              const uint8_t* last_emptied, int64_t nfills,
+                              const int64_t* f_oid, int64_t plan,
+                              int64_t* out) {
+  Router& r = *static_cast<Router*>(p);
+  r.dead.clear();
+  int64_t o0 = 0;
+  for (int64_t k = 0; k < nr; k++) {
+    int64_t nf = nfill[k];
+    if (nf < 0 || o0 + nf > nfills) return 1;
+    for (int64_t e = 0; e < nf; e++)
+      if (e + 1 < nf || last_emptied[k]) r.dead.insert(f_oid[o0 + e]);
+    o0 += nf;
+    if (act[k] == L_BUY || act[k] == L_SELL) {
+      if (ok[k] && resid[k] > 0)
+        r.dead.erase(oid[k]);
+      else
+        r.dead.insert(oid[k]);
+    } else if (act[k] == L_CANCEL && ok[k]) {
+      r.dead.insert(oid[k]);
+    }
+  }
+  for (int64_t o : r.dead) {
+    auto it = r.oid_sid.find(o);
+    if (it != r.oid_sid.end() && it->second.plan <= plan) {
+      r.oid_sid.erase(it);
+      r.routes_dropped++;
+    }
+  }
+  out[0] = r.routes_dropped;
+  out[1] = (int64_t)r.oid_sid.size();
+  return 0;
 }
 const int64_t* kme_router_o_msg(void* p) {
   return static_cast<Router*>(p)->o_msg.data();
@@ -374,7 +437,7 @@ void kme_router_export_routes(void* p, int64_t* keys, int64_t* vals) {
   int64_t i = 0;
   for (auto& kv : static_cast<Router*>(p)->oid_sid) {
     keys[i] = kv.first;
-    vals[i] = kv.second;
+    vals[i] = kv.second.sid;
     i++;
   }
 }
@@ -408,7 +471,7 @@ void kme_router_import_routes(void* p, int64_t n, const int64_t* keys,
                               const int64_t* vals) {
   auto& m = static_cast<Router*>(p)->oid_sid;
   m.clear();
-  for (int64_t i = 0; i < n; i++) m.emplace(keys[i], vals[i]);
+  for (int64_t i = 0; i < n; i++) m.emplace(keys[i], Route{vals[i], 0});
 }
 
 }  // extern "C"

@@ -500,7 +500,12 @@ def save_seq_session(ckpt_dir: str, session, offset: int,
         session.snapshot_gauges.update(
             snapshot_live_slots=layout["live_slots"],
             snapshot_live_positions=layout["live_positions"],
-            snapshot_sparse_sections=len(layout["sparse"]))
+            snapshot_sparse_sections=len(layout["sparse"]),
+            # routes in the file beyond the orders that rest in it: 0
+            # since routes die with their orders (a snapshot is taken
+            # after the drain, so both speak of one input prefix)
+            stale_routes=(session.snapshot_gauges["snapshot_routes"]
+                          - layout["live_slots"]))
     return path
 
 

@@ -60,13 +60,18 @@ class UnsupportedJavaOp(RuntimeError):
     engines (COMPAT.md)."""
 
 
-# the routers' cumulative lifecycle counts, in the order both export
-# them (SeqRouter.stats / kme_router_stats); `lanes_bound` is a level,
-# the rest only grow. `route_purge_ns` / `route_purge_n`: time spent
-# dropping a wiped symbol's oid routes, and how many such purges
+# the routers' cumulative counts as a plan returns, in the order both
+# export them (SeqRouter.stats / kme_router_stats); `lanes_bound` is a
+# level, the rest only grow. `route_purge_ns` / `route_purge_n`: time
+# spent dropping a wiped symbol's oid routes, and how many such purges;
+# `routes_made`: trades that wrote a route; `cancels_routed` /
+# `cancels_host_rejected`: cancels that found a route and went to the
+# device / found none; `plans`: route() calls, the stamp a route made
+# by this plan carries (SeqRouter.drop)
 ROUTER_STATS = ("symbols_listed", "symbols_settled", "lanes_released",
                 "lanes_reused", "unlisted_rejects", "route_purge_ns",
-                "route_purge_n", "lanes_bound")
+                "route_purge_n", "routes_made", "cancels_routed",
+                "cancels_host_rejected", "plans", "lanes_bound")
 
 
 class SeqRouter(DictRoutes):
@@ -88,7 +93,16 @@ class SeqRouter(DictRoutes):
     does). A new id takes the LOWEST free lane, a function of
     `sid_lane` alone, so a replay and a restore choose as the first run
     did. A trade, cancel or barrier naming an id that holds no lane is
-    host-rejected and takes none (the reference rejects each)."""
+    host-rejected and takes none (the reference rejects each).
+
+    Fixed mode, an order's route: written when its trade is routed,
+    gone when the order is known to have left the book (`drop`, called
+    by the session as it collects a batch) or when its symbol is wiped
+    (`_purge`). The router plans ahead of the collect, so a route
+    carries the ordinal of the plan that wrote it (`oid_plan`; a route
+    that was imported carries none and reads 0): a drop learned from
+    plan k leaves alone what a later plan wrote for the same oid. java
+    mode keeps every route and no stamps."""
 
     def __init__(self, num_lanes: int, num_accounts: int,
                  compat: str = "fixed") -> None:
@@ -96,10 +110,22 @@ class SeqRouter(DictRoutes):
         self.A = num_accounts
         self.compat = compat
         self.aid_idx: Dict[int, int] = {}
-        self.oid_sid: Dict[int, int] = {}
+        self.oid_sid = {}
         self.delisted: set = set()
         self.counts = dict.fromkeys(ROUTER_STATS[:-1], 0)
+        self.routes_dropped = 0
         self.sid_lane = {}
+
+    @property
+    def oid_sid(self) -> Dict[int, int]:
+        return self._oid_sid
+
+    @oid_sid.setter
+    def oid_sid(self, d: Dict[int, int]) -> None:
+        """A wholesale import (construction, checkpoint restore): no
+        plan is in flight, so the routes carry no stamp."""
+        self._oid_sid = d
+        self.oid_plan: Dict[int, int] = {}
 
     @property
     def sid_lane(self) -> Dict[int, int]:
@@ -159,8 +185,38 @@ class SeqRouter(DictRoutes):
         dead = [o for o, s2 in self.oid_sid.items() if s2 == sid]
         for o in dead:
             del self.oid_sid[o]
+            self.oid_plan.pop(o, None)
         self.counts["route_purge_ns"] += perf_counter_ns() - t0
         self.counts["route_purge_n"] += 1
+
+    def n_routes(self) -> int:
+        return len(self.oid_sid)
+
+    def drop(self, oids, alive, plan: int) -> tuple:
+        """What the collect of plan `plan` learned, in message order:
+        `oids[j]` rests on the book after its event (`alive[j]`: a
+        trade that rested) or has left it (a trade refused or filled
+        at once, an accepted cancel, a maker a sweep emptied). The
+        last event of an oid decides; an oid that ends dead loses its
+        route unless a plan after `plan` wrote it.
+        -> (routes dropped so far, routes held)."""
+        dead = set()
+        for o, a in zip(np.asarray(oids).tolist(),
+                        np.asarray(alive).tolist()):
+            if a:
+                dead.discard(o)
+            else:
+                dead.add(o)
+        for o in dead:
+            if o in self.oid_sid and self.oid_plan.get(o, 0) <= plan:
+                del self.oid_sid[o]
+                self.oid_plan.pop(o, None)
+                self.routes_dropped += 1
+        return self.routes_dropped, self.n_routes()
+
+    def drop_batch(self, cols, host, fills, plan: int) -> tuple:
+        """drop(), of what one fetched batch says (route_events)."""
+        return self.drop(*route_events(cols, host, fills), plan)
 
     def acct_of_idx(self) -> List[int]:
         out = [0] * len(self.aid_idx)
@@ -220,6 +276,8 @@ class SeqRouter(DictRoutes):
                     f"message {i}: trade outside the java device domain "
                     f"(price={m.price}, size={m.size}); use the native "
                     f"engine")
+        self.counts["plans"] += 1
+        plan = self.counts["plans"]
         for i, m in enumerate(msgs):
             a = m.action
             aid, sid, oid = jl.jlong(m.aid), jl.jlong(m.sid), jl.jlong(m.oid)
@@ -240,13 +298,18 @@ class SeqRouter(DictRoutes):
                         unlisted(i)
                         continue
                 self.oid_sid[oid] = sid
+                if not java:
+                    self.oid_plan[oid] = plan
+                self.counts["routes_made"] += 1
                 emit(i, _TRADE_ACTS[a], self._acct(aid), lane, m, oid,
                      aid, sid)
             elif a == op.CANCEL:
                 rsid = self.oid_sid.get(oid)
                 if rsid is None:
                     host_rejects.add(i)
+                    self.counts["cancels_host_rejected"] += 1
                     continue
+                self.counts["cancels_routed"] += 1
                 if java:
                     emit(i, SQ.L_CANCEL, self._acct(aid),
                          self._lane(rsid), m, oid, aid, rsid)
@@ -455,6 +518,35 @@ class NativeSeqRouter:
         self._import_arrays(self._lib.kme_router_import_routes, keys,
                             vals, np.int64)
 
+    def n_routes(self) -> int:
+        """Routes held, without exporting the map."""
+        return int(self._lib.kme_router_n_routes(self._h))
+
+    def drop_batch(self, cols, host, fills, plan: int) -> tuple:
+        """SeqRouter.drop_batch in one native call: route_events' walk
+        and the drop, on the C++ map."""
+        from kme_tpu.native import BoundaryError
+
+        nr, nf = len(cols["act"]), fills.shape[1]
+        # the dtypes the walk reads, made so here; it reads the six
+        # per-row arrays to nr and the fills' oids to nf
+        arrs = [np.ascontiguousarray(a, dt) for a, dt in (
+            (cols["act"], np.int32), (cols["oid"], np.int64),
+            (host["ok"], np.uint8), (host["residual"], np.int32),
+            (host["nfill"], np.int32), (host["last_emptied"], np.uint8),
+            (fills[0], np.int64))]
+        for a, n in zip(arrs, (nr,) * 6 + (nf,)):
+            if a.ndim != 1 or len(a) < n:
+                raise BoundaryError(
+                    f"drop_batch: shape {a.shape}, the walk reads {n}")
+        out = np.empty(2, np.int64)
+        rc = self._lib.kme_router_drop_batch(
+            self._h, nr, *(a.ctypes.data for a in arrs[:6]), nf,
+            arrs[6].ctypes.data, int(plan), out.ctypes.data)
+        if rc != 0:
+            raise BoundaryError("drop_batch: nfill runs past the fills")
+        return int(out[0]), int(out[1])
+
     def acct_of_idx(self) -> List[int]:
         m = self.aid_idx
         out = [0] * len(m)
@@ -582,6 +674,30 @@ def count_lane_switches(cfg: SQ.SeqConfig, stacked: dict) -> int:
                                     | (lane[1:] != lane[:-1])))
 
 
+def route_events(cols: dict, host: dict, fills: np.ndarray):
+    """What one fetched batch says of its orders' places on the book,
+    in message order, for SeqRouter.drop: -> (oids, alive). A trade is
+    one event (alive where it was accepted and a residual rested), an
+    accepted cancel one (dead), and every maker a sweep emptied one
+    (dead, before its taker's own event): all of a taker's makers but
+    the last are emptied, and of the last the kernel says so
+    (`last_emptied`)."""
+    act, ok, nfill = cols["act"], host["ok"], host["nfill"]
+    trade = (act == SQ.L_BUY) | (act == SQ.L_SELL)
+    rested = trade & ok & (host["residual"] > 0)
+    own = np.nonzero(trade | ((act == SQ.L_CANCEL) & ok))[0]
+    gone = np.ones(fills.shape[1], bool)
+    swept = np.nonzero(nfill > 0)[0]
+    gone[np.cumsum(nfill)[swept] - 1] = host["last_emptied"][swept]
+    taker = np.repeat(np.arange(len(nfill)), nfill)[gone]
+    order = np.argsort(np.concatenate([2 * taker, 2 * own + 1]),
+                       kind="stable")
+    oids = np.concatenate([fills[0][gone], cols["oid"][own]])[order]
+    alive = np.concatenate([np.zeros(len(taker), bool),
+                            rested[own]])[order]
+    return oids, alive
+
+
 class SeqSession:
     """Drop-in fixed-mode engine over the sequential mega-kernel.
 
@@ -593,8 +709,9 @@ class SeqSession:
     # loop registers each as a heartbeat gauge pair before the first
     # heartbeat, so that a reader of two snapshots finds it in both
     SPANS = ("plan_s", "stage_s", "dispatch_s", "fetch_s", "recon_s",
-             "session_metrics", "metrics_export", "metrics_count",
-             "snapshot_export", "snapshot_meta", "snapshot_write")
+             "route_drop", "session_metrics", "metrics_export",
+             "metrics_count", "snapshot_export", "snapshot_meta",
+             "snapshot_write")
 
     def __init__(self, cfg: SQ.SeqConfig) -> None:
         self.cfg = cfg
@@ -645,6 +762,12 @@ class SeqSession:
         # loop publishes them as counters, `lanes_bound` and what is
         # left of cfg.lanes as gauges, the purge as span `route_purge`
         self.router_stats = dict.fromkeys(ROUTER_STATS, 0)
+        # fixed mode: routes dropped because their order left the book
+        # (cumulative) and routes the router holds, both as of the
+        # newest drop (_drop_routes); the serve loop publishes them as
+        # counter `routes_dropped` and gauge `routes_held`
+        self.routes_dropped = 0
+        self.routes_held = 0
         # metrics()' narrow read, compiled here and not at the first
         # refresh: a compile inside a served batch is a stall
         self._occupancy = SQ.build_seq_occupancy(cfg)
@@ -728,6 +851,8 @@ class SeqSession:
             _jax.block_until_ready(self.state)
         with self.timer.phase("fetch_s"):
             host, fills = self._fetch_outputs(outp, cnts, K)
+        with self.timer.phase("recon_s"):
+            self._drop_routes(cols, host, fills)
         return cols, host_rejects, host, fills
 
     def _fetch_outputs(self, outp, cnts, K):
@@ -743,8 +868,9 @@ class SeqSession:
         fdev = outp[:, :HR + 5 * ghint, :]
         async_prefetch([fdev])
         fetched = np.asarray(fdev)
-        host = {k: [] for k in ("ok", "cap_reject", "append", "residual",
-                                "nfill", "prev_oid")}
+        host = {k: [] for k in ("ok", "cap_reject", "append",
+                                "last_emptied", "residual", "nfill",
+                                "prev_oid")}
         results = []
         mets = np.zeros(SQ.N_METRICS, np.int64)
         hists = np.zeros((SQ.N_HIST, SQ.N_HIST_BUCKETS), np.int64)
@@ -861,6 +987,20 @@ class SeqSession:
         t.counts["route_purge"] = t.counts.get("route_purge", 0) + (
             new["route_purge_n"] - old["route_purge_n"])
 
+    def _drop_routes(self, cols, host, fills) -> None:
+        """Routes die with their orders: tell the router which orders
+        of the batch just fetched have left the book (span
+        `route_drop`, inside `recon_s`). The batch is the one whose
+        router counts `_note_router` took up last, so its plan's
+        ordinal is theirs. java mode keeps every route (its fills carry
+        Q2 ghosts and merged books: COMPAT.md)."""
+        if self.cfg.compat == "java":
+            self.routes_held = self.router.n_routes()
+            return
+        with self.timer.phase("route_drop"):
+            self.routes_dropped, self.routes_held = self.router.drop_batch(
+                cols, host, fills, self.router_stats["plans"])
+
     @property
     def h2d_overlap_frac(self) -> float:
         """Fraction of H2D staging wall hidden under in-flight device
@@ -883,6 +1023,7 @@ class SeqSession:
         with self.timer.phase("fetch_s"):
             host, fills = self._fetch_outputs(outp, cnts, K)
         with self.timer.phase("recon_s"):
+            self._drop_routes(cols, host, fills)
             r = self._recon_buffer(batch, cols, host_rejects, host,
                                    fills)
         self.windows.append(("collect", self._n_collect, t0,
@@ -1126,6 +1267,18 @@ class SeqSession:
                 self._count_for_metrics(self._export_for_metrics()))
             self._publish(counters)
         return counters
+
+    def stale_routes(self, open_orders: int):
+        """Routes the router holds beyond the `open_orders` resting on
+        the device (metrics()' count): 0 since routes die with their
+        orders; a restored older snapshot's stale routes show here
+        until their symbols are wiped. None where the two cannot be
+        set side by side: java mode keeps every route, and while a
+        batch is in flight the router is ahead of the device."""
+        if (self.cfg.compat == "java"
+                or self._n_submit != self._n_collect):
+            return None
+        return self.router.n_routes() - open_orders
 
     def _export_for_metrics(self) -> np.ndarray:
         """The device -> host fetch of metrics(): SQ.OCCUPANCY_NAMES'

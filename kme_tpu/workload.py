@@ -425,6 +425,71 @@ def cancel_heavy_stream(num_events: int, num_symbols: int, num_accounts: int,
     return msgs
 
 
+def quote_churn_stream(num_events: int, num_symbols: int,
+                       num_accounts: int, seed: int = 0,
+                       zipf_a: float = 1.2, cancel_ratio: float = 0.8,
+                       standing: int = 32768, take: float = 0.05,
+                       deposit: int = 10_000_000) -> Iterator[OrderMsg]:
+    """A quote-driven market (BASELINE.json config 4, "80% cancels"):
+    market makers place quotes either side of a mid of 50, pull most of
+    them and have a few lifted, so that about four quotes in five end
+    by an accepted cancel and one by a fill.
+
+    Preamble as zipf_symbol_stream (accounts created and funded, ids
+    0..num_symbols-1 listed). Then cancel_heavy_stream's law with three
+    departures:
+      (a) a cancel is drawn (with probability cancel_ratio) only while
+          the pool of submitted orders holds more than `standing`, else
+          the event is a submit: about `standing` orders are open at any
+          time (cancel_heavy_stream's pool drains to nothing at 0.8, so
+          its books stay empty) and the mix settles near one cancel a
+          submit. The cancel takes a uniformly drawn member of the pool
+          out of it, whether or not that order still rests
+          (exchange_test.js:97-104: the pool never learns of fills);
+      (b) a submit's symbol ~ Zipf(zipf_a) over the ranks, its account
+          uniform, its side a coin;
+      (c) with probability 1 - take it is a passive quote, BUY at
+          49 - floor(|N(0, 4)|) or SELL at 51 + floor(|N(0, 4)|), size
+          floor(N(50, 10)); with probability take a taker on the far
+          side of the mid, BUY at 51 + floor(|N(0, 4)|) or SELL at
+          49 - floor(|N(0, 4)|), size floor(N(200, 40)): it lifts about
+          four quotes. Prices and sizes clamped into the device domain.
+    Oids are uniform in [0, 2^53) as create_buy draws them. Lazy (a
+    generator): the preamble can be served while the rest is drawn; the
+    pool is a list with O(1) removal, so a long stream draws at the
+    pace of its random numbers. Seed-deterministic."""
+    gen = WorkloadGen(num_accounts, num_symbols, seed=seed, validate=True,
+                      payout_opcode_bug=False)
+    yield from _storm_preamble(gen, num_accounts, num_symbols, deposit)
+    cdf = _zipf_cdf(num_symbols, zipf_a)
+    rnd, normal, floor = gen.rng.random, gen._random_normal, math.floor
+    pool: List[Tuple[int, int]] = []        # (oid, aid), in no order
+    for _ in range(num_events):
+        if len(pool) > standing and rnd() < cancel_ratio:
+            i = floor(rnd() * len(pool))
+            oid, aid = pool[i]
+            last = pool.pop()
+            if i < len(pool):
+                pool[i] = last
+            yield OrderMsg(action=op.CANCEL, oid=oid, aid=aid)
+            continue
+        sid = bisect.bisect_left(cdf, rnd())
+        aid = floor(rnd() * num_accounts)
+        buy = rnd() < 0.5
+        away = floor(abs(normal()) * 4)
+        if rnd() < take:
+            price = 51 + away if buy else 49 - away
+            size = floor(normal() * 40 + 200)
+        else:
+            price = 49 - away if buy else 51 + away
+            size = floor(normal() * 10 + 50)
+        oid = floor(rnd() * (2 ** 53 - 1))
+        pool.append((oid, aid))
+        yield OrderMsg(action=op.BUY if buy else op.SELL, oid=oid,
+                       aid=aid, sid=sid, price=min(125, max(0, price)),
+                       size=max(1, size))
+
+
 def cross_account_stream(num_events: int, num_symbols: int,
                          num_accounts: int, ngroups: int,
                          seed: int = 0, cross_frac: float = 0.5,

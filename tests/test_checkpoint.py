@@ -835,7 +835,10 @@ def test_sparse_snapshot_loads_as_the_canonical_state(case, tmp_path):
         "snapshot_live_slots": int(used.sum()),
         "snapshot_live_positions": int(
             ((want["pos_amt"] != 0) | (want["pos_avail"] != 0)).sum()),
-        "snapshot_sparse_sections": n_sparse}
+        "snapshot_sparse_sections": n_sparse,
+        # routes in the file beyond its resting orders (this state was
+        # planted on the device: no router ever saw its orders)
+        "stale_routes": -int(used.sum())}
 
 
 def _reuse_stream():
@@ -1123,7 +1126,8 @@ def test_routes_round_trip_as_two_sorted_arrays(kind, tmp_path,
     cut = 400
     ses.process_wire([m.copy() for m in msgs[:cut]])
     want = dict(_router(ses).oid_sid)
-    assert len(want) > 100
+    # a fixed-mode seq router holds the resting orders' routes only
+    assert len(want) > (50 if kind.startswith("fixed") else 100)
     raw, meta = _raw(save(str(tmp_path), ses, cut))
     assert meta["version"] == 3 and "oid_sid" not in meta
     for k in ("route_oid", "route_sid"):

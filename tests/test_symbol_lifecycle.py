@@ -363,10 +363,12 @@ def test_snapshot_between_a_settlement_and_the_next_listing(stream,
 @pytest.mark.parametrize("router", ["native", "python"])
 def test_a_snapshot_after_payouts_holds_only_the_surviving_routes(
         stream, router, tmp_path):
-    """A settlement purges its symbol's routes from the router, and the
+    """A settlement purges its symbol's routes from the router, an
+    order that leaves the book takes its own with it (PR 41), and the
     snapshot carries what the router holds, nothing thinned and nothing
-    kept back: the file's two arrays are the Python router's map over
-    the same messages, and none of them names a settled id."""
+    kept back: the file's two arrays are the orders that rest, a subset
+    of a never-forgetting router's map over the same messages, and none
+    of them names a settled id."""
     msgs, lines, _ = stream
     payouts = [i for i, (m, g) in enumerate(zip(msgs, lines))
                if m.action == op.PAYOUT and '"action":200' in g[-1]]
@@ -382,11 +384,14 @@ def test_a_snapshot_after_payouts_holds_only_the_surviving_routes(
     with np.load(ck.save_seq_session(str(tmp_path), ses, len(head))) as z:
         got = dict(zip(z["route_oid"].tolist(), z["route_sid"].tolist()))
         assert z["route_oid"].tolist() == sorted(got)
-    assert got == ref.oid_sid and got
+    resting = ses.export_state()["orders"]
+    assert got == {oid: o["sid"] for oid, o in resting.items()} and got
+    assert got.items() <= ref.oid_sid.items()
     traded = {m.oid for m in head if m.action in (op.BUY, op.SELL)}
     assert len(got) < len(traded) / 2           # most went with a wipe
     assert set(got.values()) <= set(ses.router.sid_lane)
     assert ses.snapshot_gauges["snapshot_routes"] == len(got)
+    assert ses.snapshot_gauges["stale_routes"] == 0
 
 
 def sid_listed(msgs):

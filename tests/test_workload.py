@@ -10,7 +10,8 @@ import pytest
 from kme_tpu import opcodes as op
 from kme_tpu.oracle import OracleEngine
 from kme_tpu.workload import WorkloadGen, cancel_heavy_stream, harness_stream, \
-    payout_storm_stream, zipf_hot_stream, zipf_symbol_stream
+    payout_storm_stream, quote_churn_stream, zipf_hot_stream, \
+    zipf_symbol_stream
 
 
 def test_deterministic_under_seed():
@@ -106,6 +107,78 @@ def test_scale_streams_shape():
     cancels = sum(1 for m in ch if m.action == op.CANCEL)
     # every cancel consumes one prior submit: steady state caps near 50%
     assert cancels > 0.45 * 2_000
+
+
+def test_quote_churn_is_lazy_and_deterministic_under_seed():
+    kw = dict(num_symbols=16, num_accounts=64, standing=256)
+    it = quote_churn_stream(4_000, seed=9, **kw)
+    assert iter(it) is it           # a generator: the harness draws it
+    a = list(it)                    # beside the server's start-up
+    assert a == list(quote_churn_stream(4_000, seed=9, **kw))
+    assert a != list(quote_churn_stream(4_000, seed=10, **kw))
+    pre = a[:2 * 64 + 16]           # zipf_symbol_stream's preamble
+    assert [m.action for m in pre[:2]] == [op.CREATE_BALANCE, op.TRANSFER]
+    assert pre[1].size == 10_000_000
+    assert [m.sid for m in pre[128:]] == list(range(16))
+    assert len(a) == len(pre) + 4_000
+
+
+@pytest.mark.parametrize("seed", [7, 2147540107])
+def test_quote_churn_holds_its_mix(seed):
+    """BASELINE.json config 4 as quote_churn_stream reads it: once the
+    pool stands at `standing`, every second event a cancel; one submit
+    in twenty a taker on the far side of the mid; quotes never cross
+    the mid; through the reference, about four cancels in five are
+    accepted, a taker lifts about four quotes, no trade is refused and
+    a message makes about 2.2 records."""
+    from kme_tpu.native.oracle import NativeOracleEngine
+
+    standing, n = 512, 12_000
+    msgs = list(quote_churn_stream(n, 16, 64, seed=seed,
+                                   standing=standing))
+    body = msgs[2 * 64 + 16:]
+    assert all(m.action in (op.BUY, op.SELL) for m in body[:standing])
+    steady = body[2 * standing:]
+    cancels = [m for m in steady if m.action == op.CANCEL]
+    trades = [m for m in steady if m.action in (op.BUY, op.SELL)]
+    assert len(cancels) + len(trades) == len(steady)
+    assert 0.48 < len(cancels) / len(steady) < 0.52
+    far = [m for m in trades if (m.price >= 51) == (m.action == op.BUY)]
+    assert 0.035 < len(far) / len(trades) < 0.065
+    assert all(m.price != 50 and 0 <= m.price <= 125 and m.size >= 1
+               for m in trades)
+    sent_all = [m.oid for m in body if m.action != op.CANCEL]
+    assert len(set(sent_all)) == len(sent_all)      # no oid twice
+    # a cancel names an order submitted before it, once
+    sent, pulled = set(), set()
+    for m in body:
+        if m.action == op.CANCEL:
+            assert m.oid in sent and m.oid not in pulled
+            pulled.add(m.oid)
+        else:
+            sent.add(m.oid)
+    assert standing - 1 <= len(sent - pulled) <= standing + 1
+    # zipf over the ranks: the first symbol leads
+    by_sid = collections.Counter(m.sid for m in trades)
+    assert by_sid[0] > 2 * by_sid[3] > 0
+    try:
+        eng = NativeOracleEngine("fixed", book_slots=128, max_fills=16)
+    except RuntimeError:
+        return                      # no native library: the mix stands
+    lines = eng.process_wire([m.copy() for m in msgs])[-len(steady):]
+    rejected = ['"action":7,' in g[-1] for g in lines]
+    took = [not r for m, r in zip(steady, rejected)
+            if m.action == op.CANCEL]
+    assert 0.70 < sum(took) / len(took) < 0.86
+    assert not any(r for m, r in zip(steady, rejected)
+                   if m.action != op.CANCEL)
+    lifted = [(len(g) - 2) // 2 for m, g in zip(steady, lines)
+              if m in far]
+    assert 3.0 < sum(lifted) / len(lifted) < 5.0
+    assert 2.0 < sum(map(len, lines)) / len(lines) < 2.5
+    assert max(collections.Counter(
+        (o["sid"], o["action"]) for o in eng.export_state()["orders"]
+        .values()).values()) <= 96      # 3/4 of the 128 slots a side
 
 
 def test_zipf_hot_deterministic_and_skewed():
