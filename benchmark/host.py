@@ -3,7 +3,8 @@ host for `kme_tpu.bridge.serve.main` (the `kme-serve` entry point), the
 same layout traced and untraced.
 
     python -m benchmark.host --report R.json [--trace-dir D --trace-flag F
-        --trace-seconds S --spans SPANS.json] -- <kme-serve arguments>
+        --trace-closed-flag C --trace-seconds S --spans SPANS.json]
+        -- <kme-serve arguments>
 
 Untraced it only calls `serve.main` (with the program's heartbeat
 writes serialised, see `serialise_heartbeats`) and then writes what the
@@ -11,7 +12,10 @@ parent cannot know without touching jax: the device as jax reports it and
 the peak device memory. With --trace-dir it also wraps the calls listed in
 SPANS.json in `jax.profiler.TraceAnnotation`, waits for the parent to
 create the flag file (the window has begun), records a profiler trace
-for S seconds, and after `serve.main` has returned reduces the trace
+for S seconds or until the parent creates the second flag file (the
+window has closed: a stream that ran out closes it at the drain, and the
+server's idle tail after it is the harness's, not the program's), and
+after `serve.main` has returned reduces the trace
 (benchmark/xplane.py) into the report. It installs no signal handler:
 `--idle-exit` ends the server."""
 
@@ -89,9 +93,12 @@ def serialise_heartbeats() -> bool:
     return True
 
 
-def trace_when_flagged(flag: str, trace_dir: str, seconds: float,
-                       stop: threading.Event, out: dict) -> None:
-    """Wait for the parent's flag file, then trace for `seconds`."""
+def trace_when_flagged(flag: str, closed_flag: str, trace_dir: str,
+                       seconds: float, stop: threading.Event,
+                       out: dict) -> None:
+    """Wait for the parent's flag file, then trace for `seconds`, or
+    until `closed_flag` exists (the measured window has closed) or the
+    server has ended, whichever comes first."""
     import jax
 
     while not os.path.exists(flag):
@@ -102,7 +109,10 @@ def trace_when_flagged(flag: str, trace_dir: str, seconds: float,
     opts.host_tracer_level = 2
     jax.profiler.start_trace(trace_dir, profiler_options=opts)
     out["t_start"] = time.time()
-    stop.wait(seconds)
+    deadline = time.monotonic() + seconds
+    while time.monotonic() < deadline and not os.path.exists(closed_flag):
+        if stop.wait(0.05):
+            break
     out["t_stop"] = time.time()
     jax.profiler.stop_trace()
     out["t_saved"] = time.time()
@@ -113,6 +123,7 @@ def main(argv=None) -> int:
     ap.add_argument("--report", required=True)
     ap.add_argument("--trace-dir")
     ap.add_argument("--trace-flag")
+    ap.add_argument("--trace-closed-flag")
     ap.add_argument("--trace-seconds", type=float, default=5.0)
     ap.add_argument("--spans", help="JSON list of {name, target}")
     ap.add_argument("serve", nargs=argparse.REMAINDER)
@@ -131,8 +142,8 @@ def main(argv=None) -> int:
         report["spans_missing"] = install_spans(spans)
         tracer = threading.Thread(
             target=trace_when_flagged, daemon=True,
-            args=(args.trace_flag, args.trace_dir, args.trace_seconds,
-                  stop, traced))
+            args=(args.trace_flag, args.trace_closed_flag, args.trace_dir,
+                  args.trace_seconds, stop, traced))
         tracer.start()
     try:
         report["rc"] = serve.main(serve_args)

@@ -1,6 +1,7 @@
 """The yardstick's own arithmetic: the copied generators against the
 program's, the kernel's byte count against `SeqConfig`, the layer-metric
-reductions, the peaks table, the paced schedule."""
+reductions, the peaks table, the paced schedule, which saturated windows
+stand, where the trace ends."""
 
 import json
 import os
@@ -225,6 +226,132 @@ def test_a_tail_cell_holds_many_stalls_and_its_stream_suffices(cell):
     assert due / every >= 8, (due, every)
     events = (traffic.get("stream") or config["stream"])["events"]
     assert traffic["warmup_messages"] + due <= events
+
+
+T_OPEN, FIRST, LEAD = 1000.0, 6144, 8192
+
+
+def served(orders, span, late=0, late_at=None):
+    """Fetch clocks of a saturated run: the warm-up done by T_OPEN, the
+    window's first 1,024 orders in the fetch that opened it (not after
+    T_OPEN, so not in the window), then `orders` in batches of 2,048
+    evenly up to T_OPEN + span; `late` more at `late_at` (None: never
+    fetched) -> (last_t, sent)."""
+    batches = -(-orders // 2048)
+    last_t = [T_OPEN - 1.0] * (FIRST - 1) + [T_OPEN] * 1025
+    for b in range(batches):
+        last_t += [T_OPEN + span * (b + 1) / batches] * min(
+            2048, orders - 2048 * b)
+    if late_at is not None:
+        last_t += [T_OPEN + late_at] * late
+    return last_t, FIRST + 1024 + orders + late
+
+
+SATURATED = {"t_open": T_OPEN, "first": FIRST}
+# case -> (arguments of `served`, the kind says `drained`, orders_per_s or
+# None for a run that fails, the rule the `window:` line names)
+WINDOWS = {
+    # what PR 42's tree did in zipf1k-sat: the whole stream in 9.7 s
+    "drained at 9.7 s on 297,952 orders: stands on its work":
+        ((297952, 9.7), True, 297952 / 9.7, "drain"),
+    "drained at 16.5 s: stands on its seconds, the same arithmetic":
+        ((297952, 16.5), True, 297952 / 16.5, "seconds"),
+    "drained at 5 s on exactly 200,000 orders: stands":
+        ((200000, 5.0), True, 200000 / 5.0, "drain"),
+    "drained at 5 s on 199,999 orders: too little work":
+        ((199999, 5.0), True, None, None),
+    "drained at 3 s on 50,000 orders: too little work":
+        ((50000, 3.0), True, None, None),
+    "a server that stalled 9 s in on 250,000 orders, backlog unserved":
+        ((250000, 9.0, LEAD), False, None, None),
+    "the stream all sent, the server stalled before its last orders":
+        ((250000, 9.0, LEAD), True, None, None),
+    "the stream all sent, its last orders fetched after the close":
+        ((250000, 9.0, LEAD, 31.0), True, None, None),
+    "a stream that outlasts the window: closed at the last completion":
+        ((540672, 29.8, LEAD, 30.2), False, 540672 / 29.8, "seconds"),
+    "all sent 0.5 s before the close, the tail after it: by its seconds":
+        ((540672, 29.8, LEAD, 30.2), True, 540672 / 29.8, "seconds"),
+    "nothing completed in the window":
+        ((0, 1.0, LEAD), False, None, None),
+}
+
+
+@pytest.mark.parametrize("case", WINDOWS)
+def test_a_saturated_window_stands_on_its_seconds_or_on_its_work(case,
+                                                                 capsys):
+    """`run.window_numbers`, no server and no chip: a window that the
+    drain closed before half of `--seconds` stands if it held
+    MIN_DRAINED_ORDERS; one that closed early without the drain (a
+    stalled server) fails however much it held."""
+    clocks, drained, rate, rule = WINDOWS[case]
+    last_t, sent = served(*clocks)
+    facts = dict(SATURATED, drained=drained)
+    if rate is None:
+        with pytest.raises(run.RunFailure,
+                           match="orders completed in the window .* stands "
+                                 "from 15 s on, or closed at the drain on "
+                                 "200000 orders or more"):
+            run.window_numbers(facts, last_t, sent, 30.0)
+        return
+    metrics, attempted, failed, _ = run.window_numbers(facts, last_t, sent,
+                                                       30.0)
+    assert metrics == {"orders_per_s": pytest.approx(rate, rel=1e-12)}
+    assert attempted == sent - FIRST and failed == sent - len(last_t)
+    assert {"drain": f"(closed at the drain: {clocks[0]} orders >= 200000)",
+            "seconds": "(closed at the last completion inside it)"}[
+        rule] in capsys.readouterr().out
+
+
+def test_a_paced_window_is_judged_on_latency_whatever_drained():
+    due = [j / 100.0 for j in range(3000)]
+    last_t = [T_OPEN - 1.0] * FIRST + [T_OPEN + d + 0.05 for d in due]
+    for drained in (False, True):
+        facts = dict(SATURATED, due=due, late=[0.001] * len(due),
+                     drained=drained)
+        metrics, attempted, failed, numbers = run.window_numbers(
+            facts, last_t, FIRST + len(due), 30.0)
+        assert metrics == {"p50_ms": pytest.approx(50.0),
+                           "p99_ms": pytest.approx(50.0)}
+        assert (attempted, failed) == (3000, 0)
+        assert numbers == {"gen_late_p99_ms": pytest.approx(1.0)}
+
+
+def test_the_trace_ends_when_the_window_has_closed(tmp_path, monkeypatch):
+    """A stream that runs out closes the measured window at the drain;
+    the parent says so with a second flag file and the trace ends there,
+    not `--trace-seconds` later: nothing after the close is in it."""
+    import jax
+
+    from benchmark import host
+
+    calls = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d, **kw: calls.append(("start", d)))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: calls.append(("stop", time.monotonic())))
+    opened, closed = tmp_path / "window.open", tmp_path / "window.closed"
+    opened.touch()
+    for close_after, lasts in ((0.3, (0.3, 1.0)),      # closed at the drain
+                               (None, (1.5, 2.5))):    # never: S seconds
+        closed.unlink(missing_ok=True)
+        timer = threading.Timer(close_after or 0.0, closed.touch
+                                if close_after else lambda: None)
+        out, t = {}, time.monotonic()
+        timer.start()
+        host.trace_when_flagged(str(opened), str(closed), "D", 1.5,
+                                threading.Event(), out)
+        timer.join()
+        assert lasts[0] <= time.monotonic() - t < lasts[1]
+        assert lasts[0] - 0.1 <= out["t_stop"] - out["t_start"] < lasts[1]
+        assert [c[0] for c in calls[-2:]] == ["start", "stop"]
+    # the server's end stops it too, as before
+    stop, out = threading.Event(), {}
+    timer = threading.Timer(0.2, stop.set)
+    timer.start()
+    host.trace_when_flagged(str(opened), str(closed), "D", 5.0, stop, out)
+    timer.join()
+    assert out["t_stop"] - out["t_start"] < 1.0
 
 
 def test_percentile_is_nearest_rank():
