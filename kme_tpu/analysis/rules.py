@@ -87,9 +87,20 @@ HOT_SCOPES: Dict[str, Set[str]] = {
     # this path exists to remove. produce_stamped is its egress twin
     # (one stamped run of MatchOut records a call) with the same one
     # exit, and _stamped_rows builds that run's rows: a write or flush
-    # per record inside either is the same tax on the serve loop
+    # per record inside either is the same tax on the serve loop.
+    # Since PR 44 a run travels as one buffer: produce_stamped and
+    # produce_stamped_buffer are adapters onto _produce_run (the one
+    # stamped-run path, whose one exit is still _flush_log_lines);
+    # _admit_run is its per-record walk on a bounded topic, _run_rows
+    # its rows (one native call through run_native, _stamped_rows the
+    # twin), split_run / run_of_pairs / line_offsets lay the buffer out
     "kme_tpu/bridge/broker.py": {"produce_frames", "produce_stamped",
-                                 "_stamped_rows"},
+                                 "_stamped_rows",
+                                 "produce_stamped_buffer",
+                                 "_produce_run", "_admit_run",
+                                 "_run_rows", "run_native",
+                                 "split_run", "run_of_pairs",
+                                 "line_offsets"},
 }
 
 # Replay scopes: functions whose outputs must be bit-identical when a
@@ -115,7 +126,11 @@ REPLAY_SCOPES: Dict[str, Set[str]] = {
                                   # assignment must regenerate
                                   # identically on crash-replay
                                   "_produce_out", "_produce_xfer",
-                                  "_produce_records", "_produce_run"},
+                                  "_produce_records", "_produce_run",
+                                  # the same split and stamps where
+                                  # a batch goes out as one buffer
+                                  "_buffer_call", "_produce_runs",
+                                  "_send_run"},
     # the split IS the transfer regeneration path: a crash-replay
     # re-runs route_line over the MatchIn prefix and must emit the
     # byte-identical injected legs (same grants, same xids)
@@ -221,8 +236,12 @@ FEED_SCOPES: Dict[str, Set[str]] = {
 CLOCK_SCOPES: Dict[str, Set[str]] = {
     "kme_tpu/bridge/service.py": {
         "step", "_step_pipelined", "_process_batch", "_produce_retry",
-        "_broker_retry", "_publish_batch", "_write_heartbeat"},
-    "kme_tpu/bridge/broker.py": {"produce", "produce_stamped", "fetch"},
+        "_broker_retry", "_publish_batch", "_write_heartbeat",
+        "_produce_runs", "_send_run"},
+    "kme_tpu/bridge/broker.py": {"produce", "produce_stamped", "fetch",
+                                 "produce_stamped_buffer",
+                                 "_produce_run", "fetch_runs",
+                                 "_fetch_pieces", "_delivered"},
     "kme_tpu/bridge/replica.py": {"fetch", "run", "_write_heartbeat",
                                   "_promote"},
     "kme_tpu/bridge/tcp.py": {"_ats_for"},

@@ -41,6 +41,7 @@ out_seq]``) only when stamped, so pre-existing logs load unchanged.
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import json
 import os
@@ -102,11 +103,170 @@ class Record:
     tid: Optional[int] = None
 
 
+class Run:
+    """A stamped run of records as ONE buffer — how a batch's output
+    stays from the reconstruction (native/kme_wire.cpp wrote it) to the
+    log file and a consumer's socket without a Python object a record.
+    Line i of `buf` is ``buf[off[i]:off[i + 1]]``; its key is the first
+    ``klen[i]`` bytes (``klen[i] < 0``: the key is None and the line is
+    the value), its value starts one separator byte after the key.
+    This object is the records ``[lo, hi)`` of the buffer: record i has
+    ``offset = base + i - lo`` and ``out_seq = seq0 + i``; the run has
+    one ``epoch`` and one admission stamp ``ats``. A slice shares the
+    arrays. `records()` makes the Records, when someone asks."""
+
+    __slots__ = ("buf", "off", "klen", "lo", "hi", "base", "epoch",
+                 "seq0", "ats")
+
+    def __init__(self, buf, off, klen, lo, hi, base, epoch, seq0,
+                 ats) -> None:
+        self.buf, self.off, self.klen = buf, off, klen
+        self.lo, self.hi, self.base = lo, hi, base
+        self.epoch, self.seq0, self.ats = epoch, seq0, ats
+
+    @property
+    def n(self) -> int:
+        return self.hi - self.lo
+
+    def slice(self, lo: int, hi: int, base: Optional[int] = None) -> "Run":
+        """Records [lo, hi) of the buffer (absolute line indices), at
+        log offset `base` (default: where this run has them)."""
+        if base is None:
+            base = self.base + lo - self.lo
+        return Run(self.buf, self.off, self.klen, lo, hi, base,
+                   self.epoch, self.seq0, self.ats)
+
+    def pairs(self, lo: Optional[int] = None,
+              hi: Optional[int] = None) -> list:
+        """The ``(key, value)`` strings of lines [lo, hi)."""
+        lo = self.lo if lo is None else lo
+        hi = self.hi if hi is None else hi
+        off = self.off[lo:hi + 1].tolist()
+        klen = self.klen[lo:hi].tolist()
+        buf, out = self.buf, []
+        for i, kl in enumerate(klen):
+            a, b = off[i], off[i + 1]
+            out.append((
+                None if kl < 0
+                else buf[a:a + kl].decode("utf-8", "surrogatepass"),
+                buf[min(a + kl + 1, b):b].decode("utf-8",
+                                                 "surrogatepass")))
+        return out
+
+    def records(self) -> List[Record]:
+        base, seq, epoch, ats = (self.base, self.seq0 + self.lo,
+                                 self.epoch, self.ats)
+        return [Record(base + i, key, value, epoch, seq + i, ats)
+                for i, (key, value) in enumerate(self.pairs())]
+
+
+def split_run(buf: bytes, off) -> "np.ndarray":
+    """Key lengths of the lines of a "KEY value" buffer, by
+    ``str.partition(" ")``: the bytes before a line's first space, the
+    whole line where it has none (native kme_run_split, or its twin)."""
+    import numpy as np
+
+    from kme_tpu.native import load_library
+
+    n = len(off) - 1
+    klen = np.empty(n, np.int32)
+    lib = load_library()
+    if lib is not None:
+        lib.kme_run_split(buf, off.ctypes.data, n, klen.ctypes.data)
+    else:
+        lo = off.tolist()
+        for i in range(n):
+            k = buf.find(b" ", lo[i], lo[i + 1])
+            klen[i] = lo[i + 1] - lo[i] if k < 0 else k - lo[i]
+    return klen
+
+
+def line_offsets(parts) -> "np.ndarray":
+    """The n + 1 int64 offsets of `parts` laid back to back (their
+    ``len``s summed: bytes, or ASCII strings)."""
+    import numpy as np
+
+    off = np.zeros(len(parts) + 1, np.int64)
+    np.cumsum(np.fromiter(map(len, parts), np.int64, len(parts)),
+              out=off[1:])
+    return off
+
+
+def run_of_pairs(records) -> tuple:
+    """``(buf, off, klen)`` of a list of ``(key, value)`` pairs: the
+    buffer shape of produce_stamped's records."""
+    import numpy as np
+
+    n = len(records)
+    parts, klen = [], np.empty(n, np.int32)
+    for i, (key, value) in enumerate(records):
+        vb = value.encode("utf-8", "surrogatepass")
+        if key is None:
+            klen[i] = -1
+            parts.append(vb)
+        else:
+            kb = key.encode("utf-8", "surrogatepass")
+            klen[i] = len(kb)
+            parts.append(kb + b" " + vb)
+    return b"".join(parts), line_offsets(parts), klen
+
+
+class _Log:
+    """One topic's log: single Records and Runs, in offset order. The
+    one structure behind every reader and writer of a topic's records
+    — `len`, `append` (a Record), `append_run`, and `pieces` (a
+    fetch's slice, nothing made: each piece a Run, whole or sliced, or
+    a list of consecutive single Records)."""
+
+    def __init__(self) -> None:
+        self._segs: list = []       # a list of Records, or a Run
+        self._starts: List[int] = []
+        self._n = 0
+
+    def __len__(self) -> int:
+        return self._n
+
+    def append(self, rec: Record) -> None:
+        segs = self._segs
+        if segs and type(segs[-1]) is list:
+            segs[-1].append(rec)
+        else:
+            self._starts.append(self._n)
+            segs.append([rec])
+        self._n += 1
+
+    def append_run(self, run: Run) -> None:
+        self._starts.append(self._n)
+        self._segs.append(run)
+        self._n += run.n
+
+    def pieces(self, offset: int, max_records: int) -> list:
+        end = min(self._n, offset + max_records)
+        out: list = []
+        if offset < 0 or offset >= end:
+            return out
+        k = bisect.bisect_right(self._starts, offset) - 1
+        while offset < end:
+            seg, start = self._segs[k], self._starts[k]
+            if type(seg) is list:
+                part = seg[offset - start:end - start]
+                out.append(part)
+                offset += len(part)
+            else:
+                a = seg.lo + offset - start
+                b = min(seg.hi, seg.lo + end - start)
+                out.append(seg if (a, b) == (seg.lo, seg.hi)
+                           else seg.slice(a, b))
+                offset += b - a
+            k += 1
+        return out
+
+
 class _Topic:
     def __init__(self, partitions: int = 1,
                  logfile: Optional[IO] = None) -> None:
         self.partitions = partitions
-        self.log: List[Record] = []
+        self.log = _Log()
         self.logfile = logfile
         # idempotent-produce watermark: highest out_seq made durable on
         # this topic (-1 = no stamped record yet); recovered from the
@@ -404,13 +564,17 @@ def simulate_overload(values: List[str], windows, controller:
             "controller": controller.snapshot()}
 
 
-def _flush_log_lines(logfile, lines: List[str]) -> None:
-    """The batched durable-write exit point for produce_frames and
-    produce_stamped: ONE write + flush for a whole admitted prefix.
-    Deliberately outside their lint hot-scope — this is the sanctioned
-    place for the blocking I/O, so anything blocking reappearing inside
-    a per-record loop fails KME-H002."""
-    logfile.write("".join(lines))
+def _flush_log_lines(logfile, rows) -> None:
+    """The durable-write exit point of every produce call: ONE write +
+    flush for a whole admitted prefix. The log files are binary; rows
+    come as the row bytes of a run (_run_rows: the native call's
+    result goes to the file as it is, never through a `str`) or as a
+    list of ASCII row strings (json.dumps escapes everything else).
+    Deliberately outside the callers' lint hot-scope — this is the
+    sanctioned place for the blocking I/O, so anything blocking
+    reappearing inside a per-record loop fails KME-H002."""
+    logfile.write(rows if isinstance(rows, bytes)
+                  else "".join(rows).encode("ascii"))
     logfile.flush()
 
 
@@ -424,6 +588,38 @@ def _stamped_rows(records, epoch: int, seq0: int) -> List[str]:
     return [f"[{'null' if key is None else enc(key)},{enc(value)},"
             f"{epoch},{out_seq}]\n"
             for out_seq, (key, value) in enumerate(records, seq0)]
+
+
+def run_native(name: str, run: Run, *args) -> Optional[bytes]:
+    """The result of the native call `name` (kme_run_rows /
+    kme_run_pack) over a run's buffer, read back from the calling
+    thread's result buffer; None where the library is absent or the
+    call refuses the run (-1), and the caller takes the Python twin."""
+    import ctypes
+
+    from kme_tpu.native import load_library
+
+    lib = load_library()
+    if lib is None:
+        return None
+    n = getattr(lib, name)(run.buf, run.off.ctypes.data,
+                           run.klen.ctypes.data, *args)
+    return ctypes.string_at(lib.kme_run_out(), n) if n >= 0 else None
+
+
+def _run_rows(run: Run, lo: int, hi: int) -> bytes:
+    """The durable rows of lines [lo, hi) of a run's buffer as the
+    bytes the log file takes, equal to _stamped_rows over the same
+    records: ONE native call over the buffer (kme_wire.cpp
+    kme_run_rows: split at the key, JSON-escape, append the two
+    integers), or _stamped_rows itself where the library is absent or
+    the bytes are not utf-8."""
+    rows = run_native("kme_run_rows", run, lo, hi, run.epoch,
+                      run.seq0 + lo)
+    if rows is not None:
+        return rows
+    return "".join(_stamped_rows(run.pairs(lo, hi), run.epoch,
+                                 run.seq0 + lo)).encode("ascii")
 
 
 class InProcessBroker:
@@ -477,7 +673,8 @@ class InProcessBroker:
         self.fenced_produces = 0
         self.dup_suppressed = 0
         # latency attribution hook: fn(topic, records, now_us) called
-        # after each non-empty fetch DELIVERS records to a consumer —
+        # after each non-empty fetch DELIVERS records to a consumer
+        # (fetch_runs hands it a Run — `ats`, `n` — for a whole run) —
         # the serving process hosts the broker, so consumer receipt of
         # MatchOut is observable here (MatchService wires this to the
         # lat_consume histogram). Called outside the broker lock.
@@ -540,7 +737,7 @@ class InProcessBroker:
                   f"({len(data) - torn_at} bytes)", file=sys.stderr)
             with open(path, "r+b") as f:
                 f.truncate(torn_at)
-        topic.logfile = open(path, "a", encoding="utf-8")
+        topic.logfile = open(path, "ab")
         self._topics[name] = topic
 
     # -- admin ----------------------------------------------------------
@@ -558,7 +755,7 @@ class InProcessBroker:
                 return False
             logfile = None
             if self._persist_dir is not None:
-                logfile = open(self._log_path(name), "a", encoding="utf-8")
+                logfile = open(self._log_path(name), "ab")
             self._topics[name] = _Topic(partitions, logfile)
             return True
 
@@ -631,10 +828,8 @@ class InProcessBroker:
                     row = ([key, value]
                            if epoch is None and out_seq is None
                            else [key, value, epoch, out_seq])
-                    t.logfile.write(json.dumps(row,
-                                               separators=(",", ":"))
-                                    + "\n")
-                    t.logfile.flush()
+                    _flush_log_lines(t.logfile, [json.dumps(
+                        row, separators=(",", ":")) + "\n"])
                 self._data.notify_all()
                 return off
         # controller shed: annotate + raise OUTSIDE the broker lock (the
@@ -792,26 +987,70 @@ class InProcessBroker:
         """Stamped batch append for output records — the egress twin of
         produce_frames: `records` is the list of one run's ``(key,
         value)`` pairs in order, record i carries ``out_seq = seq0 +
-        i``. Record for record the semantics are produce()'s: the
-        `broker.produce` fault point is asked once, before anything is
-        appended; a stale epoch raises BrokerFenced with nothing
-        appended; records at or below the topic's durable watermark
-        are suppressed and counted (the stamps of a run are dense and
-        rising, so a replayed tail is a prefix of it); on a `max_lag` /
+        i``. A thin adapter: the pairs are laid out as one buffer
+        (run_of_pairs) and take the one stamped-run path,
+        _produce_run, whose docstring has the semantics. Returns how
+        many records were appended."""
+        return self._produce_run(topic, *run_of_pairs(records), epoch,
+                                 seq0)
+
+    def produce_stamped_buffer(self, topic: str, buf: bytes, off,
+                               epoch: int, seq0: int) -> int:
+        """produce_stamped for a run that already IS a buffer: `buf`
+        holds "KEY value" lines back to back, `off` (n + 1 int64) their
+        offsets, as SeqSession.collect returns them; each line is split
+        at its first space, as ``str.partition(" ")`` splits it. Record
+        i carries ``out_seq = seq0 + i``. Nothing is made a record:
+        the buffer goes into the log as a Run. Returns how many records
+        were appended."""
+        import numpy as np
+
+        from kme_tpu.native import BoundaryError, check_buffer
+
+        # the native calls read buf[off[i]:off[i + 1]] with no way to
+        # check: offsets that leave the buffer are refused here
+        check_buffer("produce_stamped_buffer.off", off, np.int64, 1)
+        if (off[0] < 0 or off[-1] > len(buf)
+                or (len(off) > 1 and np.diff(off).min() < 0)):
+            raise BoundaryError(
+                "produce_stamped_buffer.off: offsets are not rising "
+                f"within the buffer's {len(buf)} bytes")
+        klen = split_run(buf, off)
+        if not buf.isascii():
+            # what fetch() could not make a Record of never enters the
+            # log, persisted or not: a line that is not utf-8 raises
+            # here (UnicodeDecodeError), as the rows' twin would
+            Run(buf, off, klen, 0, len(klen), 0, epoch, seq0,
+                None).pairs()
+        return self._produce_run(topic, buf, off, klen, epoch, seq0)
+
+    def _produce_run(self, topic: str, buf: bytes, off, klen,
+                     epoch: int, seq0: int) -> int:
+        """The one stamped-run path. Record for record the semantics
+        are produce()'s: the `broker.produce` fault point is asked
+        once, before anything is appended; a stale epoch raises
+        BrokerFenced with nothing appended; records at or below the
+        topic's durable watermark are suppressed and counted (the
+        stamps of a run are dense and rising, so a replayed tail is a
+        prefix of it); on a bounded topic (`topic in self._commits`)
+        admission is per record (_admit_run) and on a `max_lag` /
         controller refusal the admitted prefix STAYS appended and the
         BrokerOverload carries `.admitted`, as in produce_frames. The
         rows are the bytes produce() writes, through ONE write + flush
         (_flush_log_lines) and ONE notify_all, all under one hold of
         the data lock: no consumer can fetch a record that is not yet
-        flushed. One admission stamp (`ats`) for the run. Returns how
-        many records were appended."""
+        flushed. One admission stamp (`ats`) for the run, which the
+        log holds as ONE Run — the work under the lock does not grow
+        with the run on a topic that is not bounded."""
         if faults.should("broker.produce"):
             raise BrokerError("injected fault: broker.produce")
+        n = len(klen)
+        run = Run(buf, off, klen, 0, n, 0, epoch, seq0, None)
         # the stamps are known before the lock, so the rows are built
         # outside it (produce_frames parses before it locks)
-        rows = (_stamped_rows(records, epoch, seq0)
-                if self._persist_dir is not None else None)
-        ats = self._clock.time_us()
+        rows = (_run_rows(run, 0, n)
+                if n and self._persist_dir is not None else None)
+        run.ats = self._clock.time_us()
         shed_detail = overload_msg = None
         with self._data:
             t = self._topics.get(topic)
@@ -823,40 +1062,47 @@ class InProcessBroker:
                     f"fenced: produce to {topic!r} from stale epoch "
                     f"{epoch} < fence {self._fence_epoch}")
             self._fence_epoch = epoch
-            first = min(len(records), max(0, t.max_out_seq + 1 - seq0))
+            first = min(n, max(0, t.max_out_seq + 1 - seq0))
             self.dup_suppressed += first
-            bounded = topic in self._commits
-            log = t.log
-            end = first
-            for key, value in records[first:]:
-                if bounded:
-                    backlog = len(log) - self._commits[topic]
-                    if (self._max_lag is not None
-                            and backlog >= self._max_lag):
-                        self.overload_rejects += 1
-                        overload_msg = (
-                            f"rej_overload: topic {topic!r} backlog "
-                            f"{backlog} >= max_lag {self._max_lag}")
-                        break
-                    if self.overload is not None:
-                        ok, shed_detail = self.overload.admit(
-                            value, backlog)
-                        if not ok:
-                            self.overload_rejects += 1
-                            break
-                    self.wire_json_records += 1
-                log.append(Record(len(log), key, value, epoch,
-                                  seq0 + end, ats))
-                end += 1
+            end = n
+            if topic in self._commits:
+                end, overload_msg, shed_detail = self._admit_run(
+                    topic, len(t.log), run, first)
             appended = end - first
             if appended:
+                t.log.append_run(run.slice(first, end, len(t.log)))
                 t.max_out_seq = seq0 + end - 1
                 if t.logfile is not None:
-                    _flush_log_lines(t.logfile, rows[first:end])
+                    if appended != n:
+                        rows = _run_rows(run, first, end)
+                    _flush_log_lines(t.logfile, rows)
                 self._data.notify_all()
         if overload_msg is None and shed_detail is None:
             return appended
         self._raise_overload(topic, overload_msg, shed_detail, appended)
+
+    def _admit_run(self, topic: str, log_len: int, run: Run,
+                   first: int) -> tuple:
+        """Per-record admission of a run's records from `first` on, on
+        a bounded topic, under the data lock: ``(end, overload_msg,
+        shed_detail)`` — records [first, end) are admitted, and the
+        other two say why it stopped, where it did."""
+        end = first
+        for _key, value in run.pairs(first, run.hi):
+            backlog = log_len + end - first - self._commits[topic]
+            if self._max_lag is not None and backlog >= self._max_lag:
+                self.overload_rejects += 1
+                return end, (
+                    f"rej_overload: topic {topic!r} backlog "
+                    f"{backlog} >= max_lag {self._max_lag}"), None
+            if self.overload is not None:
+                ok, shed_detail = self.overload.admit(value, backlog)
+                if not ok:
+                    self.overload_rejects += 1
+                    return end, None, shed_detail
+            self.wire_json_records += 1
+            end += 1
+        return end, None, None
 
     def fence(self, epoch: int) -> None:
         """Advance the fence so every produce stamped below `epoch` is
@@ -875,7 +1121,32 @@ class InProcessBroker:
     def fetch(self, topic: str, offset: int, max_records: int = 1024,
               timeout: float = 0.0) -> List[Record]:
         """Records from `offset` (at most max_records). Blocks up to
-        `timeout` seconds while the log end is <= offset."""
+        `timeout` seconds while the log end is <= offset. Where the log
+        holds a stamped run as one buffer (Run), its Records are made
+        here, for this caller, outside the lock: equal to what
+        produce() would have stored (offset, key, value, epoch,
+        out_seq, the run's one ats)."""
+        recs: List[Record] = []
+        for p in self._fetch_pieces(topic, offset, max_records, timeout):
+            recs.extend(p.records() if type(p) is Run else p)
+        self._delivered(topic, recs)
+        return recs
+
+    def fetch_runs(self, topic: str, offset: int, max_records: int = 1024,
+                   timeout: float = 0.0) -> list:
+        """fetch() for a caller that wants bytes (tcp.py's fetch_bin):
+        the same records in the same order as a list of pieces — a
+        stamped run comes back as ONE Run (a slice of it where the
+        fetch starts or ends inside) and no Record is made of it; a
+        stretch of single Records as a list of them."""
+        pieces = self._fetch_pieces(topic, offset, max_records, timeout)
+        if self.deliver_observer is not None:
+            self._delivered(topic, [x for p in pieces for x in (
+                (p,) if type(p) is Run else p)])
+        return pieces
+
+    def _fetch_pieces(self, topic: str, offset: int, max_records: int,
+                      timeout: float) -> list:
         if faults.should("broker.fetch"):
             raise BrokerError("injected fault: broker.fetch")
         with self._data:
@@ -885,14 +1156,18 @@ class InProcessBroker:
             if timeout > 0 and len(t.log) <= offset:
                 self._data.wait_for(lambda: len(t.log) > offset,
                                     timeout=timeout)
-            recs = t.log[offset:offset + max_records]
+            return t.log.pieces(offset, max_records)
+
+    def _delivered(self, topic: str, recs: list) -> None:
+        """Tell the deliver observer, outside the lock: Records from
+        fetch(), Records and Runs (one `ats`, `n` records) from
+        fetch_runs()."""
         obs = self.deliver_observer
         if obs is not None and recs:
             try:
                 obs(topic, recs, self._clock.time_us())
             except Exception:
                 pass        # observability must never fail a fetch
-        return recs
 
     def commit(self, topic: str, offset: int) -> None:
         """Advance a consumer watermark (arms the `max_lag` ingress

@@ -233,8 +233,10 @@ class MatchService:
         self.epoch: Optional[int] = None  # leader fencing token
         self.out_seq = 0                  # next MatchOut produce stamp
         # output-stream records handed to the broker and the calls that
-        # took them (counters matchout_records / matchout_produce_calls)
-        self._out_calls = self._out_records = 0
+        # took them (counters matchout_records / matchout_produce_calls),
+        # and those of them that went inside a buffer run (counter
+        # matchout_records_buffered)
+        self._out_calls = self._out_records = self._out_buffered = 0
         # the run of organic records _produce_records is gathering for
         # one produce_stamped call; None where records go out one by one
         self._run = None
@@ -811,10 +813,13 @@ class MatchService:
             def _on_deliver(topic, recs, now_us):
                 if topic != topic_out:
                     return
+                # a Run (broker.fetch_runs) has one ats for its n
+                # records: one observation with its count
                 for r in recs:
                     ats = getattr(r, "ats", None)
                     if ats is not None:
-                        lat_consume.observe(max(0, now_us - ats) * 1e-6)
+                        lat_consume.observe(max(0, now_us - ats) * 1e-6,
+                                            getattr(r, "n", 1))
 
             self.broker.deliver_observer = _on_deliver
 
@@ -1494,19 +1499,81 @@ class MatchService:
 
     def _produce_buffer(self, buf, line_off, ordinal=None) -> None:
         """Produce a reconstructed record buffer — the collect-side
-        twin of _produce_lines: both hand the batch's lines to
-        _produce_records (same stamping, retry and flow-arrow
-        semantics)."""
+        twin of _produce_lines, with the same stamping, retry and
+        flow-arrow semantics. Where the buffer may go whole
+        (_buffer_call) it is handed to the broker as `session.collect`
+        returned it (_produce_runs): no line is sliced out of it.
+        Otherwise its lines go through _produce_records' walk."""
         import time as _t
 
         t0 = _t.perf_counter()
         with self._span("produce_buffer", ordinal):
             self._flow("f", ordinal)
-            text = buf.decode("ascii")
-            lo = line_off.tolist()
-            self._produce_records(
-                [text[lo[i]:lo[i + 1]] for i in range(len(lo) - 1)])
+            send = self._buffer_call()
+            if send is not None:
+                self._produce_runs(send, buf, line_off)
+            else:
+                text = buf.decode("ascii")
+                lo = line_off.tolist()
+                self._produce_records(
+                    [text[lo[i]:lo[i + 1]] for i in range(len(lo) - 1)])
         self._last_produce_s += _t.perf_counter() - t0
+
+    def _buffer_call(self):
+        """The broker's call that takes a stamped run as one buffer, or
+        None — read off what the code can see, never off a flag: the
+        leader stamps, the broker has the stamped batch calls
+        (InProcessBroker; one that hides `produce_stamped` is served
+        record by record), and `_produce_out` is the class's own —
+        where a subclass, a monkeypatch or the benchmark's broken host
+        has replaced the gate, every record passes through it."""
+        if (self.epoch is None
+                or getattr(self._produce_out, "__func__", None)
+                is not _PRODUCE_OUT
+                or getattr(self.broker, "produce_stamped", None) is None):
+            return None
+        return getattr(self.broker, "produce_stamped_buffer", None)
+
+    def _produce_runs(self, send, buf: bytes, line_off) -> None:
+        """Send a batch's "KEY value" buffer (`line_off`: n + 1 int64
+        offsets) through the broker's buffer call `send`, in order:
+        whole where no Xfer mark occurs in it — one `bytes` search —
+        and split at every Xfer-marked line otherwise: the lines before
+        it go as one run, the line goes through _produce_xfer in its
+        place, so the stamp stream is the per-record walk's. Each run
+        is one call under _produce_retry's backoff: a BrokerError
+        retries the whole run from the same `seq0`, and the broker's
+        dedup makes that idempotent."""
+        n = len(line_off) - 1
+        start = 0
+        mark = self._xfer_mark
+        markb = None if mark is None else mark.encode("ascii")
+        pos = -1 if mark is None else buf.find(markb)
+        while pos >= 0:
+            # the line the match lies in; the walk's own test decides
+            # (a match in a key, or across two lines, marks nothing)
+            li = int(line_off.searchsorted(pos, "right")) - 1
+            key, _, value = buf[line_off[li]:line_off[li + 1]].decode(
+                "ascii").partition(" ")
+            if mark in value:
+                self._send_run(send, buf, line_off, start, li)
+                self._produce_xfer(key, value)
+                start = li + 1
+                pos = int(line_off[start]) - 1
+            pos = buf.find(markb, pos + 1)
+        self._send_run(send, buf, line_off, start, n)
+
+    def _send_run(self, send, buf: bytes, line_off, lo: int,
+                  hi: int) -> None:
+        n = hi - lo
+        if n <= 0:
+            return
+        self._broker_retry(send, self.topic_out, buf,
+                           line_off[lo:hi + 1], self.epoch, self.out_seq)
+        self._out_calls += 1
+        self._out_records += n
+        self._out_buffered += n
+        self.out_seq += n
 
     def _publish_batch(self, nrecs: int, ndropped: int) -> None:
         """Per-batch service counters + a rate-limited engine refresh.
@@ -1583,7 +1650,11 @@ class MatchService:
                   "elsewhere").set(self._out_calls)
         t.counter("matchout_records",
                   "output-stream records handed to the broker, by "
-                  "either path").set(self._out_records)
+                  "any path").set(self._out_records)
+        t.counter("matchout_records_buffered",
+                  "of matchout_records, those that reached the broker "
+                  "inside a buffer run (produce_stamped_buffer): no "
+                  "Python object a record").set(self._out_buffered)
         # host engines never load jax: nothing compiles
         jaxsetup = sys.modules.get("kme_tpu._jaxsetup")
         compiles = (jaxsetup.compiles if jaxsetup is not None
@@ -1786,8 +1857,11 @@ class MatchService:
 
     def _produce_records(self, lines) -> None:
         """Produce one batch's "KEY value" output lines in order — the
-        one walk behind _produce_buffer and _produce_lines; every line
-        is routed by _produce_out. How many records a broker call
+        per-record walk behind _produce_buffer and _produce_lines,
+        taken wherever the batch may not go to the broker as one
+        buffer (_buffer_call): every line is routed by _produce_out,
+        which is the definition the buffer path is held to
+        (tests/test_produce_stamped.py). How many records a broker call
         takes is read off the broker object: a stamping leader whose
         broker has `produce_stamped` (InProcessBroker) gathers each
         run of consecutive organic lines and sends it in ONE call —
@@ -1890,14 +1964,27 @@ class MatchService:
 
     def _produce_lines(self, out) -> None:
         """Produce a batch's per-message line lists — the serial
-        path's twin of _produce_buffer, through the same
-        _produce_records."""
+        path's twin of _produce_buffer. Where a buffer may go whole
+        (_buffer_call) the lines are joined into that shape — one
+        `join`, one `encode`, lengths by ``map(len, ...)`` — and take
+        the same call (_produce_runs); lines that are not ASCII (their
+        lengths would not be byte offsets) and every other case go
+        through _produce_records' walk."""
         import time as _t
 
         t0 = _t.perf_counter()
         with self._span("produce_lines"):
             self._flow("f")
-            self._produce_records([ln for lines in out for ln in lines])
+            lines = [ln for lines in out for ln in lines]
+            send = self._buffer_call() if lines else None
+            text = None if send is None else "".join(lines)
+            if text is not None and text.isascii():
+                from kme_tpu.bridge.broker import line_offsets
+
+                self._produce_runs(send, text.encode("ascii"),
+                                   line_offsets(lines))
+            else:
+                self._produce_records(lines)
         # accumulates across the branch paths that produce more than
         # once per step (native partial + REJ annotations)
         self._last_produce_s += _t.perf_counter() - t0
@@ -2213,3 +2300,8 @@ class MatchService:
             print(f"kme-serve: TSDB append failed: {e}",
                   file=sys.stderr)
             self.tsdb = None
+
+
+# the gate as the class defines it: _buffer_call sends a buffer past it
+# only while nothing has replaced it
+_PRODUCE_OUT = MatchService._produce_out

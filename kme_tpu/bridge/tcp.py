@@ -50,6 +50,13 @@ i64 offset/epoch/out_seq/ats/tid (INT64_MIN = absent), u8 key-length
 (255 = null) + key, u32 value-length + value. Both paths carry the
 (epoch, out_seq) stamps and ats without a per-record dict on either
 side; JSON stays fully supported on the same socket (COMPAT.md).
+**What a fetch row is made from**: `fetch` rows from Records
+(`InProcessBroker.fetch` makes them of a stamped run when asked);
+`fetch_bin` rows from `InProcessBroker.fetch_runs` — a stamped run the
+serve loop produced as one buffer (`produce_stamped_buffer`) is packed
+from that buffer in one native call (`kme_run_pack`, `_pack_run`), a
+single Record by `_pack_records`, the format's definition; the bytes
+of a reply are the same either way.
 
 Errors come back as {"ok":false,"error":"..."}; the client raises
 BrokerError (BrokerOverload when the reply carries
@@ -73,7 +80,7 @@ from typing import List, Optional, Tuple
 from kme_tpu import faults
 from kme_tpu.bridge.broker import (BrokerError, BrokerFenced,
                                    BrokerOverload, InProcessBroker,
-                                   Record)
+                                   Record, Run, run_native)
 from kme_tpu.wire import (FRAME_PRODUCE, WIRE_MAGIC, WIRE_VERSION,
                           WireFrameError, rej_name)
 
@@ -92,6 +99,47 @@ def _opt(v: Optional[int]) -> int:
 
 def _unopt(v: int) -> Optional[int]:
     return None if v == _I64_NONE else v
+
+
+def _pack_records(recs) -> bytes:
+    """fetch_bin's fixed-width rows of single Records — the definition
+    of the format, and the twin kme_run_pack (native/kme_wire.cpp) is
+    held equal to (tests/test_fetch_runs.py)."""
+    parts = []
+    for r in recs:
+        kb = b"" if r.key is None else r.key.encode()
+        vb = r.value.encode()
+        parts.append(
+            _REC_HDR.pack(r.offset, _opt(r.epoch), _opt(r.out_seq),
+                          _opt(getattr(r, "ats", None)),
+                          _opt(getattr(r, "tid", None)))
+            + bytes([255 if r.key is None else len(kb)]) + kb
+            + struct.pack("<I", len(vb)) + vb)
+    return b"".join(parts)
+
+
+def _pack_run(run: Run) -> bytes:
+    """fetch_bin's rows of a stamped run, straight from its buffer in
+    ONE native call — no Record is made; _pack_records over the run's
+    Records where the library is absent (or a key is too long for its
+    length byte: that raises there, as it always did)."""
+    rows = run_native("kme_run_pack", run, run.lo, run.hi, run.base,
+                      run.epoch, run.seq0 + run.lo, _opt(run.ats))
+    return _pack_records(run.records()) if rows is None else rows
+
+
+def _pack_pieces(pieces) -> Tuple[int, bytes]:
+    """(record count, reply tail) of a fetch_runs() result: each Run
+    packed whole, each list of single Records by _pack_records."""
+    n, parts = 0, []
+    for p in pieces:
+        if type(p) is Run:
+            parts.append(_pack_run(p))
+            n += p.n
+        else:
+            parts.append(_pack_records(p))
+            n += len(p)
+    return n, b"".join(parts)
 
 
 def _row(r: Record) -> list:
@@ -251,23 +299,16 @@ class _Handler(socketserver.StreamRequestHandler):
             # [o,k,v,epoch,out_seq,ats] with an admission stamp
             resp = {"ok": True, "records": [_row(r) for r in recs]}
         elif op == "fetch_bin":
-            recs = broker.fetch(
-                req["topic"], int(req["offset"]),
-                int(req.get("max", 1024)),
-                float(req.get("timeout_ms", 0)) / 1e3)
-            parts = []
-            for r in recs:
-                kb = b"" if r.key is None else r.key.encode()
-                vb = r.value.encode()
-                parts.append(
-                    _REC_HDR.pack(r.offset, _opt(r.epoch),
-                                  _opt(r.out_seq),
-                                  _opt(getattr(r, "ats", None)),
-                                  _opt(getattr(r, "tid", None)))
-                    + bytes([255 if r.key is None else len(kb)]) + kb
-                    + struct.pack("<I", len(vb)) + vb)
-            tail = b"".join(parts)
-            resp = {"ok": True, "n": len(recs), "nbytes": len(tail)}
+            # a stamped run comes back as one Run and is packed from
+            # its buffer; a broker without fetch_runs hands Records
+            args = (req["topic"], int(req["offset"]),
+                    int(req.get("max", 1024)),
+                    float(req.get("timeout_ms", 0)) / 1e3)
+            fetch_runs = getattr(broker, "fetch_runs", None)
+            n, tail = _pack_pieces([broker.fetch(*args)]
+                                   if fetch_runs is None
+                                   else fetch_runs(*args))
+            resp = {"ok": True, "n": n, "nbytes": len(tail)}
         elif op == "fence":
             broker.fence(int(req["epoch"]))
             resp = {"ok": True}

@@ -296,6 +296,17 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         "kme_parse_emit": ([c.c_void_p], c.c_int64),
         "kme_parse_emit_buf": ([c.c_void_p], c.c_void_p),
         "kme_parse_emit_off": ([c.c_void_p], P64),
+        # a stamped run of output records as one buffer (kme_wire.cpp
+        # kme_run_*): the broker's durable rows and fetch_bin's reply
+        # rows, each into a buffer of the calling thread. Array
+        # pointers go as plain addresses, as kme_router_drop_batch's do
+        "kme_run_split": ([c.c_char_p, c.c_void_p, c.c_int64,
+                           c.c_void_p], None),
+        "kme_run_rows": ([c.c_char_p, c.c_void_p, c.c_void_p]
+                         + [c.c_int64] * 4, c.c_int64),
+        "kme_run_pack": ([c.c_char_p, c.c_void_p, c.c_void_p]
+                         + [c.c_int64] * 6, c.c_int64),
+        "kme_run_out": ([], c.c_void_p),
         # native front-door acceptor (kme_front.cpp): validate + route
         # + plan in one call per batch
         "kme_front_new": ([], c.c_void_p),

@@ -550,3 +550,227 @@ const int64_t* kme_parse_emit_off(void* p) {
 }
 
 }  // extern "C"
+
+// ---------------------------------------------------------------------------
+// kme_run: a stamped run of output records as ONE buffer (the egress
+// twin of kme_parse above). The reconstruction buffer already is the
+// records — "KEY value" lines with n+1 offsets — and both places it is
+// going want bytes: the broker's durable log (one JSON row a record)
+// and a consumer's fetch_bin reply (one fixed-width row a record).
+// Line i is buf[off[i], off[i+1]); its key is the first klen[i] bytes
+// (klen < 0: the key is null and the whole line is the value), its
+// value starts one separator byte after the key (or is empty where the
+// line ends with the key). Each call leaves its result in a buffer of
+// the CALLING THREAD (the serve loop and every fetch handler have their
+// own), valid until that thread's next call; kme_run_out reads it.
+// Semantics authorities: broker._stamped_rows (json's
+// encode_basestring_ascii) and tcp._pack_records; equivalence is pinned
+// by tests/test_produce_stamped.py and tests/test_fetch_runs.py.
+
+namespace {
+
+// the calling thread's result buffer: grown, never shrunk, freed with
+// the thread
+struct RunOut {
+  char* buf = nullptr;
+  int64_t cap = 0, len = 0;
+  ~RunOut() { delete[] buf; }
+  void reserve(int64_t need) {
+    if (cap >= need) return;
+    delete[] buf;
+    cap = need + need / 4;
+    buf = new char[cap];
+  }
+};
+thread_local RunOut run_out;
+
+// bytes json's encode_basestring_ascii copies as they are: printable
+// ASCII but '"' and '\\'
+struct PlainTable {
+  bool t[256];
+  constexpr PlainTable() : t() {
+    for (int c = 0; c < 256; c++)
+      t[c] = c >= 0x20 && c < 0x7f && c != '"' && c != '\\';
+  }
+};
+constexpr PlainTable PLAIN;
+
+inline char* put_u4(char* p, uint32_t cp) {
+  static const char hex[] = "0123456789abcdef";
+  p[0] = '\\';
+  p[1] = 'u';
+  p[2] = hex[(cp >> 12) & 15];
+  p[3] = hex[(cp >> 8) & 15];
+  p[4] = hex[(cp >> 4) & 15];
+  p[5] = hex[cp & 15];
+  return p + 6;
+}
+
+// json.encoder.encode_basestring_ascii over utf-8 bytes, at most 6
+// bytes out for one in, plus the quotes: nullptr where the bytes are
+// not utf-8 (the caller then takes the Python twin).
+inline char* put_json_string(char* p, const uint8_t* s, int64_t n) {
+  *p++ = '"';
+  int64_t i = 0;
+  while (i < n) {
+    const uint8_t ch = s[i];
+    // a record's value is JSON, a quote every few bytes: a byte at a
+    // time is as fast as finding stretches to memcpy
+    if (PLAIN.t[ch]) {
+      *p++ = static_cast<char>(ch);
+      i++;
+      continue;
+    }
+    if (ch < 0x80) {
+      char e = 0;
+      switch (ch) {
+        case '"': e = '"'; break;
+        case '\\': e = '\\'; break;
+        case '\n': e = 'n'; break;
+        case '\r': e = 'r'; break;
+        case '\t': e = 't'; break;
+        case '\b': e = 'b'; break;
+        case '\f': e = 'f'; break;
+      }
+      if (e) {
+        *p++ = '\\';
+        *p++ = e;
+      } else {
+        p = put_u4(p, ch);
+      }
+      i++;
+      continue;
+    }
+    const int extra = ch >= 0xf0 ? 3 : ch >= 0xe0 ? 2 : ch >= 0xc0 ? 1 : -1;
+    if (extra < 0 || ch >= 0xf8 || i + extra >= n) return nullptr;
+    uint32_t cp = ch & (0x3f >> extra);
+    for (int k = 1; k <= extra; k++) {
+      if ((s[i + k] & 0xc0) != 0x80) return nullptr;
+      cp = (cp << 6) | (s[i + k] & 0x3f);
+    }
+    // what Python's decoder refuses — an overlong form, a code point
+    // past U+10FFFF — goes to the twin too (a surrogate is taken:
+    // "surrogatepass" on both sides)
+    static const uint32_t least[4] = {0, 0x80, 0x800, 0x10000};
+    if (cp < least[extra] || cp > 0x10ffff) return nullptr;
+    i += extra + 1;
+    if (cp >= 0x10000) {
+      cp -= 0x10000;
+      p = put_u4(p, 0xd800 | ((cp >> 10) & 0x3ff));
+      p = put_u4(p, 0xdc00 | (cp & 0x3ff));
+    } else {
+      p = put_u4(p, cp);
+    }
+  }
+  *p++ = '"';
+  return p;
+}
+
+inline char* put_le(char* p, uint64_t v, int nbytes) {
+  for (int k = 0; k < nbytes; k++) p[k] = static_cast<char>(v >> (8 * k));
+  return p + nbytes;
+}
+
+}  // namespace
+
+extern "C" {
+
+// klen[i] = length of line i's key: the bytes before its first space,
+// the whole line where it has none (str.partition(" ")).
+void kme_run_split(const uint8_t* buf, const int64_t* off, int64_t n,
+                   int32_t* klen) {
+  for (int64_t i = 0; i < n; i++) {
+    const uint8_t* s = buf + off[i];
+    int64_t len = off[i + 1] - off[i];
+    const void* sp = len > 0 ? std::memchr(s, ' ', len) : nullptr;
+    klen[i] = static_cast<int32_t>(
+        sp ? static_cast<const uint8_t*>(sp) - s : len);
+  }
+}
+
+// The durable rows of lines [lo, hi): `["KEY","value",epoch,seq]\n`,
+// line i stamped seq_lo + (i - lo). Returns the byte count, -1 where a
+// line is not utf-8.
+int64_t kme_run_rows(const uint8_t* buf, const int64_t* off,
+                     const int32_t* klen, int64_t lo, int64_t hi,
+                     int64_t epoch, int64_t seq_lo) {
+  RunOut& o = run_out;
+  o.len = 0;
+  if (hi <= lo) return 0;
+  // a row: '[', two strings (6 bytes out for one in at most, two
+  // quotes each; "null"), three commas, two integers of up to 20
+  // characters, "]\n" — 64 covers all but the strings' bytes
+  o.reserve(6 * (off[hi] - off[lo]) + 64 * (hi - lo));
+  char ep[24];
+  const int64_t eplen = std::to_chars(ep, ep + sizeof ep, epoch).ptr - ep;
+  char* p = o.buf;
+  for (int64_t i = lo; i < hi; i++) {
+    const uint8_t* s = buf + off[i];
+    const int64_t len = off[i + 1] - off[i];
+    const int64_t kl = klen[i];
+    int64_t vs = kl + 1;
+    if (vs > len) vs = len;
+    *p++ = '[';
+    if (kl < 0) {
+      std::memcpy(p, "null", 4);
+      p += 4;
+    } else if (!(p = put_json_string(p, s, kl))) {
+      return -1;
+    }
+    *p++ = ',';
+    if (!(p = put_json_string(p, s + vs, len - vs))) return -1;
+    *p++ = ',';
+    std::memcpy(p, ep, eplen);
+    p += eplen;
+    *p++ = ',';
+    p = std::to_chars(p, p + 24, seq_lo + (i - lo)).ptr;
+    *p++ = ']';
+    *p++ = '\n';
+  }
+  o.len = p - o.buf;
+  return o.len;
+}
+
+// The fetch_bin rows of lines [lo, hi) (bridge/tcp.py): per record five
+// little-endian i64 (offset, epoch, out_seq, ats, tid = absent), u8
+// key length (255: null) + key, u32 value length + value. Returns the
+// byte count, -1 where a key is too long for its length byte.
+int64_t kme_run_pack(const uint8_t* buf, const int64_t* off,
+                     const int32_t* klen, int64_t lo, int64_t hi,
+                     int64_t offset_lo, int64_t epoch, int64_t seq_lo,
+                     int64_t ats) {
+  RunOut& o = run_out;
+  o.len = 0;
+  if (hi <= lo) return 0;
+  o.reserve((off[hi] - off[lo]) + 45 * (hi - lo));
+  char* p = o.buf;
+  for (int64_t i = lo; i < hi; i++) {
+    const char* s = reinterpret_cast<const char*>(buf) + off[i];
+    const int64_t len = off[i + 1] - off[i];
+    const int64_t kl = klen[i];
+    if (kl >= 255) return -1;
+    int64_t vs = kl + 1;
+    if (vs > len) vs = len;
+    p = put_le(p, static_cast<uint64_t>(offset_lo + (i - lo)), 8);
+    p = put_le(p, static_cast<uint64_t>(epoch), 8);
+    p = put_le(p, static_cast<uint64_t>(seq_lo + (i - lo)), 8);
+    p = put_le(p, static_cast<uint64_t>(ats), 8);
+    p = put_le(p, static_cast<uint64_t>(INT64_MIN), 8);
+    if (kl < 0) {
+      *p++ = static_cast<char>(255);
+    } else {
+      *p++ = static_cast<char>(kl);
+      std::memcpy(p, s, kl);
+      p += kl;
+    }
+    p = put_le(p, static_cast<uint64_t>(len - vs), 4);
+    std::memcpy(p, s + vs, len - vs);
+    p += len - vs;
+  }
+  o.len = p - o.buf;
+  return o.len;
+}
+
+const char* kme_run_out() { return run_out.buf; }
+
+}  // extern "C"

@@ -67,6 +67,34 @@ class Broker:
     def produce_stamped(self, topic, records, epoch, seq0):
         _flush_log_lines(self.logfile, list(records))
 """),
+    # the one stamped-run path (PR 44): a run is one buffer, and a
+    # flush a record coming back into its bounded-topic walk gates
+    ("KME-H002", "kme_tpu/bridge/broker.py", """
+class Broker:
+    def _produce_run(self, topic, buf, off, klen, epoch, seq0):
+        for i in range(len(klen)):
+            self.logfile.flush()
+""", """
+def _flush_log_lines(logfile, lines):
+    logfile.write("".join(lines))
+    logfile.flush()
+class Broker:
+    def _produce_run(self, topic, buf, off, klen, epoch, seq0):
+        _flush_log_lines(self.logfile, [self.rows])
+"""),
+    # fetch_runs is fetch() for a caller that wants bytes: the same
+    # clock seam
+    ("KME-C001", "kme_tpu/bridge/broker.py", """
+import time
+class Broker:
+    def fetch_runs(self, name, offset):
+        t0 = time.monotonic()
+""", """
+import time
+class Broker:
+    def _segment_stats(self):
+        t0 = time.monotonic()
+"""),
     ("KME-D001", "kme_tpu/bridge/broker.py", """
 import time
 class Broker:
