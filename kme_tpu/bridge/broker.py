@@ -658,6 +658,11 @@ class InProcessBroker:
         self.wire_binary_records = 0
         self.wire_json_records = 0
         self.wire_parse_ns = 0
+        # the CPU seconds of a TCP front door serving this broker, by
+        # role, one book a handler thread (bridge/tcp.py registers
+        # them): the service sums them into its heartbeat as it reads
+        # the counts above
+        self.tcp_cpu_books: list = []
         # adaptive overload control: an OverloadController makes the
         # shed decision priority-aware (same arming rule as max_lag —
         # only topics with a committed watermark are bounded). The

@@ -87,6 +87,12 @@ class MatchService:
     # spans nested inside those
     INNER_SPANS = ("engine_refresh", "checkpoint_drain", "broker_sync",
                    "snapshot_save")
+    # spans BETWEEN those of LOOP_SPANS: named parts of what
+    # loop_other_ms_per_batch reads, which still subtracts LOOP_SPANS
+    # alone (loop_unnamed_ms_per_batch subtracts these too): the
+    # per-order latency stamping, the broker's watermark commit (a wait
+    # for its lock), and the publishing of these very gauges
+    BETWEEN_SPANS = ("latency_stamp", "commit_watermark", "publish_spans")
 
     def __init__(self, broker, engine: str = "lanes",
                  compat: str = "fixed", batch: int = 1024,
@@ -461,10 +467,11 @@ class MatchService:
             return
         from kme_tpu.bridge.broker import BrokerError
 
-        try:
-            commit(self.topic_in, self.offset)
-        except BrokerError:
-            pass        # topic not provisioned yet / transport blip
+        with self._span("commit_watermark"):
+            try:
+                commit(self.topic_in, self.offset)
+            except BrokerError:
+                pass        # topic not provisioned yet / transport blip
 
     def _init_observability(self, resumed: bool) -> None:
         """Flight recorder + invariant auditor wiring. The journal
@@ -743,6 +750,10 @@ class MatchService:
           plan    — host batch planning (session plan_s delta, charged
                     to every order in the batch)
           device  — dispatch + device fetch (dispatch_s + fetch_s)
+          inflight — pipelined path only: session_submit returned ->
+                    _collect_one takes the batch up (the loop submits up
+                    to `pipeline` more batches in between); no other
+                    stage holds this interval
           produce — MatchOut produce wall time for the batch
           e2e     — admission -> the batch's outputs are visible
           consume — admission -> a consumer's fetch delivers the
@@ -760,6 +771,8 @@ class MatchService:
                 ("ingress", "broker admission to serve-loop fetch"),
                 ("plan", "host batch planning"),
                 ("device", "device dispatch + fetch"),
+                ("inflight", "submit returned to collect begun "
+                             "(--pipeline > 0)"),
                 ("produce", "MatchOut produce wall time"),
                 ("e2e", "broker admission to produce visible"),
                 ("consume", "broker admission to consumer delivery"),
@@ -780,6 +793,11 @@ class MatchService:
         # every span and counter is in the registry before the first
         # heartbeat: a reader of two snapshots needs the key in both
         self._loop_t0 = _t.perf_counter()
+        self._process_cpu0 = _t.process_time()
+        # the poll thread's CPU seconds since then (gauge serve_cpu_s)
+        # and where they were last read: (thread, its thread_time())
+        self._serve_cpu_s = 0.0
+        self._serve_cpu_mark = (threading.get_ident(), _t.thread_time())
         # None: no batch yet; (ordinal, t0): the first one is in
         # flight since t0; False: gauge first_batch_s is set
         self._first_batch = None
@@ -824,6 +842,41 @@ class MatchService:
             self.broker.deliver_observer = _on_deliver
 
     _EXEMPLARS = 8
+
+    def _stamp_latency(self, in_atss, atss, offs, oids, aids, fetch_us,
+                       done_us, plan_d, dev_d, batch) -> None:
+        """The per-order stamping of one batch, serial and pipelined
+        path alike; its callers open span `latency_stamp` around it and
+        the lists they make for it. `lat_ingress` for every record
+        fetched (`in_atss`: admission stamps), `lat_e2e` and the
+        overload controller's feed for every message served (`atss`),
+        then _stamp_orders. Three Python walks over the batch, always
+        on and all for instrumentation: the span says what they cost."""
+        lat = self._lat
+        for ats in in_atss:
+            if ats is not None:
+                # ingress = broker admission -> the loop's fetch; per
+                # record, from the intended-start stamp
+                lat["ingress"].observe(max(0, fetch_us - ats) * 1e-6)
+        if not atss:
+            return
+        e2e_hot = 0.0
+        for ats in atss:
+            if ats is not None:
+                d = max(0, done_us - ats) * 1e-6
+                lat["e2e"].observe(d)
+                if d > e2e_hot:
+                    e2e_hot = d
+        ctl = getattr(self.broker, "overload", None)
+        if ctl is not None and e2e_hot > 0:
+            # admission-to-produce feed for the degradation state
+            # machine (latency can trip shedding before backlog does)
+            ctl.observe_latency(e2e_hot)
+        # full batch wall per order (what the order EXPERIENCED — same
+        # convention as the histograms), not an amortized share
+        self._stamp_orders(
+            offs, oids, aids, atss, fetch_us, done_us, int(plan_d * 1e6),
+            int(dev_d * 1e6), int(self._last_produce_s * 1e6), batch=batch)
 
     def _stamp_orders(self, offs, oids, aids, atss, fetch_us, done_us,
                       plan_us, dev_us, prod_us, batch) -> None:
@@ -1157,19 +1210,15 @@ class MatchService:
                 self.clock.sleep(min(timeout, 0.05))
                 return None
 
-    def _parse_records(self, recs, fetch_us: int) -> tuple:
+    def _parse_records(self, recs) -> tuple:
         """Per-record parse of a fetched batch, the serial path's (drop
-        or die on a malformed record, `_parse`) ->
-        (msgs, offsets, drops, admission stamps)."""
-        lat = self._lat
-        msgs, offs, drops, atss = [], [], [], []
+        or die on a malformed record, `_parse`) -> (msgs, offsets,
+        drops, the messages' admission stamps, every record's)."""
+        msgs, offs, drops, atss, in_atss = [], [], [], [], []
         with self._span("parse_batch"):
             for r in recs:
                 ats = getattr(r, "ats", None)
-                if ats is not None:
-                    # ingress = broker admission -> this fetch;
-                    # per-record, from the intended-start stamp
-                    lat["ingress"].observe(max(0, fetch_us - ats) * 1e-6)
+                in_atss.append(ats)
                 m = self._parse(r.value)
                 if m is not None:
                     msgs.append(m)
@@ -1177,7 +1226,7 @@ class MatchService:
                     atss.append(ats)
                 else:
                     drops.append((-1, r.offset))
-        return msgs, offs, drops, atss
+        return msgs, offs, drops, atss, in_atss
 
     def _process_batch(self, recs) -> int:
         """Serial batch processing: parse, engine, produce, commit —
@@ -1189,7 +1238,7 @@ class MatchService:
         fetch_us = self.clock.time_us()
         lat = self._lat
         self._batch_ordinal += 1
-        msgs, offs, drops, atss = self._parse_records(recs, fetch_us)
+        msgs, offs, drops, atss, in_atss = self._parse_records(recs)
         out = reasons = None
         self._last_produce_s = 0.0
         phases = getattr(self._session, "phases", None)
@@ -1235,8 +1284,8 @@ class MatchService:
             if self.annotate_rejects and out is not None:
                 self._produce_rej_annotations(out, reasons)
         # -- latency attribution: charge the batch's stage wall times to
-        # every order in it (per-order quantiles), e2e from each
-        # record's own admission stamp
+        # every order in it (per-order quantiles); ingress and e2e from
+        # each record's own admission stamp (_stamp_latency, below)
         done_us = self.clock.time_us()
         n = len(msgs)
         plan_d = dev_d = 0.0
@@ -1261,18 +1310,6 @@ class MatchService:
                     round(dev_d * 1e3, 3))
             if self._last_produce_s > 0:
                 lat["produce"].observe(self._last_produce_s, n)
-            e2e_hot = 0.0
-            for ats in atss:
-                if ats is not None:
-                    d = max(0, done_us - ats) * 1e-6
-                    lat["e2e"].observe(d)
-                    if d > e2e_hot:
-                        e2e_hot = d
-            ctl = getattr(self.broker, "overload", None)
-            if ctl is not None and e2e_hot > 0:
-                # admission-to-produce feed for the degradation state
-                # machine (latency can trip shedding before backlog does)
-                ctl.observe_latency(e2e_hot)
         if self.journal is not None and (out or drops):
             jout = out or []
             if self._journal_tamper is not None:
@@ -1280,16 +1317,11 @@ class MatchService:
             self.journal.record_batch(jout, reasons=reasons,
                                       offsets=offs[:len(out or [])],
                                       drops=drops)
-        if n:
-            # full batch wall per order (what the order EXPERIENCED —
-            # same convention as the histograms above), not an
-            # amortized per-order share
-            self._stamp_orders(
-                offs[:n], [int(m.oid) for m in msgs],
-                [int(m.aid) for m in msgs], atss, fetch_us, done_us,
-                int(plan_d * 1e6), int(dev_d * 1e6),
-                int(self._last_produce_s * 1e6),
-                batch=self._batch_ordinal)
+        with self._span("latency_stamp"):
+            self._stamp_latency(
+                in_atss, atss, offs[:n], [int(m.oid) for m in msgs],
+                [int(m.aid) for m in msgs], fetch_us, done_us,
+                plan_d, dev_d, self._batch_ordinal)
         if self.watch is not None and n:
             # batch barrier: the serving oracle IS the deterministic
             # state machine, so predicates read it directly — no
@@ -1372,13 +1404,7 @@ class MatchService:
             self._drain_pipeline()
             return self._process_batch(recs)
         fetch_us = self.clock.time_us()
-        lat = self._lat
-        atss = []
-        for r in recs:
-            ats = getattr(r, "ats", None)
-            atss.append(ats)
-            if ats is not None:
-                lat["ingress"].observe(max(0, fetch_us - ats) * 1e-6)
+        atss = [getattr(r, "ats", None) for r in recs]
         end_off = recs[-1].offset + 1
         if (self.checkpoint_dir is not None and not self.follower
                 and self._pipe
@@ -1399,7 +1425,8 @@ class MatchService:
         plan_d = phases.get("plan_s", 0.0) - p0.get("plan_s", 0.0)
         self._pipe.append((end_off, handle, wb,
                            [r.offset for r in recs], atss, fetch_us,
-                           plan_d, self._batch_ordinal))
+                           plan_d, self._batch_ordinal,
+                           self.clock.time_us()))
         while len(self._pipe) > self.pipeline:
             self._collect_one()
         return len(recs)
@@ -1412,9 +1439,15 @@ class MatchService:
         visible on MatchOut."""
         import time as _t
 
-        (end_off, handle, wb, offs, atss, fetch_us, plan_d,
-         ordinal) = self._pipe.popleft()
+        (end_off, handle, wb, offs, atss, fetch_us, plan_d, ordinal,
+         submitted_us) = self._pipe.popleft()
         lat = self._lat
+        # in flight: since session_submit returned the loop has gone
+        # on to submit up to `pipeline` more batches and collect older
+        # ones (fewer where a poll came back empty and drained the
+        # window); one observation a batch, charged to each order in it
+        lat["inflight"].observe(
+            max(0, self.clock.time_us() - submitted_us) * 1e-6, wb.n)
         self._last_produce_s = 0.0
         phases = self._session.phases
         p0 = dict(phases)
@@ -1439,16 +1472,6 @@ class MatchService:
                 round(dev_d * 1e3, 3))
         if self._last_produce_s > 0:
             lat["produce"].observe(self._last_produce_s, n)
-        e2e_hot = 0.0
-        for ats in atss:
-            if ats is not None:
-                d = max(0, done_us - ats) * 1e-6
-                lat["e2e"].observe(d)
-                if d > e2e_hot:
-                    e2e_hot = d
-        ctl = getattr(self.broker, "overload", None)
-        if ctl is not None and e2e_hot > 0:
-            ctl.observe_latency(e2e_hot)
         out = None
         if (self.journal is not None or self.watch is not None) and n:
             out = self._lines_of(buf, line_off, msg_lines)
@@ -1458,12 +1481,11 @@ class MatchService:
                 jout = self._journal_tamper(jout)
             self.journal.record_batch(jout, reasons=reasons,
                                       offsets=offs, drops=[])
-        if n:
-            self._stamp_orders(
-                offs, wb.oid.tolist(), wb.aid.tolist(), atss,
-                fetch_us, done_us, int(plan_d * 1e6),
-                int(dev_d * 1e6), int(self._last_produce_s * 1e6),
-                batch=ordinal)
+        with self._span("latency_stamp", ordinal):
+            # every record of a pipelined batch parsed: one list
+            self._stamp_latency(atss, atss, offs, wb.oid.tolist(),
+                                wb.aid.tolist(), fetch_us, done_us,
+                                plan_d, dev_d, ordinal)
         if self.watch is not None and out:
             self.watch.observe_lines(out, reasons=reasons,
                                      offsets=offs, drops=[],
@@ -1586,14 +1608,20 @@ class MatchService:
             if now - self._last_engine_pub >= 1.0:
                 self._last_engine_pub = now
                 self._engine_refresh()
-        self._publish_spans(1, nrecs, ndropped)
+        # (its own totals are in the NEXT batch's gauges: it closes
+        # after they are set)
+        with self._span("publish_spans"):
+            self._publish_spans(1, nrecs, ndropped)
 
     def _publish_spans(self, batches: int = 0, nrecs: int = 0,
                        ndropped: int = 0) -> None:
         """The batch counters, and every span of the loop's timer and
         of the session's as two cumulative gauges, `<name>_s` and
-        `<name>_n`, with the loop's own wall (`serve_loop_s`), the lane
-        switches and the XLA compile totals beside them — all at ONE
+        `<name>_n` (and `<name>_cpu_s` for the session's CPU_SPANS),
+        with the loop's own wall and CPU (`serve_loop_s`,
+        `serve_cpu_s`), the process's CPU and the front door's by role
+        (_thread_gauges), the lane switches and the XLA compile totals
+        beside them — all at ONE
         instant, after the batch's last span has closed: a difference
         of two heartbeats then holds whole batches of each (a counter
         stepped before the engine refresh and a gauge set after it
@@ -1604,7 +1632,8 @@ class MatchService:
         t.counter("service_batches").inc(batches)
         t.counter("service_records").inc(nrecs)
         t.counter("service_dropped").inc(ndropped)
-        gauges = self._ptimer.gauges(self.LOOP_SPANS + self.INNER_SPANS)
+        gauges = self._ptimer.gauges(self.LOOP_SPANS + self.INNER_SPANS
+                                     + self.BETWEEN_SPANS)
         timer = getattr(self._session, "timer", None)
         if timer is not None:
             gauges.update(timer.gauges(getattr(self._session, "SPANS", ())))
@@ -1621,6 +1650,7 @@ class MatchService:
             # the newest snapshot's size and live counts, from the
             # first one a fixed-mode SeqSession writes
             gauges.update(getattr(self._session, "snapshot_gauges", {}))
+        gauges.update(self._thread_gauges())
         gauges["serve_loop_s"] = round(_t.perf_counter() - self._loop_t0, 6)
         t.counter("lane_switches",
                   "HBM book-cache lane switches the seq kernel made "
@@ -1664,6 +1694,44 @@ class MatchService:
                   "cache in this process").set(compiles["n"])
         gauges["xla_compile_s"] = round(compiles["seconds"], 6)
         t.publish_gauges(gauges)
+
+    def _thread_gauges(self) -> dict:
+        """CPU seconds by the role of the thread that spent them, all
+        cumulative since `_loop_t0`: the poll thread's (`serve_cpu_s`,
+        beside the wall `serve_loop_s`), the whole process's
+        (`process_cpu_s`) and, where the broker is this process's own,
+        what its TCP front door's handler threads booked by the role
+        they serve (bridge/tcp.py: `tcp_ingress_cpu_s` — produce
+        requests — and `tcp_egress_cpu_s` — fetches) and its decode totals
+        (`wire_parse_s` over `wire_binary_records`: parse_ns_per_msg,
+        windowed by whoever takes two heartbeats).
+        What no role claims — XLA's threads, the beater, a profiler —
+        is process_cpu_s less the three. A broker behind a socket
+        (TcpBroker) has no such counters: those gauges are absent."""
+        import threading
+        import time as _t
+
+        # CPU counts while one thread polls: what another thread ran
+        # between two reads (a service built on one, run on another)
+        # is in no thread_time() difference and is left out
+        ident, at = self._serve_cpu_mark
+        now = (threading.get_ident(), _t.thread_time())
+        if now[0] == ident:
+            self._serve_cpu_s += now[1] - at
+        self._serve_cpu_mark = now
+        out = {"serve_cpu_s": round(self._serve_cpu_s, 6),
+               "process_cpu_s": round(
+                   _t.process_time() - self._process_cpu0, 6)}
+        books = getattr(self.broker, "tcp_cpu_books", None)
+        if books is not None:
+            # other threads own the books and may be adding: a sum can
+            # miss a request's CPU for one reading, never count it twice
+            for role in ("ingress", "egress"):
+                out[f"tcp_{role}_cpu_s"] = round(
+                    sum(b[role] for b in books), 6)
+            out["wire_parse_s"] = round(self.broker.wire_parse_ns * 1e-9, 9)
+            out["wire_binary_records"] = self.broker.wire_binary_records
+        return out
 
     def _first_batch_done(self, t0: float) -> None:
         """Gauge first_batch_s, set once: the first batch's submit to

@@ -75,6 +75,7 @@ import socket
 import socketserver
 import struct
 import threading
+import time
 from typing import List, Optional, Tuple
 
 from kme_tpu import faults
@@ -91,6 +92,12 @@ _ENV_META = struct.Struct("<qqq")       # epoch, seq0, ats
 _REC_HDR = struct.Struct("<qqqqq")      # offset, epoch, out_seq, ats, tid
 _I64_NONE = -(1 << 63)                  # "absent" for optional i64s
 _MAGIC_BYTE = bytes([WIRE_MAGIC])
+# whose thread serves a request: the producers' handler threads or the
+# consumers'. A handler thread books its CPU seconds by this role
+# (_Handler._book_cpu); a request of neither role books none
+_ROLE = {"produce_frames": "ingress", "produce": "ingress",
+         "produce_batch": "ingress", "fetch": "egress",
+         "fetch_bin": "egress"}
 
 
 def _opt(v: Optional[int]) -> int:
@@ -159,6 +166,37 @@ def _row(r: Record) -> list:
 
 
 class _Handler(socketserver.StreamRequestHandler):
+    def setup(self) -> None:
+        super().setup()
+        # this thread's CPU seconds by role, in a book of its own (the
+        # sums are added unlocked): an idle one, or a new one on the
+        # broker's list, where its sums stay when the connection closes
+        try:
+            self.cpu_book = self.server.idle_cpu_books.pop()
+        except IndexError:
+            self.cpu_book = {"ingress": 0.0, "egress": 0.0}
+            books = getattr(self.server.broker, "tcp_cpu_books", None)
+            if books is not None:
+                books.append(self.cpu_book)
+        self._op = None             # the latest request's operation
+        self._cpu = time.thread_time()
+
+    def finish(self) -> None:
+        self._book_cpu()
+        self.server.idle_cpu_books.append(self.cpu_book)
+        super().finish()
+
+    def _book_cpu(self) -> None:
+        """What this thread has run since it last read its CPU clock
+        (once a request, after the reply; the wait for the next first
+        byte runs nothing), booked to the role of the request it has
+        just served."""
+        cpu = time.thread_time()
+        role = _ROLE.get(self._op)
+        if role is not None:
+            self.cpu_book[role] += cpu - self._cpu
+        self._cpu = cpu
+
     def _read_exact(self, n: int) -> bytes:
         data = self.rfile.read(n)
         if len(data) != n:        # client died mid-frame
@@ -218,10 +256,12 @@ class _Handler(socketserver.StreamRequestHandler):
             tail = b""      # binary payload appended after the JSON line
             try:
                 if first == _MAGIC_BYTE:
+                    self._op = "produce_frames"
                     resp = self._produce_frames_req(broker)
                 else:
-                    raw = first + self.rfile.readline()
-                    resp, tail = self._dispatch(broker, raw)
+                    req = json.loads(first + self.rfile.readline())
+                    self._op = req.get("op")
+                    resp, tail = self._dispatch(broker, req)
             except ConnectionResetError:
                 return
             except WireFrameError as e:
@@ -258,13 +298,14 @@ class _Handler(socketserver.StreamRequestHandler):
                 self.wfile.write(blob)
             except (BrokenPipeError, ConnectionResetError):
                 return
+            self._book_cpu()
 
     def _dispatch(self, broker: InProcessBroker,
-                  raw: bytes) -> Tuple[dict, bytes]:
-        """One JSON request -> (reply dict, binary tail). Broker/protocol
-        exceptions propagate to handle()'s shared error mapping."""
+                  req: dict) -> Tuple[dict, bytes]:
+        """One parsed JSON request -> (reply dict, binary tail).
+        Broker/protocol exceptions propagate to handle()'s shared error
+        mapping."""
         tail = b""
-        req = json.loads(raw)
         op = req.get("op")
         if op == "create_topic":
             created = broker.create_topic(
@@ -329,6 +370,11 @@ class _Handler(socketserver.StreamRequestHandler):
 class _Server(socketserver.ThreadingTCPServer):
     allow_reuse_address = True
     daemon_threads = True
+    # the CPU books of handler threads whose connection has closed, for
+    # the next connection to take up (_Handler.setup): the broker's
+    # list grows to the most connections open at once, not with every
+    # one ever made. list.pop / append are atomic: no lock
+    idle_cpu_books: list
 
 
 def serve_broker(host: str = "127.0.0.1", port: int = 9092,
@@ -336,9 +382,18 @@ def serve_broker(host: str = "127.0.0.1", port: int = 9092,
     """Start serving `broker` on (host, port) in a daemon thread.
     Returns (server, broker); server.shutdown() stops it. port=0 picks a
     free port (server.server_address has the real one)."""
+    # a handler thread decodes binary frames with numpy, which wire.py
+    # imports where it is first used; the first produce can land while
+    # the caller's thread imports jax, and numpy with it, for its
+    # session, and two threads importing numpy at once can each see the
+    # other's half-made modules (one server died of it: PR 46). So it
+    # is imported before a handler thread exists
+    import numpy  # noqa: F401
+
     broker = broker or InProcessBroker()
     srv = _Server((host, port), _Handler)
     srv.broker = broker  # type: ignore
+    srv.idle_cpu_books = []
     t = threading.Thread(target=srv.serve_forever, daemon=True)
     t.start()
     return srv, broker

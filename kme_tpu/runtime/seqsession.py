@@ -712,6 +712,14 @@ class SeqSession:
              "route_drop", "session_metrics", "metrics_export",
              "metrics_count", "snapshot_export", "snapshot_meta",
              "snapshot_write")
+    # of them, those whose thread CPU is read beside their wall (gauge
+    # `<name>_cpu_s`): the ones a metric reads, no more, since the
+    # thread's CPU clock is a system call on the chip's host. Pure
+    # host work (plan_s, recon_s) is off-CPU only for the interpreter
+    # lock and the scheduler; fetch_s and snapshot_export wait for the
+    # device and the transfer; dispatch_s is the fixed cost of a call
+    CPU_SPANS = ("plan_s", "dispatch_s", "fetch_s", "recon_s",
+                 "snapshot_export")
 
     def __init__(self, cfg: SQ.SeqConfig) -> None:
         self.cfg = cfg
@@ -722,7 +730,7 @@ class SeqSession:
         self._hist = np.zeros((SQ.N_HIST, SQ.N_HIST_BUCKETS), np.int64)
         self._recon = None          # native reconstructor handle
         self.telemetry = Registry()
-        self.timer = PhaseTimer(track="seq")
+        self.timer = PhaseTimer(track="seq", cpu=self.CPU_SPANS)
         # CUMULATIVE wall time per phase across every batch (the timer's
         # totals dict IS this attribute; snapshot/reset via self.timer)
         self.phases = self.timer.totals
@@ -981,11 +989,11 @@ class SeqSession:
         path)."""
         new = dict(zip(ROUTER_STATS, stats))
         old, self.router_stats = self.router_stats, new
-        t = self.timer
-        t.totals["route_purge"] = t.totals.get("route_purge", 0.0) + 1e-9 * (
-            new["route_purge_ns"] - old["route_purge_ns"])
-        t.counts["route_purge"] = t.counts.get("route_purge", 0) + (
-            new["route_purge_n"] - old["route_purge_n"])
+        # (a wall clock, `steady_clock` in C++)
+        self.timer.add(
+            "route_purge",
+            1e-9 * (new["route_purge_ns"] - old["route_purge_ns"]),
+            n=new["route_purge_n"] - old["route_purge_n"])
 
     def _drop_routes(self, cols, host, fills) -> None:
         """Routes die with their orders: tell the router which orders

@@ -147,6 +147,22 @@ def _gauge(node: dict, name: str):
     return node.get("metrics", {}).get("gauges", {}).get(name)
 
 
+def _per_loop_second(cur: dict, prev: Optional[dict], name: str):
+    """Cumulative gauge `name` per second of the serve loop's own wall
+    (`serve_loop_s`, set at the same instant): between two collections,
+    or since the service started where there is one. None where the
+    leader publishes neither."""
+    wall, v = _gauge(cur, "serve_loop_s"), _gauge(cur, name)
+    if wall is None or v is None:
+        return None
+    if prev is not None:
+        wall0, v0 = _gauge(prev, "serve_loop_s"), _gauge(prev, name)
+        if wall0 is not None and v0 is not None \
+                and wall > wall0 and v >= v0:
+            return (v - v0) / (wall - wall0)
+    return v / wall if wall > 0 else None
+
+
 def build_view(cur: dict, prev: Optional[dict] = None) -> dict:
     """Fold two collections into the render model: point-in-time state
     plus rates derived from the deltas between them."""
@@ -160,6 +176,13 @@ def build_view(cur: dict, prev: Optional[dict] = None) -> dict:
             rate = (b - a) / dt
     view["records_per_s"] = rate
     lead = cur["leader"]
+    before = prev["leader"] if prev is not None else None
+    # wall is not work: how much of its wall the serve loop's thread
+    # ran, and the cores the whole process took (every thread's CPU:
+    # the interpreter's, XLA's and the device runtime's)
+    view["serve_cpu_share"] = _per_loop_second(lead, before, "serve_cpu_s")
+    view["process_cpu_cores"] = _per_loop_second(lead, before,
+                                                 "process_cpu_s")
     stby = cur["standby"]
     lag = _gauge(stby, "replica_lag_records")
     if lag is None:
@@ -307,7 +330,10 @@ def render(view: dict, width: int = 78) -> list:
         f"leader   epoch={_fmt(view.get('epoch'))} "
         f"offset={_fmt(view.get('offset'))} "
         f"records={_fmt(_counter(lead, 'service_records'))} "
-        f"rate={_fmt(rate) + '/s' if rate is not None else '-'}")
+        f"rate={_fmt(rate) + '/s' if rate is not None else '-'}"
+        + (f" loop_cpu={view['serve_cpu_share']:.0%}"
+           f" process={_fmt(view.get('process_cpu_cores'), 2)}cores"
+           if view.get("serve_cpu_share") is not None else ""))
     if not lead["ok"]:
         lines.append(f"  leader source unreachable: "
                      f"{lead.get('error', 'no source')}")

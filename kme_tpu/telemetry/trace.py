@@ -149,10 +149,19 @@ class PhaseTimer:
     Sessions expose `totals` directly as `self.phases`. Spans nest; a
     span given no `batch` takes its parent's, so the serve loop's batch
     ordinal ties a batch's submit, collect, produce and publish
-    together down into the session's spans."""
+    together down into the session's spans.
 
-    def __init__(self, track: str = "main"):
+    Wall is not work in a process whose threads share one interpreter:
+    for the spans named in `cpu` (those whose CPU something reads — the
+    clock is a system call on some hosts, so it is not read for the
+    rest) `cpu_totals` keeps the seconds of their wall that the span's
+    own thread ran (`time.thread_time()`, read where the wall clock
+    is); wall less CPU is what the thread waited, for the device, the
+    disk, the interpreter lock or the scheduler."""
+
+    def __init__(self, track: str = "main", cpu=()):
         self.totals: dict = {}
+        self.cpu_totals: dict = dict.fromkeys(cpu, 0.0)
         self.counts: dict = {}
         self.track = track
 
@@ -169,7 +178,9 @@ class PhaseTimer:
         if prof is not None and prof.TraceAnnotation.is_enabled():
             ann = prof.TraceAnnotation(name, **args)
         stack.append(args)
+        timed = name in self.cpu_totals
         t0 = time.perf_counter()
+        c0 = time.thread_time() if timed else 0.0
         try:
             if ann is None:
                 yield
@@ -178,6 +189,8 @@ class PhaseTimer:
                     yield
         finally:
             dt = time.perf_counter() - t0
+            if timed:
+                self.cpu_totals[name] += time.thread_time() - c0
             stack.pop()
             self.totals[name] = self.totals.get(name, 0.0) + dt
             self.counts[name] = self.counts.get(name, 0) + 1
@@ -186,22 +199,30 @@ class PhaseTimer:
                 tr.add(name, t0, dt, track=self.track,
                        args=args or None)
 
-    def add(self, name: str, seconds: float) -> None:
-        """Fold an externally-timed duration into the totals."""
+    def add(self, name: str, seconds: float, n: int = 1) -> None:
+        """Fold `n` externally-timed spans into the totals (wall only:
+        the C++ router's clock, the one caller's, knows no CPU)."""
         self.totals[name] = self.totals.get(name, 0.0) + seconds
-        self.counts[name] = self.counts.get(name, 0) + 1
+        self.counts[name] = self.counts.get(name, 0) + n
 
     def gauges(self, also=()) -> dict:
         """Every span as two cumulative heartbeat gauges: `<name>_s`
         (seconds; a phase already named `..._s` keeps its name) and
-        `<name>_n` (entries). Names in `also` not entered yet read 0."""
+        `<name>_n` (entries), with `<name>_cpu_s` beside them for the
+        spans named in `cpu`. Names in `also` not entered yet read 0,
+        as every `_cpu_s` does before its span's first entry."""
         out = {}
         for name in (*also, *self.totals):
             base = name[:-2] if name.endswith("_s") else name
             out[base + "_s"] = round(self.totals.get(name, 0.0), 6)
             out[base + "_n"] = self.counts.get(name, 0)
+        for name, cpu in self.cpu_totals.items():
+            base = name[:-2] if name.endswith("_s") else name
+            out[base + "_cpu_s"] = round(cpu, 6)
         return out
 
     def reset(self) -> None:
         self.totals.clear()
         self.counts.clear()
+        for name in self.cpu_totals:
+            self.cpu_totals[name] = 0.0

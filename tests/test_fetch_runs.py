@@ -162,7 +162,7 @@ def test_a_broker_without_fetch_runs_is_served_records():
             return [Record(offset, "K", "v", 1, 2, 3)]
 
     resp, tail = tcp._Handler._dispatch(
-        None, Plain(), b'{"op":"fetch_bin","topic":"t","offset":5}')
+        None, Plain(), {"op": "fetch_bin", "topic": "t", "offset": 5})
     assert resp == {"ok": True, "n": 1, "nbytes": len(tail)}
     assert tail == tcp._pack_records([Record(5, "K", "v", 1, 2, 3)])
 

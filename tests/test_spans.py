@@ -57,6 +57,153 @@ def test_phase_counts_entries_and_nests():
     assert t.totals == {} and t.counts == {}
 
 
+def _cpu_clock_step():
+    """The step of the thread CPU clock, by spinning until it moves: a
+    microsecond or less on Linux proper; 10 ms where the kernel counts
+    CPU in ticks (the chip's host does: PERF.md section 6, PR 46). The
+    tests below spin long enough for either, and allow two steps."""
+    import time
+
+    c0 = c = time.thread_time()
+    end = time.perf_counter() + 0.1
+    while c == c0 and time.perf_counter() < end:
+        c = time.thread_time()
+    return max(c - c0, 1e-6)
+
+
+def _best_of(tries, body, good):
+    """`body()` -> a reading, up to `tries` times until `good(reading)`:
+    a reading of CPU against wall on a machine other tests load is
+    judged by the best of a few, never by one."""
+    for _ in range(tries):
+        got = body()
+        if good(got):
+            break
+    return got
+
+
+def test_phase_keeps_cpu_beside_wall():
+    import time
+
+    step = _cpu_clock_step()
+    spin = max(0.03, 20 * step)
+
+    def asleep():
+        t = PhaseTimer(cpu=("asleep",))
+        with t.phase("asleep"):
+            time.sleep(0.05)
+        return t
+
+    def spinning():
+        t = PhaseTimer(cpu=("spinning",))
+        with t.phase("spinning"):
+            end = time.perf_counter() + spin
+            while time.perf_counter() < end:
+                pass
+        return t
+
+    t = asleep()
+    assert t.totals["asleep"] >= 0.05
+    # a sleeping thread runs nothing: wall is not work
+    assert 0 <= t.cpu_totals["asleep"] <= 0.005 + 2 * step
+    assert t.cpu_totals["asleep"] <= t.totals["asleep"] + 1e-3 + 2 * step
+    t = _best_of(8, spinning, lambda t: t.cpu_totals["spinning"]
+                 >= 0.8 * t.totals["spinning"])
+    assert t.cpu_totals["spinning"] >= 0.8 * t.totals["spinning"]
+    assert t.cpu_totals["spinning"] <= t.totals["spinning"] + 1e-3 \
+        + 2 * step
+    g = t.gauges()
+    assert g["spinning_cpu_s"] == round(t.cpu_totals["spinning"], 6)
+    assert set(g) == {"spinning_s", "spinning_cpu_s", "spinning_n"}
+
+
+def test_nested_phases_each_keep_their_own_cpu():
+    import time
+
+    step = _cpu_clock_step()
+    spin = max(0.03, 20 * step)
+
+    def nested():
+        t = PhaseTimer(cpu=("outer", "waits", "works"))
+        with t.phase("outer"):
+            with t.phase("waits"):
+                time.sleep(spin)
+            with t.phase("works"):
+                end = time.perf_counter() + spin
+                while time.perf_counter() < end:
+                    pass
+        return t
+
+    t = _best_of(8, nested, lambda t: t.cpu_totals["works"]
+                 >= 0.8 * t.totals["works"])
+    cpu, wall = t.cpu_totals, t.totals
+    assert cpu["waits"] <= 0.005 + 2 * step
+    assert cpu["works"] >= 0.8 * wall["works"]
+    # the outer span holds both walls and, of CPU, the inner one's work
+    assert wall["outer"] >= wall["waits"] + wall["works"]
+    assert cpu["works"] <= cpu["outer"] <= wall["outer"] - spin / 2
+
+
+def test_cpu_gauges_read_zero_before_the_first_entry_and_after_add():
+    t = PhaseTimer(cpu=("fetch_s", "route_purge"))
+    g = t.gauges(also=("fetch_s", "never_entered"))
+    assert g["fetch_cpu_s"] == 0.0 and g["route_purge_cpu_s"] == 0.0
+    assert "fetch_s_cpu_s" not in g and "never_entered_cpu_s" not in g
+    # a span timed outside (the C++ router's) keeps wall only
+    t.add("route_purge", 0.25)
+    t.add("route_purge", 0.5, n=3)
+    g = t.gauges()
+    assert (g["route_purge_s"], g["route_purge_cpu_s"],
+            g["route_purge_n"]) == (0.75, 0.0, 4)
+    with t.phase("fetch_s"):
+        sum(range(20000))
+    t.reset()
+    assert t.cpu_totals == {"fetch_s": 0.0, "route_purge": 0.0}
+    assert t.gauges() == {"fetch_cpu_s": 0.0, "route_purge_cpu_s": 0.0}
+
+
+def test_only_the_spans_named_read_the_cpu_clock(monkeypatch):
+    """The thread's CPU clock is a system call on some hosts: a span
+    reads it, once as it opens and once as it closes, only if the timer
+    was given its name; the others keep wall and entries as before."""
+    import time
+
+    wall, cpu, reads = [100.0], [5.0], []
+
+    def thread_time():
+        reads.append(wall[0])
+        return cpu[0]
+
+    def work(seconds, ran):
+        wall[0] += seconds
+        cpu[0] += ran
+
+    monkeypatch.setattr(time, "perf_counter", lambda: wall[0])
+    monkeypatch.setattr(time, "thread_time", thread_time)
+    t = PhaseTimer(cpu=("fetch_s",))
+    with t.phase("session_collect"):
+        work(0.001, 0.001)
+        with t.phase("fetch_s"):
+            work(0.004, 0.003)
+        with t.phase("recon_s"):
+            work(0.002, 0.002)
+    assert reads == pytest.approx([100.001, 100.005])
+    assert t.cpu_totals == {"fetch_s": pytest.approx(0.003)}
+    assert t.totals == {"session_collect": pytest.approx(0.007),
+                        "fetch_s": pytest.approx(0.004),
+                        "recon_s": pytest.approx(0.002)}
+    assert set(t.gauges()) == {
+        "session_collect_s", "session_collect_n", "fetch_s", "fetch_n",
+        "fetch_cpu_s", "recon_s", "recon_n"}
+    # a span that raises is booked like any other
+    with pytest.raises(KeyError):
+        with t.phase("fetch_s"):
+            work(0.001, 0.0005)
+            raise KeyError("x")
+    assert len(reads) == 4 and t.counts["fetch_s"] == 2
+    assert t.cpu_totals["fetch_s"] == pytest.approx(0.0035)
+
+
 def test_parent_is_per_thread():
     t = PhaseTimer()
     seen = {}
@@ -197,13 +344,22 @@ def _feed(broker, msgs):
 
 
 def _span_gauges(svc):
-    names = list(MatchService.LOOP_SPANS + MatchService.INNER_SPANS)
+    names = list(MatchService.LOOP_SPANS + MatchService.INNER_SPANS
+                 + MatchService.BETWEEN_SPANS)
     names += list(getattr(svc._session, "SPANS", ()))
     out = []
     for n in names:
         base = n[:-2] if n.endswith("_s") else n
         out += [base + "_s", base + "_n"]
-    return out + ["host_path_s", "serve_loop_s", "xla_compile_s",
+    # CPU beside wall for the session's spans that a metric reads, and
+    # the front door's by role, summed over its handler threads: at 0
+    # on a broker nobody reaches over TCP
+    for n in getattr(svc._session, "CPU_SPANS", ()):
+        out.append((n[:-2] if n.endswith("_s") else n) + "_cpu_s")
+    return out + ["tcp_ingress_cpu_s", "tcp_egress_cpu_s",
+                  "host_path_s", "serve_loop_s", "serve_cpu_s",
+                  "process_cpu_s", "wire_parse_s", "wire_binary_records",
+                  "xla_compile_s",
                   "startup_import_s", "startup_backend_s",
                   "startup_session_s", "first_batch_s",
                   "left_device_at_offset", "metrics_fetch_bytes"]
@@ -226,6 +382,30 @@ def _run_with_heartbeats(svc, n, path, monkeypatch):
     return beats
 
 
+def _check_cpu_beside_wall(g0, g1):
+    """In every heartbeat a span's CPU is part of its wall (1 ms an
+    entry for the clocks' grain), the loop's CPU part of the loop's
+    wall and the roles' CPU part of the process's; between two, none
+    runs backwards."""
+    grain = 1e-3 + 2 * _cpu_clock_step()
+    for g in (g0, g1):
+        for k in g:
+            if k.endswith("_cpu_s") and k not in (
+                    "serve_cpu_s", "process_cpu_s", "tcp_ingress_cpu_s",
+                    "tcp_egress_cpu_s"):
+                base = k[:-len("_cpu_s")]
+                assert 0 <= g[k] <= g[base + "_s"] \
+                    + grain * max(1, g[base + "_n"]), (k, g[k])
+        assert 0 <= g["serve_cpu_s"] <= g["serve_loop_s"] + grain
+        assert g["serve_cpu_s"] + g["tcp_ingress_cpu_s"] \
+            + g["tcp_egress_cpu_s"] <= g["process_cpu_s"] * 1.01 + grain
+    for k in g1:
+        if k.endswith("_cpu_s"):
+            assert g1[k] >= g0.get(k, 0), k
+    assert g1["serve_cpu_s"] > g0["serve_cpu_s"]
+    assert g1["latency_stamp_n"] > 0 and g1["latency_stamp_s"] > 0
+
+
 def _check_partition(svc, beats):
     first, last = beats[0], beats[-1]
     assert last["closing"] is True
@@ -242,7 +422,9 @@ def _check_partition(svc, beats):
     # every span the loop records is a listed one (and so in the
     # first heartbeat): one name for one interval
     assert set(svc._ptimer.totals) <= set(
-        MatchService.LOOP_SPANS + MatchService.INNER_SPANS)
+        MatchService.LOOP_SPANS + MatchService.INNER_SPANS
+        + MatchService.BETWEEN_SPANS)
+    _check_cpu_beside_wall(g0, g1)
     # the loop's wall is the heartbeats' own, on the injected clock
     assert wall <= last["time"] - first["time"] + 0.5
     return g1

@@ -84,6 +84,32 @@ def test_build_view_rate_and_lag():
     assert build_view(cur)["records_per_s"] is None
 
 
+def test_serve_row_shows_cpu_beside_wall_where_published():
+    def at(t, loop, cpu, proc):
+        return {"t": t, "leader": _node(records=10, gauges={
+            "serve_loop_s": loop, "serve_cpu_s": cpu,
+            "process_cpu_s": proc}), "standby": _node(),
+            "supervisor": None}
+
+    prev, cur = at(0.0, 10.0, 4.0, 11.0), at(2.0, 12.0, 5.5, 13.2)
+    view = build_view(cur, prev)
+    # between the refreshes: per second of the loop's own wall
+    assert view["serve_cpu_share"] == pytest.approx(0.75)
+    assert view["process_cpu_cores"] == pytest.approx(1.1)
+    lead = next(ln for ln in render(view) if ln.startswith("leader"))
+    assert "loop_cpu=75%" in lead and "process=1.10cores" in lead
+    # one frame: since the service started
+    assert build_view(cur)["serve_cpu_share"] == pytest.approx(5.5 / 12)
+    # a restarted leader (the gauges ran backwards) reads its own start
+    assert build_view(at(3.0, 1.0, 0.5, 0.9), cur)["serve_cpu_share"] \
+        == pytest.approx(0.5)
+    # a leader that publishes neither: the row is as it was
+    old = build_view({"t": 1.0, "leader": _node(records=10),
+                      "standby": _node(), "supervisor": None})
+    assert old["serve_cpu_share"] is None
+    assert "loop_cpu" not in "\n".join(render(old))
+
+
 def test_render_shows_stages_slo_and_supervisor():
     lats = {"lat_e2e": {"count": 10, "sum_s": 0.1, "p50_ms": 4.0,
                         "p90_ms": 8.0, "p99_ms": 9.0, "p999_ms": 9.5},
