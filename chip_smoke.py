@@ -259,11 +259,17 @@ def serve_phase(name, state_name, kids, out, env, serve_args, feed,
     check(m is not None, f"{name}: no final metrics line in the log")
     check("t" in first, f"{name}: never saw a MatchOut record "
                         f"({first.get('err', 'no error')})")
+    gauges = hb.get("metrics", {}).get("gauges", {})
     return {"phase": name, "sent": sent, "offset": hb["offset"],
             "heartbeat": {k: hb.get(k) for k in (
                 "backend", "interpret", "device_kind", "device_count",
                 "engine", "pipeline", "compile_cache_dir", "epoch")},
             "metrics": json.loads(m.group(1)),
+            # the newest snapshot's device -> host half (fixed mode:
+            # engine/seq.py:export_snapshot), as the loop published it
+            "snapshot_fetch": {k: gauges[k] for k in (
+                "snapshot_fetch_bytes", "snapshot_live_rows",
+                "snapshot_fetch_calls") if k in gauges},
             "start_to_first_matchout_s": round(first["t"], 3),
             "wall_s": round(wall, 3), "state": state, "log": log}
 
@@ -469,6 +475,11 @@ def main(argv=None) -> int:
               f"A: scan program compile was a {a['scan_cache']} and the "
               f"compile cache {cache_dir} holds {len(scans_0)} -> "
               f"{len(scans_a)} scan entries")
+        # (none published where the stream ended before the first
+        # snapshot inside it: a rehearsal's 900 messages)
+        check(a["snapshot_fetch"].get("snapshot_fetch_calls", 1) >= 1,
+              f"A: the books of the newest snapshot did not cross by "
+              f"their live rows ({a['snapshot_fetch']})")
         done(a)
 
         # read before B's own snapshots prune it
@@ -568,7 +579,8 @@ def main(argv=None) -> int:
         "wall_s": round(time.monotonic() - T_START, 1),
         "phases": {p["phase"]: {k: p.get(k) for k in (
             "messages", "records", "parity", "start_to_first_matchout_s",
-            "wall_s", "resumed_at", "resumed_from", "scan_cache",
+            "wall_s", "resumed_at", "resumed_from", "snapshot_fetch",
+            "scan_cache",
             "rej_capacity",
             "max_book_depth", "duplicate_stamps", "shard_devices")
             if p.get(k) is not None} for p in phases},

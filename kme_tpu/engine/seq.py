@@ -117,9 +117,10 @@ MIN32 = _i(-(1 << 31))
 BIG = _i(1 << 30)
 LN = 128
 
-_STATE_KEYS = ("bo_lo", "bo_hi", "ba", "bp", "bs", "bq",
-               "seqc", "bex", "bal_lo", "bal_hi", "bal_u",
-               "pos", "dep", "err")
+BOOK_KEYS = ("bo_lo", "bo_hi", "ba", "bp", "bs", "bq")
+_BS = BOOK_KEYS.index("bs")
+_STATE_KEYS = BOOK_KEYS + (
+    "seqc", "bex", "bal_lo", "bal_hi", "bal_u", "pos", "dep", "err")
 
 # one tile of the position plane: 8 rows = 256 accounts x 4 values
 POS_TILE_ROWS = 8
@@ -382,7 +383,6 @@ def build_seq_step(cfg: SeqConfig):
     PTL = cfg.pos_tiles_per_lane
     KEYS = state_keys(cfg)
     NSMEM = 12 if JAVA else 7
-    BOOK_KEYS = ("bo_lo", "bo_hi", "ba", "bp", "bs", "bq")
 
     def kernel(*args):
         # args: NSMEM message arrays, then aliased state ins, state outs
@@ -1833,35 +1833,142 @@ _SLOT_DENSE_B, _SLOT_LIVE_B = 8 + 4 * 4 + 1, 8 + 8 + 4 * 4
 _POS_DENSE_B, _POS_LIVE_B = 2 * 8, 8 + 2 * 8
 
 
+def live_rows_chunk(cfg: SeqConfig) -> int:
+    """Rows one call of build_seq_live_rows' program returns: a
+    thirty-second of a book plane's rows, in whole sublane tiles of 8
+    (4,096 rows = 2 MiB a plane at 1024 lanes x 8192 slots)."""
+    return max(8, -(-(2 * cfg.lanes * cfg.nr // 32) // 8) * 8)
+
+
+@functools.lru_cache(maxsize=None)
+def build_seq_live_rows(cfg: SeqConfig):
+    """The books' half of export_snapshot's fetch: ONE jitted program,
+    (the six book planes, row) -> (live rows in a plane, the ascending
+    indices of the first live_rows_chunk(cfg) live rows at or after
+    `row`, those rows of each plane), so that a snapshot fetches the
+    rows that hold orders and not the planes. A row is live where any
+    of its slots has `bs > 0`; past the last live row the indices read
+    2 * S * NR and the rows are padding. A resting order takes the
+    lowest free slot of its side, so the live rows are the first few of
+    each side however deep the books are configured. The program reads
+    only: nothing is donated."""
+    rows, R = 2 * cfg.lanes * cfg.nr, live_rows_chunk(cfg)
+    G = -(-rows // LN)
+
+    def prefix_count(mask):
+        # jnp.cumsum(mask), in two levels of 128: a triangular matmul
+        # inside each group, a masked sum over the groups before it
+        # (XLA's TPU compiler takes 3 s over a cumsum of 131,072
+        # lanes and 0.2 s over this)
+        m = jnp.pad(mask, (0, G * LN - rows)).reshape(G, LN)
+        i, g = jnp.arange(LN, dtype=I32), jnp.arange(G, dtype=I32)
+        within = jnp.dot(m.astype(jnp.int8),
+                         (i[:, None] <= i[None, :]).astype(jnp.int8),
+                         preferred_element_type=I32)
+        before = jnp.sum(jnp.where(g[None, :] < g[:, None],
+                                   within[None, :, -1], _i(0)),
+                         axis=1, dtype=I32)
+        return (within + before[:, None]).reshape(-1)[:rows]
+
+    def live_rows(planes, start):
+        live = jnp.any(planes[_BS] > 0, axis=1)
+        row = jnp.arange(rows, dtype=I32)
+        # rank[r] = live rows in [start, r]: the k-th of them is the
+        # first r whose rank reaches k
+        rank = prefix_count(live & (row >= start))
+        idx = jnp.searchsorted(rank, jnp.arange(1, R + 1, dtype=I32),
+                               side="left").astype(I32)
+        take = jnp.minimum(idx, _i(rows - 1))
+        return (jnp.sum(live, dtype=I32), idx,
+                tuple(p[take] for p in planes))
+
+    return jax.jit(live_rows)
+
+
+def live_rows_call(cfg: SeqConfig, state, start=0):
+    """One call of build_seq_live_rows' program on `state`'s planes,
+    not fetched — the one place that says what the program is called
+    with (SeqSession.__init__ compiles it by this call)."""
+    return build_seq_live_rows(cfg)(
+        tuple(state[k] for k in BOOK_KEYS), _i(start))
+
+
+def _nbytes(tree) -> int:
+    return sum(a.nbytes for a in jax.tree_util.tree_leaves(tree))
+
+
 def export_snapshot(cfg: SeqConfig, state):
-    """Device planes -> (canon, layout): export_canonical's state with
-    each SPARSE_SECTIONS section given by its LIVE entries where that
-    takes fewer bytes than the dense section, and densely (as
-    export_canonical gives it) where it does not — the occupancy
+    """Device planes -> (canon, layout, fetch): export_canonical's
+    state with each SPARSE_SECTIONS section given by its LIVE entries
+    where that takes fewer bytes than the dense section, and densely
+    (as export_canonical gives it) where it does not — the occupancy
     decides, section by section. `layout` names the sparse sections,
     the dense shapes densify_canonical restores them to, and the live
     counts. A slot is live where `bs > 0` (the rule import_canonical
     and build_seq_occupancy apply; what a freed slot last held is not
     state); a position where ANY of its four words is non-zero (an
     amount of 0 with an available balance is state). No dense canonical
-    array is built for a sparse section: 16.7 M slots holding 60,000
-    orders cost one pass over `bs` and six gathers of 60,000."""
+    array is built for a sparse section.
+
+    The books cross by their live ROWS where those are at most a
+    quarter of a plane's (build_seq_live_rows: the pass over `bs` runs
+    on the device; 16.7 M slots holding 60,000 orders cost the host one
+    pass over the ~2,500 rows fetched and six gathers of 60,000), by as
+    many calls of that one program as it takes, each from the row after
+    the last one returned; a book denser than that crosses whole and
+    the host makes the pass. Either way gives the same arrays. `pos`
+    and the small sections cross whole, with the first call's rows.
+    `fetch` says what crossed: `snapshot_fetch_bytes` (every section),
+    `snapshot_live_rows`, `snapshot_fetch_calls` (calls of the row
+    program that fetched the books; 0 = they crossed whole)."""
     if cfg.compat != "fixed":
         raise ValueError("java-mode state snapshots via "
                          "runtime/javasnap.export_seqjava")
     S, N, A = cfg.lanes, cfg.slots, cfg.accounts
-    h = jax.device_get({k: state[k] for k in _STATE_KEYS if k != "dep"})
+    R = live_rows_chunk(cfg)
+    h = jax.device_get({
+        "rows": live_rows_call(cfg, state),
+        **{k: state[k] for k in _STATE_KEYS
+           if k not in BOOK_KEYS + ("dep",)}})
+    crossed = _nbytes(h)
+    live_rows, *chunk = h.pop("rows")
+    live_rows, chunks = int(live_rows), []
     canon = _small_sections(cfg, h)
     layout = {"slot_shape": [S, 2, N], "pos_size": S * A, "sparse": []}
 
     # books: slots % 128 == 0, so a plane's flat order (lane, side, row,
     # column) IS the canonical (S, 2, N) flat order and no row is padding
-    idx = np.flatnonzero(h["bs"].reshape(-1) > 0)
-    layout["live_slots"] = int(idx.size)
-    if idx.size * _SLOT_LIVE_B < S * 2 * N * _SLOT_DENSE_B:
+    if live_rows * 4 <= 2 * S * cfg.nr:
+        chunks.append(chunk)
+        while len(chunks) * R < live_rows:
+            chunks.append(jax.device_get(live_rows_call(
+                cfg, state, chunks[-1][0][-1] + 1)[1:]))
+        crossed += _nbytes(chunks[1:])
+        idx, vals = [], []
+        for n, (row, got) in enumerate(chunks):
+            # (what a call returns past the last live row is padding)
+            r, c = np.nonzero(got[_BS][:live_rows - n * R] > 0)
+            idx.append(row[r].astype(np.int64) * LN + c)
+            vals.append([p[r, c] for p in got])
+        idx = np.concatenate(idx)
+        at = {k: np.concatenate(v)
+              for k, v in zip(BOOK_KEYS, zip(*vals))}.get
+    else:
+        h.update(jax.device_get({k: state[k] for k in BOOK_KEYS}))
+        crossed += _nbytes([h[k] for k in BOOK_KEYS])
+        idx = np.flatnonzero(h["bs"].reshape(-1) > 0)
+
         def at(k):
             return h[k].reshape(-1)[idx]
 
+    fetch = {"snapshot_fetch_bytes": crossed,
+             "snapshot_live_rows": live_rows,
+             "snapshot_fetch_calls": len(chunks)}
+    layout["live_slots"] = int(idx.size)
+    # (live rows <= a quarter of the rows puts the live slots under a
+    # quarter of the slots: books fetched by their rows are written by
+    # their live entries)
+    if idx.size * _SLOT_LIVE_B < S * 2 * N * _SLOT_DENSE_B:
         canon.update(slot_idx=idx, slot_oid=_j64(at("bo_lo"), at("bo_hi")),
                      slot_aid=at("ba"), slot_price=at("bp"),
                      slot_size=at("bs"), slot_seq=at("bq"))
@@ -1889,7 +1996,7 @@ def export_snapshot(cfg: SeqConfig, state):
         layout["sparse"].append("positions")
     else:
         canon.update(_dense_positions(cfg, h))
-    return canon, layout
+    return canon, layout, fetch
 
 
 def densify_canonical(canon: dict, layout: dict) -> dict:

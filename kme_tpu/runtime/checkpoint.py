@@ -406,22 +406,26 @@ def _restore_one(path: str, shards: Optional[int], width: Optional[int]):
 
 def _snapshot_export(session):
     """The device -> host half of a seq snapshot (span
-    `snapshot_export` of the session's timer): (arrays, layout), with
-    `layout` None where the arrays are dense throughout."""
+    `snapshot_export` of the session's timer): (arrays, layout, fetch),
+    with `layout` None where the arrays are dense throughout and
+    `fetch` what engine/seq.py:export_snapshot says crossed (a
+    fixed-mode SeqSession's books cross by their live rows, gathered
+    on the device; every other export brings its planes whole and says
+    nothing)."""
     from kme_tpu.runtime.seqsession import SeqSession
 
     with session.timer.phase("snapshot_export"):
         if session.cfg.compat == "java":
             from kme_tpu.runtime.javasnap import export_seqjava_device
 
-            return export_seqjava_device(session), None
+            return export_seqjava_device(session), None, {}
         from kme_tpu.engine import seq as SQ
 
         if type(session) is SeqSession:
             return SQ.export_snapshot(session.cfg, session.state)
         # a subclass keeps its state elsewhere (SeqMeshSession: sharded
         # across devices) and its dense export
-        return SQ.export_canonical(session.cfg, session.state), None
+        return SQ.export_canonical(session.cfg, session.state), None, {}
 
 
 def _snapshot_payload(session, kind: str, offset: int,
@@ -481,13 +485,15 @@ def save_seq_session(ckpt_dir: str, session, offset: int,
     (engine/seq.py:export_snapshot; _load_file densifies), so a file's
     size follows what is live and not the configured capacity. Three
     spans of the session's timer split the call: `snapshot_export` (the
-    device -> host fetch), `snapshot_meta` (the meta and the routes'
-    arrays) and `snapshot_write`."""
+    device's gather of the books' live rows, the device -> host fetch
+    of those, `pos` and the small sections, and the host's pass over
+    them), `snapshot_meta` (the meta and the routes' arrays) and
+    `snapshot_write`."""
     if session.cfg.compat == "java":
         return _save_seqjava(ckpt_dir, session, offset, keep=keep,
                              extra=extra)
     os.makedirs(ckpt_dir, exist_ok=True)
-    canon, layout = _snapshot_export(session)
+    canon, layout, fetch = _snapshot_export(session)
     arrays = {k: v for k, v in canon.items()
               if k != "metrics" and v is not None}
     arrays["err"] = np.asarray(canon["err"])
@@ -496,6 +502,7 @@ def save_seq_session(ckpt_dir: str, session, offset: int,
     path = _snapshot_write(
         ckpt_dir, session, offset, _snapshot_payload(
             session, "seq", offset, extra, arrays, layout), keep)
+    session.snapshot_gauges.update(fetch)
     if layout:
         session.snapshot_gauges.update(
             snapshot_live_slots=layout["live_slots"],
@@ -518,7 +525,7 @@ def _save_seqjava(ckpt_dir: str, session, offset: int,
     orders with direction tags and bucket seq, balances, and the
     router id maps."""
     os.makedirs(ckpt_dir, exist_ok=True)
-    snap, _ = _snapshot_export(session)
+    snap, _, _ = _snapshot_export(session)
     arrays = {k: np.asarray(v) for k, v in snap.items()}
     return _snapshot_write(
         ckpt_dir, session, offset, _snapshot_payload(

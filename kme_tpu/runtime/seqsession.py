@@ -780,14 +780,23 @@ class SeqSession:
         # refresh: a compile inside a served batch is a stall
         self._occupancy = SQ.build_seq_occupancy(cfg)
         self._occupancy(self.state)
+        # likewise the program a fixed-mode snapshot fetches the books'
+        # live rows by (engine/seq.py:export_snapshot takes it from
+        # the same cache)
+        if cfg.compat == "fixed":
+            SQ.live_rows_call(cfg, self.state)
         # bytes metrics() has brought device -> host (cumulative; the
         # serve loop publishes it as gauge `metrics_fetch_bytes`)
         self.metrics_fetch_bytes = 0
         # what the newest fixed-mode snapshot held (set by
         # runtime/checkpoint.py:save_seq_session; the serve loop
         # publishes them as gauges): `snapshot_bytes` of the file,
-        # `snapshot_live_slots` / `snapshot_live_positions` in it, and
-        # `snapshot_sparse_sections` (0-2) written by their live entries
+        # `snapshot_live_slots` / `snapshot_live_positions` in it,
+        # `snapshot_sparse_sections` (0-2) written by their live
+        # entries, and of its device -> host half `snapshot_fetch_bytes`
+        # (all that crossed), `snapshot_live_rows` (rows of a book plane
+        # that hold an order) and `snapshot_fetch_calls` (calls of the
+        # live-row program that brought the books; 0: they crossed whole)
         self.snapshot_gauges: Dict[str, int] = {}
 
     # ------------------------------------------------------------------

@@ -718,6 +718,9 @@ def test_oldest_retained_offset_tracks_pruning(tmp_path):
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 SMALL = dict(lanes=8, slots=128, accounts=128, max_fills=16)
+# what export_snapshot says of its device -> host half
+FETCH_GAUGES = ("snapshot_fetch_bytes", "snapshot_live_rows",
+                "snapshot_fetch_calls")
 
 
 def _seq_session(state=None, **shape):
@@ -730,15 +733,22 @@ def _seq_session(state=None, **shape):
     return ses
 
 
-def _random_canon(shape, book_load, pos_load, amount=True, seed=5):
+def _random_canon(shape, book_load, pos_load, amount=True, seed=5,
+                  packed=False):
     """A canonical state with those shares of its slots and positions
     live — and something in EVERY word of every dead slot, as a slot
-    freed by a fill or a cancel keeps what it held."""
+    freed by a fill or a cancel keeps what it held. The live slots are
+    scattered, or `packed` from slot 0 of each side to a depth of its
+    own (as the kernel leaves them: a resting order takes the lowest
+    free slot of its side)."""
     import numpy as np
 
     rng = np.random.default_rng(seed)
     S, N, A = shape["lanes"], shape["slots"], shape["accounts"]
-    used = rng.random((S, 2, N)) < book_load
+    if packed:
+        used = np.arange(N) < rng.random((S, 2, 1)) * 2 * book_load * N
+    else:
+        used = rng.random((S, 2, N)) < book_load
     live = rng.random(S * A) < pos_load
 
     def words(hi, shape, dtype):
@@ -829,7 +839,13 @@ def test_sparse_snapshot_loads_as_the_canonical_state(case, tmp_path):
             assert not data[k][~used].any(), k
         else:
             assert np.array_equal(data[k], v), k
-    assert ses.snapshot_gauges == {
+    gauges = dict(ses.snapshot_gauges)
+    fetch = {k: gauges.pop(k) for k in FETCH_GAUGES}
+    live_rows = int((np.asarray(ses.state["bs"]) > 0).any(axis=1).sum())
+    assert fetch["snapshot_live_rows"] == live_rows
+    assert (fetch["snapshot_fetch_calls"] > 0) \
+        == (4 * live_rows <= 2 * ses.cfg.lanes * ses.cfg.nr)
+    assert gauges == {
         "snapshot_bytes": os.path.getsize(path),
         "snapshot_routes": 0,
         "snapshot_live_slots": int(used.sum()),
@@ -1062,6 +1078,225 @@ def test_snapshot_gauges_read_what_the_file_holds(tmp_path):
     assert gauges["snapshot_bytes"] == os.path.getsize(path)
     assert gauges["snapshot_sparse_sections"] == 2
     assert gauges["snapshot_export_n"] == gauges["snapshot_write_n"] == 1
+    # SMALL's sides are one row deep and most hold an order: the books
+    # crossed whole, and the loop says so
+    assert gauges["snapshot_fetch_calls"] == 0
+    assert gauges["snapshot_live_rows"] > 4
+    assert gauges["snapshot_fetch_bytes"] > 6 * 16 * 512
+
+
+# ---------------------------------------------------------------------------
+# the books fetched by their live rows (engine/seq.py:
+# build_seq_live_rows): whatever crosses, the arrays are those of the
+# dense fetch and the host's pass over every slot
+
+# sides 32 rows deep: 512 rows a plane, 16 a call, 128 the most that
+# still cross by rows
+DEEP = dict(lanes=8, slots=4096, accounts=128, max_fills=16,
+            hbm_books=True)
+
+
+def _dense_export(cfg, state):
+    """export_snapshot as the parent (617a85d) wrote it: every plane
+    brought to the host, one pass over `bs`, six gathers."""
+    import numpy as np
+
+    from kme_tpu.engine import seq as SQ
+
+    S, N, A = cfg.lanes, cfg.slots, cfg.accounts
+    h = {k: np.asarray(state[k]) for k in SQ._STATE_KEYS if k != "dep"}
+    canon = SQ._small_sections(cfg, h)
+    layout = {"slot_shape": [S, 2, N], "pos_size": S * A, "sparse": []}
+    idx = np.flatnonzero(h["bs"].reshape(-1) > 0)
+    layout["live_slots"] = int(idx.size)
+    if idx.size * SQ._SLOT_LIVE_B < S * 2 * N * SQ._SLOT_DENSE_B:
+        def at(k):
+            return h[k].reshape(-1)[idx]
+
+        canon.update(slot_idx=idx,
+                     slot_oid=SQ._j64(at("bo_lo"), at("bo_hi")),
+                     slot_aid=at("ba"), slot_price=at("bp"),
+                     slot_size=at("bs"), slot_seq=at("bq"))
+        layout["sparse"].append("books")
+    else:
+        canon.update(SQ._dense_books(cfg, h))
+    PTL = cfg.pos_tiles_per_lane
+    rows = h["pos"].reshape(S, PTL, 2, 4, SQ.LN)
+    lane, acct = np.divmod(np.flatnonzero(rows.any(axis=3)),
+                           PTL * SQ.POS_TILE_ACCOUNTS)
+    lane, acct = lane[acct < A], acct[acct < A]
+    layout["live_positions"] = int(lane.size)
+    if lane.size * SQ._POS_LIVE_B < S * A * SQ._POS_DENSE_B:
+        def word(k):
+            return rows[lane, acct >> 8, (acct >> 7) & 1, k,
+                        acct & (SQ.LN - 1)]
+
+        canon.update(pos_idx=lane * A + acct,
+                     pos_amt=SQ._j64(word(0), word(1)),
+                     pos_avail=SQ._j64(word(2), word(3)))
+        layout["sparse"].append("positions")
+    else:
+        canon.update(SQ._dense_positions(cfg, h))
+    return canon, layout
+
+
+def _fetch_of(cfg, state, calls):
+    """Bytes a fetch of `calls` calls brings device -> host: `pos` and
+    the small sections, the count and one chunk (row indices + six
+    planes' rows) a call, and the planes whole where no call brought
+    the books (the first call's chunk crossed before that was known)."""
+    import numpy as np
+
+    from kme_tpu.engine import seq as SQ
+
+    R = SQ.live_rows_chunk(cfg)
+    rest = sum(np.asarray(state[k]).nbytes for k in SQ._STATE_KEYS
+               if k not in SQ.BOOK_KEYS + ("dep",))
+    planes = sum(np.asarray(state[k]).nbytes for k in SQ.BOOK_KEYS)
+    chunk = R * 4 + len(SQ.BOOK_KEYS) * R * SQ.LN * 4
+    return rest + 4 + chunk * max(calls, 1) + (0 if calls else planes)
+
+
+def _same_export(cfg, state):
+    """export_snapshot against the dense fetch and the host's pass:
+    array for array, dtype for dtype, one digest. -> fetch."""
+    import numpy as np
+
+    from kme_tpu.engine import seq as SQ
+
+    want, want_layout = _dense_export(cfg, state)
+    canon, layout, fetch = SQ.export_snapshot(cfg, state)
+    assert layout == want_layout
+    assert list(canon) == list(want)
+    for k, v in want.items():
+        got = np.asarray(canon[k])
+        assert got.dtype == np.asarray(v).dtype, k
+        assert np.array_equal(got, v), k
+    assert ck._payload_digest(canon) == ck._payload_digest(want)
+    rows = 2 * cfg.lanes * cfg.nr
+    live_rows = int((np.asarray(state["bs"]) > 0).any(axis=1).sum())
+    R = SQ.live_rows_chunk(cfg)
+    calls = max(-(-live_rows // R), 1) if 4 * live_rows <= rows else 0
+    assert fetch == {"snapshot_fetch_bytes": _fetch_of(cfg, state, calls),
+                     "snapshot_live_rows": live_rows,
+                     "snapshot_fetch_calls": calls}
+    return fetch
+
+
+# name -> (book_load, packed)
+BOOK_CASES = {
+    "empty": (0.0, False),
+    "scattered-33-slots": (0.0005, False),
+    "scattered-thin": (0.001, False),
+    "scattered-a-hundredth": (0.01, False),
+    "scattered-half": (0.5, False),
+    "scattered-past-the-break-even": (0.9, False),
+    "full": (1.0, False),
+    "packed-shallow": (0.002, True),
+    "packed": (0.02, True),
+    "packed-deep": (0.3, True),
+}
+
+
+@pytest.mark.parametrize("shape", ["deep", "small"])
+@pytest.mark.parametrize("case", BOOK_CASES)
+def test_books_fetched_by_rows_are_the_dense_fetchs_arrays(case, shape):
+    """Scattered or packed from slot 0, few live rows or all of them,
+    sides 32 rows deep or one: (canon, layout) and the digest are those
+    of the parent's dense fetch + host pass, and `fetch` counts the
+    bytes that crossed."""
+    from kme_tpu.engine import seq as SQ
+
+    shape = DEEP if shape == "deep" else SMALL
+    load, packed = BOOK_CASES[case]
+    cfg = SQ.SeqConfig(**shape)
+    state = SQ.import_canonical(
+        cfg, _random_canon(shape, load, 0.05, packed=packed))
+    fetch = _same_export(cfg, state)
+    if shape is DEEP and case in ("empty", "packed-shallow"):
+        assert fetch["snapshot_fetch_calls"] == 1
+    if shape is DEEP and case in ("scattered-half", "full", "packed-deep"):
+        assert fetch["snapshot_fetch_calls"] == 0       # crossed whole
+
+
+@pytest.mark.parametrize("case", ["scattered", "packed", "full-side"])
+def test_more_live_rows_than_a_call_returns_take_more_calls(case):
+    """One program, called again from the row after the last one it
+    returned: the same bytes in 2 calls or more. A side that is full
+    (32 rows of one lane) still crosses by its rows."""
+    import numpy as np
+
+    from kme_tpu.engine import seq as SQ
+
+    cfg = SQ.SeqConfig(**DEEP)
+    canon = _random_canon(DEEP, *{"scattered": (0.001, 0.05),
+                                  "packed": (0.02, 0.05),
+                                  "full-side": (0.002, 0.05)}[case],
+                          packed=case != "scattered")
+    if case == "full-side":
+        canon["slot_used"][3, 1, :] = True
+    fetch = _same_export(cfg, SQ.import_canonical(cfg, canon))
+    assert fetch["snapshot_live_rows"] > SQ.live_rows_chunk(cfg) == 16
+    assert fetch["snapshot_fetch_calls"] >= 2
+    if case == "full-side":
+        assert fetch["snapshot_live_rows"] >= 32
+        assert np.asarray(canon["slot_used"][3, 1]).all()
+
+
+def test_an_empty_book_is_sparse_and_a_packed_one_crosses_a_tenth():
+    """0 live rows: `slot_idx` is empty and the section is still
+    written by its live entries. A packed book at a depth worth
+    compacting brings under a tenth of what the planes weigh."""
+    import numpy as np
+
+    from kme_tpu.engine import seq as SQ
+
+    cfg = SQ.SeqConfig(**DEEP)
+    canon, layout, fetch = SQ.export_snapshot(cfg, SQ.make_seq_state(cfg))
+    assert fetch["snapshot_live_rows"] == 0
+    assert fetch["snapshot_fetch_calls"] == 1
+    assert canon["slot_idx"].shape == (0,) \
+        and canon["slot_idx"].dtype == np.int64
+    assert layout["sparse"] == ["books", "positions"]
+    state = SQ.import_canonical(
+        cfg, _random_canon(DEEP, 0.002, 0.05, packed=True))
+    fetch = _same_export(cfg, state)
+    assert fetch["snapshot_fetch_calls"] == 1
+    assert fetch["snapshot_fetch_bytes"] * 10 < _fetch_of(cfg, state, 0)
+
+
+def test_a_snapshot_of_a_serving_session_compiles_nothing(tmp_path):
+    """The live-row program is built and compiled in
+    SeqSession.__init__ (a compile inside a served batch is a stall):
+    batches served, two snapshots taken, a restore — no new program,
+    no new signature of the one there is."""
+    from kme_tpu.engine import seq as SQ
+
+    def calls():
+        info = SQ.build_seq_live_rows.cache_info()
+        return info.hits + info.misses
+
+    n = calls()
+    ses = _seq_session(**SMALL)
+    assert calls() == n + 1                     # __init__ asked for it
+    program = SQ.build_seq_live_rows(ses.cfg)
+    misses = SQ.build_seq_live_rows.cache_info().misses
+    compiled = program._cache_size()
+    assert compiled >= 1                        # and ran it
+    msgs = list(zipf_symbol_stream(900, 8, 64, seed=12, zipf_a=0.0))
+    done = 0
+    for off in (400, 600):
+        ses.process_wire([m.copy() for m in msgs[done:off]])
+        ck.save_seq_session(str(tmp_path), ses, off)
+        assert program._cache_size() == compiled
+        done = off
+    back, off = ck.load_seq_session(str(tmp_path))
+    assert off == 600
+    ck.save_seq_session(str(tmp_path / "again"), back, off)
+    assert program._cache_size() == compiled
+    assert SQ.build_seq_live_rows.cache_info().misses == misses
+    assert back.snapshot_gauges["snapshot_live_rows"] \
+        == ses.snapshot_gauges["snapshot_live_rows"] > 0
 
 
 # ---------------------------------------------------------------------------
