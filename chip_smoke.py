@@ -269,7 +269,8 @@ def serve_phase(name, state_name, kids, out, env, serve_args, feed,
             # engine/seq.py:export_snapshot), as the loop published it
             "snapshot_fetch": {k: gauges[k] for k in (
                 "snapshot_fetch_bytes", "snapshot_live_rows",
-                "snapshot_fetch_calls") if k in gauges},
+                "snapshot_fetch_calls", "snapshot_pos_fetch_bytes",
+                "snapshot_pos_calls") if k in gauges},
             "start_to_first_matchout_s": round(first["t"], 3),
             "wall_s": round(wall, 3), "state": state, "log": log}
 
@@ -480,6 +481,9 @@ def main(argv=None) -> int:
         check(a["snapshot_fetch"].get("snapshot_fetch_calls", 1) >= 1,
               f"A: the books of the newest snapshot did not cross by "
               f"their live rows ({a['snapshot_fetch']})")
+        check(a["snapshot_fetch"].get("snapshot_pos_calls", 1) >= 1,
+              f"A: the positions of the newest snapshot did not cross "
+              f"by their live entries ({a['snapshot_fetch']})")
         done(a)
 
         # read before B's own snapshots prune it

@@ -1833,6 +1833,43 @@ _SLOT_DENSE_B, _SLOT_LIVE_B = 8 + 4 * 4 + 1, 8 + 8 + 4 * 4
 _POS_DENSE_B, _POS_LIVE_B = 2 * 8, 8 + 2 * 8
 
 
+def _scan128(x, reduce, combine, small=False):
+    """Inclusive scan of a non-negative i32 vector under sum or max, in
+    levels of 128: inside each group of 128 a masked reduction (for a
+    sum of `small` values, <= 128, a triangular matmul: bf16 holds them
+    exactly and their sums, <= 16,384, accumulate in f32), then the
+    groups' last values scanned one level up. (XLA's TPU compiler takes
+    3 s over a jnp.cumsum of 131,072 elements and 0.2 s over this.)"""
+    n = x.shape[0]
+    i = jnp.arange(LN, dtype=I32)
+    if n <= LN:
+        return reduce(jnp.where(i[None, :n] <= i[:n, None], x[None, :],
+                                _i(0)), axis=1)
+    G = -(-n // LN)
+    m = jnp.pad(x, (0, G * LN - n)).reshape(G, LN)
+    if small:
+        within = jnp.dot(m.astype(jnp.bfloat16),
+                         (i[:, None] <= i[None, :]).astype(jnp.bfloat16),
+                         preferred_element_type=jnp.float32).astype(I32)
+    else:
+        within = reduce(jnp.where(i[None, None, :] <= i[None, :, None],
+                                  m[:, None, :], _i(0)), axis=2)
+    upto = _scan128(within[:, -1], reduce, combine)
+    before = jnp.concatenate([jnp.zeros((1,), I32), upto[:-1]])
+    return combine(within, before[:, None]).reshape(-1)[:n]
+
+
+def _prefix_sum(x, small=False):
+    """jnp.cumsum(x) of an i32 vector (`small`: every value <= 128)."""
+    return _scan128(x, functools.partial(jnp.sum, dtype=I32), jnp.add,
+                    small)
+
+
+def _running_max(x):
+    """Inclusive running maximum of a non-negative i32 vector."""
+    return _scan128(x, jnp.max, jnp.maximum)
+
+
 def live_rows_chunk(cfg: SeqConfig) -> int:
     """Rows one call of build_seq_live_rows' program returns: a
     thirty-second of a book plane's rows, in whole sublane tiles of 8
@@ -1853,29 +1890,13 @@ def build_seq_live_rows(cfg: SeqConfig):
     each side however deep the books are configured. The program reads
     only: nothing is donated."""
     rows, R = 2 * cfg.lanes * cfg.nr, live_rows_chunk(cfg)
-    G = -(-rows // LN)
-
-    def prefix_count(mask):
-        # jnp.cumsum(mask), in two levels of 128: a triangular matmul
-        # inside each group, a masked sum over the groups before it
-        # (XLA's TPU compiler takes 3 s over a cumsum of 131,072
-        # lanes and 0.2 s over this)
-        m = jnp.pad(mask, (0, G * LN - rows)).reshape(G, LN)
-        i, g = jnp.arange(LN, dtype=I32), jnp.arange(G, dtype=I32)
-        within = jnp.dot(m.astype(jnp.int8),
-                         (i[:, None] <= i[None, :]).astype(jnp.int8),
-                         preferred_element_type=I32)
-        before = jnp.sum(jnp.where(g[None, :] < g[:, None],
-                                   within[None, :, -1], _i(0)),
-                         axis=1, dtype=I32)
-        return (within + before[:, None]).reshape(-1)[:rows]
 
     def live_rows(planes, start):
         live = jnp.any(planes[_BS] > 0, axis=1)
         row = jnp.arange(rows, dtype=I32)
         # rank[r] = live rows in [start, r]: the k-th of them is the
         # first r whose rank reaches k
-        rank = prefix_count(live & (row >= start))
+        rank = _prefix_sum((live & (row >= start)).astype(I32), small=True)
         idx = jnp.searchsorted(rank, jnp.arange(1, R + 1, dtype=I32),
                                side="left").astype(I32)
         take = jnp.minimum(idx, _i(rows - 1))
@@ -1891,6 +1912,105 @@ def live_rows_call(cfg: SeqConfig, state, start=0):
     with (SeqSession.__init__ compiles it by this call)."""
     return build_seq_live_rows(cfg)(
         tuple(state[k] for k in BOOK_KEYS), _i(start))
+
+
+def live_positions_chunk(cfg: SeqConfig) -> int:
+    """Entries one call of build_seq_live_positions' program returns: a
+    sixty-fourth of the store's capacity in whole rows of 128, no fewer
+    than 8,192 (or all the store can hold) and no more than 262,144 (20
+    bytes an entry: 5 MB a call). What a call costs the host is the
+    chunk it fetches, live or padding, so the chunk is sized by what a
+    store of that capacity is seen to hold (1024 x 2048 holds about
+    100,000 positions: 32,768 a call; 3425 x 25,088 holds 80,000 to
+    500,000: 262,144 a call) and a fuller store takes more calls, each
+    one more pass of the device over the plane."""
+    whole = -(-cfg.pos_capacity // LN) * LN
+    return min(whole, 262144, max(8192, -(-whole // 64 // LN) * LN))
+
+
+@functools.lru_cache(maxsize=None)
+def build_seq_live_positions(cfg: SeqConfig):
+    """The positions' half of export_snapshot's fetch: ONE jitted
+    program, (`pos`, start) -> (live entries in the store, the ascending
+    flat indices lane * A + account of the first live_positions_chunk
+    (cfg) live entries at or after `start`, their four words as (K, 4):
+    amount lo | hi, available lo | hi), so that a snapshot fetches the
+    positions that are held and not the plane. An entry is live where
+    ANY of its four words is non-zero (an amount of 0 with an available
+    balance is state); the padding half-tile of a lane whose A is no
+    multiple of 256 never is. Past the last live entry the indices read
+    S * A and the words are padding. The program reads only: nothing
+    is donated.
+
+    `pos` is read in rows of 128 entries (half a tile: four plane rows,
+    one a word). Per row a count; over the rows a prefix sum
+    (_prefix_sum); the row of the k-th entry by ONE scatter of the
+    rows' first ranks and a running maximum; then each result's row
+    gathered whole, word by word, from the plane as it lies (512 B a
+    word) and the column picked in it by a triangular matmul over the
+    row's mask. Why these forms (TPU v5e, 3425 x 25,088, K 262,144:
+    PERF.md section 6, PR 48): the rows' ranks by scatter 4.7 ms, by
+    binary search 38.6; the words as whole rows 4.3 ms, as scalars
+    15-16; the count as `any` over a (H, 4, 128) view 5.1 ms, as an OR
+    of its four slices or over the plane's 8-row tiles 21-22. A call
+    is 25-30 ms there, 6% of its HBM bound; the column select (about
+    8 ms) is its largest piece."""
+    S, A, K = cfg.lanes, cfg.accounts, live_positions_chunk(cfg)
+    HL = 2 * cfg.pos_tiles_per_lane     # rows of a lane
+    H = S * HL
+
+    def live_positions(pos, start):
+        h = jnp.arange(H, dtype=I32)
+        c = jnp.arange(LN, dtype=I32)
+        k = jnp.arange(K, dtype=I32)
+        # A % 128 == 0: account a of a lane is (row, column) =
+        # (a >> 7, a & 127) of the lane's rows
+        hs, cs = (start // A) * HL + (start % A) // LN, start % LN
+
+        def masks(nonzero, row):
+            """Of the (n, 128) entries of rows `row`: the live ones (a
+            lane's last row is padding where A is no multiple of 256),
+            and those of them at or after `start`."""
+            live = nonzero & (row % HL < A // LN)[:, None]
+            return live, live & ((row[:, None] > hs)
+                                 | ((row[:, None] == hs)
+                                    & (c[None, :] >= cs)))
+
+        live, ahead = masks(jnp.any(pos.reshape(H, 4, LN) != 0, axis=1), h)
+        cnt = jnp.sum(ahead, axis=1, dtype=I32)
+        rank = _prefix_sum(cnt, small=True)
+        first = rank - cnt              # entries at or after start, before
+        # row[k] = the last row that starts at or before entry k and
+        # holds one (its first rank is unique among such rows; a row
+        # that does not, writes past the end: dropped)
+        at = jnp.where((cnt > 0) & (first < K), first, _i(K) + h)
+        row = _running_max(jnp.zeros((K,), I32).at[at].set(
+            h + 1, mode="drop", unique_indices=True)) - 1
+        row = jnp.maximum(row, _i(0))
+        got = [pos[4 * row + w] for w in range(4)]
+        _, mine = masks((got[0] | got[1] | got[2] | got[3]) != 0, row)
+        upto = jnp.dot(mine.astype(jnp.int8),
+                       (c[:, None] <= c[None, :]).astype(jnp.int8),
+                       preferred_element_type=I32)
+        # the (k - first[row])-th live column of the row, from 0: the
+        # columns whose count up to and including them is <= that
+        # (past the last entry no column is: words 0, idx masked)
+        col = jnp.sum(upto <= (k - first[row])[:, None], axis=1, dtype=I32)
+        words = jnp.stack(
+            [jnp.sum(jnp.where(c[None, :] == col[:, None], g, _i(0)),
+                     axis=1, dtype=I32) for g in got], axis=1)
+        idx = jnp.where(k < rank[-1],
+                        row // HL * A + row % HL * LN + col, _i(S * A))
+        return jnp.sum(live, dtype=I32), idx, words
+
+    return jax.jit(live_positions)
+
+
+def live_positions_call(cfg: SeqConfig, state, start=0):
+    """One call of build_seq_live_positions' program on `state`'s
+    position store, not fetched (SeqSession.__init__ compiles it by
+    this call)."""
+    return build_seq_live_positions(cfg)(state["pos"], _i(start))
 
 
 def _nbytes(tree) -> int:
@@ -1916,11 +2036,19 @@ def export_snapshot(cfg: SeqConfig, state):
     pass over the ~2,500 rows fetched and six gathers of 60,000), by as
     many calls of that one program as it takes, each from the row after
     the last one returned; a book denser than that crosses whole and
-    the host makes the pass. Either way gives the same arrays. `pos`
-    and the small sections cross whole, with the first call's rows.
-    `fetch` says what crossed: `snapshot_fetch_bytes` (every section),
+    the host makes the pass. The positions cross by their live ENTRIES
+    where the section is written by them (build_seq_live_positions: the
+    device finds them, so the host never sees the plane — 1.4 GB at
+    3425 lanes x 25,088 accounts), again by as many calls as it takes,
+    each from the index after the last; a store dense enough to be
+    written whole crosses whole. Either way gives the same arrays. The
+    small sections cross whole, with the first calls' chunks. `fetch`
+    says what crossed: `snapshot_fetch_bytes` (every section),
     `snapshot_live_rows`, `snapshot_fetch_calls` (calls of the row
-    program that fetched the books; 0 = they crossed whole)."""
+    program that fetched the books; 0 = they crossed whole),
+    `snapshot_pos_fetch_bytes` (the positions' share of the bytes),
+    `snapshot_pos_calls` (calls of the entry program that fetched the
+    positions; 0 = they crossed whole)."""
     if cfg.compat != "fixed":
         raise ValueError("java-mode state snapshots via "
                          "runtime/javasnap.export_seqjava")
@@ -1928,9 +2056,11 @@ def export_snapshot(cfg: SeqConfig, state):
     R = live_rows_chunk(cfg)
     h = jax.device_get({
         "rows": live_rows_call(cfg, state),
+        "entries": live_positions_call(cfg, state),
         **{k: state[k] for k in _STATE_KEYS
-           if k not in BOOK_KEYS + ("dep",)}})
-    crossed = _nbytes(h)
+           if k not in BOOK_KEYS + ("dep", "pos")}})
+    crossed, pos_crossed = _nbytes(h), _nbytes(h["entries"])
+    live_pos, *entries = h.pop("entries")
     live_rows, *chunk = h.pop("rows")
     live_rows, chunks = int(live_rows), []
     canon = _small_sections(cfg, h)
@@ -1961,9 +2091,7 @@ def export_snapshot(cfg: SeqConfig, state):
         def at(k):
             return h[k].reshape(-1)[idx]
 
-    fetch = {"snapshot_fetch_bytes": crossed,
-             "snapshot_live_rows": live_rows,
-             "snapshot_fetch_calls": len(chunks)}
+    calls = len(chunks)
     layout["live_slots"] = int(idx.size)
     # (live rows <= a quarter of the rows puts the live slots under a
     # quarter of the slots: books fetched by their rows are written by
@@ -1976,26 +2104,32 @@ def export_snapshot(cfg: SeqConfig, state):
     else:
         canon.update(_dense_books(cfg, h))
 
-    # positions: `pos` is [lane, tile, half, value, column]; account
-    # a of a lane is (tile, half, column) = (a >> 8, a >> 7 & 1, a & 127)
-    # and, where A is no multiple of a tile's 256, the lane's last
-    # tile ends in padding
-    PTL = cfg.pos_tiles_per_lane
-    rows = h["pos"].reshape(S, PTL, 2, 4, LN)
-    lane, acct = np.divmod(np.flatnonzero(rows.any(axis=3)),
-                           PTL * POS_TILE_ACCOUNTS)
-    lane, acct = lane[acct < A], acct[acct < A]
-    layout["live_positions"] = int(lane.size)
-    if lane.size * _POS_LIVE_B < S * A * _POS_DENSE_B:
-        def word(k):
-            return rows[lane, acct >> 8, (acct >> 7) & 1, k, acct & (LN - 1)]
-
-        canon.update(pos_idx=lane * A + acct,
-                     pos_amt=_j64(word(0), word(1)),
-                     pos_avail=_j64(word(2), word(3)))
+    # positions: the device counted them (and skipped a lane's padding
+    # half-tile where A is no multiple of a tile's 256)
+    live_pos, K = int(live_pos), live_positions_chunk(cfg)
+    layout["live_positions"] = live_pos
+    if live_pos * _POS_LIVE_B < S * A * _POS_DENSE_B:
+        parts = [entries]
+        while len(parts) * K < live_pos:
+            parts.append(jax.device_get(live_positions_call(
+                cfg, state, parts[-1][0][-1] + 1)[1:]))
+        more = _nbytes(parts[1:])
+        # (what the last call returns past the last live entry is padding)
+        idx, words = (np.concatenate(v)[:live_pos] for v in zip(*parts))
+        canon.update(pos_idx=idx.astype(np.int64),
+                     pos_amt=_j64(words[:, 0], words[:, 1]),
+                     pos_avail=_j64(words[:, 2], words[:, 3]))
         layout["sparse"].append("positions")
     else:
+        parts = []
+        h["pos"] = jax.device_get(state["pos"])
+        more = h["pos"].nbytes
         canon.update(_dense_positions(cfg, h))
+    fetch = {"snapshot_fetch_bytes": crossed + more,
+             "snapshot_live_rows": live_rows,
+             "snapshot_fetch_calls": calls,
+             "snapshot_pos_fetch_bytes": pos_crossed + more,
+             "snapshot_pos_calls": len(parts)}
     return canon, layout, fetch
 
 

@@ -409,9 +409,9 @@ def _snapshot_export(session):
     `snapshot_export` of the session's timer): (arrays, layout, fetch),
     with `layout` None where the arrays are dense throughout and
     `fetch` what engine/seq.py:export_snapshot says crossed (a
-    fixed-mode SeqSession's books cross by their live rows, gathered
-    on the device; every other export brings its planes whole and says
-    nothing)."""
+    fixed-mode SeqSession's books cross by their live rows and its
+    positions by their live entries, both gathered on the device;
+    every other export brings its planes whole and says nothing)."""
     from kme_tpu.runtime.seqsession import SeqSession
 
     with session.timer.phase("snapshot_export"):
@@ -485,10 +485,10 @@ def save_seq_session(ckpt_dir: str, session, offset: int,
     (engine/seq.py:export_snapshot; _load_file densifies), so a file's
     size follows what is live and not the configured capacity. Three
     spans of the session's timer split the call: `snapshot_export` (the
-    device's gather of the books' live rows, the device -> host fetch
-    of those, `pos` and the small sections, and the host's pass over
-    them), `snapshot_meta` (the meta and the routes' arrays) and
-    `snapshot_write`."""
+    device's gather of the books' live rows and of the positions' live
+    entries, the device -> host fetch of those and the small sections,
+    and the host's pass over them), `snapshot_meta` (the meta and the
+    routes' arrays) and `snapshot_write`."""
     if session.cfg.compat == "java":
         return _save_seqjava(ckpt_dir, session, offset, keep=keep,
                              extra=extra)

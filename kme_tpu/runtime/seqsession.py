@@ -780,11 +780,12 @@ class SeqSession:
         # refresh: a compile inside a served batch is a stall
         self._occupancy = SQ.build_seq_occupancy(cfg)
         self._occupancy(self.state)
-        # likewise the program a fixed-mode snapshot fetches the books'
-        # live rows by (engine/seq.py:export_snapshot takes it from
-        # the same cache)
+        # likewise the two programs a fixed-mode snapshot fetches the
+        # books' live rows and the positions' live entries by
+        # (engine/seq.py:export_snapshot takes them from the same caches)
         if cfg.compat == "fixed":
             SQ.live_rows_call(cfg, self.state)
+            SQ.live_positions_call(cfg, self.state)
         # bytes metrics() has brought device -> host (cumulative; the
         # serve loop publishes it as gauge `metrics_fetch_bytes`)
         self.metrics_fetch_bytes = 0
@@ -795,8 +796,11 @@ class SeqSession:
         # `snapshot_sparse_sections` (0-2) written by their live
         # entries, and of its device -> host half `snapshot_fetch_bytes`
         # (all that crossed), `snapshot_live_rows` (rows of a book plane
-        # that hold an order) and `snapshot_fetch_calls` (calls of the
-        # live-row program that brought the books; 0: they crossed whole)
+        # that hold an order), `snapshot_fetch_calls` (calls of the
+        # live-row program that brought the books; 0: they crossed
+        # whole), `snapshot_pos_fetch_bytes` (the positions' share of
+        # what crossed) and `snapshot_pos_calls` (calls of the
+        # live-entry program that brought them; 0: they crossed whole)
         self.snapshot_gauges: Dict[str, int] = {}
 
     # ------------------------------------------------------------------

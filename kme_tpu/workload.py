@@ -31,8 +31,8 @@ from __future__ import annotations
 import bisect
 import math
 import random
-from typing import (Callable, Iterator, List, NamedTuple, Sequence,
-                    Tuple)
+from typing import (Callable, Iterator, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 from kme_tpu import opcodes as op
 from kme_tpu.wire import OrderMsg
@@ -485,6 +485,90 @@ def quote_churn_stream(num_events: int, num_symbols: int,
             size = floor(normal() * 10 + 50)
         oid = floor(rnd() * (2 ** 53 - 1))
         pool.append((oid, aid))
+        yield OrderMsg(action=op.BUY if buy else op.SELL, oid=oid,
+                       aid=aid, sid=sid, price=min(125, max(0, price)),
+                       size=max(1, size))
+
+
+def brokerage_stream(num_events: int, num_symbols: int,
+                     num_accounts: int, seed: int = 0,
+                     zipf_a: float = 1.2, account_zipf: float = 0.99,
+                     take: float = 0.6, cancel_share: float = 0.1,
+                     standing: int = 512, take_size: int = 150,
+                     deposit: int = 1_000_000_000) -> Iterator[OrderMsg]:
+    """A retail brokerage (TPC-E's populations and order mix, YCSB's
+    senders): accounts outnumber contracts several times over, a few
+    hundred of them send most of the flow, and most orders are
+    marketable.
+
+    Preamble as quote_churn_stream (accounts created and funded, ids
+    0..num_symbols-1 listed). Then, an event at a time:
+      (a) with probability cancel_share, while the pool has a member, a
+          cancel of a uniformly drawn member. The pool holds only the
+          last `standing` PASSIVE quotes: an older one falls out
+          uncancelled, a marketable order never enters, and the pool
+          never learns of fills (exchange_test.js:97-104), so a cancel
+          may name an order already filled and be rejected;
+      (b) else a submit: its symbol ~ Zipf(zipf_a) over the ranks, its
+          account ~ Zipf(account_zipf) over ranks that a permutation
+          drawn from the seed maps to account ids (YCSB's scrambled
+          zipfian: the hot accounts are no neighbours), its side a coin;
+      (c) with probability take it is marketable (TPC-E Trade-Order's
+          market order, as a limit on the far side of the mid: BUY at
+          51 + floor(|N(0, 4)|), SELL at 49 - floor(|N(0, 4)|), size
+          floor(N(take_size, take_size / 5))), else a passive quote,
+          BUY at 49 - floor(|N(0, 4)|) or SELL at 51 + floor(|N(0, 4)|),
+          size floor(N(50, 10)). Prices and sizes clamped into the
+          device domain. What a marketable order does not fill rests.
+    take_size 150 against quotes of 50 keeps the hot books shallow (a
+    taker sweeps what its limit reaches; at 30 the levels takers seldom
+    reach fill up and the hottest side meets 8,192 slots after 700,000
+    events); standing 512 keeps the pool young enough that over half of
+    the cancels find their quote resting (PERF.md section 4 has the
+    counts). The deposit covers the hottest account's margin.
+    Oids are uniform in [0, 2^53). Lazy (a generator); every draw is
+    O(1) but the two bisections (the pool is a ring of `standing`
+    places, a cancel redraws until it hits a place still held, about
+    1.2 draws). Seed-deterministic."""
+    gen = WorkloadGen(num_accounts, num_symbols, seed=seed, validate=True,
+                      payout_opcode_bug=False)
+    yield from _storm_preamble(gen, num_accounts, num_symbols, deposit)
+    sym_cdf = _zipf_cdf(num_symbols, zipf_a)
+    acct_cdf = _zipf_cdf(num_accounts, account_zipf)
+    sym_cdf[-1] = acct_cdf[-1] = 1.0    # (the sums stop an ulp short)
+    rnd, normal, floor = gen.rng.random, gen._random_normal, math.floor
+    acct_of_rank = list(range(num_accounts))
+    gen.rng.shuffle(acct_of_rank)
+    ring: List[Optional[Tuple[int, int]]] = [None] * standing
+    quotes = held = 0       # passive quotes so far; places still held
+    for _ in range(num_events):
+        if held and rnd() < cancel_share:
+            while True:
+                i = floor(rnd() * min(quotes, standing))
+                if ring[i] is not None:
+                    break
+            oid, aid = ring[i]
+            ring[i] = None
+            held -= 1
+            yield OrderMsg(action=op.CANCEL, oid=oid, aid=aid)
+            continue
+        sid = bisect.bisect_left(sym_cdf, rnd())
+        aid = acct_of_rank[bisect.bisect_left(acct_cdf, rnd())]
+        buy = rnd() < 0.5
+        away = floor(abs(normal()) * 4)
+        marketable = rnd() < take
+        if marketable:
+            price = 51 + away if buy else 49 - away
+            size = floor(normal() * (take_size / 5) + take_size)
+        else:
+            price = 49 - away if buy else 51 + away
+            size = floor(normal() * 10 + 50)
+        oid = floor(rnd() * (2 ** 53 - 1))
+        if not marketable:
+            i = quotes % standing
+            held += ring[i] is None
+            ring[i] = (oid, aid)
+            quotes += 1
         yield OrderMsg(action=op.BUY if buy else op.SELL, oid=oid,
                        aid=aid, sid=sid, price=min(125, max(0, price)),
                        size=max(1, size))

@@ -720,7 +720,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 SMALL = dict(lanes=8, slots=128, accounts=128, max_fills=16)
 # what export_snapshot says of its device -> host half
 FETCH_GAUGES = ("snapshot_fetch_bytes", "snapshot_live_rows",
-                "snapshot_fetch_calls")
+                "snapshot_fetch_calls", "snapshot_pos_fetch_bytes",
+                "snapshot_pos_calls")
 
 
 def _seq_session(state=None, **shape):
@@ -784,6 +785,10 @@ SPARSE_CASES = {
     "amount-0-available-not": (SMALL, (0.1, 0.1, False), 2),
     "past-the-break-even": (SMALL, (0.9, 0.8), 0),
     "full-books-thin-positions": (SMALL, (1.0, 0.01), 1),
+    # a lane count that is no multiple of 8, accounts in a tile and a
+    # half: the round trip save -> _load_file -> import (PR 48)
+    "40-lanes-384-accounts": (dict(lanes=40, slots=128, accounts=384,
+                                   max_fills=16), (0.05, 0.02), 2),
 }
 
 
@@ -845,6 +850,7 @@ def test_sparse_snapshot_loads_as_the_canonical_state(case, tmp_path):
     assert fetch["snapshot_live_rows"] == live_rows
     assert (fetch["snapshot_fetch_calls"] > 0) \
         == (4 * live_rows <= 2 * ses.cfg.lanes * ses.cfg.nr)
+    assert (fetch["snapshot_pos_calls"] > 0) == ("positions" in sparse)
     assert gauges == {
         "snapshot_bytes": os.path.getsize(path),
         "snapshot_routes": 0,
@@ -855,6 +861,15 @@ def test_sparse_snapshot_loads_as_the_canonical_state(case, tmp_path):
         # routes in the file beyond its resting orders (this state was
         # planted on the device: no router ever saw its orders)
         "stale_routes": -int(used.sum())}
+    # and import_canonical lays the file out as the device held it (a
+    # dead slot's words, which are no state, come back 0)
+    back = SQ.import_canonical(ses.cfg, data)
+    live = np.asarray(ses.state["bs"]) > 0
+    for k in SQ._STATE_KEYS:
+        got, held = np.asarray(back[k]), np.asarray(ses.state[k])
+        if k in SQ.BOOK_KEYS:
+            got, held = got[live], held[live]
+        assert np.array_equal(got, held), k
 
 
 def _reuse_stream():
@@ -1140,9 +1155,24 @@ def _dense_export(cfg, state):
     return canon, layout
 
 
-def _fetch_of(cfg, state, calls):
-    """Bytes a fetch of `calls` calls brings device -> host: `pos` and
-    the small sections, the count and one chunk (row indices + six
+def _pos_fetch_of(cfg, state, calls):
+    """Bytes the positions' fetch of `calls` calls brings device ->
+    host: the count and one chunk (indices + four words an entry) a
+    call, and the plane whole where no call brought them (the first
+    call's chunk crossed before that was known)."""
+    import numpy as np
+
+    from kme_tpu.engine import seq as SQ
+
+    chunk = 4 + SQ.live_positions_chunk(cfg) * (4 + 4 * 4)
+    return chunk * max(calls, 1) - 4 * (max(calls, 1) - 1) + (
+        0 if calls else np.asarray(state["pos"]).nbytes)
+
+
+def _fetch_of(cfg, state, calls, pos_calls):
+    """Bytes a fetch of `calls` calls for the books and `pos_calls` for
+    the positions brings device -> host: the small sections, the
+    positions' share, the count and one chunk (row indices + six
     planes' rows) a call, and the planes whole where no call brought
     the books (the first call's chunk crossed before that was known)."""
     import numpy as np
@@ -1151,10 +1181,11 @@ def _fetch_of(cfg, state, calls):
 
     R = SQ.live_rows_chunk(cfg)
     rest = sum(np.asarray(state[k]).nbytes for k in SQ._STATE_KEYS
-               if k not in SQ.BOOK_KEYS + ("dep",))
+               if k not in SQ.BOOK_KEYS + ("dep", "pos"))
     planes = sum(np.asarray(state[k]).nbytes for k in SQ.BOOK_KEYS)
     chunk = R * 4 + len(SQ.BOOK_KEYS) * R * SQ.LN * 4
-    return rest + 4 + chunk * max(calls, 1) + (0 if calls else planes)
+    return (rest + _pos_fetch_of(cfg, state, pos_calls) + 4
+            + chunk * max(calls, 1) + (0 if calls else planes))
 
 
 def _same_export(cfg, state):
@@ -1177,9 +1208,15 @@ def _same_export(cfg, state):
     live_rows = int((np.asarray(state["bs"]) > 0).any(axis=1).sum())
     R = SQ.live_rows_chunk(cfg)
     calls = max(-(-live_rows // R), 1) if 4 * live_rows <= rows else 0
-    assert fetch == {"snapshot_fetch_bytes": _fetch_of(cfg, state, calls),
-                     "snapshot_live_rows": live_rows,
-                     "snapshot_fetch_calls": calls}
+    live, K = layout["live_positions"], SQ.live_positions_chunk(cfg)
+    pos_calls = max(-(-live // K), 1) if "positions" in layout["sparse"] \
+        else 0
+    assert fetch == {
+        "snapshot_fetch_bytes": _fetch_of(cfg, state, calls, pos_calls),
+        "snapshot_live_rows": live_rows,
+        "snapshot_fetch_calls": calls,
+        "snapshot_pos_fetch_bytes": _pos_fetch_of(cfg, state, pos_calls),
+        "snapshot_pos_calls": pos_calls}
     return fetch
 
 
@@ -1262,25 +1299,148 @@ def test_an_empty_book_is_sparse_and_a_packed_one_crosses_a_tenth():
         cfg, _random_canon(DEEP, 0.002, 0.05, packed=True))
     fetch = _same_export(cfg, state)
     assert fetch["snapshot_fetch_calls"] == 1
-    assert fetch["snapshot_fetch_bytes"] * 10 < _fetch_of(cfg, state, 0)
+    assert fetch["snapshot_fetch_bytes"] * 10 < _fetch_of(cfg, state, 0, 1)
 
 
-def test_a_snapshot_of_a_serving_session_compiles_nothing(tmp_path):
-    """The live-row program is built and compiled in
-    SeqSession.__init__ (a compile inside a served batch is a stall):
-    batches served, two snapshots taken, a restore — no new program,
-    no new signature of the one there is."""
+# ---------------------------------------------------------------------------
+# the positions fetched by their live entries (engine/seq.py:
+# build_seq_live_positions, PR 48): whatever crosses, the arrays are
+# those of the dense fetch and the host's pass over every word
+
+# 10 lanes x 4096 accounts: 40,960 entries, 8,192 a call
+WIDE = dict(lanes=10, slots=128, accounts=4096, max_fills=16)
+# 384 accounts in two tiles of 256: a padding half-tile a lane
+PADDED = dict(lanes=5, slots=128, accounts=384, max_fills=16)
+
+
+def _with_positions(shape, flat_idx, amount=True, seed=9):
+    """A state whose position store holds exactly the entries
+    `flat_idx` (lane * A + account)."""
+    import numpy as np
+
     from kme_tpu.engine import seq as SQ
 
+    rng = np.random.default_rng(seed)
+    canon = _random_canon(shape, 0.01, 0.0)
+    n = len(flat_idx)
+    if amount:
+        canon["pos_amt"][flat_idx] = rng.integers(1, 10**12, n)
+    canon["pos_avail"][flat_idx] = rng.integers(1, 10**12, n)
+    cfg = SQ.SeqConfig(**shape)
+    return cfg, SQ.import_canonical(cfg, canon)
+
+
+def _spread(shape, n, seed=4):
+    import numpy as np
+
+    size = shape["lanes"] * shape["accounts"]
+    return np.sort(np.random.default_rng(seed).choice(size, n,
+                                                      replace=False))
+
+
+# name -> (shape, the live entries' flat indices, calls)
+POS_CASES = {
+    "empty": (WIDE, lambda: [], 1),
+    "one-in-the-last-tile-of-the-last-lane":
+        (WIDE, lambda: [10 * 4096 - 3], 1),
+    "first-and-last": (PADDED, lambda: [0, 5 * 384 - 1], 1),
+    "a-padding-half-tile": (PADDED, lambda: _spread(PADDED, 300), 1),
+    "amount-0-available-not": (WIDE, lambda: _spread(WIDE, 500), 1),
+    "exactly-a-call": (WIDE, lambda: _spread(WIDE, 8192), 1),
+    "a-call-and-one": (WIDE, lambda: _spread(WIDE, 8193), 2),
+    "three-calls-and-five": (WIDE, lambda: _spread(WIDE, 3 * 8192 + 5), 4),
+    "a-lane-full": (WIDE, lambda: list(range(3 * 4096, 4 * 4096 + 1)), 1),
+    "dense-enough-to-cross-whole":
+        (WIDE, lambda: _spread(WIDE, 30000), 0),
+}
+
+
+@pytest.mark.parametrize("case", POS_CASES)
+def test_positions_fetched_by_entries_are_the_dense_fetchs_arrays(case):
+    """Few entries or most, one call or four, a lane's padding
+    half-tile holding words the kernel never wrote: (canon, layout)
+    and the digest are those of the parent's dense fetch + host pass
+    (_dense_export), on either branch, and `fetch` counts the bytes."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from kme_tpu.engine import seq as SQ
+
+    shape, entries, calls = POS_CASES[case]
+    entries = np.asarray(entries(), np.int64)
+    cfg, state = _with_positions(shape, entries,
+                                 amount=case != "amount-0-available-not")
+    assert SQ.live_positions_chunk(cfg) == (8192 if shape is WIDE else 1920)
+    if case == "a-padding-half-tile":
+        # the second half of each lane's last tile is no account's
+        pos = np.array(state["pos"]).reshape(5, 2, 2, 4, SQ.LN)
+        assert not pos[:, 1, 1].any()
+        pos[:, 1, 1] = 7
+        state["pos"] = jnp.asarray(pos.reshape(-1, SQ.LN))
+    fetch = _same_export(cfg, state)
+    assert fetch["snapshot_pos_calls"] == calls
+    canon, layout, _ = SQ.export_snapshot(cfg, state)
+    assert layout["live_positions"] == entries.size
+    if calls:
+        assert canon["pos_idx"].tolist() == entries.tolist()
+        assert canon["pos_idx"].dtype == np.int64
+    else:
+        assert "pos_idx" not in canon
+        assert fetch["snapshot_pos_fetch_bytes"] > np.asarray(
+            state["pos"]).nbytes
+
+
+def test_the_entry_program_continues_from_any_index():
+    """Called from an index in the middle of a row, at a live entry,
+    after the last one and past the end: the entries at or after it, in
+    order, and the store's count whatever the start."""
+    import jax
+    import numpy as np
+
+    from kme_tpu.engine import seq as SQ
+
+    live = _spread(PADDED, 200)
+    cfg, state = _with_positions(PADDED, live)
+    size = cfg.lanes * cfg.accounts
+    both = SQ.pos_to_values(cfg, np.asarray(state["pos"]))
+    for start in (0, int(live[0]), int(live[0]) + 1, int(live[77]),
+                  int(live[-1]), int(live[-1]) + 1, size - 1, size):
+        n, idx, words = jax.device_get(
+            SQ.live_positions_call(cfg, state, start))
+        want = live[live >= start]
+        assert n == 200
+        assert idx[:want.size].tolist() == want.tolist(), start
+        assert (idx[want.size:] == size).all()
+        lane, acct = np.divmod(want, cfg.accounts)
+        words = words[:want.size]
+        assert np.array_equal(SQ._j64(words[:, 0], words[:, 1]),
+                              both[0, lane, acct])
+        assert np.array_equal(SQ._j64(words[:, 2], words[:, 3]),
+                              both[1, lane, acct])
+
+
+@pytest.mark.parametrize("build, gauge", [
+    ("build_seq_live_rows", "snapshot_live_rows"),
+    ("build_seq_live_positions", "snapshot_live_positions")])
+def test_a_snapshot_of_a_serving_session_compiles_nothing(build, gauge,
+                                                          tmp_path):
+    """The live-row and the live-entry program are built and compiled
+    in SeqSession.__init__ (a compile inside a served batch is a
+    stall): batches served, two snapshots taken, a restore — no new
+    program, no new signature of the one there is."""
+    from kme_tpu.engine import seq as SQ
+
+    build = getattr(SQ, build)
+
     def calls():
-        info = SQ.build_seq_live_rows.cache_info()
+        info = build.cache_info()
         return info.hits + info.misses
 
     n = calls()
     ses = _seq_session(**SMALL)
     assert calls() == n + 1                     # __init__ asked for it
-    program = SQ.build_seq_live_rows(ses.cfg)
-    misses = SQ.build_seq_live_rows.cache_info().misses
+    program = build(ses.cfg)
+    misses = build.cache_info().misses
     compiled = program._cache_size()
     assert compiled >= 1                        # and ran it
     msgs = list(zipf_symbol_stream(900, 8, 64, seed=12, zipf_a=0.0))
@@ -1294,9 +1454,9 @@ def test_a_snapshot_of_a_serving_session_compiles_nothing(tmp_path):
     assert off == 600
     ck.save_seq_session(str(tmp_path / "again"), back, off)
     assert program._cache_size() == compiled
-    assert SQ.build_seq_live_rows.cache_info().misses == misses
-    assert back.snapshot_gauges["snapshot_live_rows"] \
-        == ses.snapshot_gauges["snapshot_live_rows"] > 0
+    assert build.cache_info().misses == misses
+    assert back.snapshot_gauges[gauge] == ses.snapshot_gauges[gauge] > 0
+    assert back.snapshot_gauges["snapshot_pos_calls"] == 1
 
 
 # ---------------------------------------------------------------------------
