@@ -54,10 +54,11 @@ RULES: Dict[str, str] = {
 # a batch's fetch and its device dispatch, any host sync or blocking
 # I/O serializes the pipeline: the collect wall stops hiding under
 # device execution. Collect-side functions (_collect_one, collect,
-# _fetch_outputs) legitimately sync and are NOT listed.
+# _finish_fetch) legitimately sync and are NOT listed; _start_fetch,
+# the fetch's half that submit runs, is.
 HOT_SCOPES: Dict[str, Set[str]] = {
     "kme_tpu/bridge/service.py": {"_step_pipelined", "_parse_batch"},
-    "kme_tpu/runtime/seqsession.py": {"submit", "_plan"},
+    "kme_tpu/runtime/seqsession.py": {"submit", "_plan", "_start_fetch"},
     "kme_tpu/native/sched.py": {"plan_batch", "apply_placement",
                                 "slice_windows"},
     # the mesh planner + elastic placement decision run per batch on

@@ -76,6 +76,17 @@ _LIFECYCLE_COUNTERS = {
                                   "the seq kernel (its own count)",
 }
 
+_FETCH_COUNTERS = {
+    "fetch_early": "seq dispatches whose output prefix was sliced and "
+                   "sent to the host with them, ahead of any later scan",
+    "fetch_ready": "seq fetches that found that prefix's slice already "
+                   "run (jax.Array.is_ready(): no wait for the "
+                   "device's queue)",
+    "fetch_second_rounds": "seq fetches that needed a second round of "
+                           "slices: a call's fills overflowed the hint "
+                           "its prefix was cut by",
+}
+
 
 class MatchService:
     # the spans that PARTITION one iteration of the serve loop (names of
@@ -1660,6 +1671,12 @@ class MatchService:
                   "tiles of the position store the seq kernel brought "
                   "in from HBM (the kernel's own count)"
                   ).set(getattr(self._session, "pos_probe_tiles", 0))
+        # a seq session's fetch: whether a batch's output prefix was
+        # sent on its way with its dispatch, and had arrived by the time
+        # the batch was collected (0 from an engine that has no such
+        # fetch)
+        for name, what in _FETCH_COUNTERS.items():
+            t.counter(name, what).set(getattr(self._session, name, 0))
         # the symbol lifecycle: the router's counts as of the newest
         # batch collected and the kernel's own of its barrier section
         # (0 from an engine that has no lanes to hand back)

@@ -765,6 +765,14 @@ class SeqSession:
         # positions a YES payout credited
         self.barrier_wiped_orders = 0
         self.barrier_credited_positions = 0
+        # the fetch's first round (_start_fetch / _finish_fetch):
+        # dispatches whose prefix slice was launched with them, late
+        # halves that found that slice already run, and late halves
+        # that needed the overflow slices; the serve loop publishes
+        # them as counters of the same names
+        self.fetch_early = 0
+        self.fetch_ready = 0
+        self.fetch_second_rounds = 0
         # the router's ROUTER_STATS as of the newest batch COLLECTED
         # (the router itself runs `pipeline` batches ahead); the serve
         # loop publishes them as counters, `lanes_bound` and what is
@@ -856,8 +864,9 @@ class SeqSession:
 
     def _run(self, msgs):
         """Plan (route + pack) + dispatch (ONE lax.scan jit call over
-        all chunks), then fetch in one concurrent round (headers +
-        adaptive fill prefix; rare overflow slices in a second round).
+        all chunks, its output prefix's slice and copy launched behind
+        it), then fetch in one concurrent round (headers + adaptive
+        fill prefix; rare overflow slices in a second round).
         Phase wall times ACCUMULATE in self.phases (the bench and the
         service read them; reset via self.timer.reset()).
         Returns (cols, host_rejects, host dict, fills (4, F))."""
@@ -868,26 +877,45 @@ class SeqSession:
         with self.timer.phase("dispatch_s"):
             self.state, outp = SQ.build_seq_scan(self.cfg, K)(
                 self.state, stacked)
+            prefix = self._start_fetch(outp)
             import jax as _jax
             _jax.block_until_ready(self.state)
         with self.timer.phase("fetch_s"):
-            host, fills = self._fetch_outputs(outp, cnts, K)
+            host, fills = self._finish_fetch(outp, prefix, cnts, K)
         with self.timer.phase("recon_s"):
             self._drop_routes(cols, host, fills)
         return cols, host_rejects, host, fills
 
-    def _fetch_outputs(self, outp, cnts, K):
-        """Fetch + unpack one dispatch's output planes: ONE fetch round
-        in the common case (headers + the adaptive fill-group hint's
-        worth of fill rows per call; calls whose fill_total overflows
-        the hint get a rare second-round slice)."""
+    def _start_fetch(self, outp):
+        """The early half of a dispatch's fetch, called right behind
+        the scan's own dispatch (inside `dispatch_s`): slice the output
+        planes' prefix (headers + the adaptive fill-group hint's worth
+        of fill rows per call) and start its copy to the host. The
+        device runs programs in launch order, so the slice stands
+        directly behind the scan whose output it reads and ahead of
+        every scan dispatched later: launched at the fetch it would
+        wait out whichever scan was dispatched in between. Returns
+        (the prefix on the device, the hint it was cut by)."""
+        from kme_tpu.utils import async_prefetch, pow2_bucket
+
+        ghint = min(pow2_bucket(self._ghint, lo=1),
+                    self.cfg.fill_cap // 128)
+        fdev = outp[:, :SQ.hdr_rows(self.cfg) + 5 * ghint, :]
+        async_prefetch([fdev])
+        self.fetch_early += 1
+        return fdev, ghint
+
+    def _finish_fetch(self, outp, prefix, cnts, K):
+        """The late half: take `_start_fetch`'s prefix to the host and
+        unpack it. ONE fetch round in the common case; calls whose
+        fill_total overflows the hint THE PREFIX WAS CUT BY (the
+        session's may have grown since) get a rare second-round slice,
+        launched here, behind whatever was dispatched since."""
         from kme_tpu.utils import async_prefetch, pow2_bucket
 
         HR = SQ.hdr_rows(self.cfg)
-        ghint = min(pow2_bucket(self._ghint, lo=1),
-                    self.cfg.fill_cap // 128)
-        fdev = outp[:, :HR + 5 * ghint, :]
-        async_prefetch([fdev])
+        fdev, ghint = prefix
+        self.fetch_ready += fdev.is_ready()
         fetched = np.asarray(fdev)
         host = {k: [] for k in ("ok", "cap_reject", "append",
                                 "last_emptied", "residual", "nfill",
@@ -910,6 +938,7 @@ class SeqSession:
         over = [ci for ci in range(K) if gneed[ci] > ghint]
         extra = {}
         if over:
+            self.fetch_second_rounds += 1
             slices = [outp[ci, HR:HR + 5 * pow2_bucket(gneed[ci], lo=1)]
                       for ci in over]
             async_prefetch(slices)
@@ -990,10 +1019,12 @@ class SeqSession:
             # runs this batch while the host plans/collects others
             self.state, outp = SQ.build_seq_scan(self.cfg, K)(
                 self.state, stacked)
+            prefix = self._start_fetch(outp)
         self.windows.append(("submit", self._n_submit, t0,
                              perf_counter()))
         self._n_submit += 1
-        return (msgs, cols, host_rejects, outp, cnts, K, switches, routed)
+        return (msgs, cols, host_rejects, outp, prefix, cnts, K, switches,
+                routed)
 
     def _note_router(self, stats: tuple) -> None:
         """Take up the router's cumulative counts as of one batch, and
@@ -1038,11 +1069,12 @@ class SeqSession:
         from time import perf_counter
 
         t0 = perf_counter()
-        batch, cols, host_rejects, outp, cnts, K, switches, routed = handle
+        (batch, cols, host_rejects, outp, prefix, cnts, K, switches,
+         routed) = handle
         self.lane_switches += switches
         self._note_router(routed)
         with self.timer.phase("fetch_s"):
-            host, fills = self._fetch_outputs(outp, cnts, K)
+            host, fills = self._finish_fetch(outp, prefix, cnts, K)
         with self.timer.phase("recon_s"):
             self._drop_routes(cols, host, fills)
             r = self._recon_buffer(batch, cols, host_rejects, host,

@@ -59,8 +59,8 @@ STAGE_FUNCS: Dict[str, tuple] = {
     "parse": ("_parse_batch", "_parse", "parse_order", "decode_frames"),
     "plan": ("_plan", "plan_batch", "pack_msgs", "route_line"),
     "dispatch": ("submit", "_stage_and_dispatch", "dispatch",
-                 "build_seq_scan", "call_scan"),
-    "collect": ("collect", "_collect_one", "_fetch_outputs", "_run",
+                 "build_seq_scan", "call_scan", "_start_fetch"),
+    "collect": ("collect", "_collect_one", "_finish_fetch", "_run",
                 "_drain_pipeline"),
     "produce": ("_produce_out", "_produce_buffer", "_produce_xfer",
                 "produce_batch", "produce_frames", "record_batch"),

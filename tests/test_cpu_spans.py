@@ -237,9 +237,14 @@ def _settled(srv, idle, timeout=10.0):
 def _produce_and_consume(host, port, msgs, chunks, reads):
     """Two clients at once over real sockets: `chunks` produce_frames
     requests, `reads` fetch_bin requests (the last three long polls
-    that wait their time out)."""
+    that wait their time out). Neither closes before both have been
+    served: a handler that had wound up before the other's began would
+    hand it its book, and the server would hold one where two
+    connections were open at once (on a loaded machine the second
+    client's thread can start that late)."""
     failed = []
     frames, per = encode_frames(msgs), FRAME_SIZE
+    both_served = threading.Barrier(2)
 
     def producer():
         try:
@@ -249,8 +254,10 @@ def _produce_and_consume(host, port, msgs, chunks, reads):
                 c.produce_frames(
                     TOPIC_IN, None,
                     frames[k * step * per:(k + 1) * step * per])
+            both_served.wait(timeout=30)
             c.close()
         except Exception as e:      # a thread's failure fails the test
+            both_served.abort()
             failed.append(e)
 
     def consumer():
@@ -259,8 +266,10 @@ def _produce_and_consume(host, port, msgs, chunks, reads):
             for k in range(reads):
                 c.fetch_bin(TOPIC_IN, 0 if k < reads - 3 else 10 ** 6, 64,
                             timeout=0.01 if k < reads - 3 else 0.03)
+            both_served.wait(timeout=30)
             c.close()
         except Exception as e:
+            both_served.abort()
             failed.append(e)
 
     threads = [threading.Thread(target=producer),
