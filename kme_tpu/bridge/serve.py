@@ -13,6 +13,12 @@ from __future__ import annotations
 import argparse
 import sys
 
+# a leading "{checkpoint_dir}" in --journal-out, --audit-repro-dir and
+# --tsdb stands for --checkpoint-dir: a deployment written down once
+# (a benchmark configuration, a unit file) keeps the planes' files with
+# the leader's state, wherever a run puts that
+_CKPT_PREFIX = "{checkpoint_dir}"
+
 
 def build_parser() -> argparse.ArgumentParser:
     """kme-serve's options; their defaults ARE the flagless deployment
@@ -130,7 +136,11 @@ def build_parser() -> argparse.ArgumentParser:
                         "rest/cancel/payout with provenance stamps) "
                         "here; .bin/.kmej selects the compact binary "
                         "framing, anything else JSONL. Query with "
-                        "kme-trace")
+                        "kme-trace. A leading {checkpoint_dir} stands "
+                        "for --checkpoint-dir (here, in "
+                        "--audit-repro-dir and in --tsdb): the planes' "
+                        "files then live and go with the leader's "
+                        "state directory")
     p.add_argument("--trace-spans", action="store_true",
                    help="journal distributed-tracing span events "
                         "(ingress/plan/device/produce per order, keyed "
@@ -169,7 +179,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "requires --journal-out)")
     p.add_argument("--audit-repro-dir", default=None, metavar="DIR",
                    help="write audit violation repro dumps here "
-                        "(replayable with kme-trace --replay-repro)")
+                        "(replayable with kme-trace --replay-repro); "
+                        "a leading {checkpoint_dir} as in --journal-out")
     p.add_argument("--slo-p99-ms", type=float, default=None,
                    metavar="MS",
                    help="latency SLO: keep the p99 of --slo-stage under "
@@ -213,7 +224,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "an on-disk time-series store in DIR (kme-prof "
                         "queries it); samples carry a monotonic "
                         "sample_seq persisted with the checkpoint so a "
-                        "crash-resume dedups replayed heartbeats")
+                        "crash-resume dedups replayed heartbeats; a "
+                        "leading {checkpoint_dir} as in --journal-out")
     p.add_argument("--profile", action="store_true",
                    help="always-on host sampling profiler: attributes "
                         "serve-loop wall time to pipeline stages "
@@ -268,6 +280,16 @@ def main(argv=None) -> int:
         except XrayError as e:
             print(f"kme-serve: {e}", file=sys.stderr)
             return 2
+
+    for flag in ("journal_out", "audit_repro_dir", "tsdb"):
+        path = getattr(args, flag)
+        if path is not None and path.startswith(_CKPT_PREFIX):
+            if args.checkpoint_dir is None:
+                print(f"kme-serve: --{flag.replace('_', '-')} {path} "
+                      f"needs --checkpoint-dir", file=sys.stderr)
+                return 2
+            setattr(args, flag, args.checkpoint_dir
+                    + path[len(_CKPT_PREFIX):])
 
     group = None
     if args.group is not None:

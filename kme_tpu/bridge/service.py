@@ -104,6 +104,17 @@ class MatchService:
     # per-order latency stamping, the broker's watermark commit (a wait
     # for its lock), and the publishing of these very gauges
     BETWEEN_SPANS = ("latency_stamp", "commit_watermark", "publish_spans")
+    # spans of the observability planes, in the gauges of a service that
+    # has the plane on: the flight recorder's (journal_lines: a
+    # pipelined batch's buffer back into lines; journal_record: all of
+    # Journal.record_batch on the serve thread, with journal_events,
+    # journal_write — the latency stamps' write too — and the auditor's
+    # audit_observe inside it), the auditor's snapshot-cadence compare,
+    # the metrics history's append (heartbeat cadence)
+    JOURNAL_SPANS = ("journal_lines", "journal_record", "journal_events",
+                     "journal_write")
+    AUDIT_SPANS = ("audit_observe", "audit_check_engine")
+    TSDB_SPANS = ("tsdb_append",)
 
     def __init__(self, broker, engine: str = "lanes",
                  compat: str = "fixed", batch: int = 1024,
@@ -485,6 +496,12 @@ class MatchService:
                 pass        # topic not provisioned yet / transport blip
 
     def _init_observability(self, resumed: bool) -> None:
+        self._init_planes(resumed)
+        if self._plane_spans:
+            # the planes' counters and gauges, before the first heartbeat
+            self._publish_spans()
+
+    def _init_planes(self, resumed: bool) -> None:
         """Flight recorder + invariant auditor wiring. The journal
         subscribes the auditor as an observer, so the shadow replay
         sees exactly what lands in the journal file; on resume the
@@ -515,7 +532,7 @@ class MatchService:
                     return ck.oldest_retained_offset(ckpt_dir)
             j = Journal(j, rotate_bytes=rb, fsync=self._journal_fsync,
                         rotate_keep=self._journal_keep,
-                        retention_guard=guard)
+                        retention_guard=guard, timer=self._ptimer)
         self.journal = j
         if j is not None and resumed:
             j.rewind_to_offset(self.offset)
@@ -592,7 +609,8 @@ class MatchService:
             on_violation=on_violation,
             checkpoint_ref=self.checkpoint_dir,
             journal_ref=getattr(j, "path", None),
-            log_ref=getattr(self.broker, "_persist_dir", None))
+            log_ref=getattr(self.broker, "_persist_dir", None),
+            timer=self._ptimer, counts_live=False)
         if resumed and self._session is not None:
             self.auditor.seed(self._session.export_state(),
                               self._session.histograms())
@@ -795,6 +813,10 @@ class MatchService:
         # serve-side spans land on their own trace track when a
         # TraceRecorder is installed (kme-serve --trace-out)
         self._ptimer = PhaseTimer(track="serve")
+        self._plane_spans = (
+            (self.JOURNAL_SPANS if self._journal_arg is not None else ())
+            + (self.AUDIT_SPANS if self._audit_arg else ())
+            + (self.TSDB_SPANS if self._tsdb_arg is not None else ()))
         self._batch_ordinal = 0
         self._last_produce_s = 0.0
         self._phase_snap = {}
@@ -1102,20 +1124,29 @@ class MatchService:
                 return False
         return True
 
-    def _snapshot_save(self, extra: dict) -> None:
+    def _snapshot_save(self, extra: dict) -> list:
         """Write the engine in effect to a durable snapshot at
-        `self.offset` (runtime/checkpoint.py)."""
+        `self.offset` (runtime/checkpoint.py). -> what the auditor's
+        compare may read in place of a fetch of its own: the
+        (canon, layout) a fixed-mode SeqSession's snapshot fetched,
+        or nothing."""
         from kme_tpu.runtime import checkpoint as ck
 
+        fetched = []
         with self._span("snapshot_save"):
             if self._session is not None:
                 from kme_tpu.runtime.seqsession import SeqSession
 
-                save = (ck.save_seq_session
-                        if isinstance(self._session, SeqSession)
-                        else ck.save_session)
-                save(self.checkpoint_dir, self._session, self.offset,
-                     keep=self.checkpoint_keep, extra=extra)
+                if isinstance(self._session, SeqSession):
+                    ck.save_seq_session(
+                        self.checkpoint_dir, self._session, self.offset,
+                        keep=self.checkpoint_keep, extra=extra,
+                        fetched=(fetched if self.auditor is not None
+                                 else None))
+                else:
+                    ck.save_session(
+                        self.checkpoint_dir, self._session, self.offset,
+                        keep=self.checkpoint_keep, extra=extra)
             elif self._native is not None:
                 ck.save_native(self.checkpoint_dir, self._native,
                                self.offset, keep=self.checkpoint_keep,
@@ -1124,6 +1155,7 @@ class MatchService:
                 ck.save_oracle(self.checkpoint_dir, self._oracle,
                                self.offset, keep=self.checkpoint_keep,
                                extra=extra)
+        return fetched
 
     def _checkpoint(self) -> None:
         self._checkpoint_drain()
@@ -1162,17 +1194,27 @@ class MatchService:
                 # the transfer LEGS themselves regenerate from MatchIn
                 # replay and dedup on their (epoch, out_seq) stamps
                 extra["pending_reserve"] = dict(self._xfer)
-        self._snapshot_save(extra)
+        fetched = self._snapshot_save(extra)
         self._last_ckpt_offset = self.offset
         if self.journal is not None:
             # the journal is best-effort relative to the broker log, but
             # a snapshot is a natural durability point for it too
             self.journal.flush()
         if self.auditor is not None and self._session is not None:
-            # checkpoint-cadence cross-check: shadow ledger vs the
-            # engine's exported stores + device histograms
-            self.auditor.check_engine(self._session.export_state(),
-                                      self._session.histograms())
+            self._audit_check_engine(fetched)
+
+    def _audit_check_engine(self, fetched: list) -> None:
+        """Checkpoint-cadence cross-check (span `audit_check_engine`):
+        the shadow ledger against the engine's stores and the device
+        histograms, every snapshot. The engine's side is built from the
+        live entries the snapshot itself fetched (`fetched`: its canon
+        and layout) where there are such — no second device fetch, no
+        walk over dead slots — and from export_state() otherwise; the
+        dict compared is the same either way."""
+        with self._span("audit_check_engine"):
+            state = (self._session.export_live(*fetched) if fetched
+                     else self._session.export_state())
+            self.auditor.check_engine(state, self._session.histograms())
 
     # ------------------------------------------------------------------
 
@@ -1325,9 +1367,10 @@ class MatchService:
             jout = out or []
             if self._journal_tamper is not None:
                 jout = self._journal_tamper(jout)
-            self.journal.record_batch(jout, reasons=reasons,
-                                      offsets=offs[:len(out or [])],
-                                      drops=drops)
+            with self._span("journal_record"):
+                self.journal.record_batch(jout, reasons=reasons,
+                                          offsets=offs[:len(out or [])],
+                                          drops=drops)
         with self._span("latency_stamp"):
             self._stamp_latency(
                 in_atss, atss, offs[:n], [int(m.oid) for m in msgs],
@@ -1485,13 +1528,15 @@ class MatchService:
             lat["produce"].observe(self._last_produce_s, n)
         out = None
         if (self.journal is not None or self.watch is not None) and n:
-            out = self._lines_of(buf, line_off, msg_lines)
+            with self._span("journal_lines", ordinal):
+                out = self._lines_of(buf, line_off, msg_lines)
         if self.journal is not None and n:
             jout = out
             if self._journal_tamper is not None:
                 jout = self._journal_tamper(jout)
-            self.journal.record_batch(jout, reasons=reasons,
-                                      offsets=offs, drops=[])
+            with self._span("journal_record", ordinal):
+                self.journal.record_batch(jout, reasons=reasons,
+                                          offsets=offs, drops=[])
         with self._span("latency_stamp", ordinal):
             # every record of a pipelined batch parsed: one list
             self._stamp_latency(atss, atss, offs, wb.oid.tolist(),
@@ -1644,7 +1689,8 @@ class MatchService:
         t.counter("service_records").inc(nrecs)
         t.counter("service_dropped").inc(ndropped)
         gauges = self._ptimer.gauges(self.LOOP_SPANS + self.INNER_SPANS
-                                     + self.BETWEEN_SPANS)
+                                     + self.BETWEEN_SPANS
+                                     + self._plane_spans)
         timer = getattr(self._session, "timer", None)
         if timer is not None:
             gauges.update(timer.gauges(getattr(self._session, "SPANS", ())))
@@ -1702,6 +1748,18 @@ class MatchService:
                   "of matchout_records, those that reached the broker "
                   "inside a buffer run (produce_stamped_buffer): no "
                   "Python object a record").set(self._out_buffered)
+        journal = getattr(self, "journal", None)
+        if journal is not None:
+            t.counter("journal_events",
+                      "events the flight recorder wrote: lifecycle, and "
+                      "one latency stamp an order").set(
+                getattr(journal, "events_written", 0))
+            t.counter("journal_bytes",
+                      "bytes the flight recorder wrote to its live "
+                      "file").set(getattr(journal, "bytes_written", 0))
+        if getattr(self, "auditor", None) is not None:
+            self.auditor.publish_counts()
+            gauges["audit_shadow_positions"] = len(self.auditor.positions)
         # host engines never load jax: nothing compiles
         jaxsetup = sys.modules.get("kme_tpu._jaxsetup")
         compiles = (jaxsetup.compiles if jaxsetup is not None
@@ -2379,7 +2437,9 @@ class MatchService:
         if self.tsdb is None:
             return
         try:
-            self.tsdb.append_snapshot(snap, seq)
+            # (on the heartbeat's thread, under _hb_lock: one writer)
+            with self._span("tsdb_append"):
+                self.tsdb.append_snapshot(snap, seq)
         except OSError as e:
             # history is best-effort; the live heartbeat is not
             print(f"kme-serve: TSDB append failed: {e}",

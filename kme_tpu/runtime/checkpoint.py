@@ -476,7 +476,8 @@ def _snapshot_write(ckpt_dir: str, session, offset: int, payload: dict,
 
 def save_seq_session(ckpt_dir: str, session, offset: int,
                      keep: Optional[int] = None,
-                     extra: Optional[dict] = None) -> str:
+                     extra: Optional[dict] = None,
+                     fetched=None) -> str:
     """Snapshot a SeqSession at input offset `offset` in the SAME
     canonical layout as lanes snapshots (slot_* / flat s64 positions /
     bal), so snapshots restore across ENGINES as well as across
@@ -488,7 +489,12 @@ def save_seq_session(ckpt_dir: str, session, offset: int,
     device's gather of the books' live rows and of the positions' live
     entries, the device -> host fetch of those and the small sections,
     and the host's pass over them), `snapshot_meta` (the meta and the
-    routes' arrays) and `snapshot_write`."""
+    routes' arrays) and `snapshot_write`. `fetched`, where given, is a
+    list that takes the export's canon and layout once the file is
+    written: a second reader of the state at `offset` (the auditor's
+    compare: SeqSession.export_live) then shares this snapshot's one
+    fetch. It stays empty where the export has no live-entry layout
+    (java mode, a subclass with a dense export)."""
     if session.cfg.compat == "java":
         return _save_seqjava(ckpt_dir, session, offset, keep=keep,
                              extra=extra)
@@ -513,6 +519,8 @@ def save_seq_session(ckpt_dir: str, session, offset: int,
             # after the drain, so both speak of one input prefix)
             stale_routes=(session.snapshot_gauges["snapshot_routes"]
                           - layout["live_slots"]))
+        if fetched is not None:
+            fetched += [canon, layout]
     return path
 
 

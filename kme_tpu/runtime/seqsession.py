@@ -1373,6 +1373,59 @@ class SeqSession:
         canon = SQ.export_canonical(self.cfg, self.state)
         return self._canon_to_export(canon)
 
+    def export_live(self, canon: dict, layout: dict) -> Dict[str, dict]:
+        """export_state()'s four stores (fixed mode) from what a
+        snapshot just fetched — SQ.export_snapshot's `canon` and
+        `layout` — so that its reader (the auditor's snapshot-cadence
+        compare) makes no fetch of its own and walks no dead slot: the
+        books' live slots and the positions' live entries, each section
+        sparse or dense as it crossed, named through the router's maps
+        as _canon_to_export names the dense planes. The same keys and
+        values but for an order's record, which is the tuple (aid, sid,
+        is_buy, price, size) the auditor's shadow keeps and not a dict:
+        no Python object is made an entry beyond what the stores hold.
+        Held equal to export_state() by
+        tests/test_audited_deployment.py."""
+        S, _, N = layout["slot_shape"]
+        A = self.cfg.accounts
+        aid_of = np.asarray(self.router.acct_of_idx(), np.int64)
+        n = len(aid_of)
+        sid_at, bound = np.zeros(S, np.int64), np.zeros(S, bool)
+        for sid, lane in self.router.sid_lane.items():
+            sid_at[lane], bound[lane] = sid, True
+        used = canon["bal_used"][:n]
+        balances = dict(zip(aid_of[used].tolist(),
+                            canon["bal"][:n][used].tolist()))
+
+        def live(section, flag):
+            # (flat index, values...) of a section's live entries
+            idx_key, *keys = SQ.SPARSE_SECTIONS[section]
+            if section in layout["sparse"]:
+                return [np.asarray(canon[k]) for k in (idx_key, *keys)]
+            idx = np.flatnonzero(canon[flag])
+            return [idx] + [canon[k].reshape(-1)[idx] for k in keys]
+
+        idx, amt, avail = live("positions", "pos_amt")
+        lane, a = np.divmod(idx, A)
+        # a position counts by its amount, as in _canon_to_export (the
+        # snapshot keeps an amount of 0 with an available balance too)
+        keep = (amt != 0) & bound[lane] & (a < n)
+        positions = dict(zip(
+            zip(aid_of[a[keep]].tolist(), sid_at[lane[keep]].tolist()),
+            zip(amt[keep].tolist(), avail[keep].tolist())))
+        idx, oid, aidx, price, size, _seq = live("books", "slot_used")
+        lane = idx // (2 * N)
+        keep = bound[lane]
+        # an order as the auditor's shadow keeps it
+        orders = dict(zip(oid[keep].tolist(), zip(
+            aid_of[aidx[keep]].tolist(), sid_at[lane[keep]].tolist(),
+            ((idx[keep] // N) % 2 == 0).tolist(), price[keep].tolist(),
+            size[keep].tolist())))
+        books = {sid: True for sid, lane in self.router.sid_lane.items()
+                 if canon["book_exists"][lane]}
+        return {"balances": balances, "positions": positions,
+                "orders": orders, "books": books}
+
     def _canon_to_export(self, canon: dict) -> Dict[str, dict]:
         """Canonical engine export -> oracle-comparable dict view.
         Shared with SeqMeshSession, whose canon is stitched from

@@ -31,10 +31,17 @@ per-batch conservation invariants:
     credited money it never collected. The check self-disables once a
     sell above price 100 is accepted — the reference margin formula
     `(size+adj)*(price-100)` legally mints credit there.
+  Both are kept as RUNNING sums (a position sum per symbol, the symbols
+  whose sum is not zero, the total of the balances), stepped wherever
+  the shadow changes an amount or a balance, so that a batch's check
+  costs what the batch touched and not what the shadow holds;
+  `_batch_checks_full` is the pass over every position and balance that
+  they must equal.
 
 at checkpoint cadence (`check_engine`):
   - state_mismatch  the shadow's balances/positions/orders/books
-    deep-compared against the engine's `export_state()`
+    deep-compared against the engine's `export_state()` (or, the same
+    dict, SeqSession.export_live over what the snapshot just fetched)
   - hist_mismatch   the shadow's fills_per_order histogram (exact
     mirror: one observation per accepted trade, value = fill pairs)
     and the book_depth observation COUNT (one observation per accepted
@@ -57,6 +64,7 @@ first-fill +1 corruption for end-to-end tests.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import threading
@@ -67,6 +75,8 @@ from kme_tpu.oracle import javalong as jl
 from kme_tpu.telemetry.registry import N_BUCKETS, bucket_index
 
 _J = dict(sort_keys=True, separators=(",", ":"))
+# journal records that carry timing and no lifecycle
+_TIMING = ("win", "lat", "span")
 
 
 class Violation(dict):
@@ -95,15 +105,32 @@ class InvariantAuditor:
                  max_dumps: int = 8,
                  checkpoint_ref: Optional[str] = None,
                  journal_ref: Optional[str] = None,
-                 log_ref: Optional[str] = None) -> None:
+                 log_ref: Optional[str] = None,
+                 timer=None, counts_live: bool = True) -> None:
+        # the PhaseTimer of whoever runs this auditor (the service's):
+        # span `audit_observe` is every observe() that replays a batch
+        self._timer = timer
+        # counters audit_batches / audit_entries_compared step as the
+        # work is done, or (False) only when the owner calls
+        # publish_counts(): a service publishes them with its batch
+        # counters, at one instant, so that two heartbeats differ by
+        # whole batches of both
+        self._counts_live = counts_live
+        self.entries_compared = 0
         self.balances: Dict[int, int] = {}
         # (aid, sid) -> (amount, available)
         self.positions: Dict[Tuple[int, int], Tuple[int, int]] = {}
-        # oid -> [aid, sid, is_buy, price, size]
-        self.orders: Dict[int, list] = {}
+        # oid -> (aid, sid, is_buy, price, size): a fill makes a new
+        # record, so _hold's shallow copy keeps the pre-batch sizes and
+        # check_engine compares the store as it stands
+        self.orders: Dict[int, tuple] = {}
         # sid -> ({price: [oid FIFO]} buys, {price: [oid FIFO]} sells)
         self.books: Dict[int, Tuple[dict, dict]] = {}
         self.inflow = 0
+        # running conservation state (_reindex derives it anew)
+        self._sid_sum: Dict[int, int] = {}
+        self._unbalanced: set = set()
+        self._bal_total = 0
         self.violations: List[Violation] = []
         self.batches = 0
         self.dumps: List[str] = []
@@ -122,32 +149,47 @@ class InvariantAuditor:
         self._lock = threading.Lock()
         self._counter = None
         self._batch_counter = None
+        self._compared = None
         if registry is not None:
             self._counter = registry.counter(
                 "audit_violations",
                 help="conservation-invariant violations detected")
             self._batch_counter = registry.counter(
                 "audit_batches", help="batches audited")
+            self._compared = registry.counter(
+                "audit_entries_compared",
+                help="balances, positions, resting orders and books of "
+                     "the engine that check_engine compared with the "
+                     "shadow's")
 
     # ------------------------------------------------------------------
     # journal observer entry point
 
     def observe(self, events: List[dict], lines=None) -> None:
         """Replay one journaled batch and run the per-batch checks.
-        Signature matches Journal observer fan-out (events, lines)."""
+        Signature matches Journal observer fan-out (events, lines). A
+        fan-out of timing records alone (the journal's "lat", "span"
+        and "win" jobs) is no batch: nothing to replay, nothing counted."""
+        if events and all(ev["e"] in _TIMING for ev in events):
+            return
+        with (self._timer.phase("audit_observe") if self._timer is not None
+              else contextlib.nullcontext()):
+            self._observe(events, lines)
+
+    def _observe(self, events: List[dict], lines) -> None:
         if self.tamper is not None:
             events = self.tamper(events)
         with self._lock:
             batch = next((ev.get("b", -1) for ev in events), -1)
-            pre = self._snapshot() if self.repro_dir else None
+            pre = self._hold() if self.repro_dir else None
             found: List[Violation] = []
             for ev in events:
                 self._apply(ev, found)
             self._finalize_pending(found)
             self._batch_checks(found, batch)
             self.batches += 1
-            if self._batch_counter is not None:
-                self._batch_counter.inc()
+            if self._counts_live:
+                self.publish_counts()
             if not found:
                 return
             self.violations.extend(found)
@@ -155,10 +197,15 @@ class InvariantAuditor:
                 self._counter.inc(len(found))
             dump = None
             if pre is not None and len(self.dumps) < self.max_dumps:
-                dump = self._write_repro(found, batch, pre, events,
+                dump = self._write_repro(found, batch, _wire(pre), events,
                                          lines)
         if self.on_violation is not None:
             self.on_violation(found, dump)
+
+    def publish_counts(self) -> None:
+        if self._batch_counter is not None:
+            self._batch_counter.set(self.batches)
+            self._compared.set(self.entries_compared)
 
     # ------------------------------------------------------------------
     # event replay (exact fixed-mode arithmetic; see oracle/engine.py)
@@ -181,13 +228,13 @@ class InvariantAuditor:
             if aid in self.balances:
                 bad("create_dup", f"aid={aid} already exists")
             else:
-                self.balances[aid] = 0
+                self._set_bal(aid, 0)
         elif e == "transfer":
             bal = self.balances.get(aid)
             if bal is None or bal < jl.jint(-qty):
                 bad("transfer_overdraw",
                     f"aid={aid} bal={bal} transfer={qty}")
-            self.balances[aid] = jl.jadd(bal or 0, qty)
+            self._set_bal(aid, jl.jadd(bal or 0, qty))
             self.inflow += qty
         elif e == "add_symbol":
             if sid in self.books:
@@ -224,7 +271,7 @@ class InvariantAuditor:
         if bal is None or bal < risk:
             bad("margin_overdraw",
                 f"oid={ev['oid']} aid={aid} bal={bal} risk={risk}")
-        self.balances[aid] = jl.jadd(bal or 0, -risk)
+        self._set_bal(aid, jl.jadd(bal or 0, -risk))
         if not is_buy and px > 100:
             self._unbounded_credit = True   # negative risk is legal here
         if adj != 0 and pos is not None:
@@ -245,12 +292,14 @@ class InvariantAuditor:
             if rec[3] != px:
                 bad("fill_price_mismatch",
                     f"moid={moid} resting px={rec[3]} fill px={px}")
-            rec[4] -= qty
-            if rec[4] < 0:
+            left = rec[4] - qty
+            if left < 0:
                 bad("fill_overfill",
-                    f"moid={moid} overfilled by {-rec[4]}")
-            if rec[4] <= 0:
+                    f"moid={moid} overfilled by {-left}")
+            if left <= 0:
                 self._unrest(moid, rec)
+            else:
+                self.orders[moid] = rec[:4] + (left,)
         p = self._pending
         if p is not None and p["oid"] == oid:
             limit = p["px"]
@@ -274,6 +323,7 @@ class InvariantAuditor:
         pos = self.positions.get(key)
         if pos is None:
             self.positions[key] = (sz, sz)
+            self._step_sum(sid, sz)
         else:
             na = jl.jadd(pos[0], sz)
             if na == 0:
@@ -281,11 +331,12 @@ class InvariantAuditor:
                 self.positions.pop(key, None)
             else:
                 self.positions[key] = (na, jl.jadd(pos[1], sz))
+            self._step_sum(sid, na - pos[0])
         bal = self.balances.get(aid)
         if bal is None:
             bad("fill_no_balance", f"aid={aid} filled with no balance")
             bal = 0
-        self.balances[aid] = jl.jadd(bal, jl.jint(sz * price))
+        self._set_bal(aid, jl.jadd(bal, jl.jint(sz * price)))
 
     def _rest(self, ev, bad) -> None:
         p = self._pending
@@ -300,8 +351,8 @@ class InvariantAuditor:
         side = self.books.setdefault(p["sid"], ({}, {}))[
             0 if p["is_buy"] else 1]
         side.setdefault(p["px"], []).append(oid)
-        self.orders[oid] = [p["aid"], p["sid"], p["is_buy"], p["px"],
-                            qty]
+        self.orders[oid] = (p["aid"], p["sid"], p["is_buy"], p["px"],
+                            qty)
 
     def _cancel(self, ev, bad) -> None:
         oid, aid = ev["oid"], ev["aid"]
@@ -339,12 +390,15 @@ class InvariantAuditor:
                         f"payout credits aid={key[0]} with no balance")
                     bal = 0
                 pay = jl.jmul(amt, qty)
-                self.balances[key[0]] = jl.jadd(bal, pay)
+                self._set_bal(key[0], jl.jadd(bal, pay))
                 # settlement is external funding for escrow purposes
                 self.inflow += pay
         else:
             for key in [k for k in self.positions if k[1] == s]:
                 del self.positions[key]
+        # no position of the symbol is left either way
+        self._sid_sum.pop(s, None)
+        self._unbalanced.discard(s)
 
     def _release(self, rec, bad) -> None:
         """postRemoveAdjustments (KProcessor.java:325-333), fixed."""
@@ -361,8 +415,8 @@ class InvariantAuditor:
                 f"margin release for aid={aid} with no balance")
             bal = 0
         unit = jl.jint(price) if is_buy else jl.jint(price - 100)
-        self.balances[aid] = jl.jadd(
-            bal, jl.jmul(jl.jadd(sz, adj), unit))
+        self._set_bal(aid, jl.jadd(
+            bal, jl.jmul(jl.jadd(sz, adj), unit)))
         if adj != 0 and pos is not None:
             self.positions[(aid, sid)] = (pos[0], jl.jadd(pos[1], adj))
 
@@ -393,18 +447,55 @@ class InvariantAuditor:
     # ------------------------------------------------------------------
     # per-batch conservation checks
 
+    def _set_bal(self, aid: int, new: int) -> None:
+        self._bal_total += new - self.balances.get(aid, 0)
+        self.balances[aid] = new
+
+    def _step_sum(self, sid: int, delta: int) -> None:
+        """A position of `sid` changed its amount by `delta`."""
+        total = self._sid_sum.get(sid, 0) + delta
+        self._sid_sum[sid] = total
+        if total:
+            self._unbalanced.add(sid)
+        else:
+            self._unbalanced.discard(sid)
+
+    def _reindex(self) -> None:
+        """The running sums, from the stores as they stand (after the
+        stores were set whole: seed, auditor_from_pre)."""
+        self._sid_sum = {}
+        for (_aid, sid), (amt, _a) in self.positions.items():
+            self._sid_sum[sid] = self._sid_sum.get(sid, 0) + amt
+        self._unbalanced = {s for s, t in self._sid_sum.items() if t}
+        self._bal_total = sum(self.balances.values())
+
     def _batch_checks(self, out: List[Violation], batch: int) -> None:
+        """The conservation invariants from the running sums: a symbol
+        is named while its amounts do not sum to zero, as the full pass
+        names it (_batch_checks_full; tests hold the two equal)."""
+        self._conservation(
+            out, batch, ((s, self._sid_sum[s])
+                         for s in sorted(self._unbalanced)),
+            self._bal_total)
+
+    def _batch_checks_full(self, out: List[Violation],
+                           batch: int) -> None:
+        """The same verdicts by a pass over every position and every
+        balance the shadow holds: the definition, O(state)."""
         sums: Dict[int, int] = {}
         for (aid, sid), (amt, _a) in self.positions.items():
             sums[sid] = sums.get(sid, 0) + amt
-        for sid, total in sums.items():
-            if total != 0:
-                out.append(Violation(
-                    "position_conservation",
-                    f"sid={sid} position amounts sum to {total}",
-                    batch))
+        self._conservation(
+            out, batch, ((s, t) for s, t in sorted(sums.items()) if t),
+            sum(self.balances.values()))
+
+    def _conservation(self, out, batch, unbalanced, bal_total) -> None:
+        for sid, total in unbalanced:
+            out.append(Violation(
+                "position_conservation",
+                f"sid={sid} position amounts sum to {total}", batch))
         if not self._unbounded_credit:
-            escrow = self.inflow - sum(self.balances.values())
+            escrow = self.inflow - bal_total
             if escrow < 0:
                 out.append(Violation(
                     "escrow_negative",
@@ -418,8 +509,11 @@ class InvariantAuditor:
                      histograms: Optional[dict] = None
                      ) -> List[Violation]:
         """Deep-compare the shadow against the engine's export_state()
-        (and optionally its histograms() net of the seed baseline).
-        Returns (and records) any mismatches as violations."""
+        (and optionally its histograms() net of the seed baseline);
+        `state` is that dict whoever built it — a fixed-mode SeqSession
+        builds it from the live entries its snapshot just fetched
+        (export_live). Returns (and records) any mismatches as
+        violations."""
         with self._lock:
             found: List[Violation] = []
 
@@ -429,23 +523,31 @@ class InvariantAuditor:
             if state.get("balances") != self.balances:
                 d = _dict_diff(state.get("balances", {}), self.balances)
                 bad("state_mismatch", f"balances differ: {d}")
-            eng_pos = {k: tuple(v)
-                       for k, v in state.get("positions", {}).items()}
+            # an exporter may give the shadow's own record shapes (a
+            # position as a tuple, an order as _order_rec's: export_live
+            # does), and the stores then compare as they stand
+            eng_pos = state.get("positions", {})
             if eng_pos != self.positions:
-                d = _dict_diff(eng_pos, self.positions)
-                bad("state_mismatch", f"positions differ: {d}")
-            eng_ord = {o: (v["aid"], v["sid"], v["is_buy"], v["price"],
-                           v["size"])
-                       for o, v in state.get("orders", {}).items()}
-            shd_ord = {o: tuple(v) for o, v in self.orders.items()}
-            if eng_ord != shd_ord:
-                d = _dict_diff(eng_ord, shd_ord)
-                bad("state_mismatch", f"orders differ: {d}")
+                eng_pos = {k: tuple(v) for k, v in eng_pos.items()}
+                if eng_pos != self.positions:
+                    d = _dict_diff(eng_pos, self.positions)
+                    bad("state_mismatch", f"positions differ: {d}")
+            eng_ord = state.get("orders", {})
+            if eng_ord != self.orders:
+                eng_ord = {o: _order_rec(v) for o, v in eng_ord.items()}
+                if eng_ord != self.orders:
+                    d = _dict_diff(eng_ord, self.orders)
+                    bad("state_mismatch", f"orders differ: {d}")
             eng_books = set(state.get("books", {}))
             if eng_books != set(self.books):
                 bad("state_mismatch",
                     f"books differ: engine={sorted(eng_books)} "
                     f"shadow={sorted(self.books)}")
+            self.entries_compared += (len(state.get("balances", {}))
+                                      + len(eng_pos) + len(eng_ord)
+                                      + len(eng_books))
+            if self._counts_live:
+                self.publish_counts()
             if histograms is not None:
                 base = self._hist_base or {}
                 fills = [a - b for a, b in zip(
@@ -486,8 +588,7 @@ class InvariantAuditor:
             self.balances = dict(state.get("balances", {}))
             self.positions = {k: tuple(v) for k, v in
                               state.get("positions", {}).items()}
-            self.orders = {o: [v["aid"], v["sid"], v["is_buy"],
-                               v["price"], v["size"]]
+            self.orders = {o: _order_rec(v)
                            for o, v in state.get("orders", {}).items()}
             self.books = {sid: ({}, {})
                           for sid in state.get("books", {})}
@@ -495,7 +596,8 @@ class InvariantAuditor:
                 aid, sid, is_buy, px, size = self.orders[oid]
                 book = self.books.setdefault(sid, ({}, {}))
                 book[0 if is_buy else 1].setdefault(px, []).append(oid)
-            self.inflow = sum(self.balances.values())
+            self._reindex()
+            self.inflow = self._bal_total
             self._hist_base = ({k: list(v)
                                 for k, v in histograms.items()}
                                if histograms else None)
@@ -503,17 +605,17 @@ class InvariantAuditor:
             self._depth_obs = 0
             self._pending = None
 
+    def _hold(self) -> tuple:
+        """The shadow as it stands, by shallow copies (positions are
+        tuples, and an order record is replaced, never changed): what
+        observe() keeps of the pre-batch state for a repro dump it will
+        most likely not write. _wire gives it the dump's shape."""
+        return (dict(self.balances), dict(self.positions),
+                dict(self.orders), list(self.books), self.inflow,
+                self._unbounded_credit)
+
     def _snapshot(self) -> dict:
-        return {
-            "balances": dict(self.balances),
-            "positions": {f"{a}:{s}": list(v)
-                          for (a, s), v in self.positions.items()},
-            "orders": {str(o): list(v)
-                       for o, v in self.orders.items()},
-            "books": sorted(self.books),
-            "inflow": self.inflow,
-            "unbounded_credit": self._unbounded_credit,
-        }
+        return _wire(self._hold())
 
     def _write_repro(self, found, batch, pre, events, lines
                      ) -> Optional[str]:
@@ -547,6 +649,26 @@ class InvariantAuditor:
         return cmd
 
 
+def _order_rec(v) -> tuple:
+    """An exported resting order as the shadow keeps it."""
+    return (v if isinstance(v, tuple) else
+            (v["aid"], v["sid"], v["is_buy"], v["price"], v["size"]))
+
+
+def _wire(held: tuple) -> dict:
+    """_hold's copies in the shape a repro dump carries (JSON keys)."""
+    balances, positions, orders, books, inflow, unbounded = held
+    return {
+        "balances": balances,
+        "positions": {f"{a}:{s}": list(v)
+                      for (a, s), v in positions.items()},
+        "orders": {str(o): list(v) for o, v in orders.items()},
+        "books": sorted(books),
+        "inflow": inflow,
+        "unbounded_credit": unbounded,
+    }
+
+
 def _dict_diff(a: dict, b: dict, limit: int = 4) -> str:
     keys = [k for k in set(a) | set(b) if a.get(k) != b.get(k)]
     parts = [f"{k}: engine={a.get(k)} shadow={b.get(k)}"
@@ -569,7 +691,7 @@ def auditor_from_pre(pre: dict) -> "InvariantAuditor":
     aud.positions = {(int(a), int(s)): tuple(v)
                      for ks, v in pre["positions"].items()
                      for a, s in [ks.split(":")]}
-    aud.orders = {int(o): list(v) for o, v in pre["orders"].items()}
+    aud.orders = {int(o): tuple(v) for o, v in pre["orders"].items()}
     aud.books = {sid: ({}, {}) for sid in pre["books"]}
     for oid in sorted(aud.orders):
         aid, sid, is_buy, px, size = aud.orders[oid]
@@ -577,6 +699,7 @@ def auditor_from_pre(pre: dict) -> "InvariantAuditor":
         book[0 if is_buy else 1].setdefault(px, []).append(oid)
     aud.inflow = pre["inflow"]
     aud._unbounded_credit = pre.get("unbounded_credit", False)
+    aud._reindex()
     return aud
 
 

@@ -54,6 +54,7 @@ independent replay of the same input stream — `kme-trace --verify`.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import struct
@@ -344,6 +345,12 @@ class Journal:
     Observers (`observers.append(fn)`) are called as fn(events,
     lines_per_msg) after each batch commits — the invariant auditor
     subscribes here and thus runs on the writer thread in async mode.
+
+    timer: the PhaseTimer of whoever records (the service's); a commit
+    then is two of its spans, `journal_events` (a batch's derivation
+    from its wire lines) and `journal_write` (encode, write, fsync: of
+    every job, the latency stamps' too). `events_written` and
+    `bytes_written` count what reached the file.
     """
 
     def __init__(self, path: str, fmt: Optional[str] = None,
@@ -351,7 +358,7 @@ class Journal:
                  fsync: str = "off", shard: int = 0,
                  resume: bool = True, async_write: bool = False,
                  clock=None, rotate_keep: Optional[int] = None,
-                 retention_guard=None) -> None:
+                 retention_guard=None, timer=None) -> None:
         if fmt is None:
             fmt = ("binary" if path.endswith((".bin", ".kmej"))
                    else "jsonl")
@@ -375,6 +382,9 @@ class Journal:
         self.fsync = fsync
         self.shard = shard
         self.observers: List = []
+        self._span = (timer.phase if timer is not None
+                      else lambda _name: contextlib.nullcontext())
+        self.events_written = self.bytes_written = 0
         self._clock = clock or (lambda: __import__("time").time_ns()
                                 // 1000)
         self._seq = 0
@@ -389,6 +399,7 @@ class Journal:
         self.last_offset = -1
         if resume and os.path.exists(path) and os.path.getsize(path):
             self._resume_tail()
+        os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
         self._f = open(path, "ab")
         if self.fmt == "binary" and self._f.tell() == 0:
             self._f.write(MAGIC)
@@ -526,7 +537,8 @@ class Journal:
             lines = None
             if job[0] == "batch":
                 _, lines, reasons, offsets, drops = job
-                events = batch_events(lines, reasons, offsets, drops)
+                with self._span("journal_events"):
+                    events = batch_events(lines, reasons, offsets, drops)
                 b = self._batch
                 self._batch += 1
             elif job[0] == "win":
@@ -549,7 +561,8 @@ class Journal:
                 self._seq += 1
                 ev["ts"] = ts
                 ev["sh"] = self.shard
-            self._write(events)
+            with self._span("journal_write"):
+                self._write(events)
             for ev in events:
                 off = ev.get("off", -1)
                 if off is not None and off > self.last_offset:
@@ -579,6 +592,8 @@ class Journal:
             os.fsync(self._f.fileno())
             os.kill(os.getpid(), _sig.SIGKILL)
         self._f.write(blob)
+        self.events_written += len(events)
+        self.bytes_written += len(blob)
         if self.fsync == "batch":
             self._f.flush()
             os.fsync(self._f.fileno())
