@@ -106,11 +106,14 @@ class MatchService:
     BETWEEN_SPANS = ("latency_stamp", "commit_watermark", "publish_spans")
     # spans of the observability planes, in the gauges of a service that
     # has the plane on: the flight recorder's (journal_lines: a
-    # pipelined batch's buffer back into lines; journal_record: all of
-    # Journal.record_batch on the serve thread, with journal_events,
-    # journal_write — the latency stamps' write too — and the auditor's
-    # audit_observe inside it), the auditor's snapshot-cadence compare,
-    # the metrics history's append (heartbeat cadence)
+    # pipelined batch's buffer turned into what the journal takes —
+    # its records, by the native walk inside Journal.record_buffer, or
+    # its lines; journal_record: all of Journal.record_buffer /
+    # record_batch on the serve thread, with journal_events, the
+    # batch's journal_write and the auditor's audit_observe inside it;
+    # journal_write: the latency stamps' write too), the auditor's
+    # snapshot-cadence compare, the metrics history's append
+    # (heartbeat cadence)
     JOURNAL_SPANS = ("journal_lines", "journal_record", "journal_events",
                      "journal_write")
     AUDIT_SPANS = ("audit_observe", "audit_check_engine")
@@ -929,15 +932,18 @@ class MatchService:
 
         g = self.group_id
         if self.journal is not None:
-            self.journal.record_latency(
-                [{"off": offs[i], "oid": oids[i],
-                  "in_us": (max(0, fetch_us - atss[i])
-                            if atss[i] is not None else 0),
-                  "plan_us": plan_us, "dev_us": dev_us,
-                  "prod_us": prod_us,
-                  "e2e_us": (max(0, done_us - atss[i])
-                             if atss[i] is not None else 0)}
-                 for i in range(n)], batch=batch)
+            import numpy as np
+
+            # the stamps as columns; an order with no admission stamp
+            # reads 0 in both
+            has = np.array([ats is not None for ats in atss])
+            ats = np.array([ats or 0 for ats in atss], np.int64)
+            self.journal.record_latency_columns(
+                np.array(offs, np.int64), np.array(oids, np.int64),
+                np.where(has, np.maximum(0, fetch_us - ats), 0),
+                plan_us, dev_us, prod_us,
+                np.where(has, np.maximum(0, done_us - ats), 0),
+                batch=batch)
             if self.trace_spans:
                 spans = []
                 for i in range(n):
@@ -1493,6 +1499,8 @@ class MatchService:
         visible on MatchOut."""
         import time as _t
 
+        from kme_tpu.telemetry.journal import buffer_lines
+
         (end_off, handle, wb, offs, atss, fetch_us, plan_d, ordinal,
          submitted_us) = self._pipe.popleft()
         lat = self._lat
@@ -1526,26 +1534,28 @@ class MatchService:
                 round(dev_d * 1e3, 3))
         if self._last_produce_s > 0:
             lat["produce"].observe(self._last_produce_s, n)
-        out = None
-        if (self.journal is not None or self.watch is not None) and n:
-            with self._span("journal_lines", ordinal):
-                out = self._lines_of(buf, line_off, msg_lines)
         if self.journal is not None and n:
-            jout = out
-            if self._journal_tamper is not None:
-                jout = self._journal_tamper(jout)
             with self._span("journal_record", ordinal):
-                self.journal.record_batch(jout, reasons=reasons,
-                                          offsets=offs, drops=[])
+                if self._journal_tamper is not None:
+                    # the drill rewrites a line: this batch goes as lines
+                    with self._span("journal_lines", ordinal):
+                        out = buffer_lines(buf, line_off, msg_lines)
+                    self.journal.record_batch(
+                        self._journal_tamper(out), reasons=reasons,
+                        offsets=offs, drops=[])
+                else:
+                    self.journal.record_buffer(buf, line_off, msg_lines,
+                                               reasons=reasons,
+                                               offsets=offs)
         with self._span("latency_stamp", ordinal):
             # every record of a pipelined batch parsed: one list
             self._stamp_latency(atss, atss, offs, wb.oid.tolist(),
                                 wb.aid.tolist(), fetch_us, done_us,
                                 plan_d, dev_d, ordinal)
-        if self.watch is not None and out:
-            self.watch.observe_lines(out, reasons=reasons,
-                                     offsets=offs, drops=[],
-                                     exemplars=self._slow)
+        if self.watch is not None and n:
+            self.watch.observe_lines(
+                buffer_lines(buf, line_off, msg_lines), reasons=reasons,
+                offsets=offs, drops=[], exemplars=self._slow)
         self.offset = end_off
         if not self.follower:
             faults.kill_now("serve.kill", offset=self.offset)
@@ -1561,19 +1571,6 @@ class MatchService:
         batch, a due checkpoint, shutdown)."""
         while self._pipe:
             self._collect_one()
-
-    @staticmethod
-    def _lines_of(buf, line_off, msg_lines):
-        """Reconstruction buffer -> per-message line lists (the journal
-        and annotation surfaces still speak lines)."""
-        text = buf.decode("ascii")
-        lo = line_off.tolist()
-        out, li = [], 0
-        for nl in msg_lines.tolist():
-            out.append([text[lo[li + k]:lo[li + k + 1]]
-                        for k in range(nl)])
-            li += nl
-        return out
 
     def _produce_buffer(self, buf, line_off, ordinal=None) -> None:
         """Produce a reconstructed record buffer — the collect-side
@@ -1757,6 +1754,11 @@ class MatchService:
             t.counter("journal_bytes",
                       "bytes the flight recorder wrote to its live "
                       "file").set(getattr(journal, "bytes_written", 0))
+            t.counter("journal_native_batches",
+                      "batches whose journal records were made as one "
+                      "record array by the native walk over the "
+                      "collected buffer (Journal.record_buffer)").set(
+                getattr(journal, "native_batches", 0))
         if getattr(self, "auditor", None) is not None:
             self.auditor.publish_counts()
             gauges["audit_shadow_positions"] = len(self.auditor.positions)

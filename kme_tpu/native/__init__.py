@@ -307,6 +307,12 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         "kme_run_pack": ([c.c_char_p, c.c_void_p, c.c_void_p]
                          + [c.c_int64] * 6, c.c_int64),
         "kme_run_out": ([], c.c_void_p),
+        # a collected batch's buffer -> the flight recorder's 96-byte
+        # records (kme_wire.cpp kme_journal_rows); addresses as above
+        "kme_journal_rows": ([c.c_char_p, c.c_int64, c.c_void_p,
+                              c.c_int64] + [c.c_void_p] * 3
+                             + [c.c_int64] * 2 + [c.c_int32] * 2
+                             + [c.c_void_p], c.c_int64),
         # native front-door acceptor (kme_front.cpp): validate + route
         # + plan in one call per batch
         "kme_front_new": ([], c.c_void_p),

@@ -774,3 +774,158 @@ int64_t kme_run_pack(const uint8_t* buf, const int64_t* off,
 const char* kme_run_out() { return run_out.buf; }
 
 }  // extern "C"
+
+// ---------------------------------------------------------------------------
+// kme_journal: a collected batch's reconstruction buffer -> the flight
+// recorder's records (telemetry/journal.py: _REC, 96 bytes a record).
+//
+// The journal is evidence because it is made from the bytes that went
+// to MatchOut, so this PARSES the buffer: each line is "KEY value" with
+// the value in put_order's fixed shape (fast_line above). Semantics
+// authority: journal.batch_events + Journal._commit's stamping +
+// journal._encode; equivalence is pinned by tests/test_journal.py. A
+// line of any other shape, or a value the record cannot hold, is an
+// error and the caller takes that path for the batch instead.
+
+namespace {
+
+struct JRec {  // struct "<BBBBiii10q": naturally aligned, no padding
+  uint8_t etype, rej, sh, pad;
+  int32_t act, b, i;
+  int64_t seq, ts, off, oid, aid, sid, px, qty, moid, maid;
+};
+static_assert(sizeof(JRec) == 96, "journal record is 96 bytes");
+
+// journal.ETYPES, by index
+enum : uint8_t { J_SUBMIT = 0, J_ACCEPT, J_REJECT, J_REST, J_FILL,
+                 J_CANCEL, J_CREATE, J_TRANSFER, J_PAYOUT, J_ADD_SYMBOL,
+                 J_REMOVE_SYMBOL };
+
+constexpr int64_t REJ_UNSPECIFIED = 8;
+
+// journal._ACT_EVENT: an accepted message that is no trade
+inline uint8_t act_event(int64_t act) {
+  switch (act) {
+    case 4: return J_CANCEL;
+    case 100: return J_CREATE;
+    case 101: return J_TRANSFER;
+    case 200: return J_PAYOUT;
+    case 0: return J_ADD_SYMBOL;
+    case 1: return J_REMOVE_SYMBOL;
+    default: return J_ACCEPT;
+  }
+}
+
+// wire.reason_for_reject: the cause by the rejected action, for
+// engines that report none
+inline int64_t reason_for_reject(int64_t act) {
+  switch (act) {
+    case 2: case 3: return 2;            // REJ_RISK
+    case 4: return 3;                    // REJ_CANCEL
+    case 1: case 200: return 5;          // REJ_BARRIER
+    case 0: case 100: case 101: return 7;  // REJ_OTHER
+    default: return REJ_UNSPECIFIED;
+  }
+}
+
+// the eight integers of line `li`'s value (what follows its first
+// space): action oid aid sid price size next prev
+inline bool journal_line(const uint8_t* buf, int64_t len,
+                         const int64_t* off, int64_t li, int64_t* v) {
+  const int64_t s = off[li], e = off[li + 1];
+  if (s < 0 || e < s || e > len) return false;
+  const char* p = reinterpret_cast<const char*>(buf) + s;
+  const char* end = reinterpret_cast<const char*>(buf) + e;
+  const void* sp = std::memchr(p, ' ', end - p);
+  if (!sp) return false;
+  uint8_t has[8];
+  return fast_line(static_cast<const char*>(sp) + 1, end, v, has);
+}
+
+inline bool fits_i32(int64_t v) { return v >= INT32_MIN && v <= INT32_MAX; }
+
+}  // namespace
+
+extern "C" {
+
+// Records of the nmsg messages whose lines are buf[off[k], off[k+1])
+// (msg_lines[i] lines for message i: the IN echo, OUT fill pairs, the
+// OUT result echo), stamped seq0.., ts, b, sh, into `out` — room for
+// (lines + nmsg) records is enough. `reasons` (per-message wire.REJ_*)
+// and `offsets` (per-message input offsets) may be null: the action
+// heuristic, -1. Returns the record count, or -(line + 1) for the
+// first line this cannot take.
+int64_t kme_journal_rows(const uint8_t* buf, int64_t len,
+                         const int64_t* off, int64_t nmsg,
+                         const int32_t* msg_lines, const int64_t* reasons,
+                         const int64_t* offsets, int64_t seq0, int64_t ts,
+                         int32_t b, int32_t sh, void* out) {
+  JRec* rec = static_cast<JRec*>(out);
+  int64_t n = 0, li = 0;
+  int64_t m[8], res[8], mk[8], tk[8];
+  for (int64_t i = 0; i < nmsg; i++) {
+    const int64_t nl = msg_lines[i];
+    if (nl < 1 || !journal_line(buf, len, off, li, m)) return -(li + 1);
+    if (!fits_i32(m[0])) return -(li + 1);
+    JRec base{};
+    base.sh = static_cast<uint8_t>(sh);
+    base.act = static_cast<int32_t>(m[0]);
+    base.b = b;
+    base.i = static_cast<int32_t>(i);
+    base.ts = ts;
+    base.off = offsets ? offsets[i] : -1;
+    base.oid = m[1];
+    base.aid = m[2];
+    base.sid = m[3];
+    base.px = m[4];
+    base.qty = m[5];
+    auto put = [&](uint8_t etype) -> JRec& {
+      JRec& r = rec[n];
+      r = base;
+      r.etype = etype;
+      r.seq = seq0 + n;
+      n++;
+      return r;
+    };
+    put(J_SUBMIT);
+    if (nl >= 2) {
+      const int64_t last = li + nl - 1;
+      if (!journal_line(buf, len, off, last, res)) return -(last + 1);
+      const bool rejected = res[0] == OP_REJECT;
+      const bool trade = !rejected && (m[0] == 2 || m[0] == 3);
+      if (rejected) {
+        int64_t rej = reasons ? reasons[i] : reason_for_reject(m[0]);
+        if (rej == 0) rej = REJ_UNSPECIFIED;
+        if (rej < 0 || rej > 255) return -(last + 1);
+        put(J_REJECT).rej = static_cast<uint8_t>(rej);
+      } else if (trade) {
+        // margin is reserved before matching: accept precedes fills
+        put(J_ACCEPT);
+      }
+      // OUT pairs: the resting maker's line, then the taker's
+      for (int64_t k = li + 1; k < last; k += 2) {
+        if (!journal_line(buf, len, off, k, mk)) return -(k + 1);
+        if (!journal_line(buf, len, off, k + 1, tk)) return -(k + 2);
+        if (!trade) continue;
+        int64_t px;
+        if (!fits_i32(tk[0]) || __builtin_sub_overflow(m[4], tk[4], &px))
+          return -(k + 2);
+        JRec& r = put(J_FILL);
+        r.act = static_cast<int32_t>(tk[0]);
+        r.oid = tk[1];
+        r.aid = tk[2];
+        r.sid = tk[3];
+        r.px = px;
+        r.qty = tk[5];
+        r.moid = mk[1];
+        r.maid = mk[2];
+      }
+      if (trade && res[5] > 0) put(J_REST).qty = res[5];
+      if (!rejected && !trade) put(act_event(m[0]));
+    }
+    li += nl;
+  }
+  return n;
+}
+
+}  // extern "C"

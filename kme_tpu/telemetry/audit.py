@@ -72,11 +72,23 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 from kme_tpu import opcodes as op
 from kme_tpu.oracle import javalong as jl
+from kme_tpu.telemetry.journal import _ETYPE_IDX
 from kme_tpu.telemetry.registry import N_BUCKETS, bucket_index
 
 _J = dict(sort_keys=True, separators=(",", ":"))
 # journal records that carry timing and no lifecycle
 _TIMING = ("win", "lat", "span")
+_TIMING_IDX = frozenset(_ETYPE_IDX[name] for name in _TIMING)
+# the record types the replay dispatches on, as the journal numbers them
+(_SUBMIT, _ACCEPT, _REST, _FILL, _CANCEL, _CREATE, _TRANSFER, _PAYOUT,
+ _ADD_SYMBOL, _REMOVE_SYMBOL) = (_ETYPE_IDX[name] for name in (
+     "submit", "accept", "rest", "fill", "cancel", "create", "transfer",
+     "payout", "add_symbol", "remove_symbol"))
+_LEDGER = frozenset((_ACCEPT, _REST, _FILL, _CANCEL, _CREATE, _TRANSFER,
+                     _PAYOUT, _ADD_SYMBOL, _REMOVE_SYMBOL))
+# the columns of a record array the replay reads, in _step's order
+_STEP_COLS = ("etype", "act", "b", "seq", "oid", "aid", "sid", "px", "qty",
+              "moid", "maid")
 
 
 class Violation(dict):
@@ -165,26 +177,47 @@ class InvariantAuditor:
     # ------------------------------------------------------------------
     # journal observer entry point
 
-    def observe(self, events: List[dict], lines=None) -> None:
+    def observe(self, events, lines=None) -> None:
         """Replay one journaled batch and run the per-batch checks.
-        Signature matches Journal observer fan-out (events, lines). A
-        fan-out of timing records alone (the journal's "lat", "span"
-        and "win" jobs) is no batch: nothing to replay, nothing counted."""
-        if events and all(ev["e"] in _TIMING for ev in events):
+        Signature matches Journal observer fan-out (events, lines):
+        `events` is a list of event dicts or the journal's EventBatch,
+        whose record array (`.rows`) is replayed as it is. A fan-out of
+        timing records alone (the journal's "lat", "span" and "win"
+        jobs) is no batch: nothing to replay, nothing counted."""
+        rows = getattr(events, "rows", None)
+        if rows is not None:
+            # a commit is of one kind: the first record tells all but
+            # a mixed one apart without a walk
+            timing = (len(rows) and int(rows["etype"][0]) in _TIMING_IDX
+                      and _TIMING_IDX.issuperset(rows["etype"].tolist()))
+        else:
+            timing = events and all(ev["e"] in _TIMING for ev in events)
+        if timing:
             return
         with (self._timer.phase("audit_observe") if self._timer is not None
               else contextlib.nullcontext()):
             self._observe(events, lines)
 
-    def _observe(self, events: List[dict], lines) -> None:
+    def _observe(self, events, lines) -> None:
+        journaled = events
         if self.tamper is not None:
-            events = self.tamper(events)
+            # the hook takes and gives dicts
+            events = self.tamper(list(events))
+        rows = getattr(events, "rows", None)
         with self._lock:
-            batch = next((ev.get("b", -1) for ev in events), -1)
+            if rows is not None:
+                batch = int(rows["b"][0]) if len(rows) else -1
+            else:
+                batch = next((ev.get("b", -1) for ev in events), -1)
             pre = self._hold() if self.repro_dir else None
             found: List[Violation] = []
-            for ev in events:
-                self._apply(ev, found)
+            if rows is not None:
+                for fields in zip(*(rows[name].tolist()
+                                    for name in _STEP_COLS)):
+                    self._step(*fields, found)
+            else:
+                for ev in events:
+                    self._apply(ev, found)
             self._finalize_pending(found)
             self._batch_checks(found, batch)
             self.batches += 1
@@ -197,8 +230,10 @@ class InvariantAuditor:
                 self._counter.inc(len(found))
             dump = None
             if pre is not None and len(self.dumps) < self.max_dumps:
-                dump = self._write_repro(found, batch, _wire(pre), events,
-                                         lines)
+                if lines is None and hasattr(journaled, "lines"):
+                    lines = journaled.lines()   # an EventBatch's buffer
+                dump = self._write_repro(found, batch, _wire(pre),
+                                         list(events), lines)
         if self.on_violation is not None:
             self.on_violation(found, dump)
 
@@ -211,53 +246,58 @@ class InvariantAuditor:
     # event replay (exact fixed-mode arithmetic; see oracle/engine.py)
 
     def _apply(self, ev: dict, out: List[Violation]) -> None:
-        e = ev["e"]
-        if e in ("win", "lat", "drop", "reject"):
-            return      # timing/terminal records — no ledger effect
-        if e == "submit":
+        """The dict feeder: one event dict's fields into _step."""
+        get = ev.get
+        self._step(_ETYPE_IDX.get(ev["e"], -1), get("act", 0),
+                   get("b", -1), get("seq", -1), get("oid", 0),
+                   get("aid", 0), get("sid", 0), get("px", 0),
+                   get("qty", 0), get("moid", 0), get("maid", 0), out)
+
+    def _step(self, e, act, b, seq, oid, aid, sid, px, qty, moid, maid,
+              out: List[Violation]) -> None:
+        """One journal record's ledger effect, by its fields (_STEP_COLS:
+        a row of an EventBatch's array, or _apply's reading of a dict)."""
+        if e == _SUBMIT:
             self._finalize_pending(out)
             return
-        b, seq = ev.get("b", -1), ev.get("seq", -1)
+        if e not in _LEDGER:
+            return      # reject, drop, timing records — no ledger effect
 
         def bad(kind, detail):
             out.append(Violation(kind, detail, b, seq))
 
-        aid, sid = ev.get("aid", 0), ev.get("sid", 0)
-        qty, px = ev.get("qty", 0), ev.get("px", 0)
-        if e == "create":
+        if e == _ACCEPT:
+            self._accept(oid, aid, sid, px, qty, act, bad)
+        elif e == _FILL:
+            self._fill(oid, aid, sid, px, qty, act, moid, maid, bad)
+        elif e == _REST:
+            self._rest(oid, qty, bad)
+        elif e == _CANCEL:
+            self._cancel(oid, aid, bad)
+        elif e == _CREATE:
             if aid in self.balances:
                 bad("create_dup", f"aid={aid} already exists")
             else:
                 self._set_bal(aid, 0)
-        elif e == "transfer":
+        elif e == _TRANSFER:
             bal = self.balances.get(aid)
             if bal is None or bal < jl.jint(-qty):
                 bad("transfer_overdraw",
                     f"aid={aid} bal={bal} transfer={qty}")
             self._set_bal(aid, jl.jadd(bal or 0, qty))
             self.inflow += qty
-        elif e == "add_symbol":
+        elif e == _ADD_SYMBOL:
             if sid in self.books:
                 bad("addsym_dup", f"sid={sid}")
             else:
                 self.books[sid] = ({}, {})
-        elif e == "accept":
-            self._accept(ev, bad)
-        elif e == "fill":
-            self._fill(ev, bad)
-        elif e == "rest":
-            self._rest(ev, bad)
-        elif e == "cancel":
-            self._cancel(ev, bad)
-        elif e in ("payout", "remove_symbol"):
-            self._settle(ev, e == "payout", bad)
+        else:
+            self._settle(sid, qty, e == _PAYOUT, bad)
 
-    def _accept(self, ev, bad) -> None:
-        aid, sid = ev["aid"], ev["sid"]
-        qty, px = ev["qty"], ev["px"]
-        is_buy = ev["act"] == op.BUY
+    def _accept(self, oid, aid, sid, px, qty, act, bad) -> None:
+        is_buy = act == op.BUY
         if sid not in self.books:
-            bad("accept_no_book", f"oid={ev['oid']} sid={sid}")
+            bad("accept_no_book", f"oid={oid} sid={sid}")
         # checkBalance (KProcessor.java:167-182) in fixed mode
         sz = jl.jint(qty if is_buy else -qty)
         pos = self.positions.get((aid, sid))
@@ -270,21 +310,18 @@ class InvariantAuditor:
         bal = self.balances.get(aid)
         if bal is None or bal < risk:
             bad("margin_overdraw",
-                f"oid={ev['oid']} aid={aid} bal={bal} risk={risk}")
+                f"oid={oid} aid={aid} bal={bal} risk={risk}")
         self._set_bal(aid, jl.jadd(bal or 0, -risk))
         if not is_buy and px > 100:
             self._unbounded_credit = True   # negative risk is legal here
         if adj != 0 and pos is not None:
             self.positions[(aid, sid)] = (pos[0], jl.jadd(avail, -adj))
-        self._pending = {"oid": ev["oid"], "aid": aid, "sid": sid,
+        self._pending = {"oid": oid, "aid": aid, "sid": sid,
                          "is_buy": is_buy, "px": px, "rem": qty,
                          "nf": 0, "rested": False}
 
-    def _fill(self, ev, bad) -> None:
-        oid, aid = ev["oid"], ev["aid"]
-        moid, maid = ev["moid"], ev["maid"]
-        sid, qty, px = ev["sid"], ev["qty"], ev["px"]
-        taker_bought = ev["act"] == op.BOUGHT
+    def _fill(self, oid, aid, sid, px, qty, act, moid, maid, bad) -> None:
+        taker_bought = act == op.BOUGHT
         rec = self.orders.get(moid)
         if rec is None or rec[0] != maid:
             bad("fill_unknown_maker", f"moid={moid} maid={maid}")
@@ -338,9 +375,8 @@ class InvariantAuditor:
             bal = 0
         self._set_bal(aid, jl.jadd(bal, jl.jint(sz * price)))
 
-    def _rest(self, ev, bad) -> None:
+    def _rest(self, oid, qty, bad) -> None:
         p = self._pending
-        oid, qty = ev["oid"], ev["qty"]
         if p is None or p["oid"] != oid:
             bad("rest_mismatch", f"oid={oid} rested without accept")
             return
@@ -354,8 +390,7 @@ class InvariantAuditor:
         self.orders[oid] = (p["aid"], p["sid"], p["is_buy"], p["px"],
                             qty)
 
-    def _cancel(self, ev, bad) -> None:
-        oid, aid = ev["oid"], ev["aid"]
+    def _cancel(self, oid, aid, bad) -> None:
         rec = self.orders.get(oid)
         if rec is None or rec[0] != aid:
             bad("cancel_unknown", f"oid={oid} aid={aid}")
@@ -364,11 +399,10 @@ class InvariantAuditor:
         self._release(rec, bad)
         self._depth_obs += 1
 
-    def _settle(self, ev, credit, bad) -> None:
+    def _settle(self, sid, qty, credit, bad) -> None:
         """payout / remove_symbol: wipe both book sides min-price-first
         FIFO with margin release (the fixed-mode removeAllOrders), then
         for a YES payout credit `amount * size` per position."""
-        sid = ev["sid"]
         s = abs(sid)
         book = self.books.pop(s, None)
         if book is None:
@@ -380,8 +414,7 @@ class InvariantAuditor:
                     rec = self.orders.pop(oid, None)
                     if rec is not None:
                         self._release(rec, bad)
-        if credit and ev["sid"] >= 0:
-            qty = ev["qty"]
+        if credit and sid >= 0:
             for key in [k for k in self.positions if k[1] == s]:
                 amt, _avail = self.positions.pop(key)
                 bal = self.balances.get(key[0])

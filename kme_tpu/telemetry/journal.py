@@ -55,6 +55,7 @@ independent replay of the same input stream — `kme-trace --verify`.
 from __future__ import annotations
 
 import contextlib
+import functools
 import json
 import os
 import struct
@@ -92,6 +93,25 @@ MAGIC = b"KMEJRNL1"
 # qty, moid, maid
 _REC = struct.Struct("<BBBBiii10q")
 REC_SIZE = _REC.size            # 96 bytes
+_REC_FIELDS = (("etype", "u1"), ("rej", "u1"), ("sh", "u1"), ("pad", "u1"),
+               ("act", "<i4"), ("b", "<i4"), ("i", "<i4"),
+               *((name, "<i8") for name in (
+                   "seq", "ts", "off", "oid", "aid", "sid", "px", "qty",
+                   "moid", "maid")))
+
+
+@functools.lru_cache(maxsize=None)
+def rec_dtype():
+    """_REC as a numpy structured dtype, field for field: a record
+    array of it is the file's bytes (numpy is imported on first use:
+    the telemetry package stays importable without it)."""
+    import numpy as np
+
+    dt = np.dtype(list(_REC_FIELDS))
+    if dt.itemsize != REC_SIZE:  # pragma: no cover - layout guard
+        raise AssertionError("record dtype does not match _REC")
+    return dt
+
 
 _WIN_KINDS = ("submit", "collect")
 
@@ -152,6 +172,61 @@ def batch_events(lines_per_msg: Sequence[Sequence[str]],
         else:
             evs.append(dict(base, e=_ACT_EVENT.get(act, "accept")))
     return evs
+
+
+def buffer_lines(buf, line_off, msg_lines) -> List[List[str]]:
+    """A collected batch's reconstruction buffer (`session.collect`:
+    the records back to back, n + 1 line offsets, lines per message)
+    -> the per-message line lists batch_events takes."""
+    text = buf.decode("ascii")
+    lo = line_off.tolist()
+    out, li = [], 0
+    for nl in msg_lines.tolist():
+        out.append([text[lo[li + k]:lo[li + k + 1]] for k in range(nl)])
+        li += nl
+    return out
+
+
+def buffer_rows(buf, line_off, msg_lines, reasons, offsets, seq0: int,
+                ts: int, b: int, sh: int):
+    """The same buffer -> the batch's stamped records as one record
+    array (rec_dtype), by one native walk over the bytes
+    (kme_wire.cpp kme_journal_rows): what batch_events, _commit's
+    stamps and _encode would give, byte for byte. None where the
+    library is absent, an argument is not what the call reads, or a
+    line is not of put_order's shape — the caller then derives the
+    batch from buffer_lines."""
+    import numpy as np
+
+    from kme_tpu.native import BoundaryError, check_buffer, load_library
+
+    lib = load_library()
+    if lib is None or not (0 <= sh <= 255 and -2**31 <= b < 2**31):
+        return None
+    nmsg, n_lines = len(msg_lines), len(line_off) - 1
+    try:
+        check_buffer("line_off", line_off, np.int64, 1)
+        check_buffer("msg_lines", msg_lines, np.int32)
+        cols = []
+        for name, col in (("reasons", reasons), ("offsets", offsets)):
+            if col is not None:
+                col = check_buffer(
+                    name, np.ascontiguousarray(col, np.int64), np.int64,
+                    nmsg)
+            cols.append(col)
+    except (BoundaryError, TypeError, ValueError, OverflowError):
+        return None
+    if nmsg and (int(msg_lines.min()) < 1
+                 or int(msg_lines.sum()) != n_lines):
+        return None     # the walk reads line_off by msg_lines
+    if not isinstance(buf, bytes):
+        buf = bytes(buf)
+    rows = np.empty(n_lines + nmsg, rec_dtype())
+    n = lib.kme_journal_rows(
+        buf, len(buf), line_off.ctypes.data, nmsg, msg_lines.ctypes.data,
+        *(None if col is None else col.ctypes.data for col in cols),
+        seq0, ts, b, sh, rows.ctypes.data)
+    return rows[:n] if n >= 0 else None
 
 
 def canonical_events(events: Iterable[dict]) -> List[dict]:
@@ -254,8 +329,13 @@ def _encode(ev: dict) -> bytes:
 
 
 def _decode(buf: bytes) -> dict:
+    return _event(_REC.unpack(buf))
+
+
+def _event(fields: tuple) -> dict:
+    """One record's fields, in _REC's order, as its event dict."""
     (e, rej, sh, _pad, act, b, i, seq, ts, off, oid, aid, sid, px, qty,
-     moid, maid) = _REC.unpack(buf)
+     moid, maid) = fields
     name = ETYPES[e]
     ev = {"e": name, "seq": seq, "ts": ts, "b": b, "sh": sh}
     if name == "win":
@@ -279,6 +359,32 @@ def _decode(buf: bytes) -> dict:
     if name == "reject":
         ev["rej"] = rej
     return ev
+
+
+class EventBatch:
+    """One commit as its observers get it where the journal made the
+    records as an array: `.rows` is that array (rec_dtype; what the
+    file took). `len()` and iteration give the event dicts _decode
+    gives for those records — made when something first iterates — and
+    `lines()` the batch's wire line groups where it came from a
+    collected buffer, made from the buffer when asked."""
+
+    def __init__(self, rows, buffer=None) -> None:
+        self.rows = rows
+        self._buffer = buffer   # (buf, line_off, msg_lines)
+        self._events: Optional[List[dict]] = None
+
+    def __len__(self) -> int:
+        return len(self.rows)
+
+    def __iter__(self) -> Iterator[dict]:
+        if self._events is None:
+            self._events = [_event(t) for t in self.rows.tolist()]
+        return iter(self._events)
+
+    def lines(self) -> Optional[List[List[str]]]:
+        return (buffer_lines(*self._buffer)
+                if self._buffer is not None else None)
 
 
 # ---------------------------------------------------------------------------
@@ -345,12 +451,21 @@ class Journal:
     Observers (`observers.append(fn)`) are called as fn(events,
     lines_per_msg) after each batch commits — the invariant auditor
     subscribes here and thus runs on the writer thread in async mode.
+    `events` is a list of event dicts where the commit started from
+    dicts or lines, and an EventBatch (`.rows`; iteration gives the
+    same dicts; `lines()`) where the journal made the records as an
+    array (record_buffer, record_latency_columns on a binary journal):
+    `lines_per_msg` is None then.
 
     timer: the PhaseTimer of whoever records (the service's); a commit
-    then is two of its spans, `journal_events` (a batch's derivation
-    from its wire lines) and `journal_write` (encode, write, fsync: of
-    every job, the latency stamps' too). `events_written` and
-    `bytes_written` count what reached the file.
+    then is up to three of its spans: `journal_lines` (a collected
+    buffer turned into what the journal takes: the native walk to
+    records, or the lines where that is not taken), `journal_events`
+    (a batch's derivation from wire lines) and `journal_write` (encode
+    where there are dicts, write, fsync: of every job, the latency
+    stamps' too). `events_written` and `bytes_written` count what
+    reached the file, `native_batches` the batches whose records the
+    native walk made.
     """
 
     def __init__(self, path: str, fmt: Optional[str] = None,
@@ -385,6 +500,7 @@ class Journal:
         self._span = (timer.phase if timer is not None
                       else lambda _name: contextlib.nullcontext())
         self.events_written = self.bytes_written = 0
+        self.native_batches = 0
         self._clock = clock or (lambda: __import__("time").time_ns()
                                 // 1000)
         self._seq = 0
@@ -459,6 +575,18 @@ class Journal:
                   for ln in lines)
         self._submit(job, est)
 
+    def record_buffer(self, buf, line_off, msg_lines, reasons=None,
+                      offsets=None) -> None:
+        """record_batch for a batch as `session.collect` returned it
+        (buffer_lines' arguments). On a binary journal with the native
+        library its records are made once, as an array, by one walk
+        over the buffer (buffer_rows), and the file, the counters and
+        the observers (an EventBatch) read that array; otherwise — and
+        for a buffer the walk refuses — the lines are made and the
+        batch goes record_batch's way."""
+        self._submit(("buffer", buf, line_off, msg_lines, reasons,
+                      offsets), len(buf))
+
     def record_window(self, kind: str, t0: float, t1: float,
                       batch: Optional[int] = None) -> None:
         """Record one pipeline overlap window (submit or collect):
@@ -478,6 +606,34 @@ class Journal:
         job = ("lat", tuple(dict(e) for e in entries),
                -1 if batch is None else batch)
         self._submit(job, REC_SIZE * len(entries))
+
+    def record_latency_columns(self, off, oid, in_us, plan_us, dev_us,
+                               prod_us, e2e_us,
+                               batch: Optional[int] = None) -> None:
+        """record_latency from columns (arrays or scalars, one row an
+        order): on a binary journal the records are filled column by
+        column and committed as they are; a jsonl journal gets the
+        entries."""
+        import numpy as np
+
+        n = len(off)
+        if self.fmt != "binary":
+            cols = [np.broadcast_to(c, n).tolist() for c in (
+                off, oid, in_us, plan_us, dev_us, prod_us, e2e_us)]
+            keys = ("off", "oid", "in_us", "plan_us", "dev_us",
+                    "prod_us", "e2e_us")
+            return self.record_latency(
+                [dict(zip(keys, row)) for row in zip(*cols)], batch)
+        rows = np.zeros(n, rec_dtype())
+        rows["etype"] = _ETYPE_IDX["lat"]
+        rows["b"] = -1 if batch is None else batch
+        rows["i"] = -1
+        # the stage durations ride the spare int64 slots, as in _encode
+        for name, col in (("off", off), ("oid", oid), ("aid", in_us),
+                          ("sid", plan_us), ("px", dev_us),
+                          ("qty", prod_us), ("moid", e2e_us)):
+            rows[name] = col
+        self._submit(("columns", rows), rows.nbytes)
 
     def record_spans(self, entries: Sequence[dict],
                      batch: Optional[int] = None) -> None:
@@ -534,41 +690,87 @@ class Journal:
     def _commit(self, job) -> None:
         with self._lock:
             ts = self._clock()
-            lines = None
-            if job[0] == "batch":
-                _, lines, reasons, offsets, drops = job
-                with self._span("journal_events"):
-                    events = batch_events(lines, reasons, offsets, drops)
-                b = self._batch
-                self._batch += 1
-            elif job[0] == "win":
-                _, kind, t0, t1, b = job
-                events = [{"e": "win", "kind": kind, "t0": t0,
-                           "t1": t1}]
-            elif job[0] == "lat":
-                _, entries, b = job
-                events = [dict(ev, e="lat") for ev in entries]
-            elif job[0] == "span":
-                _, entries, b = job
-                events = [dict(ev, e="span") for ev in entries]
+            if job[0] == "buffer":
+                job = self._buffer_job(job, ts)
+            elif job[0] == "columns":
+                import numpy as np
+
+                rows = job[1]
+                rows["seq"] = np.arange(self._seq, self._seq + len(rows))
+                rows["ts"] = ts
+                rows["sh"] = self.shard
+                job = ("rows", rows, None)
+            lines = job[1] if job[0] == "batch" else None
+            if job[0] == "rows":
+                events = self._commit_rows(*job[1:])
             else:
-                _, events = job
-                b = self._batch
-                self._batch += 1
-            for ev in events:
-                ev.setdefault("b", b)
-                ev["seq"] = self._seq
-                self._seq += 1
-                ev["ts"] = ts
-                ev["sh"] = self.shard
-            with self._span("journal_write"):
-                self._write(events)
-            for ev in events:
-                off = ev.get("off", -1)
-                if off is not None and off > self.last_offset:
-                    self.last_offset = off
+                events = self._commit_dicts(job, ts)
         for obs in self.observers:
             obs(events, lines)
+
+    def _buffer_job(self, job, ts: int) -> tuple:
+        """A collected buffer as the job its commit runs: its records
+        as an array where the native walk gives them (the batch id and
+        the counter step here), else its lines as a "batch" job."""
+        _, buf, line_off, msg_lines, reasons, offsets = job
+        if self.fmt == "binary":
+            with self._span("journal_lines"):
+                rows = buffer_rows(buf, line_off, msg_lines, reasons,
+                                   offsets, self._seq, ts, self._batch,
+                                   self.shard)
+            if rows is not None:
+                self._batch += 1
+                self.native_batches += 1
+                return "rows", rows, (buf, line_off, msg_lines)
+        with self._span("journal_lines"):
+            lines = buffer_lines(buf, line_off, msg_lines)
+        return "batch", lines, reasons, offsets, ()
+
+    def _commit_rows(self, rows, buffer) -> EventBatch:
+        """Stamped records to the file as they are."""
+        self._seq += len(rows)
+        with self._span("journal_write"):
+            self._write_blob(memoryview(rows).cast("B"), len(rows))
+        if len(rows):
+            self.last_offset = max(self.last_offset,
+                                   int(rows["off"].max()))
+        return EventBatch(rows, buffer)
+
+    def _commit_dicts(self, job, ts: int) -> List[dict]:
+        """The jobs that start from lines or dicts: derive, stamp,
+        encode, write."""
+        if job[0] == "batch":
+            _, lines, reasons, offsets, drops = job
+            with self._span("journal_events"):
+                events = batch_events(lines, reasons, offsets, drops)
+            b = self._batch
+            self._batch += 1
+        elif job[0] == "win":
+            _, kind, t0, t1, b = job
+            events = [{"e": "win", "kind": kind, "t0": t0, "t1": t1}]
+        elif job[0] == "lat":
+            _, entries, b = job
+            events = [dict(ev, e="lat") for ev in entries]
+        elif job[0] == "span":
+            _, entries, b = job
+            events = [dict(ev, e="span") for ev in entries]
+        else:
+            _, events = job
+            b = self._batch
+            self._batch += 1
+        for ev in events:
+            ev.setdefault("b", b)
+            ev["seq"] = self._seq
+            self._seq += 1
+            ev["ts"] = ts
+            ev["sh"] = self.shard
+        with self._span("journal_write"):
+            self._write(events)
+        for ev in events:
+            off = ev.get("off", -1)
+            if off is not None and off > self.last_offset:
+                self.last_offset = off
+        return events
 
     def _write(self, events: List[dict]) -> None:
         if self.fmt == "binary":
@@ -578,6 +780,11 @@ class Journal:
                 json.dumps(ev, sort_keys=True,
                            separators=(",", ":")) + "\n"
                 for ev in events).encode()
+        self._write_blob(blob, len(events))
+
+    def _write_blob(self, blob, n_events: int) -> None:
+        """`blob` (bytes, or a flat byte view of a record array) to the
+        file, with the fsync policy and the rotation."""
         from kme_tpu import faults
 
         if faults.should("journal.torn"):
@@ -592,7 +799,7 @@ class Journal:
             os.fsync(self._f.fileno())
             os.kill(os.getpid(), _sig.SIGKILL)
         self._f.write(blob)
-        self.events_written += len(events)
+        self.events_written += n_events
         self.bytes_written += len(blob)
         if self.fsync == "batch":
             self._f.flush()

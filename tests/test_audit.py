@@ -5,15 +5,19 @@ its repro dumps reproduce offline."""
 
 import json
 
+import numpy as np
 import pytest
 
-from kme_tpu.bridge.broker import InProcessBroker
+from kme_tpu.bridge.broker import InProcessBroker, line_offsets
 from kme_tpu.bridge.provision import provision
 from kme_tpu.bridge.service import TOPIC_IN, MatchService
 from kme_tpu.telemetry import Registry
 from kme_tpu.telemetry.audit import (InvariantAuditor, load_repro,
                                      replay_repro)
-from kme_tpu.telemetry.journal import batch_events, oracle_events
+from kme_tpu.native import load_library
+from kme_tpu.telemetry.journal import (EventBatch, Journal, _decode, _encode,
+                                       batch_events, oracle_events,
+                                       rec_dtype)
 from kme_tpu.oracle import OracleEngine
 from kme_tpu.wire import dumps_order, parse_order
 from kme_tpu.workload import harness_stream
@@ -229,3 +233,125 @@ def test_audit_requires_journal():
     with pytest.raises(ValueError, match="journal"):
         MatchService(broker, engine="oracle", compat="fixed",
                      audit=True)
+
+
+# ---------------------------------------------------------------------------
+# one arithmetic, two feeders (PR 51): a record array replayed as it is
+# against the same records as event dicts
+
+
+def _rows(evs):
+    """Event dicts as the journal's record array holds them."""
+    return EventBatch(np.frombuffer(
+        b"".join(_encode(ev) for ev in evs), rec_dtype()).copy())
+
+
+def _shadow(aud):
+    return (aud.balances, aud.positions, aud.orders, aud.books,
+            aud.inflow, aud._fills_hist, aud._depth_obs, aud._sid_sum,
+            aud._unbalanced, aud._bal_total, aud._unbounded_credit,
+            aud.batches)
+
+
+# (event type, field, what is added to it) of the first such event
+PLANTED = {
+    "clean": None,
+    "fill_qty": ("fill", "qty", 1),         # test_tampered_fill_qty_...
+    "fill_qty2": ("fill", "qty", 2),        # test_repro_dump_replays_...
+    "fill_px": ("fill", "px", 1),           # ..._balance_conjuring_...
+    "fill_maker": ("fill", "moid", 10 ** 6),
+    "fill_taker": ("fill", "oid", 10 ** 6),
+    "rest_qty": ("rest", "qty", 3),
+    "accept_aid": ("accept", "aid", 10 ** 6),
+    "accept_book": ("accept", "sid", 10 ** 4),
+    "cancel_aid": ("cancel", "aid", 1),
+    "transfer": ("transfer", "qty", -10 ** 12),
+    # these two on a stream with settlements
+    "payout_clean": None,
+    "payout_book": ("payout", "sid", 10 ** 4),
+}
+
+
+@pytest.mark.parametrize("fault", sorted(PLANTED))
+def test_row_feeder_is_the_dict_feeder(fault, tmp_path):
+    """The shadow and the violations — kind, detail, batch, seq — after
+    every batch, fed as dicts and fed as an EventBatch's rows."""
+    if fault.startswith("payout"):
+        from kme_tpu.workload import zipf_symbol_stream
+
+        evs = oracle_events([dumps_order(m) for m in zipf_symbol_stream(
+            900, num_symbols=4, num_accounts=8, seed=4,
+            payout_per_mille=30)])
+        batches = [[dict(ev, b=lo // 90) for ev in evs
+                    if lo <= ev["off"] < lo + 90]
+                   for lo in range(0, 1000, 90)]
+    else:
+        _, batches = _event_batches(700, seed=23)
+    seq = 0
+    for evs in batches:
+        for ev in evs:
+            ev.update(seq=seq, ts=5, sh=0)
+            seq += 1
+    planted = PLANTED[fault]
+    if planted is not None:
+        e, field, by = planted
+        ev = next(ev for evs in batches for ev in evs if ev["e"] == e)
+        ev[field] += by
+    by_dicts = InvariantAuditor(repro_dir=str(tmp_path / "d"))
+    by_rows = InvariantAuditor(repro_dir=str(tmp_path / "r"))
+    for evs in batches:
+        by_dicts.observe(evs)
+        by_rows.observe(_rows(evs))
+        assert _shadow(by_rows) == _shadow(by_dicts)
+    assert by_rows.violations == by_dicts.violations
+    assert bool(by_dicts.violations) == (planted is not None)
+    assert len(by_rows.dumps) == len(by_dicts.dumps)
+    for a, b in zip(by_rows.dumps, by_dicts.dumps):
+        # a dump's events are the dicts the records decode to
+        doc = load_repro(b)
+        doc["events"] = [_decode(_encode(ev)) for ev in doc["events"]]
+        assert load_repro(a) == doc
+        assert replay_repro(a) == replay_repro(b) != []
+
+
+@pytest.mark.skipif(load_library() is None,
+                    reason="native host runtime unavailable")
+def test_repro_dump_from_a_buffer_is_the_one_from_lines(tmp_path):
+    """A journal fed buffers hands its auditor EventBatches, one fed
+    the same batches as lines hands dicts and the lines: a lying line
+    (one taker fill a contract too large) gives the same violations and
+    the same repro dump, inputs included, and both reproduce."""
+    msgs = harness_stream(500, seed=21, num_accounts=8, num_symbols=3,
+                          payout_opcode_bug=False, validate=True)
+    eng = OracleEngine("fixed")
+    groups = [[r.wire() for r in eng.process(m)] for m in msgs]
+    g = next(g for g in groups[120:] if len(g) >= 4)
+    key, _, val = g[2].partition(" ")
+    tk = parse_order(val)
+    tk.size += 1
+    g[2] = f"{key} {dumps_order(tk)}"
+    auds = []
+    for name in ("buffers", "lines"):
+        j = Journal(str(tmp_path / f"{name}.kmej"), clock=lambda: 9)
+        aud = InvariantAuditor(repro_dir=str(tmp_path / name))
+        j.observers.append(aud.observe)
+        for lo in range(0, len(groups), 60):
+            part = groups[lo:lo + 60]
+            offs = list(range(lo, lo + len(part)))
+            if name == "lines":
+                j.record_batch(part, offsets=offs)
+                continue
+            flat = [ln for grp in part for ln in grp]
+            j.record_buffer("".join(flat).encode(), line_offsets(flat),
+                            np.array([len(grp) for grp in part], np.int32),
+                            None, offs)
+        j.close()
+        assert j.native_batches == (name == "buffers") * -(-len(groups) // 60)
+        auds.append(aud)
+    rows, dicts = auds
+    assert rows.violations == dicts.violations != []
+    assert _shadow(rows) == _shadow(dicts)
+    assert len(rows.dumps) == len(dicts.dumps) >= 1
+    for a, b in zip(rows.dumps, dicts.dumps):
+        assert load_repro(a) == load_repro(b)
+        assert load_repro(a)["inputs"] and replay_repro(a)

@@ -181,6 +181,37 @@ def test_auditor_saw_every_batch_and_found_nothing(served):
         == 96 * t.counter("journal_events").value > 0
     if served["how"]["pipeline"]:
         assert gauges["journal_lines_n"] == batches
+    # a pipelined batch's records are made once, by the native walk
+    # over its buffer; the serial path hands the journal lines
+    assert t.counter("journal_native_batches").value \
+        == (batches if served["how"]["pipeline"] else 0)
+
+
+def test_rows_and_dicts_replay_the_served_journal_alike(served):
+    """The journal as served, batch by batch, into a fresh auditor as
+    the file's own records (the row feeder: what the pipelined leader's
+    auditor was fed) and as the event dicts they decode to (the dict
+    feeder: the serial leader's): one shadow, the served auditor's."""
+    from kme_tpu.telemetry.journal import (ETYPES, MAGIC, EventBatch,
+                                           rec_dtype)
+
+    rows = np.fromfile(served["journal"], rec_dtype(), offset=len(MAGIC))
+    evs = read_events(served["journal"])
+    assert len(rows) == len(evs) > 0
+    life = rows["etype"] < ETYPES.index("win")
+    cuts = np.flatnonzero(np.diff(rows["b"]) | np.diff(life)) + 1
+    by_rows, by_dicts = InvariantAuditor(), InvariantAuditor()
+    for lo, hi in zip([0, *cuts], [*cuts, len(rows)]):
+        by_rows.observe(EventBatch(rows[lo:hi]))
+        by_dicts.observe(evs[lo:hi])
+    assert by_rows.batches == by_dicts.batches >= len(served["msgs"]) // BATCH
+    assert by_rows.violations == by_dicts.violations == []
+    for name in ("balances", "positions", "orders", "books", "inflow",
+                 "_fills_hist", "_depth_obs", "_sid_sum", "_bal_total"):
+        assert getattr(by_rows, name) == getattr(by_dicts, name), name
+    live = served["svc"].auditor
+    assert (by_rows.balances, by_rows.positions, by_rows.orders) \
+        == (live.balances, live.positions, live.orders)
 
 
 def test_compare_reads_what_the_snapshot_fetched(served):
@@ -245,8 +276,12 @@ def test_planted_fault_reads_alike_in_both_forms(fault, served):
         == [(v["kind"], v["detail"]) for v in old]
 
 
-def test_tampered_fill_trips_on_the_seq_engine(tmp_path, monkeypatch):
-    monkeypatch.setenv("KME_AUDIT_TAMPER", "fill_qty")
+@pytest.mark.parametrize("drill", ["fill_qty", "journal_fill_qty"])
+def test_tampered_fill_trips_on_the_seq_engine(drill, tmp_path, monkeypatch):
+    """The auditor's feed tampered (`fill_qty`) or the journal's lines
+    (`journal_fill_qty`: the one thing that takes a pipelined batch off
+    the native walk)."""
+    monkeypatch.setenv("KME_AUDIT_TAMPER", drill)
     msgs = stream(events=600)
     broker = InProcessBroker()
     provision(broker)
@@ -260,10 +295,39 @@ def test_tampered_fill_trips_on_the_seq_engine(tmp_path, monkeypatch):
     assert svc.auditor.dumps
     assert svc.auditor.dumps[0].startswith(str(tmp_path / "state" / "repro"))
     assert replay_repro(svc.auditor.dumps[0])
+    t = svc.telemetry
+    assert t.counter("journal_native_batches").value == (
+        t.counter("service_batches").value if drill == "fill_qty" else 0)
     # MatchOut is untouched: the tamper is in the auditor's feed
     eng = NativeOracleEngine("fixed", book_slots=1024, max_fills=FILLS)
     assert list(consume_lines(broker, follow=False)) == [
         ln for g in eng.process_wire(msgs) for ln in g]
+
+
+def test_a_watch_leaves_the_journal_its_buffer(tmp_path):
+    """`--watch` reads lines it makes itself: the journal of a watched
+    pipelined leader still takes every batch as its buffer, and the
+    watch fires what it fires on the oracle's leader."""
+    exprs = ["depth[1]>=2", "position[2,1]>0"]
+    msgs = stream(events=600)
+    hits = []
+    for seq in (True, False):
+        broker = InProcessBroker()
+        provision(broker)
+        for m in msgs:
+            broker.produce(TOPIC_IN, None, dumps_order(m))
+        svc = (service(broker, tmp_path, watch=exprs, **RUNS["pipelined"])
+               if seq else MatchService(broker, engine="oracle",
+                                        compat="fixed", batch=BATCH,
+                                        watch=exprs))
+        assert svc.run(max_messages=len(msgs)) == len(msgs)
+        svc.close()
+        hits.append(list(svc.watch.hits))
+        if seq:
+            t = svc.telemetry
+            assert t.counter("journal_native_batches").value \
+                == t.counter("service_batches").value > 0
+    assert hits[0] == hits[1] != []
 
 
 class BothPasses(InvariantAuditor):
