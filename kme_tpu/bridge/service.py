@@ -88,6 +88,38 @@ _FETCH_COUNTERS = {
 }
 
 
+class _SnapshotWriter:
+    """One snapshot on its way to the disk beside the serve loop: a
+    thread that runs `write` (MatchService._snapshot_save over a
+    captured boundary) from the moment it is made. At most one is in
+    flight; the serve thread takes it back with `join`, which raises,
+    there, what the write raised. `write` (and with it the boundary's
+    device state) is the thread's argument and held nowhere else: it
+    goes when the write ends, not when the writer is taken back.
+    A daemon: a process that dies without close() leaves a `.tmp`
+    behind like any crash inside the write, and is not kept alive."""
+
+    def __init__(self, write) -> None:
+        import threading
+
+        self._error = None
+        self._thread = threading.Thread(
+            target=self._run, args=(write,), name="kme-snapshot-writer",
+            daemon=True)
+        self._thread.start()
+
+    def _run(self, write) -> None:
+        try:
+            write()
+        except BaseException as e:      # raised again by join()
+            self._error = e
+
+    def join(self) -> None:
+        self._thread.join()
+        if self._error is not None:
+            raise self._error
+
+
 class MatchService:
     # the spans that PARTITION one iteration of the serve loop (names of
     # its PhaseTimer): what is left of the loop's wall after their sum
@@ -95,8 +127,16 @@ class MatchService:
     LOOP_SPANS = ("poll_wait", "parse_batch", "session_submit",
                   "session_collect", "process_wire", "produce_buffer",
                   "produce_lines", "publish_batch", "checkpoint")
-    # spans nested inside those
+    # spans nested inside those: `checkpoint` holds the drain, the
+    # broker sync, the wait for the snapshot before (where its file is
+    # still being written) and the handoff of this one. And one beside
+    # them: `snapshot_save`, the making of a snapshot's file from start
+    # to durable, on the snapshot writer's thread for a SeqSession (its
+    # three stages are spans of the session's timer) and inside
+    # `snapshot_handoff` for the engines that are saved there; of its
+    # wall, `snapshot_writer_wait` is what the loop waited for
     INNER_SPANS = ("engine_refresh", "checkpoint_drain", "broker_sync",
+                   "snapshot_writer_wait", "snapshot_handoff",
                    "snapshot_save")
     # spans BETWEEN those of LOOP_SPANS: named parts of what
     # loop_other_ms_per_batch reads, which still subtracts LOOP_SPANS
@@ -223,6 +263,9 @@ class MatchService:
         self.checkpoint_every = checkpoint_every
         self.checkpoint_keep = checkpoint_keep
         self._last_ckpt_offset = 0
+        # the snapshot being written beside the loop (_SnapshotWriter),
+        # until the serve thread has taken it back
+        self._snap_writer = None
         self._req_symbols, self._req_accounts = symbols, accounts
         self._req_slots, self._req_max_fills = slots, max_fills
         self._last_engine_pub = 0.0
@@ -711,9 +754,18 @@ class MatchService:
                 registry=self.telemetry)
 
     def close(self) -> None:
-        """Flush + close the flight recorder (serve shutdown path)."""
-        if getattr(self, "_pipe", None):
-            self._drain_pipeline()
+        """Finish what is in flight (the pipeline's batches, the
+        snapshot being written: what its writer raised is raised here),
+        then flush + close the flight recorder (serve shutdown path)."""
+        try:
+            if getattr(self, "_pipe", None):
+                self._drain_pipeline()
+            if getattr(self, "_snap_writer", None) is not None:
+                self._snapshot_writer_wait()
+        finally:
+            self._close_planes()
+
+    def _close_planes(self) -> None:
         if getattr(self, "profiler", None) is not None:
             self.profiler.stop()
         if getattr(self, "_profile_artifact", None) is not None:
@@ -1089,17 +1141,24 @@ class MatchService:
             return
         if self.offset - self._last_ckpt_offset < self.checkpoint_every:
             return
-        self.checkpoint()
+        # on the cadence the loop goes on while the file is written
+        self.checkpoint(wait=False)
 
     def _span(self, name: str, ordinal: Optional[int] = None):
         """One span of the serve loop's timer, tied to its batch."""
         return self._ptimer.phase(
             name, batch=self._batch_ordinal if ordinal is None else ordinal)
 
-    def checkpoint(self) -> None:
-        """Snapshot engine state + input offset (batch boundary)."""
+    def checkpoint(self, wait: bool = True) -> None:
+        """Snapshot engine state + input offset (batch boundary): what
+        the file needs is captured here, and a SeqSession's file is
+        made by the snapshot writer's thread. A caller outside the
+        cadence (shutdown, promotion, a test) returns with the file
+        durable: the same handoff, waited for."""
         with self._span("checkpoint"):
             self._checkpoint()
+            if wait:
+                self._snapshot_writer_wait()
 
     def _checkpoint_drain(self) -> None:
         """A snapshot must capture engine state at a committed offset
@@ -1130,43 +1189,77 @@ class MatchService:
                 return False
         return True
 
-    def _snapshot_save(self, extra: dict) -> list:
-        """Write the engine in effect to a durable snapshot at
-        `self.offset` (runtime/checkpoint.py). -> what the auditor's
-        compare may read in place of a fetch of its own: the
-        (canon, layout) a fixed-mode SeqSession's snapshot fetched,
-        or nothing."""
+    def _snapshot_writer_wait(self) -> None:
+        """Take back the snapshot in flight, if there is one (span
+        `snapshot_writer_wait`): returns once its file is durable, and
+        raises here, on the serve thread, what its writer raised. At
+        most one snapshot is in flight, none is skipped or merged: a
+        boundary that comes due before the file of the last is durable
+        waits here, then hands off."""
+        writer, self._snap_writer = self._snap_writer, None
+        if writer is not None:
+            with self._span("snapshot_writer_wait"):
+                writer.join()
+
+    def _snapshot_save(self, write) -> None:
+        """Make one snapshot's file (span `snapshot_save`, start to
+        durable): `write` is the save of runtime/checkpoint.py that
+        _snapshot_handoff chose, with what it captured."""
+        with self._span("snapshot_save"):
+            write()
+
+    def _snapshot_handoff(self, extra: dict) -> list:
+        """Capture, as of `self.offset`, all that the snapshot's file
+        needs and the loop changes afterwards, and give it to the
+        snapshot writer, which starts at once (span `snapshot_handoff`).
+        A SeqSession's device state goes by reference (the scan is not
+        donated: the boundary's arrays stay valid while the writer
+        holds them), its host state and `extra` by copy
+        (checkpoint.capture_seq_session); the fetch, the host's passes,
+        the digest, the write and the fsyncs are the writer's. The
+        engines no deployment serves (lanes, native, oracle: their
+        state is a LaneSession's or the host's own, a text dump or a
+        pickle that has to be made at the boundary anyway) are saved
+        here, on the serve thread. -> the list that takes what a
+        fixed-mode SeqSession's snapshot fetched, for the auditor's
+        compare, once the file is written."""
+        import functools
+
         from kme_tpu.runtime import checkpoint as ck
+        from kme_tpu.runtime.seqsession import SeqSession
 
         fetched = []
-        with self._span("snapshot_save"):
+        with self._span("snapshot_handoff"):
+            if isinstance(self._session, SeqSession):
+                write = functools.partial(
+                    ck.write_seq_snapshot, self.checkpoint_dir,
+                    ck.capture_seq_session(self._session, self.offset,
+                                           extra),
+                    keep=self.checkpoint_keep,
+                    fetched=fetched if self.auditor is not None else None)
+                self._snap_writer = _SnapshotWriter(
+                    functools.partial(self._snapshot_save, write))
+                return fetched
             if self._session is not None:
-                from kme_tpu.runtime.seqsession import SeqSession
-
-                if isinstance(self._session, SeqSession):
-                    ck.save_seq_session(
-                        self.checkpoint_dir, self._session, self.offset,
-                        keep=self.checkpoint_keep, extra=extra,
-                        fetched=(fetched if self.auditor is not None
-                                 else None))
-                else:
-                    ck.save_session(
-                        self.checkpoint_dir, self._session, self.offset,
-                        keep=self.checkpoint_keep, extra=extra)
+                save, engine = ck.save_session, self._session
             elif self._native is not None:
-                ck.save_native(self.checkpoint_dir, self._native,
-                               self.offset, keep=self.checkpoint_keep,
-                               extra=extra)
+                save, engine = ck.save_native, self._native
             else:
-                ck.save_oracle(self.checkpoint_dir, self._oracle,
-                               self.offset, keep=self.checkpoint_keep,
-                               extra=extra)
+                save, engine = ck.save_oracle, self._oracle
+            self._snapshot_save(functools.partial(
+                save, self.checkpoint_dir, engine, self.offset,
+                keep=self.checkpoint_keep, extra=extra))
         return fetched
 
     def _checkpoint(self) -> None:
         self._checkpoint_drain()
         if not self._broker_sync():
             return
+        # the snapshot before this one, where it is still being written
+        # (it has had the drain and the sync to finish in): after this
+        # line nothing is in flight, so a fence raised below leaves no
+        # writer that renames afterwards
+        self._snapshot_writer_wait()
         # the heartbeat sample cursor rides EVERY snapshot (not just
         # exactly-once leaders'): a resumed service continues the TSDB
         # sequence so replayed heartbeat samples dedup on ingestion
@@ -1200,13 +1293,23 @@ class MatchService:
                 # the transfer LEGS themselves regenerate from MatchIn
                 # replay and dedup on their (epoch, out_seq) stamps
                 extra["pending_reserve"] = dict(self._xfer)
-        fetched = self._snapshot_save(extra)
+        # the epoch was checked directly above and the writer starts
+        # inside the handoff: check-to-rename is no longer than it was
+        # with the save on this thread
+        fetched = self._snapshot_handoff(extra)
+        # the cadence counts from the handoff; what reads "the newest
+        # durable snapshot" reads the directory, which the writer's
+        # rename updates (oldest_retained_offset, recovery)
         self._last_ckpt_offset = self.offset
         if self.journal is not None:
             # the journal is best-effort relative to the broker log, but
             # a snapshot is a natural durability point for it too
             self.journal.flush()
         if self.auditor is not None and self._session is not None:
+            # the compare reads the state at THIS boundary (the
+            # snapshot's own fetch) against a shadow ledger that moves
+            # with the next batch: the loop waits for the file first
+            self._snapshot_writer_wait()
             self._audit_check_engine(fetched)
 
     def _audit_check_engine(self, fetched: list) -> None:
@@ -2359,6 +2462,9 @@ class MatchService:
                     # in-flight batches hold committed-but-invisible
                     # work — finish them before the final heartbeat
                     self._drain_pipeline()
+                # and the snapshot being written: a loop that has
+                # returned (or been fenced out) leaves no writer behind
+                self._snapshot_writer_wait()
             finally:
                 if beat_stop is not None:
                     # the beater may be in the middle of a beat: let it

@@ -404,73 +404,146 @@ def _restore_one(path: str, shards: Optional[int], width: Optional[int]):
     return ses
 
 
-def _snapshot_export(session):
+@dataclasses.dataclass
+class SeqCapture:
+    """A SeqSession as of one batch boundary: all that its snapshot at
+    `offset` needs and the session changes afterwards, so that the file
+    can be made by another thread while the session goes on
+    (capture_seq_session makes one, write_seq_snapshot writes it).
+    `state` is a reference and no copy: the scan is not donated
+    (engine/seq.py:build_seq_step), so a boundary's arrays stay as they
+    are for as long as somebody holds them, and the next submit makes
+    new ones."""
+    session: object         # its cfg, kind and timer; never its state
+    offset: int
+    extra: Optional[dict]
+    state: object
+    metrics: np.ndarray
+    hist: np.ndarray
+    router: object          # SeqRouter.capture(): the id maps, a call
+
+
+def capture_seq_session(session, offset: int,
+                        extra: Optional[dict] = None) -> SeqCapture:
+    """The session's side of a snapshot, on the session's own thread at
+    a batch boundary: a reference to the device state, copies of the
+    host state (the counters; the router's three id maps as they come
+    out of it, unsorted). What costs more — the fetch, the host's
+    passes, the maps' sorting, the meta's JSON, the digest and the
+    write — is write_seq_snapshot's."""
+    return SeqCapture(
+        session=session, offset=int(offset),
+        extra=dict(extra) if extra else None, state=session.state,
+        metrics=session._metrics.copy(), hist=session._hist.copy(),
+        router=session.router.capture())
+
+
+def _snapshot_export(session, state):
     """The device -> host half of a seq snapshot (span
-    `snapshot_export` of the session's timer): (arrays, layout, fetch),
-    with `layout` None where the arrays are dense throughout and
-    `fetch` what engine/seq.py:export_snapshot says crossed (a
-    fixed-mode SeqSession's books cross by their live rows and its
-    positions by their live entries, both gathered on the device;
-    every other export brings its planes whole and says nothing)."""
+    `snapshot_export` of the session's timer) from `state`, the
+    session's as of the boundary: (arrays, layout, fetch), with
+    `layout` None where the arrays are dense throughout and `fetch`
+    what engine/seq.py:export_snapshot says crossed (a fixed-mode
+    SeqSession's books cross by their live rows and its positions by
+    their live entries, both gathered on the device; every other
+    export brings its planes whole and says nothing)."""
     from kme_tpu.runtime.seqsession import SeqSession
 
     with session.timer.phase("snapshot_export"):
         if session.cfg.compat == "java":
             from kme_tpu.runtime.javasnap import export_seqjava_device
 
-            return export_seqjava_device(session), None, {}
+            return export_seqjava_device(session.cfg, state), None, {}
         from kme_tpu.engine import seq as SQ
 
         if type(session) is SeqSession:
-            return SQ.export_snapshot(session.cfg, session.state)
+            return SQ.export_snapshot(session.cfg, state)
         # a subclass keeps its state elsewhere (SeqMeshSession: sharded
         # across devices) and its dense export
-        return SQ.export_canonical(session.cfg, session.state), None, {}
+        return SQ.export_canonical(session.cfg, state), None, {}
 
 
-def _snapshot_payload(session, kind: str, offset: int,
-                      extra: Optional[dict], arrays: dict,
+def _snapshot_payload(snap: SeqCapture, kind: str, arrays: dict,
                       layout: Optional[dict] = None) -> dict:
     """The host half of a seq snapshot between the fetch and the write
     (span `snapshot_meta` of the session's timer): the file's payload —
-    `arrays`, the routes' two (_routes_payload) and the meta JSON, which
+    `arrays`, the routes' two (sorted here) and the meta JSON, which
     holds all that is not an array, the two small id maps included
     (<= `accounts` and <= `lanes` entries)."""
-    with session.timer.phase("snapshot_meta"):
-        r = session.router
+    with snap.session.timer.phase("snapshot_meta"):
+        aid_idx, sid_lane, route_oid, route_sid = snap.router()
         meta = {
             "version": _VERSION,
             "kind": kind,
-            "offset": int(offset),
-            "cfg": dataclasses.asdict(session.cfg),
-            "metrics": [int(x) for x in session._metrics],
-            "hist": [[int(x) for x in row] for row in session._hist],
-            "aid_idx": sorted(r.aid_idx.items()),
-            "sid_lane": sorted(r.sid_lane.items()),
+            "offset": snap.offset,
+            "cfg": dataclasses.asdict(snap.session.cfg),
+            "metrics": [int(x) for x in snap.metrics],
+            "hist": [[int(x) for x in row] for row in snap.hist],
+            "aid_idx": aid_idx,
+            "sid_lane": sid_lane,
         }
         if kind == "seq":   # lanes-session cross-restore compatibility
             meta.update(rr_lane=0, width=0, shards=1)
         if layout and layout["sparse"]:
             meta["layout"] = layout
-        if extra:
-            meta["extra"] = dict(extra)
-        payload = dict(arrays)
-        payload.update(_routes_payload(r))
+        if snap.extra:
+            meta["extra"] = snap.extra
+        payload = dict(arrays, route_oid=route_oid, route_sid=route_sid)
         payload["meta"] = np.frombuffer(
             json.dumps(meta).encode(), dtype=np.uint8)
         return payload
 
 
-def _snapshot_write(ckpt_dir: str, session, offset: int, payload: dict,
-                    keep: Optional[int]) -> str:
-    """The durable half of a seq snapshot (span `snapshot_write`), and
-    what the file holds as the session's `snapshot_gauges`."""
+def write_seq_snapshot(ckpt_dir: str, snap: SeqCapture,
+                       keep: Optional[int] = None, fetched=None) -> str:
+    """Make the file of a captured boundary durable, on whichever
+    thread calls: three spans of the session's timer split it,
+    `snapshot_export` (the device's gather of the books' live rows and
+    of the positions' live entries, the device -> host fetch of those
+    and the small sections, and the host's pass over them),
+    `snapshot_meta` (the meta's JSON over the routes' arrays) and
+    `snapshot_write` (_atomic_savez, whole). Once the file is durable
+    the session's `snapshot_gauges` are replaced, whole, with what it
+    holds; nothing of the session is touched before. `fetched`, where
+    given, is a list that then takes the export's canon and layout: a
+    second reader of the state at that offset (the auditor's compare:
+    SeqSession.export_live) shares this snapshot's one fetch. It stays
+    empty where the export has no live-entry layout (java mode: the
+    canonical java form of runtime/javasnap.py, flat 128-bit-key
+    position arrays with the Q11 garbage keys, resting orders with
+    direction tags and bucket seq, balances; a subclass with a dense
+    export)."""
+    session = snap.session
+    os.makedirs(ckpt_dir, exist_ok=True)
+    canon, layout, fetch = _snapshot_export(session, snap.state)
+    snap.state = None       # fetched: the boundary's arrays may go
+    if session.cfg.compat == "java":
+        kind, arrays = "seqjava", {k: np.asarray(v)
+                                   for k, v in canon.items()}
+    else:
+        kind, arrays = "seq", {k: v for k, v in canon.items()
+                               if k != "metrics" and v is not None}
+        arrays["err"] = np.asarray(canon["err"])
+        # lanes-session cross-restore expects the drained fill-log cursor
+        arrays["filloff"] = np.zeros(1, np.int64)
+    payload = _snapshot_payload(snap, kind, arrays, layout)
     with session.timer.phase("snapshot_write"):
-        path = _atomic_savez(ckpt_dir, offset, payload, keep=keep)
-    session.snapshot_gauges = {
-        "snapshot_bytes": os.path.getsize(path),
-        "snapshot_routes": len(payload["route_oid"]),
-    }
+        path = _atomic_savez(ckpt_dir, snap.offset, payload, keep=keep)
+    gauges = {"snapshot_bytes": os.path.getsize(path),
+              "snapshot_routes": len(payload["route_oid"]), **fetch}
+    if layout:
+        gauges.update(
+            snapshot_live_slots=layout["live_slots"],
+            snapshot_live_positions=layout["live_positions"],
+            snapshot_sparse_sections=len(layout["sparse"]),
+            # routes in the file beyond the orders that rest in it: 0
+            # since routes die with their orders (a snapshot is taken
+            # after the drain, so both speak of one input prefix)
+            stale_routes=(gauges["snapshot_routes"]
+                          - layout["live_slots"]))
+        if fetched is not None:
+            fetched += [canon, layout]
+    session.snapshot_gauges = gauges
     return path
 
 
@@ -478,66 +551,18 @@ def save_seq_session(ckpt_dir: str, session, offset: int,
                      keep: Optional[int] = None,
                      extra: Optional[dict] = None,
                      fetched=None) -> str:
-    """Snapshot a SeqSession at input offset `offset` in the SAME
-    canonical layout as lanes snapshots (slot_* / flat s64 positions /
-    bal), so snapshots restore across ENGINES as well as across
-    shard/width topologies. The books and the positions are each
-    written by their live entries where that is the smaller encoding
-    (engine/seq.py:export_snapshot; _load_file densifies), so a file's
-    size follows what is live and not the configured capacity. Three
-    spans of the session's timer split the call: `snapshot_export` (the
-    device's gather of the books' live rows and of the positions' live
-    entries, the device -> host fetch of those and the small sections,
-    and the host's pass over them), `snapshot_meta` (the meta and the
-    routes' arrays) and `snapshot_write`. `fetched`, where given, is a
-    list that takes the export's canon and layout once the file is
-    written: a second reader of the state at `offset` (the auditor's
-    compare: SeqSession.export_live) then shares this snapshot's one
-    fetch. It stays empty where the export has no live-entry layout
-    (java mode, a subclass with a dense export)."""
-    if session.cfg.compat == "java":
-        return _save_seqjava(ckpt_dir, session, offset, keep=keep,
-                             extra=extra)
-    os.makedirs(ckpt_dir, exist_ok=True)
-    canon, layout, fetch = _snapshot_export(session)
-    arrays = {k: v for k, v in canon.items()
-              if k != "metrics" and v is not None}
-    arrays["err"] = np.asarray(canon["err"])
-    # lanes-session cross-restore expects the drained fill-log cursor
-    arrays["filloff"] = np.zeros(1, np.int64)
-    path = _snapshot_write(
-        ckpt_dir, session, offset, _snapshot_payload(
-            session, "seq", offset, extra, arrays, layout), keep)
-    session.snapshot_gauges.update(fetch)
-    if layout:
-        session.snapshot_gauges.update(
-            snapshot_live_slots=layout["live_slots"],
-            snapshot_live_positions=layout["live_positions"],
-            snapshot_sparse_sections=len(layout["sparse"]),
-            # routes in the file beyond the orders that rest in it: 0
-            # since routes die with their orders (a snapshot is taken
-            # after the drain, so both speak of one input prefix)
-            stale_routes=(session.snapshot_gauges["snapshot_routes"]
-                          - layout["live_slots"]))
-        if fetched is not None:
-            fetched += [canon, layout]
-    return path
-
-
-def _save_seqjava(ckpt_dir: str, session, offset: int,
-                  keep: Optional[int] = None,
-                  extra: Optional[dict] = None) -> str:
-    """Snapshot a java-mode SeqSession: the canonical java form
-    (runtime/javasnap.py) — flat 128-bit-key position arrays (Q11
-    garbage keys included: they are parity-relevant state), resting
-    orders with direction tags and bucket seq, balances, and the
-    router id maps."""
-    os.makedirs(ckpt_dir, exist_ok=True)
-    snap, _, _ = _snapshot_export(session)
-    arrays = {k: np.asarray(v) for k, v in snap.items()}
-    return _snapshot_write(
-        ckpt_dir, session, offset, _snapshot_payload(
-            session, "seqjava", offset, extra, arrays), keep)
+    """Snapshot a SeqSession at input offset `offset`, here and now
+    (capture_seq_session, then write_seq_snapshot on the caller's
+    thread), in the SAME canonical layout as lanes snapshots (slot_* /
+    flat s64 positions / bal), so snapshots restore across ENGINES as
+    well as across shard/width topologies. The books and the positions
+    are each written by their live entries where that is the smaller
+    encoding (engine/seq.py:export_snapshot; _load_file densifies), so
+    a file's size follows what is live and not the configured
+    capacity. A java-mode session writes its own canonical form."""
+    return write_seq_snapshot(
+        ckpt_dir, capture_seq_session(session, offset, extra), keep,
+        fetched)
 
 
 def _seqjava_snap_from_file(data, meta) -> dict:

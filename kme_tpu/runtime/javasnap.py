@@ -72,21 +72,21 @@ def export_seqjava(session) -> dict:
     arrays + plain dicts; see module docstring)."""
     r = session.router
     route_oid, route_sid = r.routes_arrays()
-    return {**export_seqjava_device(session),
+    return {**export_seqjava_device(session.cfg, session.state),
             "aid_idx": dict(r.aid_idx), "sid_lane": dict(r.sid_lane),
             "route_oid": route_oid, "route_sid": route_sid}
 
 
-def export_seqjava_device(session) -> dict:
-    """The device's half of the canonical form: the fetch and its
-    repack (all of the checkpoint's span `snapshot_export`; the
-    router's half is under `snapshot_meta` there)."""
+def export_seqjava_device(cfg, state) -> dict:
+    """The device's half of the canonical form, from a session's `cfg`
+    and its `state` as of a batch boundary: the fetch and its repack
+    (all of the checkpoint's span `snapshot_export`; the router's half
+    is under `snapshot_meta` there)."""
     from kme_tpu.engine import seq as SQ
 
-    cfg = session.cfg
     assert cfg.compat == "java"
-    j = SQ.export_java(cfg, session.state)
-    h = {k: np.asarray(session.state[k])
+    j = SQ.export_java(cfg, state)
+    h = {k: np.asarray(state[k])
          for k in ("bq", "seqc")}
     S, N, NR = cfg.lanes, cfg.slots, cfg.nr
     slot_seq = (h["bq"].reshape(S, 2, NR * 128)[:, :, :N]).astype(np.int32)

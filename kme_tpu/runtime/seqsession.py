@@ -142,6 +142,17 @@ class SeqRouter(DictRoutes):
         self._free = sorted(set(range(self._hw))
                             - set(self._sid_lane.values()))
 
+    def capture(self):
+        """The router as a seq snapshot carries it, in two halves:
+        copies of its three id maps as they stand, made here, and -> a
+        call that gives (`aid_idx`'s items sorted, `sid_lane`'s items
+        sorted, *routes_arrays()) of those copies, for any thread at
+        any later time."""
+        acc, sym = dict(self.aid_idx), dict(self.sid_lane)
+        routes = self.routes_capture()
+        return lambda: (sorted(acc.items()), sorted(sym.items()),
+                        *routes())
+
     def set_listed(self, book_exists) -> None:
         """After an import of `sid_lane`: the bound ids whose lane holds
         no book (`book_exists[lane]` of the restored device state) are
@@ -506,13 +517,33 @@ class NativeSeqRouter:
     def oid_sid(self, d):
         self._import(self._lib.kme_router_import_routes, d, np.int64)
 
-    def routes_arrays(self):
-        """`oid_sid` as a snapshot carries it (sorted_routes), straight
-        from the C++ map: no dict in between."""
+    def routes_capture(self):
+        """routes_arrays() in two halves: the C++ map's export, made
+        here, and -> a call that sorts it, for any thread at any later
+        time. No dict in between."""
         lib = self._lib
-        return sorted_routes(*self._export_arrays(
-            lib.kme_router_n_routes, lib.kme_router_export_routes,
-            np.int64))
+        raw = self._export_arrays(lib.kme_router_n_routes,
+                                  lib.kme_router_export_routes, np.int64)
+        return lambda: sorted_routes(*raw)
+
+    def routes_arrays(self):
+        """`oid_sid` as a snapshot carries it (sorted_routes)."""
+        return self.routes_capture()()
+
+    def capture(self):
+        """SeqRouter.capture's two halves from the C++ maps: their
+        exports here, the lists and the sorting in the call."""
+        lib = self._lib
+        acc = self._export_arrays(lib.kme_router_n_accounts,
+                                  lib.kme_router_export_accounts, np.int32)
+        sym = self._export_arrays(lib.kme_router_n_symbols,
+                                  lib.kme_router_export_symbols, np.int32)
+        routes = self.routes_capture()
+
+        def items(keys, vals):
+            return sorted(zip(keys.tolist(), vals.tolist()))
+
+        return lambda: (items(*acc), items(*sym), *routes())
 
     def import_routes(self, keys, vals) -> None:
         self._import_arrays(self._lib.kme_router_import_routes, keys,

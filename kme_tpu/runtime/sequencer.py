@@ -118,17 +118,28 @@ def sorted_routes(keys: np.ndarray, vals: np.ndarray):
     return keys[order], vals[order]
 
 
+def _dict_routes(d: Dict[int, int]):
+    return sorted_routes(np.fromiter(d.keys(), np.int64, len(d)),
+                         np.fromiter(d.values(), np.int64, len(d)))
+
+
 class DictRoutes:
     """The snapshot's view of a Python router's `oid_sid` dict (the
-    native twins answer the same two calls from their C++ maps)."""
+    native twins answer the same calls from their C++ maps)."""
 
     oid_sid: Dict[int, int]
 
     def routes_arrays(self):
         """`oid_sid` as a snapshot carries it (sorted_routes)."""
-        d = self.oid_sid
-        return sorted_routes(np.fromiter(d.keys(), np.int64, len(d)),
-                             np.fromiter(d.values(), np.int64, len(d)))
+        return _dict_routes(self.oid_sid)
+
+    def routes_capture(self):
+        """routes_arrays() in two halves: a copy of `oid_sid` as it
+        stands, made here, and -> a call that makes the two arrays of
+        that copy, for any thread at any later time (a snapshot's
+        writer, while the router routes on)."""
+        d = dict(self.oid_sid)
+        return lambda: _dict_routes(d)
 
     def import_routes(self, keys, vals) -> None:
         self.oid_sid = dict(zip(np.asarray(keys).tolist(),

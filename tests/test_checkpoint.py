@@ -1775,3 +1775,381 @@ def test_snapshot_meta_span_and_routes_gauge_are_published(compat,
         assert gauges[span + "_n"] == 1 and gauges[span + "_s"] > 0
     assert gauges["snapshot_export_s"] + gauges["snapshot_meta_s"] \
         + gauges["snapshot_write_s"] <= gauges["snapshot_save_s"]
+
+
+# ---------------------------------------------------------------------------
+# a cadenced snapshot is made beside the loop (PR 53): the serve thread
+# captures the boundary and hands it to the snapshot writer's thread
+
+
+def _writer_kinds():
+    return {
+        "pipelined": dict(compat="fixed", pipeline=2, exactly_once=True,
+                          slots=128, max_fills=16, batch=64),
+        "serial": dict(compat="fixed", pipeline=0, slots=128,
+                       max_fills=16, batch=64),
+        "java": dict(compat="java", slots=512, max_fills=128, batch=64),
+    }
+
+
+def _cadenced(tmp_path, name, kind, n=1024, every=256, **more):
+    """A broker that holds `n` messages and the arguments of a seq
+    service that snapshots every `every` of them, keeping every file."""
+    how = _writer_kinds()[kind]
+    msgs = (_java_stream(n=n, seed=5) if how["compat"] == "java"
+            else list(zipf_symbol_stream(n, num_symbols=8, num_accounts=24,
+                                         seed=17, zipf_a=1.0)))
+    if how.get("pipeline"):
+        from kme_tpu.native import load_library
+
+        if load_library() is None:
+            pytest.skip("native host runtime unavailable")
+    broker = InProcessBroker(persist_dir=str(tmp_path / (name + "-log")))
+    provision(broker)
+    for m in msgs:
+        broker.produce(TOPIC_IN, None, dumps_order(m))
+    kw = dict(engine="seq", symbols=8, accounts=128,
+              checkpoint_dir=str(tmp_path / (name + "-ck")),
+              checkpoint_every=every, checkpoint_keep=100, **how)
+    kw.update(more)
+    return broker, kw, len(msgs)
+
+
+def _snapshot_offsets(kw):
+    return sorted(off for off, _ in
+                  ck.list_snapshots(kw["checkpoint_dir"]))
+
+
+def _writers_alive():
+    import threading
+
+    return [t for t in threading.enumerate()
+            if t.name == "kme-snapshot-writer"]
+
+
+@pytest.fixture
+def held_writer(monkeypatch):
+    """Every snapshot writer stops before _atomic_savez until the serve
+    thread has come to wait for it (or 20 s have passed: a test that
+    never waits fails by its own assertions, and does not hang). ->
+    the offsets whose write was let through, in order."""
+    import threading
+
+    waiting, written = threading.Event(), []
+    savez, wait = ck._atomic_savez, MatchService._snapshot_writer_wait
+
+    def held(ckpt_dir, offset, payload, keep=None):
+        if threading.current_thread().name == "kme-snapshot-writer":
+            waiting.wait(20.0)
+            waiting.clear()
+        written.append(offset)
+        return savez(ckpt_dir, offset, payload, keep=keep)
+
+    def announced(self):
+        if self._snap_writer is not None:
+            waiting.set()
+        return wait(self)
+
+    monkeypatch.setattr(ck, "_atomic_savez", held)
+    monkeypatch.setattr(MatchService, "_snapshot_writer_wait", announced)
+    return written
+
+
+@pytest.mark.parametrize("kind", sorted(_writer_kinds()))
+def test_files_made_beside_the_loop_are_those_of_a_loop_that_waits(
+        kind, tmp_path, monkeypatch):
+    """Same offsets, and at each the same file: every array, the meta
+    and the digest. The reference waits for the writer inside every
+    handoff (the save on the serve thread, as it was); the run under
+    test starts each fetch only once the loop has gone on past the
+    boundary, so what it reads is the boundary's state by capture and
+    not by luck."""
+    import time
+
+    import numpy as np
+
+    handoff = MatchService._snapshot_handoff
+
+    def waited(self, extra):
+        fetched = handoff(self, extra)
+        self._snapshot_writer_wait()
+        return fetched
+
+    with monkeypatch.context() as mp:
+        mp.setattr(MatchService, "_snapshot_handoff", waited)
+        b0, kw0, n = _cadenced(tmp_path, "waits", kind)
+        ref = MatchService(b0, **kw0)
+        assert ref.run(max_messages=n) == n
+        ref.close()
+
+    b1, kw1, n = _cadenced(tmp_path, "beside", kind)
+    svc = MatchService(b1, **kw1)
+    write, ahead = ck.write_seq_snapshot, []
+
+    def late(ckpt_dir, snap, keep=None, fetched=None):
+        deadline = time.monotonic() + 3.0
+        while svc.offset == snap.offset and time.monotonic() < deadline:
+            time.sleep(0.005)
+        ahead.append(svc.offset > snap.offset)
+        return write(ckpt_dir, snap, keep=keep, fetched=fetched)
+
+    monkeypatch.setattr(ck, "write_seq_snapshot", late)
+    assert svc.run(max_messages=n) == n
+    svc.close()
+    assert not _writers_alive()
+    offsets = _snapshot_offsets(kw1)
+    assert offsets == _snapshot_offsets(kw0) == list(range(256, n + 1, 256))
+    # all but the stream's last boundary were fetched with the loop ahead
+    assert len(ahead) == len(offsets) and sum(ahead) >= len(offsets) - 1
+    for off in offsets:
+        want, wmeta = _raw(ck.snapshot_path(kw0["checkpoint_dir"], off))
+        got, gmeta = _raw(ck.snapshot_path(kw1["checkpoint_dir"], off))
+        assert gmeta == wmeta and gmeta["offset"] == off
+        assert sorted(got) == sorted(want)
+        for k in want:
+            assert got[k].dtype == want[k].dtype, (off, k)
+            assert np.array_equal(got[k], want[k]), (off, k)
+    assert list(consume_lines(b1, follow=False)) \
+        == list(consume_lines(b0, follow=False))
+
+
+def test_a_slow_writer_skips_no_snapshot_and_the_loop_waits_for_it(
+        tmp_path, held_writer):
+    """K x checkpoint_every records give K files, at the cadence's own
+    offsets and written in order, when no file is durable before the
+    next boundary comes due; the wait is span `snapshot_writer_wait`."""
+    broker, kw, n = _cadenced(tmp_path, "slow", "pipelined", n=768,
+                              every=128)
+    svc = MatchService(broker, **kw)
+    assert svc.run(max_messages=n) == n
+    want = list(range(128, n + 1, 128))
+    assert held_writer == want == _snapshot_offsets(kw)
+    svc._publish_spans()
+    g = svc.telemetry.snapshot()["gauges"]
+    assert g["snapshot_handoff_n"] == g["snapshot_save_n"] == len(want)
+    assert g["snapshot_writer_wait_n"] == len(want)
+    assert 0 < g["snapshot_writer_wait_s"] <= g["snapshot_save_s"]
+    assert g["snapshot_writer_wait_s"] + g["snapshot_handoff_s"] \
+        <= g["checkpoint_s"]
+    svc.close()
+
+
+def test_a_snapshot_in_flight_is_no_snapshot_yet(tmp_path, held_writer):
+    """Between the handoff and the rename the cadence has moved on and
+    nothing else has: the directory, the retention guard's oldest
+    offset and the session's gauges speak of the file before; once
+    durable, the gauges are one new dict."""
+    broker, kw, n = _cadenced(tmp_path, "flight", "pipelined", n=512,
+                              every=128)
+    svc = MatchService(broker, **kw)
+    svc.checkpoint()                           # a file at offset 0
+    first = svc._session.snapshot_gauges
+    assert first["snapshot_routes"] == 0 and _snapshot_offsets(kw) == [0]
+    while svc._snap_writer is None:
+        assert svc.step(timeout=0.0) > 0
+    at = svc._last_ckpt_offset
+    assert at == svc.offset == 128             # the cadence counts on
+    assert _snapshot_offsets(kw) == [0] and held_writer == [0]
+    assert ck.oldest_retained_offset(kw["checkpoint_dir"]) == 0
+    assert svc._session.snapshot_gauges is first
+    assert ck.snapshot_extra(kw["checkpoint_dir"], at) == {}
+    svc._snapshot_writer_wait()
+    assert _snapshot_offsets(kw) == [0, 128]
+    now = svc._session.snapshot_gauges
+    assert now is not first and now["snapshot_routes"] > 0
+    assert set(now) >= set(first) | set(FETCH_GAUGES)
+    assert now["snapshot_bytes"] == os.path.getsize(
+        ck.snapshot_path(kw["checkpoint_dir"], 128))
+    svc.close()
+
+
+def test_a_crash_between_handoff_and_rename_resumes_from_the_file_before(
+        tmp_path, monkeypatch):
+    """The writer dies with the second file written and not renamed
+    (what a kill inside _atomic_savez leaves): the next leader resumes
+    from the first, replays, and MatchOut holds the uninterrupted run's
+    bytes with no stamp twice."""
+    import numpy as np
+
+    from kme_tpu.bridge.consume import DedupRing
+    from kme_tpu.bridge.service import TOPIC_OUT
+
+    b0, kw0, n = _cadenced(tmp_path, "whole", "pipelined", n=768)
+    assert MatchService(b0, **kw0).run(max_messages=n) == n
+    whole = list(consume_lines(b0, follow=False))
+
+    savez = ck._atomic_savez
+
+    def dies_at_512(ckpt_dir, offset, payload, keep=None):
+        if offset != 512:
+            return savez(ckpt_dir, offset, payload, keep=keep)
+        with open(ck.snapshot_path(ckpt_dir, offset) + ".tmp", "wb") as f:
+            np.savez(f, **payload)
+        raise RuntimeError("killed before the rename")
+
+    b1, kw, n = _cadenced(tmp_path, "cut", "pipelined", n=768)
+    with monkeypatch.context() as mp:
+        mp.setattr(ck, "_atomic_savez", dies_at_512)
+        svc = MatchService(b1, **kw)
+        with pytest.raises(RuntimeError, match="before the rename"):
+            svc.run(max_messages=n)
+        assert svc.offset > 512                # the loop had gone on
+    del svc                                    # no close(): it is dead
+    assert _snapshot_offsets(kw) == [256]
+    assert os.path.exists(ck.snapshot_path(kw["checkpoint_dir"], 512)
+                          + ".tmp")
+
+    b2 = InProcessBroker(persist_dir=str(tmp_path / "cut-log"))
+    svc2 = MatchService(b2, **kw)
+    assert svc2.offset == 256 and svc2.epoch == 2
+    assert svc2.run(max_messages=n - 256) == n - 256
+    svc2.close()
+    assert _snapshot_offsets(kw) == [256, 512, 768]
+    assert list(consume_lines(b2, follow=False)) == whole
+    ring = DedupRing(capacity=1 << 20)
+    recs = b2.fetch(TOPIC_OUT, 0, 10**7)
+    assert b2.dup_suppressed > 0
+    assert not any(ring.is_dup(r.epoch, r.out_seq) for r in recs)
+
+
+@pytest.mark.parametrize("how", ["checkpoint", "close", "run"])
+def test_outside_the_cadence_the_caller_returns_with_the_file_durable(
+        how, tmp_path, held_writer):
+    """An explicit checkpoint() is the same handoff, waited for;
+    close() and a run() that returns take the writer in flight back."""
+    broker, kw, n = _cadenced(tmp_path, how, "serial", n=384, every=128)
+    svc = MatchService(broker, **kw)
+    if how == "run":
+        assert svc.run(max_messages=n) == n
+        want = [128, 256, 384]
+    else:
+        while svc._snap_writer is None:
+            assert svc.step(timeout=0.0) > 0
+        assert _snapshot_offsets(kw) == [] and _writers_alive()
+        if how == "checkpoint":
+            assert svc.step(timeout=0.0) == 64     # off the cadence
+            assert _snapshot_offsets(kw) == [] and svc.offset == 192
+            svc.checkpoint()
+            want = [128, 192]
+        else:
+            svc.close()
+            want = [128]
+    assert _snapshot_offsets(kw) == want == held_writer
+    assert svc._snap_writer is None and not _writers_alive()
+    assert not [f for f in os.listdir(kw["checkpoint_dir"])
+                if f.endswith(".tmp")]
+    svc.close()
+
+
+@pytest.mark.parametrize("engine", ["oracle", "native", "lanes",
+                                    "follower"])
+def test_engines_saved_on_the_serve_thread_start_no_writer(
+        engine, tmp_path, monkeypatch):
+    """The engines no deployment serves are saved inside the handoff
+    (their state is the host's own, made a file at the boundary); a
+    follower takes no snapshot at all."""
+    from kme_tpu.bridge import service
+
+    def no_writer(self, write):
+        raise AssertionError("a snapshot writer was started")
+
+    monkeypatch.setattr(service._SnapshotWriter, "__init__", no_writer)
+    msgs = harness_stream(300, seed=13, num_symbols=4, num_accounts=8,
+                          payout_opcode_bug=False, validate=True)
+    broker = InProcessBroker()
+    provision(broker)
+    for m in msgs:
+        broker.produce(TOPIC_IN, None, dumps_order(m))
+    kw = dict(compat="fixed", batch=50, symbols=8, accounts=16, slots=64,
+              max_fills=32, checkpoint_dir=str(tmp_path),
+              checkpoint_every=100)
+    if engine == "follower":
+        kw.update(engine="oracle", follower=True)
+    else:
+        if engine == "native":
+            nat = pytest.importorskip("kme_tpu.native.oracle")
+            if not nat.native_available():
+                pytest.skip("native library unavailable")
+        kw.update(engine=engine)
+    svc = MatchService(broker, **kw)
+    seen = []
+    while svc.offset < 300:
+        assert svc.step(timeout=0.0) > 0
+        seen.append(sorted(off for off, _ in
+                           ck.all_snapshots(kw["checkpoint_dir"])))
+        assert svc._snap_writer is None
+    svc.close()
+    if engine == "follower":
+        assert seen[-1] == []
+    else:
+        # each file was there when the step that crossed its boundary
+        # returned
+        assert seen[1] == [100] and seen[3] == [100, 200]
+        assert seen[-1] == [100, 200, 300]
+        g = svc._ptimer.gauges()
+        assert g["snapshot_handoff_n"] == g["snapshot_save_n"] == 3
+        assert g["snapshot_save_s"] <= g["snapshot_handoff_s"]
+
+
+def test_with_an_auditor_on_the_loop_waits_for_each_file_and_compares(
+        tmp_path, held_writer):
+    """The auditor's compare reads the boundary's state (the snapshot's
+    own fetch) against a shadow ledger that moves with the next batch:
+    the serve thread has the file durable and the fetch in hand before
+    it compares, and nothing is in flight when a step returns."""
+    broker, kw, n = _cadenced(
+        tmp_path, "audit", "pipelined", n=512, every=128,
+        journal=str(tmp_path / "audit-journal.kmej"), audit=True)
+    svc = MatchService(broker, **kw)
+    check, compared = svc._audit_check_engine, []
+
+    def at_the_boundary(fetched):
+        compared.append((svc.offset, _snapshot_offsets(kw)[-1],
+                         len(fetched), svc._snap_writer))
+        check(fetched)
+
+    svc._audit_check_engine = at_the_boundary
+    while svc.offset < n:
+        svc.step(timeout=0.0)      # (0: the pipeline drains on an empty poll)
+        assert svc._snap_writer is None
+    assert compared == [(off, off, 2, None) for off in (128, 256, 384, 512)]
+    assert svc.auditor.violations == [] and svc.degraded is None
+    g = svc._ptimer.gauges()
+    assert g["snapshot_writer_wait_n"] == g["audit_check_engine_n"] == 4
+    svc.close()
+
+
+@pytest.mark.parametrize("kind", ["fixed-native", "fixed-python", "java"])
+def test_a_routers_capture_is_the_router_as_it_stood(kind, monkeypatch):
+    """capture() copies the three id maps now and sorts them when
+    called: what it gives is the router at the capture, whatever was
+    routed, dropped or purged in between."""
+    import numpy as np
+
+    from kme_tpu.engine import seq as SQ
+    from kme_tpu.runtime.seqsession import SeqSession
+
+    if kind == "fixed-python":      # the router KME_NATIVE=0 serves with
+        monkeypatch.setattr("kme_tpu.native.require_library", lambda: None)
+    compat = "java" if kind == "java" else "fixed"
+    shape = dict(SMALL, slots=512, max_fills=128) if kind == "java" \
+        else SMALL
+    ses = SeqSession(SQ.SeqConfig(compat=compat, **shape))
+    r = ses.router
+    assert ("Native" in type(r).__name__) == (kind == "fixed-native") \
+        or pytest.skip("native host runtime unavailable")
+    msgs = (_java_stream(n=900, seed=3) if kind == "java"
+            else list(zipf_symbol_stream(900, num_symbols=8,
+                                         num_accounts=100, seed=3)))
+    ses.process_wire([m.copy() for m in msgs[:500]])
+    want = (sorted(r.aid_idx.items()), sorted(r.sid_lane.items()),
+            *r.routes_arrays())
+    assert len(want[0]) > 8 and len(want[2]) > 50
+    later = r.capture()
+    ses.process_wire([m.copy() for m in msgs[500:]])
+    assert not np.array_equal(r.routes_arrays()[0], want[2])
+    got = later()
+    assert got[:2] == want[:2]
+    for g, w in zip(got[2:], want[2:]):
+        assert g.dtype == np.int64 and np.array_equal(g, w)
+    assert np.all(np.diff(got[2]) > 0)

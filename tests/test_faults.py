@@ -223,3 +223,132 @@ def test_exactly_once_fault_points_parse_and_fire():
     faults.configure("lease.steal:n=1")            # module registry too
     assert faults.should("lease.steal")
     assert not faults.should("lease.steal")
+
+
+# ---------------------------------------------------------------------------
+# a seq service's snapshots are made on the snapshot writer's thread
+# (PR 53): what fails or is attacked there is seen on the serve thread
+
+
+def _seq_service(tmp_path, n=384, every=128, **more):
+    """A broker holding `n` messages and a serial fixed-mode seq
+    service over it that snapshots every `every` (batches of 64)."""
+    from kme_tpu.workload import zipf_symbol_stream
+
+    broker = InProcessBroker(persist_dir=str(tmp_path / "log"))
+    provision(broker)
+    for m in zipf_symbol_stream(n, num_symbols=8, num_accounts=24, seed=17,
+                                zipf_a=1.0):
+        broker.produce(TOPIC_IN, None, dumps_order(m))
+    kw = dict(engine="seq", compat="fixed", symbols=8, accounts=128,
+              slots=128, max_fills=16, batch=64, pipeline=0,
+              checkpoint_dir=str(tmp_path / "ck"), checkpoint_every=every,
+              checkpoint_keep=100, **more)
+    return broker, kw
+
+
+def _offsets(kw):
+    from kme_tpu.runtime import checkpoint as ck
+
+    return sorted(off for off, _ in ck.list_snapshots(kw["checkpoint_dir"]))
+
+
+def _to_the_handoff(svc):
+    """Step the loop until a snapshot has been handed to its writer."""
+    while svc._snap_writer is None:
+        assert svc.step(timeout=0.0) > 0
+
+
+@pytest.mark.parametrize("where", ["next-boundary", "close"])
+def test_a_snapshot_writers_oserror_reaches_the_serve_thread(
+        where, tmp_path, monkeypatch):
+    """A disk that fills under the writer is no silent gap in the
+    snapshots: the error is raised where the save on the serve thread
+    would have raised it, one boundary later, or at close()."""
+    import errno
+
+    from kme_tpu.runtime import checkpoint as ck
+
+    def full(ckpt_dir, offset, payload, keep=None):
+        raise OSError(errno.ENOSPC, "No space left on device")
+
+    monkeypatch.setattr(ck, "_atomic_savez", full)
+    broker, kw = _seq_service(tmp_path)
+    svc = MatchService(broker, **kw)
+    _to_the_handoff(svc)
+    assert svc.offset == 128
+    with pytest.raises(OSError) as e:
+        if where == "close":
+            svc.close()
+        else:
+            svc.run(max_messages=256)
+    assert e.value.errno == errno.ENOSPC
+    # raised once, by whoever took the writer back
+    assert svc._snap_writer is None or svc.offset == 256
+    assert _offsets(kw) == []
+    if where == "close":
+        # the error did not keep the rest of close() from running
+        assert svc.events is not None and svc.events._f is None
+    svc.close()
+
+
+def test_a_fence_at_a_boundary_leaves_no_writer_that_renames_later(
+        tmp_path, monkeypatch):
+    """lease.steal at the second boundary: the first boundary's file is
+    still being written when the loop gets there. The serve thread
+    takes that writer back BEFORE it reads the epoch, so when it dies
+    fenced nothing is in flight: the directory holds the first file and
+    stays as it is."""
+    import threading
+    import time
+
+    from kme_tpu.bridge.broker import BrokerFenced
+    from kme_tpu.runtime import checkpoint as ck
+
+    savez, order = ck._atomic_savez, []
+
+    def slow(ckpt_dir, offset, payload, keep=None):
+        time.sleep(0.3)
+        path = savez(ckpt_dir, offset, payload, keep=keep)
+        order.append(("renamed", offset))
+        return path
+
+    monkeypatch.setattr(ck, "_atomic_savez", slow)
+    broker, kw = _seq_service(tmp_path, exactly_once=True)
+    svc = MatchService(broker, **kw)
+    faults.configure("lease.steal:after=1")
+    with pytest.raises(BrokerFenced, match="superseded"):
+        try:
+            svc.run(max_messages=384)
+        finally:
+            order.append(("raised", svc.offset))
+    assert order == [("renamed", 128), ("raised", 256)]
+    assert svc._snap_writer is None
+    assert not [t for t in threading.enumerate()
+                if t.name == "kme-snapshot-writer"]
+    held = sorted(os.listdir(kw["checkpoint_dir"]))
+    assert _offsets(kw) == [128] and not [f for f in held
+                                          if f.endswith(".tmp")]
+    time.sleep(0.5)
+    assert sorted(os.listdir(kw["checkpoint_dir"])) == held
+    svc.close()
+    assert sorted(os.listdir(kw["checkpoint_dir"])) == held
+
+
+@pytest.mark.parametrize("damage", ["ckpt.torn", "ckpt.bitflip"])
+def test_post_write_faults_fire_on_the_writers_file_and_the_loader_falls_back(
+        damage, tmp_path):
+    """The injection points sit where the rename is, on the writer's
+    thread now: the second file is damaged once durable, the loop
+    serves on, and the next leader resumes from the first."""
+    broker, kw = _seq_service(tmp_path)
+    faults.configure(f"{damage}:after=1")
+    svc = MatchService(broker, **kw)
+    assert svc.run(max_messages=320) == 320
+    assert faults.fired_total() == 1
+    assert _offsets(kw) == [128, 256]
+    del svc
+    svc2 = MatchService(InProcessBroker(persist_dir=str(tmp_path / "log")),
+                        **kw)
+    assert svc2.offset == 128
+    svc2.close()
