@@ -9,24 +9,28 @@ unit for buys and `100 - price` per unit for sells
 price-time-priority matching, cancels, and symbol settlement.
 
 Instead of one message at a time against five RocksDB stores, this framework
-keeps the entire exchange state resident in dense device arrays (HBM),
-processes conflict-free micro-batch steps with `lax.scan` (serial in time,
-parallel across symbols via `vmap`), and shards the symbol axis over a TPU
-mesh with `shard_map`, merging cross-shard account-balance deltas with exact
-integer `psum` collectives over ICI.
+keeps the entire exchange state resident in dense device arrays (HBM, the
+working set in VMEM) and processes a micro-batch strictly in arrival order
+inside ONE Pallas kernel call (engine/seq.py); the sharded form
+(parallel/seqmesh.py) splits the symbol axis over a TPU mesh with
+`shard_map`, merging cross-shard account-balance deltas with exact integer
+`psum` collectives over ICI.
 
 Package layout:
   oracle/    quirk-faithful pure-Python replica of the reference semantics
              (the golden parity judge; compat='java' and compat='fixed')
-  engine/    the device engines: parity.py (serial quirk-exact replica as
-             one lax.scan) and lanes.py (the throughput engine: compacted
-             per-symbol lanes, sort+prefix-sum matching, on-device
-             metrics, packed fill log)
+  engine/    the device engines: seq.py (the served one: a sequential
+             Pallas mega-kernel, on-device metrics, packed fill log) and
+             parity.py (serial quirk-exact replica as one lax.scan, a
+             reference)
   ops/       exact bit/codec device utilities and associative tables
-  parallel/  mesh construction, sharding specs, psum-merged collectives
-  runtime/   host runtime: conflict-free scheduler (sequencer.py), the
-             batching session with compact device I/O (session.py), and
-             checkpoint/resume (checkpoint.py)
+             (parity.py's)
+  parallel/  seqmesh.py: the seq engine over a mesh, psum-merged
+  runtime/   host runtime: the id router and the batching session with
+             compact device I/O (seqsession.py), checkpoint/resume
+             (checkpoint.py, javasnap.py)
+  native/    the host path's C++ (router, plan + pack, wire parse and
+             reconstruction) and the native quirk-exact engine
   bridge/    transport edge speaking the reference's Kafka wire contract:
              broker core with durable logs, TCP process boundary, and the
              MatchIn -> engine -> MatchOut service + CLIs

@@ -139,8 +139,8 @@ REPLAY_SCOPES: Dict[str, Set[str]] = {
                                 "make_internal_transfer",
                                 "make_internal_create"},
     "kme_tpu/runtime/checkpoint.py": {
-        "load_session", "load_seq_session", "load_native",
-        "load_oracle", "snapshot_extra", "oldest_retained_offset"},
+        "load_seq_session", "load_native", "load_oracle",
+        "snapshot_extra", "oldest_retained_offset"},
     # the elastic placement decision must be RNG-free: a migration is
     # replayed as part of the batch sequence, and a random tie-break
     # would put lanes on different shards across original vs resumed

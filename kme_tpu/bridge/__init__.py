@@ -13,7 +13,8 @@ is a small native-Python stack:
                 load generator, engine service and consumer run as
                 separate OS processes like the reference's stack.
 - service.py  — the engine service: polls MatchIn, runs a configurable
-                engine (device lanes engine or scalar oracle replica),
+                engine (the device seq engine, the native engine or
+                the scalar oracle replica),
                 forwards the IN/OUT record stream to MatchOut
                 (KProcessor.java:97, 124).
 - provision.py/serve.py/consume.py — the CLI roles (topic.js /

@@ -2029,8 +2029,8 @@ def main(argv=None) -> int:
     p.add_argument("--events", type=int, default=2000)
     p.add_argument("--accounts", type=int, default=10)
     p.add_argument("--symbols", type=int, default=3)
-    p.add_argument("--engine", choices=("oracle", "native", "seq",
-                                        "lanes"), default="oracle",
+    p.add_argument("--engine", choices=("oracle", "native", "seq"),
+                   default="oracle",
                    help="serving engine under attack (oracle is host-"
                         "only and fast on CPU; the recovery machinery "
                         "under test is engine-independent)")

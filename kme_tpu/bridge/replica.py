@@ -139,7 +139,7 @@ class Replica:
                  engine: str = "seq", compat: str = "fixed",
                  batch: int = 1024, symbols: int = 1024,
                  accounts: int = 4096, slots: int = 128,
-                 max_fills: int = 16, width: int = 8, shards: int = 1,
+                 max_fills: int = 16,
                  checkpoint_every: int = 4096,
                  checkpoint_keep: Optional[int] = None,
                  max_lag: Optional[int] = None,
@@ -191,7 +191,7 @@ class Replica:
         self.svc = MatchService(
             self.follow, engine=engine, compat=compat, batch=batch,
             symbols=symbols, accounts=accounts, slots=slots,
-            max_fills=max_fills, width=width, shards=shards,
+            max_fills=max_fills,
             checkpoint_dir=checkpoint_dir,
             checkpoint_every=checkpoint_every,
             checkpoint_keep=checkpoint_keep,
@@ -440,8 +440,8 @@ def main(argv=None) -> int:
                    metavar="HOST:PORT",
                    help="the leader's broker endpoint, bound at "
                         "promotion")
-    p.add_argument("--engine", choices=("seq", "lanes", "oracle",
-                                        "native"), default="seq")
+    p.add_argument("--engine", choices=("seq", "oracle", "native"),
+                   default="seq")
     p.add_argument("--compat", choices=("java", "fixed"),
                    default="fixed")
     p.add_argument("--batch", type=int, default=1024)
@@ -449,8 +449,6 @@ def main(argv=None) -> int:
     p.add_argument("--accounts", type=int, default=4096)
     p.add_argument("--slots", type=int, default=128)
     p.add_argument("--max-fills", type=int, default=16)
-    p.add_argument("--width", type=int, default=8)
-    p.add_argument("--shards", type=int, default=1)
     p.add_argument("--checkpoint-every", type=int, default=4096)
     p.add_argument("--checkpoint-keep", type=int, default=None)
     p.add_argument("--max-lag", type=int, default=None)
@@ -518,8 +516,7 @@ def main(argv=None) -> int:
                   engine=args.engine, compat=args.compat,
                   batch=args.batch, symbols=args.symbols,
                   accounts=args.accounts, slots=args.slots,
-                  max_fills=args.max_fills, width=args.width,
-                  shards=args.shards,
+                  max_fills=args.max_fills,
                   checkpoint_every=args.checkpoint_every,
                   checkpoint_keep=args.checkpoint_keep,
                   max_lag=args.max_lag,

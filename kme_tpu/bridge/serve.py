@@ -32,13 +32,12 @@ def build_parser() -> argparse.ArgumentParser:
                         "live in Kafka (durable there), --listen/--log-dir "
                         "are ignored, and the reference's unmodified Node "
                         "harness can drive the engine")
-    p.add_argument("--engine", choices=("seq", "lanes", "oracle",
-                                        "native"),
+    p.add_argument("--engine", choices=("seq", "oracle", "native"),
                    default="seq",
-                   help="seq = sequential Pallas mega-kernel (fixed "
-                        "mode, the flagship); lanes = vectorized sweep "
-                        "engine (fixed mode, shardable); native = C++ "
-                        "quirk-exact engine (fast java compat); oracle "
+                   help="seq = sequential Pallas mega-kernel, the "
+                        "device engine (fixed mode, and the java-compat "
+                        "device surface); native = C++ quirk-exact "
+                        "engine on the host (fast java compat); oracle "
                         "= Python reference replica")
     p.add_argument("--compat", choices=("java", "fixed"), default="fixed")
     p.add_argument("--batch", type=int, default=1024,
@@ -47,17 +46,11 @@ def build_parser() -> argparse.ArgumentParser:
                    help="symbols listed AT A TIME. The seq engine "
                         "(fixed mode) hands a symbol's lane back when "
                         "its PAYOUT has settled it, so any number of "
-                        "ids are served over a leader's life; the "
-                        "lanes engine and java mode bind an id for "
-                        "ever")
+                        "ids are served over a leader's life; java "
+                        "mode binds an id for ever")
     p.add_argument("--accounts", type=int, default=4096)
     p.add_argument("--slots", type=int, default=128)
     p.add_argument("--max-fills", type=int, default=16)
-    p.add_argument("--width", type=int, default=8)
-    p.add_argument("--shards", type=int, default=1,
-                   help="devices the lanes engine shards symbols over "
-                        "(engine=seq serves on one device: anything but "
-                        "1 is refused)")
     p.add_argument("--strict", action="store_true",
                    help="die on malformed input records like the "
                         "reference's serde does (KProcessor.java:513-517)")
@@ -360,8 +353,7 @@ def main(argv=None) -> int:
     svc = MatchService(broker, engine=args.engine, compat=args.compat,
                        batch=args.batch, symbols=args.symbols,
                        accounts=args.accounts, slots=args.slots,
-                       max_fills=args.max_fills, width=args.width,
-                       shards=args.shards, strict=args.strict,
+                       max_fills=args.max_fills, strict=args.strict,
                        checkpoint_dir=args.checkpoint_dir,
                        checkpoint_every=args.checkpoint_every,
                        checkpoint_keep=args.checkpoint_keep,

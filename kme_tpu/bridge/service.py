@@ -6,16 +6,19 @@ the result/fill stream with key "OUT" to `MatchOut`
 (/root/reference/src/main/java/KProcessor.java:96-126). Here the same
 contract is a poll loop over the broker API with a pluggable engine:
 
-- engine="lanes"  — the device throughput engine (fixed-mode semantics,
-  micro-batched through LaneSession.process_wire). The batch boundary
-  replaces the reference's per-record commit (KProcessor.java:125,
-  SURVEY.md §7 H5): offsets advance only after a batch's outputs are
-  produced.
+- engine="seq"    — the device engine, and the default (as kme-serve's):
+  the sequential Pallas mega-kernel behind SeqSession (fixed mode, and
+  the java-compat device surface), micro-batched; with `pipeline`,
+  batch N+1 is planned and dispatched under batch N's device step. The
+  batch boundary replaces the reference's per-record commit
+  (KProcessor.java:125, SURVEY.md §7 H5): offsets advance only after a
+  batch's outputs are produced.
 - engine="oracle" — the scalar reference replica (compat java|fixed),
   quirk-exact per message; the slow-but-byte-faithful configuration.
 - engine="native" — the C++ port of the same quirk-exact semantics
-  (kme_tpu/native/oracle.py): the FAST java-compat path (the parallel
-  engine cannot be quirk-exact under Q11 — COMPAT.md).
+  (kme_tpu/native/oracle.py): the FAST host-side java-compat path, and
+  where a java-mode seq service continues when a stream leaves the
+  device surface (COMPAT.md).
 
 Malformed values (JSON Jackson would reject) kill the reference's
 stream thread (KProcessor.java:513-517); the service instead drops the
@@ -159,11 +162,10 @@ class MatchService:
     AUDIT_SPANS = ("audit_observe", "audit_check_engine")
     TSDB_SPANS = ("tsdb_append",)
 
-    def __init__(self, broker, engine: str = "lanes",
+    def __init__(self, broker, engine: str = "seq",
                  compat: str = "fixed", batch: int = 1024,
                  symbols: int = 1024, accounts: int = 4096,
                  slots: int = 128, max_fills: int = 16,
-                 width: int = 8, shards: int = 1,
                  strict: bool = False,
                  checkpoint_dir: Optional[str] = None,
                  checkpoint_every: int = 4096,
@@ -186,22 +188,10 @@ class MatchService:
                  capture_dir: Optional[str] = None,
                  capture_p99_us: Optional[int] = None,
                  watch=None, clock=None) -> None:
-        if engine not in ("lanes", "seq", "oracle", "native"):
+        if engine not in ("seq", "oracle", "native"):
             raise ValueError(f"unknown engine {engine!r}")
         if compat not in ("java", "fixed"):
             raise ValueError(f"unknown compat {compat!r}")
-        if engine == "seq" and shards != 1:
-            # the served seq engine is one SeqSession on one device; the
-            # sharded SeqMeshSession is reachable from seqmesh's
-            # shard_proof only until it is wired in here
-            raise ValueError(
-                f"engine='seq' serves on one device; shards={shards} is "
-                f"not wired into kme-serve (use engine='lanes' for the "
-                f"sharded sweep engine)")
-        if engine == "lanes" and compat != "fixed":
-            raise ValueError("the lanes engine is fixed-mode only; use "
-                             "engine='seq' (stock wire surface), "
-                             "'native' or 'oracle' for compat='java'")
         # java-mode seq sessions checkpoint via the seqjava canonical
         # form (runtime/javasnap.py) since round 5 — no engine/compat
         # combination is excluded from durability
@@ -405,7 +395,7 @@ class MatchService:
         # built or restored: no TPU and no JAX_PLATFORMS=cpu raises
         # (kme_tpu/_jaxsetup.py). Host engines stay off jax.
         self.runs_on = {}
-        if engine in ("seq", "lanes"):
+        if engine == "seq":
             from kme_tpu import _jaxsetup
 
             self.runs_on = _jaxsetup.describe()
@@ -415,7 +405,7 @@ class MatchService:
         self._startup_session_s = 0.0
         resumed = False
         if checkpoint_dir is not None:
-            resumed = self._try_resume(engine, compat, shards, width)
+            resumed = self._try_resume(engine, compat)
         if resumed:
             self._startup_session_s = _t.perf_counter() - t_session0
             self._restore_sample_seq()
@@ -424,14 +414,7 @@ class MatchService:
             self._init_observability(resumed=True)
             self._commit_watermark()
             return
-        if engine == "lanes":
-            from kme_tpu.engine.lanes import LaneConfig
-            from kme_tpu.runtime.session import LaneSession
-
-            cfg = LaneConfig(lanes=symbols, slots=slots, accounts=accounts,
-                             max_fills=max_fills)
-            self._session = LaneSession(cfg, shards=shards, width=width)
-        elif engine == "seq":
+        if engine == "seq":
             self._session = self._make_seq_session()
         elif engine == "native":
             from kme_tpu.native.oracle import NativeOracleEngine
@@ -1056,8 +1039,7 @@ class MatchService:
             max_fills=self._req_max_fills, hbm_books=slots > 512,
             compat=self._compat)
 
-    def _try_resume(self, engine: str, compat: str, shards: int,
-                    width: int) -> bool:
+    def _try_resume(self, engine: str, compat: str) -> bool:
         from kme_tpu.runtime import checkpoint as ck
 
         if engine == "seq":
@@ -1083,22 +1065,6 @@ class MatchService:
                                               self._seq_cfg())
             if ses is None:
                 return False
-            self._session = ses
-        elif engine == "lanes":
-            # elastic restore onto the REQUESTED topology (snapshots are
-            # canonical across shards/width)
-            ses, offset = ck.load_session(self.checkpoint_dir,
-                                          shards=shards, width=width)
-            if ses is None:
-                return False
-            want = {"lanes": self._req_symbols, "accounts": self._req_accounts,
-                    "slots": self._req_slots, "max_fills": self._req_max_fills}
-            have = {k: getattr(ses.cfg, k) for k in want}
-            if want != have:
-                raise ValueError(
-                    f"snapshot in {self.checkpoint_dir} has capacity "
-                    f"config {have}, but {want} was requested — capacity "
-                    f"changes need a state migration, not a resume")
             self._session = ses
         elif engine == "native":
             nat, offset = ck.load_native(self.checkpoint_dir)
@@ -1217,20 +1183,18 @@ class MatchService:
         holds them), its host state and `extra` by copy
         (checkpoint.capture_seq_session); the fetch, the host's passes,
         the digest, the write and the fsyncs are the writer's. The
-        engines no deployment serves (lanes, native, oracle: their
-        state is a LaneSession's or the host's own, a text dump or a
-        pickle that has to be made at the boundary anyway) are saved
-        here, on the serve thread. -> the list that takes what a
-        fixed-mode SeqSession's snapshot fetched, for the auditor's
-        compare, once the file is written."""
+        host engines (native, oracle: their state is the host's own, a
+        text dump or a pickle that has to be made at the boundary
+        anyway) are saved here, on the serve thread. -> the list that
+        takes what a fixed-mode SeqSession's snapshot fetched, for the
+        auditor's compare, once the file is written."""
         import functools
 
         from kme_tpu.runtime import checkpoint as ck
-        from kme_tpu.runtime.seqsession import SeqSession
 
         fetched = []
         with self._span("snapshot_handoff"):
-            if isinstance(self._session, SeqSession):
+            if self._session is not None:
                 write = functools.partial(
                     ck.write_seq_snapshot, self.checkpoint_dir,
                     ck.capture_seq_session(self._session, self.offset,
@@ -1240,9 +1204,7 @@ class MatchService:
                 self._snap_writer = _SnapshotWriter(
                     functools.partial(self._snapshot_save, write))
                 return fetched
-            if self._session is not None:
-                save, engine = ck.save_session, self._session
-            elif self._native is not None:
+            if self._native is not None:
                 save, engine = ck.save_native, self._native
             else:
                 save, engine = ck.save_oracle, self._oracle
@@ -2349,7 +2311,8 @@ class MatchService:
                 "pos_load_pct": round(100.0 * live / cap, 4)})
 
     def metrics(self) -> Optional[dict]:
-        """On-device counters+gauges (lanes engine; None for oracle).
+        """On-device counters+gauges (seq engine; None for the host
+        engines).
         A seq session with nothing in flight adds `stale_routes`: the
         oid routes it holds beyond the orders resting on the device."""
         if self._session is None:

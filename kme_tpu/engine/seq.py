@@ -1,10 +1,11 @@
 """Sequential Pallas mega-kernel engine (round 4).
 
-The round-3 profile showed the vectorized sweep engine's scan step is
-OP-COUNT-bound: ~185 XLA ops/step at ~0.25us launch overhead each, with
-occupancy capped at ~4.4 msgs/step by hot-lane serialization under the
-conflict-free scheduler (one message per lane per step). This engine
-removes both limits at once: ONE Pallas kernel processes a micro-batch
+The round-3 profile showed the scan step of the vectorized sweep
+engine this one replaced to be OP-COUNT-bound: ~185 XLA ops/step at
+~0.25us launch overhead each, with occupancy capped at ~4.4 msgs/step
+by hot-lane serialization under its conflict-free scheduler (one
+message per lane per step). This engine removes both limits at once:
+ONE Pallas kernel processes a micro-batch
 of B messages STRICTLY SEQUENTIALLY — the reference's own execution
 model (KProcessor.java:95-126, single StreamThread) — with the entire
 engine state VMEM-resident for the duration of the call. Sequential
@@ -17,8 +18,8 @@ Measured basis (scripts/exp_seqkernel.py, v5e chip): a bare sequential
 sweep body runs at ~64ns/msg — two orders of magnitude under the sweep
 engine's per-step floor.
 
-Semantics: compat='fixed' exactly, mirroring engine/lanes.py (which the
-oracle pins byte-exact) including the capacity envelope (slots /
+Semantics: compat='fixed' exactly, byte for byte what the oracle
+(oracle/engine.py) defines, including the capacity envelope (slots /
 max_fills per-message rejects), Q9 prev-echo, Java int32/int64 wrap
 arithmetic, and barrier settles (payout/remove wipe order: buy side
 first, (price, seq) within a side — oracle._wipe_book_fixed).
@@ -39,7 +40,7 @@ are recombined only in scalar emulation helpers inside the kernel):
   four for 128..255, so a lane's positions are PTL = ceil(A/256)
   consecutive tiles (a payout scans those, not the store) and one
   4 KB DMA moves whole entries. An absent position is all zeros (the
-  delete-at-zero invariant the lanes engine already uses). The plane
+  delete-at-zero invariant). The plane
   lives in HBM at every size (1024 x 2048 is 33 MB) and the kernel
   keeps ONE tile in a VMEM scratch, written back when dirty and
   replaced on a miss — the `hbm_books` idiom; a miss costs well under
@@ -73,15 +74,60 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from kme_tpu.engine.lanes import (  # noqa: F401 (re-exported act codes)
-    L_NOP, L_BUY, L_SELL, L_CANCEL, L_CREATE, L_TRANSFER, L_ADD_SYMBOL,
-    LERR_OK, LERR_FILLBUF_FULL, METRIC_NAMES, N_METRICS,
-    MET_MSGS, MET_TRADES_OK, MET_FILLS, MET_CONTRACTS, MET_REJ_CAPACITY,
-    MET_REJ_RISK, MET_RESTED, MET_CANCELS_OK, MET_REJ_CANCEL,
-    MET_TRANSFERS_OK, MET_REJ_OTHER, MET_BARRIERS,
-    HIST_NAMES, HIST_FILLS, HIST_DEPTH, HIST_OCCUPANCY,
-    N_HIST, N_HIST_BUCKETS,
-)
+# dense lane op codes (the host router packs these; 7/8/9 below are the
+# barriers)
+L_NOP = 0
+L_BUY = 1
+L_SELL = 2
+L_CANCEL = 3
+L_CREATE = 4
+L_TRANSFER = 5
+L_ADD_SYMBOL = 6
+
+# engine error codes (sticky, per call). Book/fill CAPACITY overflow is
+# NOT an error: it is a per-message REJECT (the H2/H3 envelope policy —
+# the offending order is refused as a unit, surfaced as an OUT REJECT in
+# the wire stream, and the batch continues). What is sticky is a bound
+# of a session buffer or of java mode's device surface, not of the
+# engine's semantics (the java-mode codes are below).
+LERR_OK = 0
+LERR_FILLBUF_FULL = 3  # a call's fill log exhausted (SeqConfig.fill_cap)
+
+# on-device metrics counters (int64 on the host, accumulated from each
+# call's scalar row — SURVEY.md §5's replacement for the reference's
+# untouched JMX metrics)
+MET_MSGS = 0            # device-executed messages (non-NOP)
+MET_TRADES_OK = 1       # accepted BUY/SELL
+MET_FILLS = 2           # fill events (maker count)
+MET_CONTRACTS = 3       # contracts traded (sum of fill sizes)
+MET_REJ_CAPACITY = 4    # H2/H3 envelope rejects
+MET_REJ_RISK = 5        # margin/validation rejects
+MET_RESTED = 6          # orders appended to a book
+MET_CANCELS_OK = 7
+MET_REJ_CANCEL = 8
+MET_TRANSFERS_OK = 9
+MET_REJ_OTHER = 10      # failed create/transfer/add_symbol
+MET_BARRIERS = 11       # payout/remove settles executed
+N_METRICS = 12
+
+METRIC_NAMES = ("msgs", "trades_ok", "fills", "contracts", "rej_capacity",
+                "rej_risk", "rested", "cancels_ok", "rej_cancel",
+                "transfers_ok", "rej_other", "barriers")
+
+# on-device distribution histograms: power-of-two buckets accumulated
+# next to the metrics counters and fetched in the same device transfer
+# (no extra round-trips). Bucket index for value v is
+# #{k in 0..14 : v >= 2^k}: v <= 0 -> bucket 0, v == 1 -> 1,
+# v in [2^(i-1), 2^i) -> i, v >= 2^14 -> 15.
+HIST_FILLS = 0        # makers swept per ACCEPTED trade (0 = pure rest)
+HIST_DEPTH = 1        # resting orders (both sides) in the touched book
+#                       after each accepted trade/cancel
+HIST_OCCUPANCY = 2    # non-NOP messages per kernel call; empty calls
+#                       are unobserved
+N_HIST = 3
+N_HIST_BUCKETS = 16
+
+HIST_NAMES = ("fills_per_order", "book_depth", "batch_occupancy")
 
 # scalar-row histogram window: lanes [HIST_LANE0, HIST_LANE0 + 3*16) of
 # output row 0 carry the PER-CALL power-of-two histogram deltas (fills,
@@ -99,8 +145,7 @@ POS_TILES_LANE = HIST_LANE0 + N_HIST * N_HIST_BUCKETS
 WIPED_LANE = POS_TILES_LANE + 1
 CREDITED_LANE = POS_TILES_LANE + 2
 
-# barrier acts (device-executed, unlike the lanes engine where barriers
-# are separate settle calls): mode mapping matches barrier_ops.settle
+# barrier acts (device-executed, ordinary messages of a call)
 L_PAYOUT_YES = 7
 L_PAYOUT_NO = 8
 L_REMOVE_SYMBOL = 9
@@ -438,8 +483,8 @@ def build_seq_step(cfg: SeqConfig):
                 (ci == _i(0)) & (r0 == _i(LERR_OK)), code, r0)
 
         def hbucket(v):
-            """power-of-two bucket index of scalar v (lanes.hist_bucket
-            semantics): #{k in 0..14 : v >= 2^k}."""
+            """power-of-two bucket index of scalar v:
+            #{k in 0..14 : v >= 2^k}."""
             b = _i(0)
             for k2 in range(N_HIST_BUCKETS - 1):
                 b = b + (v >= _i(1 << k2)).astype(I32)
@@ -1731,7 +1776,7 @@ def export_java(cfg: SeqConfig, state) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# canonical (lanes-style) state import/export for checkpoint parity
+# canonical state import/export for checkpoints
 
 def _pos_views(cfg: SeqConfig, pos, both):
     """The same words seen from both sides: `pos` (pos_rows, 128) i32 as
@@ -1802,9 +1847,9 @@ def _dense_positions(cfg: SeqConfig, h: dict) -> dict:
 
 
 def export_canonical(cfg: SeqConfig, state) -> dict:
-    """Device planes -> the canonical snapshot layout the lanes engine
-    checkpoints use (slot_* (S,2,N) i64/i32/bool, flat positions s64,
-    bal s64) so snapshots restore across engines. Fixed mode only:
+    """Device planes -> the canonical snapshot layout (slot_* (S,2,N)
+    i64/i32/bool, flat positions s64, bal s64), independent of the
+    device's planes and tiles. Fixed mode only:
     java-mode state has its OWN canonical form (128-bit position keys,
     direction-tagged merged books) in runtime/javasnap.py."""
     if cfg.compat != "fixed":
@@ -2137,7 +2182,7 @@ def densify_canonical(canon: dict, layout: dict) -> dict:
     """Inverse of export_snapshot's encoding: every section `layout`
     names as sparse scattered into zeros of its dense shape (and
     `slot_used` set at the live slots), so that what comes out is the
-    canonical dict import_canonical and the lanes engine read."""
+    canonical dict import_canonical reads."""
     out = {k: v for k, v in canon.items()
            if k not in ("slot_idx", "pos_idx")}
 

@@ -156,43 +156,6 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     c = ctypes
     P64, P32 = c.POINTER(c.c_int64), c.POINTER(c.c_int32)
     sigs = {
-        "kme_sched_new": ([c.c_int32, c.c_int32, c.c_int32], c.c_void_p),
-        "kme_sched_free": ([c.c_void_p], None),
-        "kme_sched_plan": ([c.c_void_p, c.c_int64] + [P64] * 6, c.c_int32),
-        "kme_sched_n_placed": ([c.c_void_p], c.c_int64),
-        "kme_sched_p_msg": ([c.c_void_p], P64),
-        "kme_sched_p_seg": ([c.c_void_p], P32),
-        "kme_sched_p_step": ([c.c_void_p], P32),
-        "kme_sched_p_lane": ([c.c_void_p], P32),
-        "kme_sched_p_act": ([c.c_void_p], P32),
-        "kme_sched_p_aidx": ([c.c_void_p], P32),
-        "kme_sched_p_oid": ([c.c_void_p], P64),
-        "kme_sched_p_price": ([c.c_void_p], P32),
-        "kme_sched_p_size": ([c.c_void_p], P32),
-        "kme_sched_p_slot": ([c.c_void_p], P32),
-        "kme_sched_n_barriers": ([c.c_void_p], c.c_int64),
-        "kme_sched_b_msg": ([c.c_void_p], P64),
-        "kme_sched_b_lane": ([c.c_void_p], P32),
-        "kme_sched_b_mode": ([c.c_void_p], P32),
-        "kme_sched_b_credit": ([c.c_void_p], P64),
-        "kme_sched_n_rejects": ([c.c_void_p], c.c_int64),
-        "kme_sched_r_msg": ([c.c_void_p], P64),
-        "kme_sched_n_segments": ([c.c_void_p], c.c_int64),
-        "kme_sched_seg_steps": ([c.c_void_p], P32),
-        "kme_sched_n_program": ([c.c_void_p], c.c_int64),
-        "kme_sched_program": ([c.c_void_p], P32),
-        "kme_sched_err_value": ([c.c_void_p], c.c_int64),
-        "kme_sched_n_accounts": ([c.c_void_p], c.c_int64),
-        "kme_sched_n_symbols": ([c.c_void_p], c.c_int64),
-        "kme_sched_n_routes": ([c.c_void_p], c.c_int64),
-        "kme_sched_rr_lane": ([c.c_void_p], c.c_int32),
-        "kme_sched_set_rr_lane": ([c.c_void_p, c.c_int32], None),
-        "kme_sched_export_accounts": ([c.c_void_p, P64, P32], None),
-        "kme_sched_export_symbols": ([c.c_void_p, P64, P32], None),
-        "kme_sched_export_routes": ([c.c_void_p, P64, P64], None),
-        "kme_sched_import_accounts": ([c.c_void_p, c.c_int64, P64, P32], None),
-        "kme_sched_import_symbols": ([c.c_void_p, c.c_int64, P64, P32], None),
-        "kme_sched_import_routes": ([c.c_void_p, c.c_int64, P64, P64], None),
         # native quirk-exact engine (kme_oracle.cpp)
         "kme_oracle_new": ([c.c_int32, c.c_int32, c.c_int64, c.c_int32,
                             c.c_int64], c.c_void_p),

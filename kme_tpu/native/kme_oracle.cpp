@@ -2,10 +2,10 @@
 // (kme_tpu/oracle/engine.py — the semantics authority, itself an exact
 // replica of /root/reference/src/main/java/KProcessor.java:63-445).
 //
-// Purpose: quirk-exact serving AT SPEED. The parallel lanes engine is
-// provably un-schedulable under Q11 (COMPAT.md) and the serial device
-// replica is op-count-bound on TPU, so the fast java-compat path is a
-// native host engine — the same role the reference's own JVM+RocksDB
+// Purpose: quirk-exact serving AT SPEED. The java-mode device engine
+// serves the stock wire surface only (COMPAT.md) and the serial device
+// replica is op-count-bound on TPU, so the fast java-compat path for
+// every stream is a native host engine — the same role the reference's own JVM+RocksDB
 // stack plays. Byte parity with the Python oracle is pinned by
 // tests/test_native_oracle.py (wire lines AND deep store state).
 //

@@ -2,7 +2,7 @@
 //
 // The seq engine needs no conflict analysis — routing is pure id
 // mapping (dense aid/sid spaces, oid -> lane for cancels, host-reject
-// edge semantics identical to runtime/sequencer.py). The Python loop
+// edge semantics). The Python loop
 // costs ~2us/message (~0.8s on the 400k soak); this does the same work
 // over columnar int64 arrays in ~tens of ns/message. Semantics
 // authority: SeqRouter.route (runtime/seqsession.py); equality pinned
@@ -407,7 +407,7 @@ const int64_t* kme_router_o_rej(void* p) {
   return static_cast<Router*>(p)->o_rej.data();
 }
 
-// map export/import (checkpoint contract, mirrors kme_sched_*)
+// map export/import (checkpoint contract)
 int64_t kme_router_n_accounts(void* p) {
   return (int64_t)static_cast<Router*>(p)->aid_idx.size();
 }

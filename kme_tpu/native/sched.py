@@ -1,225 +1,27 @@
-"""NativeScheduler: the C++ conflict-free scheduler behind the Python
-Scheduler API.
-
-Drop-in for kme_tpu.runtime.sequencer.Scheduler (which remains the
-semantics authority and the fallback): identical plans field-for-field
-(tests/test_native_sched.py), identical id-space state surface
-(aid_idx / sid_lane / oid_sid / _rr_lane as properties backed by the
-C++ maps, so checkpoint save/restore works unchanged).
-
-One deliberate difference: the wire envelope (int32 price/size) is
-validated for the WHOLE batch up front, so an EnvelopeError leaves the
-id maps untouched (the Python fallback mutates them up to the offending
-message); both raise on the same streams.
+"""The served host path's native calls (kme_host.cpp, kme_wire.cpp)
+behind Python functions: one C++ call per stage of a batch — plan
+(envelope check + route + H2D staging pack) on the way in, output
+arrays -> byte-stream reconstruction on the way out — and the seqmesh
+planner's two staging helpers. Each validates every buffer before it
+crosses the ctypes boundary (native.check_buffer); the numpy forms in
+runtime/seqsession.py remain the semantics authority
+(tests/test_host_path.py pins the parity).
 """
 
 from __future__ import annotations
 
 import ctypes
-from typing import Dict, List, Sequence
 
 import numpy as np
 
 from kme_tpu.native import BoundaryError, check_buffer, load_library
-from kme_tpu.runtime.sequencer import (
-    Barrier, EnvelopeError, CapacityError, HostReject, Schedule,
-    sorted_routes,
-)
-from kme_tpu.wire import OrderMsg
-
-_ST_OK, _ST_CAP_ACCOUNTS, _ST_CAP_SYMBOLS = 0, 1, 2
-
-
-def native_available() -> bool:
-    return load_library() is not None
+from kme_tpu.wire import EnvelopeError
 
 
 def _arr(ptr, n, dtype):
     if n == 0:
         return np.zeros(0, dtype)
     return np.ctypeslib.as_array(ptr, shape=(n,)).astype(dtype, copy=True)
-
-
-class NativeScheduler:
-    def __init__(self, num_lanes: int, num_accounts: int,
-                 width: int = 0) -> None:
-        if width < 0:
-            raise ValueError(f"width must be >= 0, got {width}")
-        self._lib = load_library()
-        if self._lib is None:
-            raise RuntimeError("native scheduler library unavailable")
-        self.S = num_lanes
-        self.A = num_accounts
-        self.width = width
-        self._h = self._lib.kme_sched_new(num_lanes, num_accounts, width)
-
-    def __del__(self):
-        lib, h = getattr(self, "_lib", None), getattr(self, "_h", None)
-        if lib is not None and h:
-            lib.kme_sched_free(h)
-            self._h = None
-
-    # -- planning ----------------------------------------------------------
-
-    def plan(self, msgs: Sequence[OrderMsg]) -> Schedule:
-        from kme_tpu.oracle import javalong as jl
-
-        n = len(msgs)
-        la, lo_, ld, ls, lp, lz = [], [], [], [], [], []
-        jlong = jl.jlong
-        for i, m in enumerate(msgs):
-            if not (-2**31 <= m.price < 2**31 and -2**31 <= m.size < 2**31):
-                raise EnvelopeError(
-                    f"message {i}: price/size outside int32 "
-                    f"(price={m.price}, size={m.size})")
-            # action is compared RAW against the opcode table (matching
-            # the Python fallback): out-of-int64 actions are unknown
-            # opcodes, never aliased by wrapping. Ids wrap to Java longs
-            # exactly like the Python scheduler's map keys.
-            a = m.action
-            la.append(a if -2**63 <= a < 2**63 else -1)
-            lo_.append(jlong(m.oid))
-            ld.append(jlong(m.aid))
-            ls.append(jlong(m.sid))
-            lp.append(m.price)
-            lz.append(m.size)
-        arrs = [np.array(l, np.int64) if l else np.zeros(0, np.int64)
-                for l in (la, lo_, ld, ls, lp, lz)]
-        P64 = ctypes.POINTER(ctypes.c_int64)
-        ptrs = [c.ctypes.data_as(P64) for c in arrs]
-        st = self._lib.kme_sched_plan(self._h, n, *ptrs)
-        if st == _ST_CAP_ACCOUNTS:
-            raise CapacityError(
-                f"account capacity {self.A} exhausted "
-                f"(aid={self._lib.kme_sched_err_value(self._h)})")
-        if st == _ST_CAP_SYMBOLS:
-            raise CapacityError(
-                f"symbol capacity {self.S} exhausted "
-                f"(sid={self._lib.kme_sched_err_value(self._h)})")
-
-        lib, h = self._lib, self._h
-        np_ = lib.kme_sched_n_placed(h)
-        cols = {
-            "msg_index": _arr(lib.kme_sched_p_msg(h), np_, np.int64),
-            "segment": _arr(lib.kme_sched_p_seg(h), np_, np.int32),
-            "step": _arr(lib.kme_sched_p_step(h), np_, np.int32),
-            "lane": _arr(lib.kme_sched_p_lane(h), np_, np.int32),
-            "act": _arr(lib.kme_sched_p_act(h), np_, np.int32),
-            "aidx": _arr(lib.kme_sched_p_aidx(h), np_, np.int32),
-            "oid": _arr(lib.kme_sched_p_oid(h), np_, np.int64),
-            "price": _arr(lib.kme_sched_p_price(h), np_, np.int32),
-            "size": _arr(lib.kme_sched_p_size(h), np_, np.int32),
-            "slot": _arr(lib.kme_sched_p_slot(h), np_, np.int32),
-        }
-        nb = lib.kme_sched_n_barriers(h)
-        b_msg = _arr(lib.kme_sched_b_msg(h), nb, np.int64)
-        b_lane = _arr(lib.kme_sched_b_lane(h), nb, np.int32)
-        b_mode = _arr(lib.kme_sched_b_mode(h), nb, np.int32)
-        b_credit = _arr(lib.kme_sched_b_credit(h), nb, np.int64)
-        barriers = [Barrier(int(b_msg[i]), int(b_lane[i]), int(b_mode[i]),
-                            int(b_credit[i])) for i in range(nb)]
-        nr = lib.kme_sched_n_rejects(h)
-        rejects = [HostReject(int(x))
-                   for x in _arr(lib.kme_sched_r_msg(h), nr, np.int64)]
-        ns = lib.kme_sched_n_segments(h)
-        seg_steps = _arr(lib.kme_sched_seg_steps(h), ns, np.int32).tolist()
-        npr = lib.kme_sched_n_program(h)
-        prog_raw = _arr(lib.kme_sched_program(h), npr * 2, np.int32)
-        program = [("scan" if prog_raw[2 * i] == 0 else "barrier",
-                    int(prog_raw[2 * i + 1])) for i in range(npr)]
-        return Schedule(cols, barriers, rejects, seg_steps, program)
-
-    # -- id-space state (same surface as the Python Scheduler) ------------
-
-    @property
-    def aid_idx(self) -> Dict[int, int]:
-        n = self._lib.kme_sched_n_accounts(self._h)
-        keys = np.zeros(n, np.int64)
-        vals = np.zeros(n, np.int32)
-        self._lib.kme_sched_export_accounts(
-            self._h, keys.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-            vals.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)))
-        return dict(zip(keys.tolist(), vals.tolist()))
-
-    @aid_idx.setter
-    def aid_idx(self, d: Dict[int, int]) -> None:
-        keys = np.fromiter(d.keys(), np.int64, len(d))
-        vals = np.fromiter(d.values(), np.int32, len(d))
-        self._lib.kme_sched_import_accounts(
-            self._h, len(d),
-            keys.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-            vals.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)))
-
-    @property
-    def sid_lane(self) -> Dict[int, int]:
-        n = self._lib.kme_sched_n_symbols(self._h)
-        keys = np.zeros(n, np.int64)
-        vals = np.zeros(n, np.int32)
-        self._lib.kme_sched_export_symbols(
-            self._h, keys.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-            vals.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)))
-        return dict(zip(keys.tolist(), vals.tolist()))
-
-    @sid_lane.setter
-    def sid_lane(self, d: Dict[int, int]) -> None:
-        keys = np.fromiter(d.keys(), np.int64, len(d))
-        vals = np.fromiter(d.values(), np.int32, len(d))
-        self._lib.kme_sched_import_symbols(
-            self._h, len(d),
-            keys.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-            vals.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)))
-
-    def routes_arrays(self):
-        """`oid_sid` as a snapshot carries it (sorted_routes), straight
-        from the C++ map: no dict in between."""
-        n = self._lib.kme_sched_n_routes(self._h)
-        keys = np.zeros(n, np.int64)
-        vals = np.zeros(n, np.int64)
-        self._lib.kme_sched_export_routes(
-            self._h, keys.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-            vals.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)))
-        return sorted_routes(keys, vals)
-
-    def import_routes(self, keys, vals) -> None:
-        keys = np.ascontiguousarray(keys, np.int64)
-        vals = np.ascontiguousarray(vals, np.int64)
-        if keys.shape != vals.shape or keys.ndim != 1:
-            raise ValueError(f"routes: {keys.shape} keys for "
-                             f"{vals.shape} values")
-        self._lib.kme_sched_import_routes(
-            self._h, len(keys),
-            keys.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-            vals.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)))
-
-    @property
-    def oid_sid(self) -> Dict[int, int]:
-        keys, vals = self.routes_arrays()
-        return dict(zip(keys.tolist(), vals.tolist()))
-
-    @oid_sid.setter
-    def oid_sid(self, d: Dict[int, int]) -> None:
-        self.import_routes(np.fromiter(d.keys(), np.int64, len(d)),
-                           np.fromiter(d.values(), np.int64, len(d)))
-
-    @property
-    def _rr_lane(self) -> int:
-        return int(self._lib.kme_sched_rr_lane(self._h))
-
-    @_rr_lane.setter
-    def _rr_lane(self, v: int) -> None:
-        self._lib.kme_sched_set_rr_lane(self._h, int(v))
-
-    # -- reconstruction helpers (same as Scheduler) ------------------------
-
-    def acct_of_idx(self) -> List[int]:
-        d = self.aid_idx
-        out = [0] * len(d)
-        for aid, idx in d.items():
-            out[idx] = aid
-        return out
-
-    def sid_of_lane(self) -> Dict[int, int]:
-        return {lane: sid for sid, lane in self.sid_lane.items()}
 
 
 def apply_placement(perm, lanes, s_local: int):
@@ -229,7 +31,7 @@ def apply_placement(perm, lanes, s_local: int):
 
     This is the host-path mirror of SeqMeshSession.plan_windows'
     placement application (parallel/seqmesh.py); like plan_batch /
-    recon_batch above, its eventual native home is kme_host.cpp —
+    recon_batch below, its eventual native home is kme_host.cpp —
     the numpy fancy-index form here is the semantics authority and is
     already allocation-light enough for the planner's hot scope.
     Returns (slot, shard, local_row), each shaped like `lanes`."""
@@ -278,12 +80,9 @@ def slice_windows(wins: dict, win_idx, shard: int, shards: int,
 
 # -- batch host-path entry points (one C++ call per stage) ----------------
 #
-# The serve/bench hot loop's host work — envelope check + route + H2D
-# staging pack on the way in, output-array -> byte-stream reconstruction
-# on the way out — as single C calls (kme_plan_batch / kme_recon_batch).
-# plan_batch returns None when the loaded library predates its entry
-# point, so its caller falls back to the numpy pack, which remains the
-# semantics authority (parity pinned by tests/test_host_path.py).
+# kme_plan_batch / kme_recon_batch. plan_batch returns None when the
+# loaded library predates its entry point, so its caller falls back to
+# the numpy pack.
 
 
 def plan_batch(router, batch, B: int):
@@ -346,6 +145,10 @@ def collect_plan(lib, router, pack, K, B, price, size):
             f"(price={int(price[i])}, "
             f"size={int(size[i])})")
     if K < 0:
+        # the routers' error, defined beside them (a lower layer
+        # raises what its caller catches)
+        from kme_tpu.runtime.seqsession import CapacityError
+
         raise CapacityError(
             f"{'account' if K == -1 else 'symbol'} capacity "
             f"exhausted (id={lib.kme_router_err_value(router._h)})")

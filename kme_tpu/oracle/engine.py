@@ -136,7 +136,7 @@ class OracleEngine:
                  book_slots: Optional[int] = None,
                  max_fills: Optional[int] = None) -> None:
         """book_slots / max_fills: the CAPACITY ENVELOPE mirroring the
-        lane engine's static shapes (engine/lanes.py LaneConfig slots /
+        device engine's static shapes (engine/seq.py SeqConfig slots /
         max_fills). When set (fixed mode only), a BUY/SELL that would
         rest beyond `book_slots` resting orders on its (sid, side) or
         sweep more than `max_fills` makers is rejected as a unit — no

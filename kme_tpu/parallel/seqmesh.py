@@ -92,14 +92,28 @@ import numpy as np
 import kme_tpu._jaxsetup  # noqa: F401
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from kme_tpu.engine import seq as SQ
 from kme_tpu.native import sched as native_sched
-from kme_tpu.parallel.mesh import AXIS, build_mesh
-from kme_tpu.runtime.seqsession import SeqSession, make_seq_router
+from kme_tpu.runtime.seqsession import (LaneEngineError, SeqSession,
+                                        make_seq_router)
 from kme_tpu.telemetry import PhaseTimer, Registry
 from kme_tpu.utils import pow2_bucket
+
+AXIS = "symbol"
+
+
+@functools.lru_cache(maxsize=None)
+def build_mesh(shards: int) -> Mesh:
+    """One Mesh per shard count per process — sessions share it, so the
+    jitted sharded builders below cache across sessions."""
+    devs = jax.devices()
+    if len(devs) < shards:
+        raise ValueError(
+            f"need {shards} devices for {shards} shards, have {len(devs)}")
+    return Mesh(np.array(devs[:shards]), axis_names=(AXIS,))
+
 
 # per-shard per-window message capacity (windows close earlier on
 # account conflicts; 128 keeps the padded input planes small)
@@ -533,8 +547,6 @@ class SeqMeshSession(SeqSession):
         return self._run_lockstep(msgs)
 
     def _run_lockstep(self, msgs):
-        from kme_tpu.runtime.session import LaneEngineError
-
         # migrations happen BETWEEN batches only: state is quiescent
         # here, so the permutation is a pure relabeling of lane rows
         self._maybe_rebalance()
@@ -923,8 +935,6 @@ class SeqMeshSession(SeqSession):
         of the stacked all_gather array. Raises at the first errored
         cell in (w, s) order — the same error surface as lockstep
         (module docstring)."""
-        from kme_tpu.runtime.session import LaneEngineError
-
         HR = SQ.hdr_rows(self.local_cfg)
         n = len(cols["act"])
         host = {k: np.zeros(n, dt) for k, dt in

@@ -20,19 +20,19 @@ and `snapshot_extra` reads it back on resume, so the replayed tail
 re-produces with the SAME stamps and the broker suppresses it.
 
 Snapshots are self-describing single files: every state array plus a
-JSON `meta` blob (config, compaction width, shard count, input offset,
-the small id maps) in one .npz, written atomically (tmp + rename) and
-named ckpt-<offset>.npz so the latest valid one wins; a torn or corrupt
-file falls back to the previous snapshot. The router's oid -> sid
+JSON `meta` blob (config, input offset, the small id maps) in one
+.npz, written atomically (tmp + rename) and named ckpt-<offset>.npz so
+the latest valid one wins; a torn or corrupt file falls back to the
+previous snapshot. The router's oid -> sid
 routes — every oid ever routed that no payout or removal dropped, the
 one id map that grows with the stream — are two int64 arrays of the
 payload, `route_oid` ascending and `route_sid` in its order (version 3;
 older files list them in the meta, and `_load_file` hands both on as
 the arrays).
 
-The device fill log is intentionally NOT saved: at a batch boundary it
-has been drained to the host and rewound (filloff == 0), so restore
-recreates it as zeros.
+The device's output planes (fills among them) are intentionally NOT
+saved: at a batch boundary they have been fetched, and every call
+starts them anew.
 """
 
 from __future__ import annotations
@@ -69,27 +69,10 @@ class SnapshotCapacityError(ValueError):
     silently fall back to a fresh engine."""
 
 
-_SKIP_KEYS = ("fillbuf",)
-# arrays whose leading axis is the lane axis (stored in CANONICAL form:
-# user lanes only — the compact path's scrap row is provably all-zero,
-# so it is stripped at save and recreated at load; this makes snapshots
-# portable across width/shard configurations)
-_LANE_KEYS = ("slot_oid", "slot_aid", "slot_price", "slot_size",
-              "slot_seq", "slot_used", "seq", "book_exists")
-_POS_KEYS = ("pos_amt", "pos_avail")  # flat (S*A,) lane-major
-
-
 # the version every .npz writer here gives its files: the routes as the
 # payload arrays `route_oid` / `route_sid` (versions 1 and 2 list them
 # in the meta as `oid_sid`; 2 is a "seq" file with a sparse section)
 _VERSION = 3
-
-
-def _routes_payload(router) -> dict:
-    """The router's oid -> sid routes as a snapshot carries them:
-    straight from where they live, no dict and no text in between."""
-    route_oid, route_sid = router.routes_arrays()
-    return {"route_oid": route_oid, "route_sid": route_sid}
 
 
 def snapshot_path(ckpt_dir: str, offset: int) -> str:
@@ -155,52 +138,6 @@ def list_snapshots(ckpt_dir: str) -> List[Tuple[int, str]]:
     return out
 
 
-def save_session(ckpt_dir: str, session, offset: int,
-                 keep: Optional[int] = None,
-                 extra: Optional[dict] = None) -> str:
-    """Snapshot `session` (a LaneSession) at input offset `offset`.
-    Must be called at a batch boundary (the fill log drained)."""
-    import jax
-
-    os.makedirs(ckpt_dir, exist_ok=True)
-    state = jax.tree.map(np.asarray, session.state)
-    if int(state["filloff"][0]) != 0:
-        raise ValueError("snapshot requires a drained fill log "
-                         "(call at a batch boundary)")
-    sch = session.scheduler
-    meta = {
-        "version": _VERSION,
-        "kind": "lanes",
-        "offset": int(offset),
-        "cfg": dataclasses.asdict(session.cfg),
-        "width": int(session.dev_cfg.width),
-        "shards": int(session.shards),
-        "aid_idx": sorted(sch.aid_idx.items()),
-        "sid_lane": sorted(sch.sid_lane.items()),
-        "rr_lane": sch._rr_lane,
-    }
-    if extra:
-        meta["extra"] = dict(extra)
-    S = session.cfg.lanes  # canonical lane count (no scrap row)
-    A = session.cfg.accounts
-    payload = _routes_payload(sch)
-    for k, v in state.items():
-        if k in _SKIP_KEYS:
-            continue
-        if k in _LANE_KEYS:
-            v = v[:S]
-        elif k in _POS_KEYS:
-            if v.ndim == 3:  # pos_dma planar i32 rows -> canonical s64
-                from kme_tpu.ops.rowdma import unpack64_np
-
-                v = unpack64_np(v, v.shape[0]).reshape(-1)
-            v = v[:S * A]
-        payload[k] = v
-    payload["meta"] = np.frombuffer(
-        json.dumps(meta).encode(), dtype=np.uint8)
-    return _atomic_savez(ckpt_dir, offset, payload, keep=keep)
-
-
 def _fsync_dir(d: str) -> None:
     fd = os.open(d, os.O_RDONLY)
     try:
@@ -244,8 +181,9 @@ def _load_file(path: str):
                 f"{want[:12]}…, computed {got[:12]}…): corrupt snapshot")
     # pre-digest snapshots (older writers) load unverified
     meta = json.loads(bytes(data["meta"]).decode())
-    # "lanes" and "seq" snapshots share the canonical payload layout
-    # and restore into EITHER engine (cross-engine restore); "seqjava"
+    # "seq" snapshots and those of kind "lanes" (written by the sweep
+    # engine, removed in PR 54; last writer 06d92bd) share the canonical
+    # payload layout and both restore into a SeqSession; "seqjava"
     # is the java-mode canonical form (runtime/javasnap.py), restorable
     # into SeqSession(compat='java') and convertible to/from the native
     # engine's dump. Version 2 is a "seq" snapshot with a section given
@@ -271,137 +209,6 @@ def _load_file(path: str):
         raise ValueError(f"snapshot {path}: no int64 route_oid / "
                          f"route_sid arrays of one length")
     return data, meta
-
-
-def load_session(ckpt_dir: str, shards: Optional[int] = None,
-                 width: Optional[int] = None):
-    """Restore the newest valid snapshot in `ckpt_dir`.
-    Returns (session, offset) or (None, 0) when no usable snapshot
-    exists. A corrupt newest file (torn write) falls back to the next.
-    `shards`/`width` override the snapshot's values (elastic restore
-    onto a different mesh or compaction width — snapshots are canonical,
-    so any combination restores bit-exactly)."""
-    for offset, path in list_snapshots(ckpt_dir):
-        try:
-            return _restore_one(path, shards, width), offset
-        except SnapshotCapacityError:
-            raise          # operator error, not corruption: surface it
-        except Exception as e:  # torn/corrupt snapshot: fall back
-            import sys
-
-            print(f"kme_tpu.checkpoint: skipping unreadable snapshot "
-                  f"{path}: {e}", file=sys.stderr)
-    return None, 0
-
-
-def _restore_one(path: str, shards: Optional[int], width: Optional[int]):
-    """Restore one snapshot file into a live LaneSession (raises on any
-    corruption — load_session falls back to the previous snapshot)."""
-    import jax.numpy as jnp
-
-    from kme_tpu.engine.lanes import LaneConfig, make_lane_state
-    from kme_tpu.runtime.session import LaneSession
-
-    data, meta = _load_file(path)
-    if meta.get("kind") == "seqjava":
-        raise SnapshotCapacityError(
-            "java-mode snapshot cannot restore into the (fixed-mode) "
-            "lanes engine — restore with load_seq_session into "
-            "SeqConfig(compat='java') or convert to the native engine "
-            "(runtime/javasnap.py)")
-    if meta.get("kind") == "seq":  # cross-engine restore (canonical)
-        mc = meta["cfg"]
-        cfg = LaneConfig(lanes=int(mc["lanes"]), slots=int(mc["slots"]),
-                         accounts=int(mc["accounts"]),
-                         max_fills=int(mc["max_fills"]))
-    else:
-        cfg = LaneConfig(**meta["cfg"])
-    use_shards = meta["shards"] if shards is None else shards
-    use_width = meta["width"] if width is None else width
-    ses = LaneSession(cfg, shards=use_shards, width=use_width or 0)
-    fresh = make_lane_state(ses.dev_cfg)
-    S, A = cfg.lanes, cfg.accounts
-    state = {}
-    for k, v in fresh.items():
-        if k in _SKIP_KEYS:
-            state[k] = v  # recreated empty (drained at snapshot)
-            continue
-        if k == "metrics":
-            if k not in data:
-                state[k] = v  # pure observability counter: pre-metrics
-                continue      # snapshots restore with fresh zeros
-            arr = np.asarray(data[k])
-            want = (len(v),) if isinstance(v, tuple) else tuple(v.shape)
-            if arr.shape != want:
-                raise ValueError(
-                    f"snapshot {path}: shape mismatch for metrics: "
-                    f"{arr.shape} vs {want}")
-            # compact device state carries the counters as a scalar
-            # tuple; the canonical form is the (12,) array
-            state[k] = (tuple(jnp.asarray(x) for x in arr)
-                        if isinstance(v, tuple) else jnp.asarray(arr))
-            continue
-        if k == "hist":
-            if k not in data:
-                state[k] = v  # pure observability: pre-histogram
-                continue      # snapshots restore with fresh zeros
-            arr = np.asarray(data[k])
-            want = ((len(v), len(v[0])) if isinstance(v, tuple)
-                    else tuple(v.shape))
-            if arr.shape != want:
-                raise ValueError(
-                    f"snapshot {path}: shape mismatch for hist: "
-                    f"{arr.shape} vs {want}")
-            # compact device state carries one (16,) bucket row per
-            # histogram as a tuple; the canonical form is (3, 16)
-            state[k] = (tuple(jnp.asarray(x) for x in arr)
-                        if isinstance(v, tuple) else jnp.asarray(arr))
-            continue
-        arr = np.asarray(data[k])
-        if k in _POS_KEYS:
-            # canonical form is ALWAYS flat (S*A,) s64; the device
-            # layout may be pos_dma planar i32 rows
-            if arr.shape != (S * A,):
-                raise ValueError(
-                    f"snapshot {path}: shape mismatch for {k}: "
-                    f"{arr.shape} vs canonical ({S * A},)")
-            if v.ndim == 3:  # pack into planar rows, scrap row zero
-                from kme_tpu.ops.rowdma import pack64_np
-
-                S_dev = v.shape[0]
-                full64 = np.zeros((S_dev, A), np.int64)
-                full64[:S] = arr.reshape(S, A)
-                state[k] = jnp.asarray(pack64_np(full64, S_dev))
-            else:
-                full = np.array(v)
-                full[:S * A] = arr
-                state[k] = jnp.asarray(full)
-        elif k in _LANE_KEYS:
-            n = S
-            if arr.shape[:1] != (n,) or arr.shape[1:] != v.shape[1:]:
-                raise ValueError(
-                    f"snapshot {path}: shape mismatch for {k}: "
-                    f"{arr.shape} vs canonical ({n},)+{v.shape[1:]}")
-            full = np.array(v)  # writable zeros incl. scrap row
-            full[:n] = arr
-            state[k] = jnp.asarray(full)
-        else:
-            if arr.shape != tuple(v.shape):
-                raise ValueError(
-                    f"snapshot {path}: shape mismatch for {k}: "
-                    f"{arr.shape} vs {tuple(v.shape)}")
-            state[k] = jnp.asarray(arr)
-    if use_shards > 1:
-        from kme_tpu.parallel import mesh as M
-
-        state = M.shard_state(state, ses.mesh)
-    ses.state = state
-    sch = ses.scheduler
-    sch.aid_idx = {int(k): int(i) for k, i in meta["aid_idx"]}
-    sch.sid_lane = {int(k): int(l) for k, l in meta["sid_lane"]}
-    sch.import_routes(data["route_oid"], data["route_sid"])
-    sch._rr_lane = int(meta["rr_lane"])
-    return ses
 
 
 @dataclasses.dataclass
@@ -482,7 +289,9 @@ def _snapshot_payload(snap: SeqCapture, kind: str, arrays: dict,
             "aid_idx": aid_idx,
             "sid_lane": sid_lane,
         }
-        if kind == "seq":   # lanes-session cross-restore compatibility
+        if kind == "seq":
+            # read by the lanes engine's restore of a binary of PR 53 or
+            # before, and by nothing here: part of the version-3 format
             meta.update(rr_lane=0, width=0, shards=1)
         if layout and layout["sparse"]:
             meta["layout"] = layout
@@ -524,7 +333,8 @@ def write_seq_snapshot(ckpt_dir: str, snap: SeqCapture,
         kind, arrays = "seq", {k: v for k, v in canon.items()
                                if k != "metrics" and v is not None}
         arrays["err"] = np.asarray(canon["err"])
-        # lanes-session cross-restore expects the drained fill-log cursor
+        # as are the three meta fields of _snapshot_payload: read by
+        # the restore of a binary of PR 53 or before alone
         arrays["filloff"] = np.zeros(1, np.int64)
     payload = _snapshot_payload(snap, kind, arrays, layout)
     with session.timer.phase("snapshot_write"):
@@ -553,13 +363,13 @@ def save_seq_session(ckpt_dir: str, session, offset: int,
                      fetched=None) -> str:
     """Snapshot a SeqSession at input offset `offset`, here and now
     (capture_seq_session, then write_seq_snapshot on the caller's
-    thread), in the SAME canonical layout as lanes snapshots (slot_* /
-    flat s64 positions / bal), so snapshots restore across ENGINES as
-    well as across shard/width topologies. The books and the positions
-    are each written by their live entries where that is the smaller
-    encoding (engine/seq.py:export_snapshot; _load_file densifies), so
-    a file's size follows what is live and not the configured
-    capacity. A java-mode session writes its own canonical form."""
+    thread), in the canonical layout (slot_* / flat s64 positions /
+    bal), which no device layout shows through. The books and the
+    positions are each written by their live entries where that is the
+    smaller encoding (engine/seq.py:export_snapshot; _load_file
+    densifies), so a file's size follows what is live and not the
+    configured capacity. A java-mode session writes its own canonical
+    form."""
     return write_seq_snapshot(
         ckpt_dir, capture_seq_session(session, offset, extra), keep,
         fetched)
@@ -575,9 +385,10 @@ def _seqjava_snap_from_file(data, meta) -> dict:
 def load_seq_session(ckpt_dir: str, cfg=None):
     """Restore the newest valid snapshot into a SeqSession. `cfg` (a
     SeqConfig) sets the RESTORE topology — snapshots are canonical, so
-    any slots >= the snapshot's depth works, and lanes-engine snapshots
-    restore here too (cross-engine). Returns (session, offset) or
-    (None, 0)."""
+    any slots >= the snapshot's depth works, and a kind "lanes" file
+    of the sweep engine (`--engine lanes` until PR 54) restores here
+    too: the way from that engine to this one. Returns (session,
+    offset) or (None, 0)."""
     for offset, path in list_snapshots(ckpt_dir):
         try:
             # host-only read + parse + digest: whatever a torn or
@@ -640,7 +451,7 @@ def _restore_seq(data, meta, cfg):
     if cfg is None:
         if meta["kind"] == "seq":
             cfg = SQ.SeqConfig(**meta["cfg"])
-        else:  # a lanes snapshot: map the shared capacity fields
+        else:  # kind "lanes": map the capacity fields of its config
             mc = meta["cfg"]
             slots = -(-int(mc["slots"]) // 128) * 128
             cfg = SQ.SeqConfig(
@@ -652,7 +463,7 @@ def _restore_seq(data, meta, cfg):
     canon.setdefault("err", np.int32(0))
     if explicit_cfg:
         # service resume: the matching ENVELOPE must not change across
-        # a resume (the lanes/native paths enforce the same; deeper
+        # a resume (the native path enforces the same; deeper
         # books or a different max_fills alter reject behavior
         # mid-stream — that is a state migration, not a resume)
         n0 = int(np.asarray(canon["slot_oid"]).shape[2])
@@ -831,7 +642,7 @@ def load_oracle(ckpt_dir: str):
 
 
 def restore_seq_snapshot(path: str, cfg=None):
-    """Restore ONE .npz snapshot file (lanes/seq/seqjava canonical
+    """Restore ONE .npz snapshot file (seq/seqjava/lanes canonical
     form) into a SeqSession. Raises on corruption or capacity mismatch
     — the offset-addressed loaders (telemetry/xray.py) use this to
     restore a SPECIFIC anchor instead of the newest snapshot."""

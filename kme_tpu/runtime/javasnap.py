@@ -432,7 +432,7 @@ def from_native_dump(text: str) -> dict:
     for lane, c in seqc.items():
         seqc_arr[lane] = c
     lane_sid = {v: k for k, v in sid_lane.items()}
-    from kme_tpu.runtime.sequencer import sorted_routes
+    from kme_tpu.runtime.seqsession import sorted_routes
 
     # resting oids route to their symbol; non-resting oids need no
     # route (a device REJECT and a host REJECT are the same bytes)

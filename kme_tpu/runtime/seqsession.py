@@ -1,11 +1,11 @@
 """SeqSession: host half of the sequential mega-kernel engine.
 
-Unlike LaneSession, there is NO conflict-free scheduler: the kernel
-processes messages strictly sequentially (engine/seq.py), so planning
-reduces to ID ROUTING — dense aid/sid maps, oid -> lane routing for
-cancels, and host-resolved rejects for messages the device cannot act
-on (unknown-oid cancels, negative-sid ADD_SYMBOL, unmapped
-payout/remove) — the same edge semantics as runtime/sequencer.py.
+There is no scheduler: the kernel processes messages strictly
+sequentially (engine/seq.py), so planning reduces to ID ROUTING — dense
+aid/sid maps, oid -> lane routing for cancels, and host-resolved rejects
+for messages the device cannot act on (unknown-oid cancels,
+negative-sid ADD_SYMBOL, unmapped payout/remove), state-free in the
+reference too.
 Barriers (PAYOUT / REMOVE_SYMBOL) are ordinary device messages here
 (act codes 7/8/9), not separate settle calls.
 
@@ -26,24 +26,71 @@ import kme_tpu._jaxsetup  # noqa: F401
 
 from kme_tpu import opcodes as op
 from kme_tpu.engine import seq as SQ
-from kme_tpu.runtime import session as _session
-from kme_tpu.runtime.session import LaneEngineError
-from kme_tpu.runtime.sequencer import (CapacityError, DictRoutes,
-                                        EnvelopeError, sorted_routes)
 from kme_tpu.telemetry import PhaseTimer, Registry
-from kme_tpu.wire import (OrderMsg, OutRecord, WireBatch, order_json,
-                          reject_reason_codes)
+from kme_tpu.wire import (EnvelopeError, OrderMsg, OutRecord, WireBatch,
+                          order_json, reject_reason_codes)
 
-# register the seq-specific sticky-error name so LaneEngineError renders
-# it (the code space is shared with the lanes engine's LERR_*)
-_session._LERR_NAMES[SQ.LERR_HASH_FULL] = \
-    "java-mode position hash exhausted (SeqConfig.pos_cap)"
-_session._LERR_NAMES[SQ.LERR_JAVA_DOMAIN] = \
-    "java mode: price/size outside the device domain (the reference " \
-    "runs unvalidated fields; this stream needs the native engine)"
-_session._LERR_NAMES[SQ.LERR_JAVA_CAP] = \
-    "java mode: device capacity exceeded (reference stores are " \
-    "unbounded -- raise slots/max_fills or use the native engine)"
+# what LaneEngineError says for each sticky code of engine/seq.py
+_LERR_NAMES = {
+    SQ.LERR_FILLBUF_FULL: "a call's fill log exhausted (SeqConfig.fill_cap)",
+    SQ.LERR_HASH_FULL:
+        "java-mode position hash exhausted (SeqConfig.pos_cap)",
+    SQ.LERR_JAVA_DOMAIN:
+        "java mode: price/size outside the device domain (the reference "
+        "runs unvalidated fields; this stream needs the native engine)",
+    SQ.LERR_JAVA_CAP:
+        "java mode: device capacity exceeded (reference stores are "
+        "unbounded -- raise slots/max_fills or use the native engine)",
+}
+
+
+class LaneEngineError(RuntimeError):
+    def __init__(self, code: int) -> None:
+        self.code = int(code)
+        super().__init__(
+            f"lane engine error: {_LERR_NAMES.get(self.code, self.code)}")
+
+
+class CapacityError(RuntimeError):
+    """The workload exceeds a static device capacity (symbols, accounts)."""
+
+
+def sorted_routes(keys: np.ndarray, vals: np.ndarray):
+    """The oid -> sid routes a snapshot carries (runtime/checkpoint.py):
+    two int64 arrays, keys ascending and values in the keys' order — a
+    map's own order is not reproducible, and two snapshots of one state
+    must carry one digest."""
+    order = np.argsort(keys)
+    return keys[order], vals[order]
+
+
+def _dict_routes(d: Dict[int, int]):
+    return sorted_routes(np.fromiter(d.keys(), np.int64, len(d)),
+                         np.fromiter(d.values(), np.int64, len(d)))
+
+
+class DictRoutes:
+    """The snapshot's view of a Python router's `oid_sid` dict (the
+    native twin answers the same calls from its C++ map)."""
+
+    oid_sid: Dict[int, int]
+
+    def routes_arrays(self):
+        """`oid_sid` as a snapshot carries it (sorted_routes)."""
+        return _dict_routes(self.oid_sid)
+
+    def routes_capture(self):
+        """routes_arrays() in two halves: a copy of `oid_sid` as it
+        stands, made here, and -> a call that makes the two arrays of
+        that copy, for any thread at any later time (a snapshot's
+        writer, while the router routes on)."""
+        d = dict(self.oid_sid)
+        return lambda: _dict_routes(d)
+
+    def import_routes(self, keys, vals) -> None:
+        self.oid_sid = dict(zip(np.asarray(keys).tolist(),
+                                np.asarray(vals).tolist()))
+
 
 _TRADE_ACTS = {op.BUY: SQ.L_BUY, op.SELL: SQ.L_SELL}
 
@@ -75,11 +122,11 @@ ROUTER_STATS = ("symbols_listed", "symbols_settled", "lanes_released",
 
 
 class SeqRouter(DictRoutes):
-    """Arrival-order ID routing (no conflict analysis). Mirrors the
-    sequencer's id spaces and host-reject edge semantics. compat='java'
-    additionally emits the raw Java-long aid/sid columns and the Q1
-    merged-book flag the kernel needs, and REFUSES the opcodes outside
-    the java device surface.
+    """Arrival-order ID routing (no conflict analysis): the id spaces
+    and the host-reject edge semantics. compat='java' additionally
+    emits the raw Java-long aid/sid columns and the Q1 merged-book flag
+    the kernel needs, and REFUSES the opcodes outside the java device
+    surface.
 
     Fixed mode, the symbol lifecycle: a lane is bound to a symbol id by
     the first routed ADD_SYMBOL of it and goes back to the pool when an
@@ -268,8 +315,8 @@ class SeqRouter(DictRoutes):
             self.counts["unlisted_rejects"] += 1
 
         # envelope-check the WHOLE batch up front so an EnvelopeError
-        # leaves the id maps untouched (the native router's contract;
-        # native/sched.py documents the same for the scheduler)
+        # leaves the id maps untouched (the native router's contract:
+        # native/sched.py plan_batch / collect_plan)
         for i, m in enumerate(msgs):
             if not (-2**31 <= m.price < 2**31 and -2**31 <= m.size < 2**31):
                 raise EnvelopeError(
@@ -730,11 +777,10 @@ def route_events(cols: dict, host: dict, fills: np.ndarray):
 
 
 class SeqSession:
-    """Drop-in fixed-mode engine over the sequential mega-kernel.
-
-    Same public surface as LaneSession (process / process_wire /
-    metrics / export_state); single-device (the sharded path stays on
-    the lanes engine)."""
+    """The device engine's host half, over the sequential mega-kernel:
+    process / process_wire / submit + collect, metrics / histograms,
+    export_state. Single-device (parallel/seqmesh.py's SeqMeshSession
+    is the sharded subclass)."""
 
     # every span this session records (PhaseTimer names): the serve
     # loop registers each as a heartbeat gauge pair before the first

@@ -1,14 +1,14 @@
 """Metric registry: Counter / Gauge / Histogram with Prometheus text
 exposition and JSON export.
 
-One Registry instance is owned by each session (LaneSession,
-SeqSession, SeqMeshSession) and shared with the serving layer —
+One Registry instance is owned by each session (SeqSession,
+SeqMeshSession) and shared with the serving layer —
 `MatchService` publishes its per-batch counters into the same registry
 the engine projects its on-device counters into, so a single
 `/metrics` scrape (telemetry/httpd.py) sees both.
 
 Histograms use the engine's power-of-two bucket layout (16 buckets,
-engine/lanes.py): bucket 0 holds values <= 0, bucket i (1..14) holds
+engine/seq.py): bucket 0 holds values <= 0, bucket i (1..14) holds
 values in [2^(i-1), 2^i - 1], bucket 15 holds values >= 2^14. The
 Prometheus exposition therefore uses cumulative upper bounds
 le="0","1","3","7",...,"16383","+Inf". Device-filled histograms carry

@@ -1,8 +1,7 @@
 """Phase timing spans + Chrome trace-event export.
 
 PhaseTimer replaces the per-session `time.perf_counter()` blocks that
-were triplicated across runtime/session.py, runtime/seqsession.py, and
-parallel/seqmesh.py. Its `totals` dict IS the session's `phases`
+were duplicated across runtime/seqsession.py and parallel/seqmesh.py. Its `totals` dict IS the session's `phases`
 attribute (same object, assigned once), and — unlike the old code —
 totals ACCUMULATE across batches; callers snapshot/reset explicitly.
 

@@ -220,8 +220,8 @@ def is_binary_frame(first_byte: int) -> bool:
 # Reject reason codes (wire-level / journal-level).
 #
 # The reference collapses every refusal into an action=7 REJECT echo with
-# no cause; the device engines DO know why (the rej_* metric counters of
-# engine/lanes.py / engine/seq.py are incremented per cause). This table
+# no cause; the device engine DOES know why (the rej_* metric counters of
+# engine/seq.py are incremented per cause). This table
 # names the per-order code the sessions surface alongside reconstruction
 # (`last_reasons`), the flight-recorder journal records, and the opt-in
 # "REJ"-keyed MatchOut annotation carries. The default IN/OUT stream is
@@ -278,7 +278,7 @@ def rej_name(code: int) -> str:
 def reason_for_reject(action: int) -> int:
     """Heuristic reason for engines that report no per-order cause
     (native/oracle): classify by the rejected wire action. Device
-    sessions report exact codes instead (runtime/session.py)."""
+    sessions report exact codes instead (runtime/seqsession.py)."""
     if action in (2, 3):          # BUY / SELL
         return REJ_RISK
     if action == 4:               # CANCEL
@@ -394,10 +394,10 @@ def order_json(action: int, oid, aid, sid, price, size,
     """THE Jackson wire template (compact, declaration field order,
     next/prev always present — KProcessor.java:488). Every serializer in
     the tree — dumps_order on OrderMsg objects and the session's bulk
-    scalar reconstruction (runtime/session.py) — goes through this one
-    function, so a format change cannot fork the serving path from the
-    record path (the hazard is also pinned by tests/test_lanes_engine's
-    process/process_wire equivalence check)."""
+    scalar reconstruction (runtime/seqsession.py) — goes through this
+    one function, so a format change cannot fork the serving path from
+    the record path (the hazard is also pinned by
+    tests/test_seq_engine.py's process/process_wire equivalence check)."""
     nxt = "null" if next is None else str(next)
     prv = "null" if prev is None else str(prev)
     return (

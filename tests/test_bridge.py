@@ -48,19 +48,19 @@ def test_bridge_e2e_oracle_java_quirk_exact():
     assert got == _oracle_lines(msgs, "java")
 
 
-def test_bridge_e2e_lanes_engine_fixed():
-    """Validated workload through the device lanes engine service; byte
+def test_bridge_e2e_seq_engine_fixed():
+    """Validated workload through the device engine's service; byte
     parity vs the enveloped fixed-mode oracle."""
     broker = InProcessBroker()
     provision(broker)
     msgs = harness_stream(400, seed=5, num_symbols=4, num_accounts=8,
                           payout_opcode_bug=False, validate=True)
     _pump(broker, msgs)
-    svc = MatchService(broker, engine="lanes", compat="fixed", batch=128,
-                       symbols=8, accounts=16, slots=64, max_fills=32)
+    svc = MatchService(broker, engine="seq", compat="fixed", batch=128,
+                       symbols=8, accounts=16, slots=128, max_fills=32)
     assert svc.run(max_messages=len(msgs)) == len(msgs)
     got = list(consume_lines(broker, follow=False))
-    assert got == _oracle_lines(msgs, "fixed", book_slots=64, max_fills=32)
+    assert got == _oracle_lines(msgs, "fixed", book_slots=128, max_fills=32)
 
 
 def test_bridge_e2e_native_engine_quirk_exact():
@@ -113,7 +113,7 @@ def test_bridge_envelope_overflow_record_policy():
     dies on it): same drop/strict policy as non-JSON, for EVERY engine,
     and the stream continues past it."""
     for engine, compat in (("oracle", "java"), ("native", "java"),
-                           ("lanes", "fixed")):
+                           ("seq", "fixed")):
         if engine == "native":
             import pytest
 

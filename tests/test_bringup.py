@@ -81,12 +81,40 @@ def test_backend_in_process_is_the_requested_cpu():
     assert _jaxsetup.backend() == "cpu" and _jaxsetup.interpret()
 
 
-def test_serve_seq_refuses_shards():
+def test_serve_refuses_the_sweep_engine_and_its_flags(capsys):
+    """`--engine lanes` (removed in PR 54) and the two flags only it
+    read are argparse errors: exit 2, and the message names the
+    engines there are."""
+    from kme_tpu.bridge.serve import build_parser
+
+    with pytest.raises(SystemExit) as e:
+        build_parser().parse_args(["--engine", "lanes"])
+    assert e.value.code == 2
+    assert "--engine {seq,oracle,native}" in capsys.readouterr().err
+    for flag in ("--width", "--shards"):
+        with pytest.raises(SystemExit) as e:
+            build_parser().parse_args([flag, "1"])
+        assert e.value.code == 2
+
+
+def test_the_service_refuses_the_sweep_engine():
     from kme_tpu.bridge.broker import InProcessBroker
     from kme_tpu.bridge.service import MatchService
 
-    with pytest.raises(ValueError, match="shards=2"):
-        MatchService(InProcessBroker(), engine="seq", shards=2)
+    with pytest.raises(ValueError, match="unknown engine 'lanes'"):
+        MatchService(InProcessBroker(), engine="lanes")
+
+
+def test_the_services_default_engine_is_kme_serves():
+    """One decision in one place: a caller of the class and a caller of
+    the command line get the same engine."""
+    import inspect
+
+    from kme_tpu.bridge.serve import build_parser
+    from kme_tpu.bridge.service import MatchService
+
+    default = inspect.signature(MatchService).parameters["engine"].default
+    assert default == build_parser().get_default("engine") == "seq"
 
 
 def test_require_library_raises_unless_disabled(monkeypatch):

@@ -16,157 +16,201 @@ from kme_tpu.bridge.broker import InProcessBroker
 from kme_tpu.bridge.consume import consume_lines
 from kme_tpu.bridge.provision import provision
 from kme_tpu.bridge.service import TOPIC_IN, MatchService
-from kme_tpu.engine.lanes import LaneConfig
 from kme_tpu.oracle import OracleEngine
 from kme_tpu.runtime import checkpoint as ck
-from kme_tpu.runtime.session import LaneSession
 from kme_tpu.wire import dumps_order
 from kme_tpu.workload import harness_stream, zipf_symbol_stream
 
-CFG = LaneConfig(lanes=8, slots=64, accounts=32, max_fills=32, steps=16)
+HERE = os.path.dirname(os.path.abspath(__file__))
+SMALL = dict(lanes=8, slots=128, accounts=128, max_fills=16)
 
 
-def _stream(n=600, seed=21):
-    return zipf_symbol_stream(n, num_symbols=8, num_accounts=24, seed=seed,
-                              zipf_a=1.0)
+def _seq_session(state=None, **shape):
+    from kme_tpu.engine import seq as SQ
+    from kme_tpu.runtime.seqsession import SeqSession
+
+    ses = SeqSession(SQ.SeqConfig(**shape))
+    if state is not None:
+        ses.state = SQ.import_canonical(ses.cfg, state)
+    return ses
 
 
-def test_session_kill_resume_bit_identical(tmp_path):
-    """Kill the session after 300 of 600 messages; the resumed session's
+def _java_cfg():
+    from kme_tpu.engine import seq as SQ
+
+    return SQ.SeqConfig(lanes=8, slots=512, accounts=128, max_fills=128,
+                        batch=512, pos_cap=1 << 12, probe_max=16,
+                        compat="java")
+
+
+def _java_stream(n=2400, seed=7):
+    return harness_stream(n, seed=seed)
+
+
+# ---------------------------------------------------------------------------
+# the snapshot directory's discipline, over every writer there is: the
+# two .npz ones a SeqSession has (fixed mode, java mode) and the two of
+# the host engines (`native`: a header line and a text dump; `oracle`:
+# a pickle)
+
+def _writer(kind, monkeypatch=None):
+    """-> (fresh engine, its stream, save, load) of one snapshot
+    writer. `fixed-python` is `fixed-native` with the router that
+    KME_NATIVE=0 serves with: the same writer, the routes from a dict."""
+    from kme_tpu.native import load_library
+    from kme_tpu.runtime import seqsession
+
+    if kind == "java":
+        return (seqsession.SeqSession(_java_cfg()), _java_stream(n=600),
+                ck.save_seq_session, ck.load_seq_session)
+    msgs = list(zipf_symbol_stream(900, 8, 64, seed=12, zipf_a=0.0))
+    if kind == "oracle":
+        return (OracleEngine("fixed", book_slots=128, max_fills=16), msgs,
+                ck.save_oracle, ck.load_oracle)
+    if kind == "fixed-python":
+        monkeypatch.setattr(
+            seqsession, "make_seq_router",
+            lambda lanes, accounts, compat="fixed":
+            seqsession.SeqRouter(lanes, accounts, compat))
+    elif load_library() is None:
+        pytest.skip("native host runtime unavailable")
+    if kind == "native":
+        from kme_tpu.native.oracle import NativeOracleEngine
+
+        return (NativeOracleEngine("fixed", book_slots=128, max_fills=16),
+                msgs, ck.save_native, ck.load_native)
+    ses = _seq_session(**SMALL)
+    want = seqsession.SeqRouter if kind == "fixed-python" \
+        else seqsession.NativeSeqRouter
+    assert type(ses.router) is want
+    return ses, msgs, ck.save_seq_session, ck.load_seq_session
+
+
+WRITERS = ["fixed-native", "java", "native", "oracle"]
+
+
+def _serve(eng, msgs):
+    """The wire lines of `msgs`, a list a message, from any engine."""
+    if hasattr(eng, "process_wire"):
+        return eng.process_wire([m.copy() for m in msgs])
+    return [[r.wire() for r in eng.process(m.copy())] for m in msgs]
+
+
+def _offsets(ckpt_dir):
+    return [off for off, _ in ck.all_snapshots(ckpt_dir)]
+
+
+def _two_snapshots(kind, ckpt_dir, at=(100, 200)):
+    """-> (engine, stream, load, the newest file): `kind`'s engine
+    served to each offset of `at` and saved there."""
+    eng, msgs, save, load = _writer(kind)
+    done = 0
+    for off in at:
+        _serve(eng, msgs[done:off])
+        path = save(ckpt_dir, eng, off)
+        done = off
+    return eng, msgs, load, path
+
+
+@pytest.mark.parametrize("kind", WRITERS)
+def test_session_kill_resume_bit_identical(kind, tmp_path):
+    """Kill the engine after 300 of 600 messages; the resumed engine's
     tail output and final state match the uninterrupted run exactly."""
-    msgs = _stream()
-    cut = 300
-
-    full = LaneSession(CFG)
-    want_lines = full.process_wire([m.copy() for m in msgs[:cut]])
-    want_lines += full.process_wire([m.copy() for m in msgs[cut:]])
+    full, msgs, save, load = _writer(kind)
+    msgs, cut = msgs[:600], 300
+    want_lines = _serve(full, msgs[:cut]) + _serve(full, msgs[cut:])
     want_state = full.export_state()
 
-    ses = LaneSession(CFG)
-    got_head = ses.process_wire([m.copy() for m in msgs[:cut]])
-    ck.save_session(str(tmp_path), ses, offset=cut)
-    del ses  # the crash
+    eng = _writer(kind)[0]
+    got_head = _serve(eng, msgs[:cut])
+    save(str(tmp_path), eng, cut)
+    del eng  # the crash
 
-    resumed, offset = ck.load_session(str(tmp_path))
+    resumed, offset = load(str(tmp_path))
     assert offset == cut
-    got_tail = resumed.process_wire([m.copy() for m in msgs[cut:]])
+    got_tail = _serve(resumed, msgs[cut:])
     assert got_head + got_tail == want_lines
     assert resumed.export_state() == want_state
 
 
-def test_session_resume_across_width_configs(tmp_path):
-    """Snapshots are canonical: a compact-width session's snapshot
-    restores into a full-width session (and vice versa) bit-exactly."""
-    msgs = _stream(400, seed=4)
-    cut = 200
-
-    full = LaneSession(CFG, width=0)
-    want = full.process_wire([m.copy() for m in msgs])
-
-    a = LaneSession(CFG, width=16)
-    head = a.process_wire([m.copy() for m in msgs[:cut]])
-    ck.save_session(str(tmp_path), a, offset=cut)
-    _, meta = ck._load_file(ck.snapshot_path(str(tmp_path), cut))
-    assert meta["width"] == 8  # clamped to cfg.lanes
-
-    # restore the compact snapshot into a FULL-WIDTH session
-    b, offset = ck.load_session(str(tmp_path), width=0)
-    assert offset == cut and b.dev_cfg.width == 0
-    tail = b.process_wire([m.copy() for m in msgs[cut:]])
-    assert head + tail == want
-
-
-def test_session_elastic_reshard_on_restore(tmp_path):
-    """The rebalance analog (SURVEY.md §2.3): a single-device session's
-    snapshot restores onto a 4-shard mesh (and back) mid-stream, and the
-    continuation is bit-identical — symbol->shard reassignment is a
-    checkpoint/restore cycle, replacing Kafka Streams' group rebalance +
-    changelog restore."""
-    cfg = LaneConfig(lanes=8, slots=64, accounts=32, max_fills=32, steps=16)
-    msgs = _stream(600, seed=12)
-    cut1, cut2 = 200, 400
-
-    full = LaneSession(cfg)
-    want = full.process_wire([m.copy() for m in msgs])
-    want_state = full.export_state()
-
-    a = LaneSession(cfg)  # 1 device, compact
-    got = a.process_wire([m.copy() for m in msgs[:cut1]])
-    ck.save_session(str(tmp_path), a, offset=cut1)
-
-    b, off = ck.load_session(str(tmp_path), shards=4)  # scale OUT to 4
-    assert off == cut1 and b.shards == 4
-    got += b.process_wire([m.copy() for m in msgs[cut1:cut2]])
-    ck.save_session(str(tmp_path), b, offset=cut2)
-
-    c, off = ck.load_session(str(tmp_path), shards=1)  # scale back IN
-    assert off == cut2 and c.shards == 1
-    got += c.process_wire([m.copy() for m in msgs[cut2:]])
-
-    assert got == want
-    assert c.export_state() == want_state
-
-
-def test_corrupt_latest_snapshot_falls_back(tmp_path):
-    msgs = _stream(300, seed=9)
-    ses = LaneSession(CFG)
-    ses.process_wire([m.copy() for m in msgs[:100]])
-    ck.save_session(str(tmp_path), ses, offset=100)
-    ses.process_wire([m.copy() for m in msgs[100:200]])
-    ck.save_session(str(tmp_path), ses, offset=200)
+@pytest.mark.parametrize("kind", WRITERS)
+def test_corrupt_latest_snapshot_falls_back(kind, tmp_path):
+    _, _, load, newest = _two_snapshots(kind, str(tmp_path))
     # torn write of the newest snapshot
-    with open(ck.snapshot_path(str(tmp_path), 200), "r+b") as f:
+    with open(newest, "r+b") as f:
         f.truncate(100)
-    resumed, offset = ck.load_session(str(tmp_path))
+    resumed, offset = load(str(tmp_path))
     assert offset == 100  # fell back to the previous good snapshot
     assert resumed is not None
 
 
-def test_snapshot_requires_drained_fill_log(tmp_path):
-    ses = LaneSession(CFG)
-    ses.process_wire([m.copy() for m in _stream(50, seed=2)])
-    import jax.numpy as jnp
+def _crashed_and_resumed(kw, log_dir=None, n=400, first=250, seed=13):
+    """Serve `first` of `n` messages with a checkpointing seq service,
+    crash it past its last snapshot, restart it on the same broker (a
+    fresh one over `log_dir`'s durable log where that is given) and
+    checkpoint directory and serve the rest. -> (the broker that holds
+    the output, the stream, how far the first service got, the
+    snapshot the second started from)."""
+    msgs = harness_stream(n, seed=seed, num_symbols=4, num_accounts=8,
+                          payout_opcode_bug=False, validate=True)
+    broker = InProcessBroker(persist_dir=log_dir)
+    provision(broker)
+    for m in msgs:
+        broker.produce(TOPIC_IN, None, dumps_order(m))
+    svc = MatchService(broker, **kw)
+    svc.run(max_messages=first)
+    served, snap = svc.offset, svc._last_ckpt_offset
+    # a pipelined loop drains what it has in flight as run() returns
+    assert (served, snap) == (first, first // 100 * 100) \
+        or (svc.pipeline and first <= served and 100 <= snap <= served)
+    del svc  # crash: the records past the last snapshot replay
+    if log_dir is not None:
+        del broker  # the whole process died: the log is reloaded
+        broker = InProcessBroker(persist_dir=log_dir)
+    svc2 = MatchService(broker, **kw)
+    assert svc2.offset == snap  # resumed
+    rest = len(msgs) - snap
+    assert svc2.run(max_messages=rest) == rest
+    svc2.close()
+    return broker, msgs, served, snap
 
-    ses.state = dict(ses.state)
-    ses.state["filloff"] = jnp.ones((1,), jnp.int64)
-    with pytest.raises(ValueError, match="drained fill log"):
-        ck.save_session(str(tmp_path), ses, offset=50)
+
+def _seq_service(tmp_path, pipeline, **more):
+    if pipeline:
+        from kme_tpu.native import load_library
+
+        if load_library() is None:
+            pytest.skip("native host runtime unavailable")
+    return dict(engine="seq", compat="fixed", batch=50, symbols=8,
+                accounts=16, slots=128, max_fills=32, pipeline=pipeline,
+                checkpoint_dir=str(tmp_path / "ckpt"),
+                checkpoint_every=100, **more)
 
 
-def test_service_crash_resume_at_least_once(tmp_path):
+def _at_least_once(msgs, served, snap):
+    """What the output topic holds after a crash at `served` and a
+    resume from `snap`: the tail after the snapshot twice, each copy
+    what the oracle says."""
+    ora = OracleEngine("fixed", book_slots=128, max_fills=32)
+    per_msg = [[r.wire() for r in ora.process(m.copy())] for m in msgs]
+    return [ln for lines in per_msg[:served] + per_msg[snap:]
+            for ln in lines]
+
+
+# the two values the benchmark's cells serve with
+PIPELINES = [0, 2]
+
+
+@pytest.mark.parametrize("pipeline", PIPELINES)
+def test_service_crash_resume_at_least_once(pipeline, tmp_path):
     """Service-level fault injection: crash a checkpointing service
     mid-stream (after its last snapshot), restart it on the same broker
     and checkpoint dir. The tail after the snapshot replays (at-least-
     once) and every replayed record's output is bit-identical."""
-    msgs = harness_stream(400, seed=13, num_symbols=4, num_accounts=8,
-                          payout_opcode_bug=False, validate=True)
-    per_msg = []
-    ora = OracleEngine("fixed", book_slots=64, max_fills=32)
-    for m in msgs:
-        per_msg.append([r.wire() for r in ora.process(m.copy())])
-
-    broker = InProcessBroker()
-    provision(broker)
-    for m in msgs:
-        broker.produce(TOPIC_IN, None, dumps_order(m))
-
-    kw = dict(engine="lanes", compat="fixed", batch=50, symbols=8,
-              accounts=16, slots=64, max_fills=32,
-              checkpoint_dir=str(tmp_path), checkpoint_every=100)
-    svc = MatchService(broker, **kw)
-    assert svc.run(max_messages=250) == 250  # snapshots at 100, 200
-    assert svc._last_ckpt_offset == 200
-    del svc  # crash: 50 records past the last snapshot
-
-    svc2 = MatchService(broker, **kw)
-    assert svc2.offset == 200  # resumed
-    rest = len(msgs) - 200  # replays 200..end (at-least-once tail)
-    assert svc2.run(max_messages=rest) == rest
-
-    got = list(consume_lines(broker, follow=False))
-    want = [ln for lines in per_msg[:250] for ln in lines]
-    want += [ln for lines in per_msg[200:] for ln in lines]
-    assert got == want
+    broker, msgs, served, snap = _crashed_and_resumed(
+        _seq_service(tmp_path, pipeline))
+    assert list(consume_lines(broker, follow=False)) \
+        == _at_least_once(msgs, served, snap)
 
 
 def test_native_engine_crash_resume(tmp_path):
@@ -297,41 +341,16 @@ def test_broker_sync_and_consume_waits_for_topic(tmp_path):
                               idle_exit=0.2)) == ["OUT x"]
 
 
-def test_service_crash_resume_full_process_restart(tmp_path):
+@pytest.mark.parametrize("pipeline", PIPELINES)
+def test_service_crash_resume_full_process_restart(pipeline, tmp_path):
     """The kme-serve topology: broker log AND engine snapshot both live
     on disk; a full restart (fresh broker + fresh service) resumes and
     the stream completes bit-identically (at-least-once tail replay)."""
-    msgs = harness_stream(300, seed=31, num_symbols=4, num_accounts=8,
-                          payout_opcode_bug=False, validate=True)
-    per_msg = []
-    ora = OracleEngine("fixed", book_slots=64, max_fills=32)
-    for m in msgs:
-        per_msg.append([r.wire() for r in ora.process(m.copy())])
-
-    log_dir = str(tmp_path / "broker-log")
-    ck_dir = str(tmp_path / "ckpt")
-    kw = dict(engine="lanes", compat="fixed", batch=50, symbols=8,
-              accounts=16, slots=64, max_fills=32,
-              checkpoint_dir=ck_dir, checkpoint_every=100)
-
-    b1 = InProcessBroker(persist_dir=log_dir)
-    provision(b1)
-    for m in msgs:
-        b1.produce(TOPIC_IN, None, dumps_order(m))
-    svc1 = MatchService(b1, **kw)
-    assert svc1.run(max_messages=150) == 150  # snapshot at 100
-    del svc1, b1  # the whole process dies
-
-    b2 = InProcessBroker(persist_dir=log_dir)  # broker log reloaded
-    svc2 = MatchService(b2, **kw)
-    assert svc2.offset == 100
-    rest = len(msgs) - 100
-    assert svc2.run(max_messages=rest) == rest
-
-    got = list(consume_lines(b2, follow=False))
-    want = [ln for lines in per_msg[:150] for ln in lines]
-    want += [ln for lines in per_msg[100:] for ln in lines]
-    assert got == want
+    broker, msgs, served, snap = _crashed_and_resumed(
+        _seq_service(tmp_path, pipeline),
+        log_dir=str(tmp_path / "broker-log"), n=300, first=150, seed=31)
+    assert list(consume_lines(broker, follow=False)) \
+        == _at_least_once(msgs, served, snap)
 
 
 # ---------------------------------------------------------------------------
@@ -340,20 +359,6 @@ def test_service_crash_resume_full_process_restart(tmp_path):
 # seq-java <-> native with byte-identical continuation
 # (VERDICT r4 #4; reference: the changelog-restore contract,
 # KProcessor.java:30-49)
-
-def _java_cfg():
-    from kme_tpu.engine import seq as SQ
-
-    return SQ.SeqConfig(lanes=8, slots=512, accounts=128, max_fills=128,
-                        batch=512, pos_cap=1 << 12, probe_max=16,
-                        compat="java")
-
-
-def _java_stream(n=2400, seed=7):
-    from kme_tpu.workload import harness_stream
-
-    return harness_stream(n, seed=seed)
-
 
 def _judge_java(msgs):
     from kme_tpu.native.oracle import NativeOracleEngine, native_available
@@ -508,7 +513,8 @@ def test_seqjava_service_kill_resume(cpu_devices, tmp_path):
     assert ok, "replayed stream is not an exact judge segment"
 
 
-def test_journal_across_crash_resume(tmp_path):
+@pytest.mark.parametrize("pipeline", PIPELINES)
+def test_journal_across_crash_resume(pipeline, tmp_path):
     """Flight-recorder round-trip over a crash/resume cycle: the
     service replays the post-snapshot tail (at-least-once), but the
     journal rewinds to the snapshot offset first — so the final
@@ -518,27 +524,10 @@ def test_journal_across_crash_resume(tmp_path):
     from kme_tpu.telemetry.journal import (canonical_lines,
                                            oracle_events, read_events)
 
-    msgs = harness_stream(400, seed=13, num_symbols=4, num_accounts=8,
-                          payout_opcode_bug=False, validate=True)
-    broker = InProcessBroker()
-    provision(broker)
-    for m in msgs:
-        broker.produce(TOPIC_IN, None, dumps_order(m))
-
     jp = str(tmp_path / "journal.jsonl")
-    kw = dict(engine="lanes", compat="fixed", batch=50, symbols=8,
-              accounts=16, slots=64, max_fills=32,
-              checkpoint_dir=str(tmp_path / "ck"),
-              checkpoint_every=100, journal=jp)
-    svc = MatchService(broker, **kw)
-    assert svc.run(max_messages=250) == 250  # snapshots at 100, 200
-    del svc  # crash: 50 journaled records past the last snapshot
-
-    svc2 = MatchService(broker, **kw)
-    assert svc2.offset == 200                # resumed from snapshot
-    rest = len(msgs) - 200
-    assert svc2.run(max_messages=rest) == rest
-    svc2.close()
+    _, msgs, served, snap = _crashed_and_resumed(
+        _seq_service(tmp_path, pipeline, journal=jp))
+    assert served > snap        # journaled records past the snapshot
 
     evs = read_events(jp)
     seqs = [e["seq"] for e in evs]
@@ -547,7 +536,7 @@ def test_journal_across_crash_resume(tmp_path):
     offs = [e["off"] for e in evs if e["e"] == "submit"]
     assert offs == list(range(len(msgs)))
     want = canonical_lines(oracle_events(
-        [dumps_order(m) for m in msgs], book_slots=64, max_fills=32))
+        [dumps_order(m) for m in msgs], book_slots=128, max_fills=32))
     assert canonical_lines(evs) == want
 
 
@@ -556,159 +545,153 @@ def test_journal_across_crash_resume(tmp_path):
 # writes) and retention depth
 
 
-def test_digest_mismatch_snapshot_falls_back(tmp_path):
-    """Silent corruption: the newest snapshot still np.load-parses (so
-    zipfile CRCs pass) but one array was modified while its stored
-    digest went stale — the CONTENT digest must catch it and the loader
-    falls back to the previous snapshot."""
-    import numpy as np
-
-    msgs = _stream(300, seed=9)
-    ses = LaneSession(CFG)
-    ses.process_wire([m.copy() for m in msgs[:100]])
-    ck.save_session(str(tmp_path), ses, offset=100)
-    ses.process_wire([m.copy() for m in msgs[100:200]])
-    ck.save_session(str(tmp_path), ses, offset=200)
-
-    path = ck.snapshot_path(str(tmp_path), 200)
-    data = {k: v.copy() for k, v in np.load(path).items()}
-    tampered = data["pos_amt"].copy()
-    tampered.flat[0] += 1                 # one balance, one tick off
-    data["pos_amt"] = tampered            # digest array kept STALE
-    with open(path, "wb") as f:
-        np.savez(f, **data)
-
-    resumed, offset = ck.load_session(str(tmp_path))
-    assert offset == 100 and resumed is not None
-    with pytest.raises(ValueError, match="digest mismatch"):
-        ck._load_file(path)
-
-
-def test_oracle_bitflip_inside_engine_falls_back(tmp_path):
-    """A bit-flip INSIDE the pickled engine bytes leaves the outer blob
-    parseable — only the engine_pkl sha256 can catch it; load_oracle
-    must skip to the previous snapshot."""
-    ora = OracleEngine("fixed", book_slots=64, max_fills=32)
-    msgs = harness_stream(60, seed=11, num_accounts=4, num_symbols=2,
-                          payout_opcode_bug=False, validate=True)
-    for m in msgs[:30]:
-        ora.process(m)
-    ck.save_oracle(str(tmp_path), ora, 100)
-    for m in msgs[30:]:
-        ora.process(m)
-    ck.save_oracle(str(tmp_path), ora, 200)
-
+def _tamper(path):
+    """Alter one value of the state `path` holds and keep its stored
+    digest as it was: the file still parses, whatever its format."""
+    import json
     import pickle
 
-    path = os.path.join(str(tmp_path), "ckpt-200.pkl")
-    with open(path, "rb") as f:
-        raw = bytearray(f.read())
-    engine_pkl = pickle.loads(bytes(raw))["engine_pkl"]
-    at = raw.index(engine_pkl) + len(engine_pkl) // 2
-    raw[at] ^= 0x10
-    with open(path, "wb") as f:
-        f.write(raw)
-    # the outer blob still parses — the digest is the only defence
-    assert pickle.loads(bytes(raw))["engine_pkl"] != engine_pkl
+    import numpy as np
 
-    loaded, offset = ck.load_oracle(str(tmp_path))
-    assert offset == 100 and loaded is not None
+    if path.endswith(".npz"):
+        # np.load still parses it (so the zipfile's CRCs pass)
+        with np.load(path) as z:
+            data = {k: z[k].copy() for k in z.files}
+        data["bal"].flat[0] += 1              # one balance, one tick off
+        with open(path, "wb") as f:
+            np.savez(f, **data)
+    elif path.endswith(".nat"):
+        with open(path, encoding="utf-8") as f:
+            header, dump = f.readline(), f.read()
+        at = next(i for i, c in enumerate(dump) if c.isdigit())
+        dump = dump[:at] + str((int(dump[at]) + 1) % 10) + dump[at + 1:]
+        with open(path, "w", encoding="utf-8") as f:
+            f.write(header + dump)
+        assert json.loads(header)["digest"]
+    else:
+        # a bit-flip INSIDE the pickled engine bytes leaves the outer
+        # blob parseable: only the engine_pkl sha256 can catch it
+        with open(path, "rb") as f:
+            raw = bytearray(f.read())
+        engine_pkl = pickle.loads(bytes(raw))["engine_pkl"]
+        raw[raw.index(engine_pkl) + len(engine_pkl) // 2] ^= 0x10
+        with open(path, "wb") as f:
+            f.write(raw)
+        assert pickle.loads(bytes(raw))["engine_pkl"] != engine_pkl
 
 
-def test_all_snapshots_corrupt_cold_start(tmp_path):
+@pytest.mark.parametrize("kind", WRITERS)
+def test_digest_mismatch_snapshot_falls_back(kind, tmp_path):
+    """Silent corruption: the newest snapshot still parses but one
+    value of its state was modified while its stored digest went stale
+    — the CONTENT digest must catch it and the loader falls back to the
+    previous snapshot."""
+    _, _, load, newest = _two_snapshots(kind, str(tmp_path))
+    _tamper(newest)
+    resumed, offset = load(str(tmp_path))
+    assert offset == 100 and resumed is not None
+    if newest.endswith(".npz"):
+        with pytest.raises(ValueError, match="digest mismatch"):
+            ck._load_file(newest)
+    elif newest.endswith(".pkl"):
+        with pytest.raises(ValueError, match="digest mismatch"):
+            ck.load_oracle_file(newest)
+
+
+@pytest.mark.parametrize("kind", WRITERS)
+def test_all_snapshots_corrupt_cold_start(kind, tmp_path):
     """Every snapshot unreadable: the loader returns (None, 0) rather
-    than raising, and a service pointed at the wreckage starts cold at
-    offset 0 and replays the whole stream byte-exactly."""
-    msgs = harness_stream(80, seed=17, num_accounts=4, num_symbols=2,
-                          payout_opcode_bug=False, validate=True)
-    ora = OracleEngine("fixed", book_slots=64, max_fills=32)
-    want = [r.wire() for m in msgs for r in ora.process(m.copy())]
-
+    than raising, and a service of that engine pointed at the wreckage
+    starts cold at offset 0 and replays the whole stream byte-exactly."""
     ck_dir = str(tmp_path / "ck")
-    ses = LaneSession(CFG)
-    ses.process_wire([m.copy() for m in _stream(100, seed=3)])
-    ck.save_session(ck_dir, ses, offset=50)
-    ck.save_session(ck_dir, ses, offset=100)
-    for off, path in ck.list_snapshots(ck_dir):
+    _, msgs, load, _ = _two_snapshots(kind, ck_dir, at=(50, 100))
+    assert _offsets(ck_dir) == [100, 50]
+    for _, path in ck.all_snapshots(ck_dir):
         with open(path, "r+b") as f:
             f.truncate(64)
-    assert ck.load_session(ck_dir) == (None, 0)
+    assert load(ck_dir) == (None, 0)
 
+    msgs = msgs[:80]
+    want = [ln for lines in _serve(_writer(kind)[0], msgs) for ln in lines]
     broker = InProcessBroker()
     provision(broker)
     for m in msgs:
         broker.produce(TOPIC_IN, None, dumps_order(m))
-    svc = MatchService(broker, engine="oracle", compat="fixed", batch=16,
-                       slots=64, max_fills=32, checkpoint_dir=ck_dir,
-                       checkpoint_every=1000)
+    how = (dict(engine="seq", compat="java", slots=512, max_fills=128)
+           if kind == "java" else
+           dict(engine={"fixed-native": "seq"}.get(kind, kind),
+                compat="fixed", slots=128, max_fills=16))
+    svc = MatchService(broker, batch=16, symbols=8, accounts=128,
+                       checkpoint_dir=ck_dir, checkpoint_every=1000,
+                       **how)
     assert svc.offset == 0                 # cold start, not a crash
     assert svc.run(max_messages=len(msgs)) == len(msgs)
     got = [f"{r.key} {r.value}" for r in broker.fetch("MatchOut", 0, 10**6)]
     assert got == want
 
 
-def test_retention_keep_depth(tmp_path, monkeypatch):
+@pytest.mark.parametrize("kind", WRITERS)
+def test_retention_keep_depth(kind, tmp_path, monkeypatch):
     """keep= bounds the snapshot tail; KME_CKPT_KEEP sets the default
     (3 — newest + two fallbacks, since kme-chaos both tears AND
     bit-flips)."""
-    ses = LaneSession(CFG)
-    ses.process_wire([m.copy() for m in _stream(50, seed=2)])
+    eng, msgs, save, _ = _writer(kind)
+    _serve(eng, msgs[:50])
 
     d1 = str(tmp_path / "explicit")
     for off in (10, 20, 30, 40):
-        ck.save_session(d1, ses, offset=off, keep=2)
-    assert [o for o, _ in ck.list_snapshots(d1)] == [40, 30]
+        save(d1, eng, off, keep=2)
+    assert _offsets(d1) == [40, 30]
 
     d2 = str(tmp_path / "default")
     monkeypatch.delenv("KME_CKPT_KEEP", raising=False)
     for off in (10, 20, 30, 40, 50):
-        ck.save_session(d2, ses, offset=off)
-    assert [o for o, _ in ck.list_snapshots(d2)] == [50, 40, 30]
+        save(d2, eng, off)
+    assert _offsets(d2) == [50, 40, 30]
 
     d3 = str(tmp_path / "env")
     monkeypatch.setenv("KME_CKPT_KEEP", "1")
     for off in (10, 20):
-        ck.save_session(d3, ses, offset=off)
-    assert [o for o, _ in ck.list_snapshots(d3)] == [20]
+        save(d3, eng, off)
+    assert _offsets(d3) == [20]
 
 
-def test_snapshot_extra_meta_round_trips(tmp_path):
+@pytest.mark.parametrize("kind", WRITERS)
+def test_snapshot_extra_meta_round_trips(kind, tmp_path):
     """The additive `extra` dict (the exactly-once epoch/out_seq
-    cursor) survives both the pkl and npz snapshot kinds, and degrades
-    to {} when absent."""
+    cursor) survives every snapshot kind, and degrades to {} when
+    absent."""
     d = str(tmp_path)
-    ora = OracleEngine("fixed", book_slots=64, max_fills=32)
-    ck.save_oracle(d, ora, 40, extra={"epoch": 3, "out_seq": 99})
+    eng, msgs, save, load = _writer(kind)
+    _serve(eng, msgs[:50])
+    save(d, eng, 40, extra={"epoch": 3, "out_seq": 99})
     assert ck.snapshot_extra(d, 40) == {"epoch": 3, "out_seq": 99}
-    ck.save_oracle(d, ora, 80)                 # no extra stored
-    assert ck.snapshot_extra(d, 80) == {}
+    save(d, eng, 50)                           # no extra stored
+    assert ck.snapshot_extra(d, 50) == {}
     assert ck.snapshot_extra(d, 999) == {}     # no snapshot at all
-
-    ses = LaneSession(CFG)
-    ses.process_wire([m.copy() for m in _stream(50, seed=9)])
-    ck.save_session(d, ses, offset=50, extra={"epoch": 1, "out_seq": 7})
-    assert ck.snapshot_extra(d, 50) == {"epoch": 1, "out_seq": 7}
     # ...and the snapshot still restores normally alongside the meta
-    resumed, offset = ck.load_session(d)
+    resumed, offset = load(d)
     assert offset == 50
-    assert resumed.export_state() == ses.export_state()
+    assert resumed.export_state() == eng.export_state()
 
 
-def test_oldest_retained_offset_tracks_pruning(tmp_path):
+@pytest.mark.parametrize("kind", WRITERS)
+def test_oldest_retained_offset_tracks_pruning(kind, tmp_path):
     """The journal retention guard's anchor: the smallest snapshot
     offset on disk, across snapshot kinds, moving forward as `keep`
     prunes old snapshots."""
     d = str(tmp_path / "ck")
     assert ck.oldest_retained_offset(d) is None        # no dir yet
-    ora = OracleEngine("fixed", book_slots=64, max_fills=32)
-    ck.save_oracle(d, ora, 128)
-    ck.save_oracle(d, ora, 64)
+    eng, _, save, _ = _writer(kind)
+    save(d, eng, 128)
+    save(d, eng, 64)
     assert ck.oldest_retained_offset(d) == 64
-    ses = LaneSession(CFG)
-    ck.save_session(d, ses, offset=32)                 # other kind
+    other = _writer("native" if kind == "oracle" else "oracle")
+    other[2](d, other[0], 32)                          # other kind
     assert ck.oldest_retained_offset(d) == 32
-    ck.save_oracle(d, ora, 192, keep=2)                # prunes 64
-    assert ck.oldest_retained_offset(d) == 32          # npz untouched
+    save(d, eng, 192, keep=2)                          # prunes 64
+    assert _offsets(d) == [192, 128, 32]
+    assert ck.oldest_retained_offset(d) == 32          # other untouched
 
 
 # ---------------------------------------------------------------------------
@@ -716,22 +699,10 @@ def test_oldest_retained_offset_tracks_pruning(tmp_path):
 # their live entries where that is the smaller encoding
 # (engine/seq.py:export_snapshot), densified by the one loader
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-SMALL = dict(lanes=8, slots=128, accounts=128, max_fills=16)
 # what export_snapshot says of its device -> host half
 FETCH_GAUGES = ("snapshot_fetch_bytes", "snapshot_live_rows",
                 "snapshot_fetch_calls", "snapshot_pos_fetch_bytes",
                 "snapshot_pos_calls")
-
-
-def _seq_session(state=None, **shape):
-    from kme_tpu.engine import seq as SQ
-    from kme_tpu.runtime.seqsession import SeqSession
-
-    ses = SeqSession(SQ.SeqConfig(**shape))
-    if state is not None:
-        ses.state = SQ.import_canonical(ses.cfg, state)
-    return ses
 
 
 def _random_canon(shape, book_load, pos_load, amount=True, seed=5,
@@ -1026,20 +997,6 @@ def _two_sparse_snapshots(tmp_path, at=(400, 600)):
         assert ses.snapshot_gauges["snapshot_sparse_sections"] == 2
         done = off
     return msgs
-
-
-def test_sparse_seq_snapshot_restores_into_the_lanes_engine(tmp_path):
-    """Cross-engine: the canonical layout _load_file hands on is the
-    lanes engine's own, whichever way the seq engine wrote it."""
-    msgs = _two_sparse_snapshots(tmp_path)
-    ora = OracleEngine("fixed", book_slots=128, max_fills=16)
-    per_msg = [[r.wire() for r in ora.process(m.copy())] for m in msgs]
-    ses, off = ck.load_session(str(tmp_path))
-    assert isinstance(ses, LaneSession) and off == 600
-    assert ses.process_wire([m.copy() for m in msgs[off:]]) == per_msg[off:]
-    exp = ses.export_state()
-    assert exp["balances"] == dict(ora.balances)
-    assert exp["positions"] == dict(ora.positions)
 
 
 @pytest.mark.parametrize("damage", ["bitflip", "torn"])
@@ -1474,42 +1431,10 @@ def _raw(path):
     return raw, json.loads(bytes(raw["meta"]).decode())
 
 
-def _router(ses):
-    return ses.scheduler if isinstance(ses, LaneSession) else ses.router
+NPZ_WRITERS = ["fixed-native", "fixed-python", "java"]
 
 
-def _writer(kind, monkeypatch=None):
-    """-> (fresh session, its stream, save, load) of one .npz writer."""
-    from kme_tpu.native import load_library
-    from kme_tpu.runtime import seqsession
-
-    if kind == "lanes":
-        return (LaneSession(CFG), list(_stream(600, seed=21)),
-                ck.save_session, ck.load_session)
-    if kind == "java":
-        from kme_tpu.runtime.seqsession import SeqSession
-
-        return (SeqSession(_java_cfg()), _java_stream(n=600),
-                ck.save_seq_session, ck.load_seq_session)
-    if kind == "fixed-python":
-        monkeypatch.setattr(
-            seqsession, "make_seq_router",
-            lambda lanes, accounts, compat="fixed":
-            seqsession.SeqRouter(lanes, accounts, compat))
-    elif load_library() is None:
-        pytest.skip("native host runtime unavailable")
-    ses = _seq_session(**SMALL)
-    want = seqsession.SeqRouter if kind == "fixed-python" \
-        else seqsession.NativeSeqRouter
-    assert type(ses.router) is want
-    return (ses, list(zipf_symbol_stream(900, 8, 64, seed=12, zipf_a=0.0)),
-            ck.save_seq_session, ck.load_seq_session)
-
-
-WRITERS = ["fixed-native", "fixed-python", "java", "lanes"]
-
-
-@pytest.mark.parametrize("kind", WRITERS)
+@pytest.mark.parametrize("kind", NPZ_WRITERS)
 def test_routes_round_trip_as_two_sorted_arrays(kind, tmp_path,
                                                 monkeypatch):
     """Every writer puts the routes in the payload, keys ascending and
@@ -1520,7 +1445,7 @@ def test_routes_round_trip_as_two_sorted_arrays(kind, tmp_path,
     ses, msgs, save, load = _writer(kind, monkeypatch)
     cut = 400
     ses.process_wire([m.copy() for m in msgs[:cut]])
-    want = dict(_router(ses).oid_sid)
+    want = dict(ses.router.oid_sid)
     # a fixed-mode seq router holds the resting orders' routes only
     assert len(want) > (50 if kind.startswith("fixed") else 100)
     raw, meta = _raw(save(str(tmp_path), ses, cut))
@@ -1530,31 +1455,11 @@ def test_routes_round_trip_as_two_sorted_arrays(kind, tmp_path,
     assert raw["route_oid"].tolist() == sorted(want)
     assert raw["route_sid"].tolist() == [want[k] for k in sorted(want)]
     back, off = load(str(tmp_path))
-    assert off == cut and type(_router(back)) is type(_router(ses))
-    assert dict(_router(back).oid_sid) == want
+    assert off == cut and type(back.router) is type(ses.router)
+    assert dict(back.router.oid_sid) == want
     assert back.process_wire([m.copy() for m in msgs[cut:]]) \
         == ses.process_wire([m.copy() for m in msgs[cut:]])
-    assert dict(_router(back).oid_sid) == dict(_router(ses).oid_sid)
-
-
-@pytest.mark.parametrize("src", ["lanes", "fixed-native"])
-def test_routes_cross_the_engines(src, tmp_path):
-    """lanes -> seq and seq -> lanes: the one loader hands both the
-    same two arrays, and a cancel of an order that rested before the
-    cut still finds its book."""
-    ses, msgs, save, _ = _writer(src)
-    cut = 400
-    ses.process_wire([m.copy() for m in msgs[:cut]])
-    want = dict(_router(ses).oid_sid)
-    save(str(tmp_path), ses, cut)
-    load = ck.load_seq_session if src == "lanes" else ck.load_session
-    back, off = load(str(tmp_path))
-    assert off == cut and isinstance(back, LaneSession) == (src != "lanes")
-    assert dict(_router(back).oid_sid) == want
-    ora = OracleEngine("fixed", book_slots=back.cfg.slots,
-                       max_fills=back.cfg.max_fills)
-    per_msg = [[r.wire() for r in ora.process(m.copy())] for m in msgs]
-    assert back.process_wire([m.copy() for m in msgs[cut:]]) == per_msg[cut:]
+    assert dict(back.router.oid_sid) == dict(ses.router.oid_sid)
 
 
 RECORDED = ["seq_pre_pr29.npz", "seq_dense_pr34.npz", "seq_sparse_pr35.npz",
@@ -1565,7 +1470,7 @@ RECORDED = ["seq_pre_pr29.npz", "seq_dense_pr34.npz", "seq_sparse_pr35.npz",
 def test_recorded_files_restore_the_routes_their_meta_lists(name, tmp_path):
     """Every file written before version 3 lists its routes in the meta
     (`oid_sid`, sorted pairs): the loader hands them on as the arrays a
-    new file carries, and both engines' routers hold that map."""
+    new file carries, and the restored router holds that map."""
     import shutil
 
     src = os.path.join(HERE, "data", name)
@@ -1581,8 +1486,6 @@ def test_recorded_files_restore_the_routes_their_meta_lists(name, tmp_path):
     assert data["route_sid"].tolist() == [want[k] for k in sorted(want)]
     ses, off = ck.load_seq_session(str(tmp_path))
     assert off == meta["offset"] and dict(ses.router.oid_sid) == want
-    lanes, _ = ck.load_session(str(tmp_path))
-    assert dict(lanes.scheduler.oid_sid) == want
 
 
 def test_the_parents_newest_file_restores_and_serves_on(tmp_path):
@@ -1612,7 +1515,46 @@ def test_the_parents_newest_file_restores_and_serves_on(tmp_path):
     assert ses.router.sid_lane[100] == 5        # the lowest free lane
 
 
-@pytest.mark.parametrize("kind", ["fixed-native", "java", "lanes"])
+def test_a_lanes_engines_file_restores_and_serves_on(tmp_path):
+    """lanes_pr53.npz — written by `git archive 06d92bd`'s save_session,
+    the writer of the sweep engine that PR 54 removed, from a
+    LaneSession of LaneConfig(lanes=8, slots=64, accounts=32,
+    max_fills=32, steps=16) at offset 400 of the stream below (kind
+    "lanes", version 3). load_seq_session is the way from `--engine
+    lanes` to `--engine seq`: the restored session serves the
+    continuation the writer's own session served (its sha256 as
+    recorded with the file, and what the oracle says), and a service
+    asked for another envelope than the file's is refused by name,
+    never started cold."""
+    import hashlib
+    import json
+    import shutil
+
+    msgs = list(zipf_symbol_stream(600, num_symbols=8, num_accounts=24,
+                                   seed=21, zipf_a=1.0))
+    shutil.copy(os.path.join(HERE, "data", "lanes_pr53.npz"),
+                ck.snapshot_path(str(tmp_path), 400))
+    _, meta = _raw(ck.snapshot_path(str(tmp_path), 400))
+    assert (meta["kind"], meta["version"]) == ("lanes", 3)
+    ses, off = ck.load_seq_session(str(tmp_path))
+    assert off == 400 and len(ses.router.oid_sid) == 306
+    tail = ses.process_wire([m.copy() for m in msgs[400:]])
+    assert hashlib.sha256(json.dumps(tail).encode()).hexdigest() == (
+        "f1a579755549e1129d6fba8f2563306db1af9148f2561417a3c3470c4f2e5776")
+    ora = OracleEngine("fixed", book_slots=64, max_fills=32)
+    assert tail == _serve(ora, msgs)[400:]
+    exp = ses.export_state()
+    assert exp["balances"] == dict(ora.balances)
+    assert exp["positions"] == dict(ora.positions)
+
+    broker = InProcessBroker()
+    provision(broker)
+    with pytest.raises(ck.SnapshotCapacityError, match="slots=64"):
+        MatchService(broker, engine="seq", symbols=8, accounts=32,
+                     slots=128, max_fills=32, checkpoint_dir=str(tmp_path))
+
+
+@pytest.mark.parametrize("kind", ["fixed-native", "java"])
 def test_a_session_that_routed_nothing_saves_zero_routes(kind, tmp_path):
     import numpy as np
 
@@ -1621,7 +1563,7 @@ def test_a_session_that_routed_nothing_saves_zero_routes(kind, tmp_path):
     for k in ("route_oid", "route_sid"):
         assert raw[k].dtype == np.int64 and raw[k].shape == (0,)
     back, _ = load(str(tmp_path))
-    assert dict(_router(back).oid_sid) == {}
+    assert dict(back.router.oid_sid) == {}
 
 
 def test_two_snapshots_of_one_state_carry_one_digest(tmp_path):
@@ -1646,16 +1588,12 @@ def test_two_snapshots_of_one_state_carry_one_digest(tmp_path):
     assert all(np.array_equal(a[k], b[k]) for k in a)
 
 
-@pytest.mark.parametrize("kind", ["fixed-native", "lanes"])
-def test_a_native_routers_map_is_never_made_a_dict(kind, tmp_path,
-                                                   monkeypatch):
+def test_a_native_routers_map_is_never_made_a_dict(tmp_path, monkeypatch):
     """Neither the save nor the restore touches the `oid_sid` dict
     property of a native router: the arrays go C++ -> file -> C++."""
-    ses, msgs, save, load = _writer(kind)
+    ses, msgs, save, load = _writer("fixed-native")
     ses.process_wire([m.copy() for m in msgs[:400]])
-    router = _router(ses)
-    if "Native" not in type(router).__name__:
-        pytest.skip("native host runtime unavailable")
+    router = ses.router
     want = dict(router.oid_sid)
 
     def never(self, *a):
@@ -1666,8 +1604,8 @@ def test_a_native_routers_map_is_never_made_a_dict(kind, tmp_path,
         raw, _ = _raw(save(str(tmp_path), ses, 400))
         back, _ = load(str(tmp_path))
     assert len(raw["route_oid"]) == len(want)
-    assert type(_router(back)) is type(router)
-    assert dict(_router(back).oid_sid) == want
+    assert type(back.router) is type(router)
+    assert dict(back.router.oid_sid) == want
 
 
 def test_a_bit_flipped_in_the_routes_fails_the_digest(tmp_path):
@@ -1703,7 +1641,7 @@ def _version_rule_of_the_parent(meta):
         raise ValueError("unsupported snapshot")
 
 
-@pytest.mark.parametrize("kind", ["fixed-native", "java", "lanes"])
+@pytest.mark.parametrize("kind", ["fixed-native", "java"])
 def test_a_reader_of_versions_1_and_2_refuses_a_new_file(kind, tmp_path):
     """The rule itself passes every recorded file, and stops a version-3
     file of each kind before anything reads `meta["oid_sid"]`."""
@@ -1735,8 +1673,6 @@ def test_a_file_of_a_later_version_is_refused_in_load_file(tmp_path,
         ck._load_file(path)
     back, off = ck.load_seq_session(str(tmp_path), SQ.SeqConfig(**SMALL))
     assert off == 400
-    lanes, off = ck.load_session(str(tmp_path))
-    assert off == 400 and isinstance(lanes, LaneSession)
 
 
 @pytest.mark.parametrize("compat", ["fixed", "java"])
@@ -2041,8 +1977,7 @@ def test_outside_the_cadence_the_caller_returns_with_the_file_durable(
     svc.close()
 
 
-@pytest.mark.parametrize("engine", ["oracle", "native", "lanes",
-                                    "follower"])
+@pytest.mark.parametrize("engine", ["oracle", "native", "follower"])
 def test_engines_saved_on_the_serve_thread_start_no_writer(
         engine, tmp_path, monkeypatch):
     """The engines no deployment serves are saved inside the handoff

@@ -1,33 +1,27 @@
 """On-device distribution histograms vs full host-side recomputation.
 
-The kernels accumulate three power-of-two-bucket histograms alongside
-the metrics vector (engine/lanes.py one_step, engine/seq.py kernel
-epilogue) — fetched with the same transfers, never an extra device
-round-trip:
+The kernel accumulates three power-of-two-bucket histograms alongside
+the metrics vector (engine/seq.py kernel epilogue) — fetched with the
+same transfers, never an extra device round-trip:
 
 - fills_per_order: one observation per ACCEPTED trade, value = number
   of maker fills (a resting 0-fill trade lands in bucket 0);
 - book_depth: one observation per book-mutating message (accepted
   trade or cancel), value = the touched lane's occupied slot count
   (both sides) AFTER the message;
-- batch_occupancy: one observation per non-empty dispatch unit (seq:
-  messages per kernel call; lanes: scheduled messages per scan step).
+- batch_occupancy: one observation per non-empty kernel call, value =
+  the messages in it.
 
 The host recomputations here share NO code with the kernels: fills and
 depth replay the stream through the quirk-exact oracle, occupancy
-replays the host planners."""
-
-from collections import Counter
+replays the host router."""
 
 import pytest
 
 from kme_tpu import opcodes as op
 from kme_tpu.engine import seq as SQ
-from kme_tpu.engine.lanes import LaneConfig
 from kme_tpu.oracle import OracleEngine
 from kme_tpu.runtime.seqsession import SeqSession, make_seq_router
-from kme_tpu.runtime.sequencer import make_scheduler
-from kme_tpu.runtime.session import LaneSession
 from kme_tpu.telemetry import N_BUCKETS, bucket_index
 from kme_tpu.workload import zipf_symbol_stream
 
@@ -55,19 +49,6 @@ def host_fills_and_depth(msgs, book_slots, max_fills):
             d = sum(1 for o in ora.orders.values() if o.sid == sid)
             depth[bucket_index(d)] += 1
     return fills, depth
-
-
-def host_occupancy_lanes(msgs, cfg, width):
-    """Scheduled messages per (segment, scan step) — an independent
-    scheduler instance replays the plan."""
-    sch = make_scheduler(cfg.lanes, cfg.accounts, width=width)
-    sched = sch.plan([m.copy() for m in msgs])
-    occ = [0] * N_BUCKETS
-    per_step = Counter(zip(sched.cols["segment"].tolist(),
-                           sched.cols["step"].tolist()))
-    for c in per_step.values():
-        occ[bucket_index(c)] += 1
-    return occ
 
 
 def host_occupancy_seq(msgs, cfg):
@@ -102,28 +83,10 @@ def _check_seq(msgs, cfg):
     assert sum(fills) > 0 and sum(depth) > 0
 
 
-def _check_lanes(msgs, cfg):
-    W = cfg.lanes   # width == lanes: the per-step cap never binds, so
-    ses = LaneSession(cfg, width=W)  # compact == full-width occupancy
-    ses.process_wire([m.copy() for m in msgs])
-    h = ses.histograms()
-    fills, depth = host_fills_and_depth(msgs, cfg.slots, cfg.max_fills)
-    assert h["fills_per_order"] == fills
-    assert h["book_depth"] == depth
-    assert h["batch_occupancy"] == host_occupancy_lanes(msgs, cfg, W)
-    assert sum(fills) > 0 and sum(depth) > 0
-
-
 def test_seq_histograms_match_host():
     _check_seq(_stream(600),
                SQ.SeqConfig(lanes=8, slots=128, accounts=128,
                             max_fills=16))
-
-
-def test_lanes_histograms_match_host():
-    _check_lanes(_stream(600),
-                 LaneConfig(lanes=8, slots=32, accounts=32, max_fills=16,
-                            steps=16))
 
 
 def test_seq_java_fills_histogram():
@@ -141,33 +104,17 @@ def test_seq_java_fills_histogram():
     assert sum(h["batch_occupancy"]) > 0
 
 
-def test_lanes_histograms_shard_invariant():
-    msgs = _stream(800, payout_per_mille=4)
-    cfg = LaneConfig(lanes=8, slots=32, accounts=32, max_fills=16,
-                     steps=16)
-    base = None
-    for shards in (1, 2, 8):
-        ses = LaneSession(cfg, shards=shards)
-        ses.process_wire([m.copy() for m in msgs])
-        h = ses.histograms()
-        if base is None:
-            base = h
-        else:
-            assert h == base, f"histograms diverged at shards={shards}"
-
-
 def test_hist_observation_counts_match_metrics():
     """Structural invariants tying the histograms to the counters:
     one fills observation per accepted trade, one depth observation per
     accepted trade or cancel."""
     msgs = _stream(600)
-    cfg = LaneConfig(lanes=8, slots=32, accounts=32, max_fills=16,
-                     steps=16)
-    ses = LaneSession(cfg)
+    ses = SeqSession(SQ.SeqConfig(lanes=8, slots=128, accounts=128,
+                                  max_fills=16))
     ses.process_wire([m.copy() for m in msgs])
     h = ses.histograms()
     met = ses.metrics()
-    assert sum(h["fills_per_order"]) == met["trades_ok"]
+    assert sum(h["fills_per_order"]) == met["trades_ok"] > 0
     assert sum(h["book_depth"]) == met["trades_ok"] + met["cancels_ok"]
 
 
@@ -177,10 +124,3 @@ def test_seq_histograms_match_host_10k():
     _check_seq(_stream(10_000, symbols=16, accounts=64, seed=7),
                SQ.SeqConfig(lanes=16, slots=128, accounts=128,
                             max_fills=16))
-
-
-@pytest.mark.slow
-def test_lanes_histograms_match_host_10k():
-    _check_lanes(_stream(10_000, symbols=16, accounts=64, seed=7),
-                 LaneConfig(lanes=16, slots=128, accounts=128,
-                            max_fills=16, steps=64))

@@ -1,7 +1,7 @@
 """Multi-host execution proof: 2 OS processes x 4 virtual CPU devices
 form one 8-way jax.distributed mesh running the sharded session SPMD,
 and the wire output is bit-identical to a single-process run — the
-evidence behind parallel/mesh.py's DCN paragraph (SURVEY.md §2.3
+evidence that parallel/seqmesh.py's mesh spans hosts (SURVEY.md §2.3
 cross-node backend; reference analog: multiple Kafka Streams instances
 joining one consumer group, KProcessor.java:59-60)."""
 
@@ -25,8 +25,7 @@ def _free_port() -> int:
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("engine", ["lanes", "seq"])
-def test_two_process_mesh_bit_exact(engine):
+def test_two_process_mesh_bit_exact():
     port = _free_port()
     coord = f"127.0.0.1:{port}"
     outs = [os.path.join(_HERE, f"_mh_out_{i}.txt") for i in range(2)]
@@ -39,7 +38,7 @@ def test_two_process_mesh_bit_exact(engine):
             os.unlink(outs[i])
         procs.append(subprocess.Popen(
             [sys.executable, os.path.join(_HERE, "distributed_worker.py"),
-             coord, "2", str(i), outs[i], engine],
+             coord, "2", str(i), outs[i]],
             env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
             text=True))
     results = []
@@ -59,7 +58,7 @@ def test_two_process_mesh_bit_exact(engine):
     # session/stream definition the workers use
     from tests.distributed_worker import build_session_and_stream
 
-    ses, msgs = build_session_and_stream(engine)
+    ses, msgs = build_session_and_stream()
     golden = ses.process_wire(msgs)
     blob = "\n".join(l for ls in golden for l in ls).encode()
     want = f"{hashlib.sha256(blob).hexdigest()} {len(blob)}"

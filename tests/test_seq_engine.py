@@ -52,8 +52,8 @@ def assert_seq_parity(msgs, cfg=CFG):
 
 
 def test_seq_scenario_end_to_end():
-    """The lanes engine's scenario stream: every opcode incl. barriers,
-    double cancel, unknown oid, payout YES/NO, remove + re-add."""
+    """Every opcode incl. barriers, double cancel, unknown oid, payout
+    YES/NO, remove + re-add."""
     msgs = []
     for a in range(4):
         msgs.append(OrderMsg(action=op.CREATE_BALANCE, aid=a))
@@ -85,9 +85,9 @@ def test_seq_scenario_end_to_end():
 
 
 def test_seq_same_account_same_symbol_runs():
-    """The workload shape the lanes scheduler serializes (H1): one
-    account hammering one symbol back-to-back — the seq kernel has no
-    scheduling constraints, but must still be byte-exact."""
+    """The workload shape a conflict-free scheduler serializes (H1):
+    one account hammering one symbol back-to-back — the seq kernel has
+    no scheduling constraints, but must still be byte-exact."""
     msgs = [OrderMsg(action=op.CREATE_BALANCE, aid=1),
             OrderMsg(action=op.TRANSFER, aid=1, size=10**6),
             OrderMsg(action=op.CREATE_BALANCE, aid=2),
@@ -129,20 +129,92 @@ def test_seq_max_fills_envelope_reject():
     assert m["trades_ok"] == 4  # 3 resting sells + the 2-maker buy
 
 
-def test_seq_book_slots_envelope_reject():
+@pytest.mark.parametrize("over", [1, 2])
+def test_seq_book_slots_envelope_reject(over):
+    """H2 envelope policy: a non-crossing buy into a full book side is
+    rejected as a unit (OUT REJECT), that message only; the batch
+    continues, no exception, no sticky poison — a crossing sell then
+    fills the best buy. Byte-exact vs the enveloped oracle."""
     msgs = [OrderMsg(action=op.CREATE_BALANCE, aid=1),
             OrderMsg(action=op.TRANSFER, aid=1, size=10**8),
+            OrderMsg(action=op.CREATE_BALANCE, aid=2),
+            OrderMsg(action=op.TRANSFER, aid=2, size=10**8),
             OrderMsg(action=op.ADD_SYMBOL, sid=1)]
-    for k in range(CFG.slots + 1):   # the last one overflows the side
+    for k in range(CFG.slots + over):   # the last `over` overflow the side
         msgs.append(OrderMsg(action=op.BUY, oid=100 + k, aid=1, sid=1,
                              price=1 + (k % 30), size=1))
+    msgs.append(OrderMsg(action=op.SELL, oid=9000, aid=2, sid=1, price=30,
+                         size=1))
     ses, _ = assert_seq_parity(msgs)
-    assert ses.metrics()["rej_capacity"] == 1
+    assert ses.metrics()["rej_capacity"] == over
+    # the stream both engines gave: the overflowing buys were rejected,
+    # and only those; the final sell produced fills
+    ora = OracleEngine("fixed", book_slots=CFG.slots,
+                       max_fills=CFG.max_fills)
+    flat = [r.wire() for m in msgs for r in ora.process(m.copy())]
+    assert sum(ln.startswith('OUT {"action":7') for ln in flat) == over
+    assert any(ln.startswith('OUT {"action":5') for ln in flat)
+
+
+def test_seq_self_cross_and_zero_residual():
+    """An account trading against itself, exact-fill takers, and a taker
+    sweeping an entire side."""
+    msgs = [OrderMsg(action=op.CREATE_BALANCE, aid=1),
+            OrderMsg(action=op.TRANSFER, aid=1, size=100000),
+            OrderMsg(action=op.ADD_SYMBOL, sid=0),
+            OrderMsg(action=op.BUY, oid=1, aid=1, sid=0, price=50, size=3),
+            OrderMsg(action=op.SELL, oid=2, aid=1, sid=0, price=50, size=3),
+            OrderMsg(action=op.BUY, oid=3, aid=1, sid=0, price=55, size=4),
+            OrderMsg(action=op.BUY, oid=4, aid=1, sid=0, price=54, size=4),
+            OrderMsg(action=op.SELL, oid=5, aid=1, sid=0, price=1, size=20)]
+    assert_seq_parity(msgs)
+
+
+def test_seq_fill_credit_wraps_at_int32():
+    """Per-fill taker credit is Java int*int — wraps at int32 before the
+    long balance add (oracle._fill_order after the round-2 fix); the
+    kernel's planar lo/hi arithmetic must wrap identically."""
+    msgs = []
+    for a in (0, 1):
+        msgs.append(OrderMsg(action=op.CREATE_BALANCE, aid=a))
+        for _ in range(3):
+            msgs.append(OrderMsg(action=op.TRANSFER, aid=a, size=2**31 - 1))
+    msgs.append(OrderMsg(action=op.ADD_SYMBOL, sid=0))
+    msgs.append(OrderMsg(action=op.SELL, oid=1, aid=0, sid=0, price=0,
+                         size=2**25))
+    msgs.append(OrderMsg(action=op.BUY, oid=2, aid=1, sid=0, price=125,
+                         size=2**25))
+    assert_seq_parity(msgs)
+
+
+def test_seq_transfer_int_min_negation_wraps():
+    """`-order.size` negates in int32 (INT_MIN stays INT_MIN): the
+    size=INT_MIN withdrawal is ACCEPTED — the kernel must mirror the
+    oracle."""
+    msgs = [
+        OrderMsg(action=op.CREATE_BALANCE, aid=1),
+        OrderMsg(action=op.TRANSFER, aid=1, size=-(2**31)),
+    ]
+    ses, ora = assert_seq_parity(msgs)
+    assert ora.balances[1] == -(2**31)
+
+
+def test_seq_capacity_envelope_zipf_stream_parity():
+    """A skewed stream that actually overflows its books stays
+    byte-exact vs the enveloped oracle (the BENCH_r02 failure class):
+    passive quotes pile onto two symbols' 128-slot sides."""
+    msgs = zipf_symbol_stream(1600, num_symbols=2, num_accounts=16, seed=7,
+                              zipf_a=1.5)
+    ses, _ = assert_seq_parity(msgs, SQ.SeqConfig(
+        lanes=8, slots=128, accounts=128, max_fills=16, batch=256,
+        pos_cap=1 << 11, fill_cap=1 << 13, probe_max=16))
+    # the point of the scenario: overflow actually happened
+    assert ses.metrics()["rej_capacity"] > 0
 
 
 def test_seq_harness_stream_parity():
     """Stock harness distribution (10 accounts, 3 symbols) — the exact
-    shape H1 penalizes on the lanes engine."""
+    shape H1 penalizes under a conflict-free scheduler."""
     msgs = harness_stream(600, seed=7)
     assert_seq_parity(msgs, SQ.SeqConfig(
         lanes=8, slots=128, accounts=128, max_fills=64, batch=256,
@@ -227,7 +299,7 @@ def test_seq_hash_full_error():
     probe_max=1 trip the sticky error. The fixed store is sized by
     lanes x accounts and takes the same stream whole, whatever
     pos_cap says."""
-    from kme_tpu.runtime.session import LaneEngineError
+    from kme_tpu.runtime.seqsession import LaneEngineError
     kw = dict(lanes=8, slots=128, accounts=128, max_fills=8, batch=128,
               pos_cap=128, fill_cap=1 << 12, probe_max=1)
     ses = SeqSession(SQ.SeqConfig(compat="java", **kw))
@@ -282,8 +354,8 @@ def test_seq_hbm_books_parity():
 
 def test_seq_service_and_cross_engine_restore(tmp_path):
     """MatchService with engine='seq': serve a stream byte-exact, crash
-    after a checkpoint, resume — and restore the SAME snapshot into the
-    LANES engine (snapshots are canonical across engines)."""
+    after a checkpoint, resume — and the newest snapshot, restored
+    outside the service, holds the oracle's stores exactly."""
     from kme_tpu.bridge.broker import InProcessBroker
     from kme_tpu.bridge.provision import provision
     from kme_tpu.bridge.service import MatchService
@@ -319,11 +391,10 @@ def test_seq_service_and_cross_engine_restore(tmp_path):
     want += [ln for lines in per_msg[snap_off:] for ln in lines]
     assert got == want
 
-    # cross-engine: the newest seq snapshot restores into a
-    # LaneSession; the restored canonical STATE must equal the
-    # oracle's stores exactly, and any remaining stream tail must
-    # replay byte-exact
-    ses, off = ck.load_session(ck_dir)
+    # the newest snapshot restores under its own configuration; the
+    # restored canonical STATE must equal the oracle's stores exactly,
+    # and any remaining stream tail must replay byte-exact
+    ses, off = ck.load_seq_session(ck_dir)
     assert ses is not None and off >= snap_off
     if off < len(msgs):
         tail = ses.process_wire([m.copy() for m in msgs[off:]])

@@ -185,7 +185,7 @@ def test_an_engine_error_surfaces_at_the_collect_of_its_batch(cpu_devices):
     """fill_cap 128: the sweep's first call overflows the fill buffer
     (LERR_FILLBUF_FULL). Its submit, and the submit behind it, return;
     the batch before it is collected whole; its own collect raises."""
-    from kme_tpu.runtime.session import LaneEngineError
+    from kme_tpu.runtime.seqsession import LaneEngineError
 
     cfg = SQ.SeqConfig(lanes=LANES, slots=128, accounts=128, max_fills=32,
                        batch=BATCH, pos_cap=1 << 12, fill_cap=128,

@@ -37,9 +37,9 @@ from kme_tpu.native import load_library
 from kme_tpu.native.oracle import NativeOracleEngine
 from kme_tpu.runtime import checkpoint as ck
 from kme_tpu.runtime import seqsession
-from kme_tpu.runtime.seqsession import (ROUTER_STATS, NativeSeqRouter,
-                                        SeqRouter, SeqSession)
-from kme_tpu.runtime.sequencer import CapacityError
+from kme_tpu.runtime.seqsession import (ROUTER_STATS, CapacityError,
+                                        NativeSeqRouter, SeqRouter,
+                                        SeqSession)
 from kme_tpu.wire import OrderMsg, WireBatch, dumps_order
 from kme_tpu.workload import WorkloadGen, zipf_symbol_stream
 

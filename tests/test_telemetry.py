@@ -156,22 +156,6 @@ def _stream(n=300):
 PHASE_KEYS = {"plan_s", "dispatch_s", "fetch_s", "recon_s"}
 
 
-def test_lanes_phases_accumulate():
-    from kme_tpu.engine.lanes import LaneConfig
-    from kme_tpu.runtime.session import LaneSession
-
-    ses = LaneSession(LaneConfig(lanes=8, slots=32, accounts=32,
-                                 max_fills=16, steps=16))
-    msgs = _stream()
-    ses.process_wire([m.copy() for m in msgs])
-    assert PHASE_KEYS <= set(ses.phases)
-    first = dict(ses.phases)
-    ses.process_wire([m.copy() for m in msgs[:100]])
-    for k in PHASE_KEYS:
-        assert ses.phases[k] >= first[k]
-    assert ses.phases["dispatch_s"] > first["dispatch_s"]
-
-
 def test_seq_phases_accumulate():
     from kme_tpu.engine import seq as SQ
     from kme_tpu.runtime.seqsession import SeqSession
@@ -184,32 +168,6 @@ def test_seq_phases_accumulate():
     first = dict(ses.phases)
     ses.process_wire([m.copy() for m in msgs[:100]])
     assert ses.phases["dispatch_s"] > first["dispatch_s"]
-
-
-def test_counter_names_identical_seq_vs_lanes():
-    """The same stream exposes the SAME counter names from either
-    engine's registry (the operator's dashboards don't care which
-    engine serves)."""
-    from kme_tpu.engine import seq as SQ
-    from kme_tpu.engine.lanes import LaneConfig
-    from kme_tpu.runtime.seqsession import SeqSession
-    from kme_tpu.runtime.session import LaneSession
-
-    msgs = _stream()
-    lanes = LaneSession(LaneConfig(lanes=8, slots=32, accounts=32,
-                                   max_fills=16, steps=16))
-    lanes.process_wire([m.copy() for m in msgs])
-    lanes.metrics()
-    lanes.histograms()
-    seq = SeqSession(SQ.SeqConfig(lanes=8, slots=128, accounts=128,
-                                  max_fills=16))
-    seq.process_wire([m.copy() for m in msgs])
-    seq.metrics()
-    seq.histograms()
-    a, b = lanes.telemetry.snapshot(), seq.telemetry.snapshot()
-    assert set(a["counters"]) == set(b["counters"])
-    assert set(a["gauges"]) == set(b["gauges"])
-    assert set(a["histograms"]) == set(b["histograms"])
 
 
 @pytest.mark.slow
@@ -268,11 +226,11 @@ def test_metrics_http_concurrent_scrape_with_engine_steps():
     the registry surface is read concurrently with session writes."""
     import threading
 
-    from kme_tpu.engine.lanes import LaneConfig
-    from kme_tpu.runtime.session import LaneSession
+    from kme_tpu.engine import seq as SQ
+    from kme_tpu.runtime.seqsession import SeqSession
 
-    ses = LaneSession(LaneConfig(lanes=8, slots=32, accounts=32,
-                                 max_fills=16, steps=16))
+    ses = SeqSession(SQ.SeqConfig(lanes=8, slots=128, accounts=128,
+                                  max_fills=16))
     msgs = _stream(400)
     srv = start_metrics_server(ses.telemetry, 0, host="127.0.0.1")
     host, port = srv.server_address[:2]
@@ -320,23 +278,6 @@ def test_metrics_http_concurrent_scrape_with_engine_steps():
 # ---------------------------------------------------------------------------
 # checkpoint round-trips: counters and histogram buckets are part of the
 # resume contract (a restart must not zero the operator's dashboards)
-
-
-def test_lanes_checkpoint_roundtrip_telemetry(tmp_path):
-    from kme_tpu.engine.lanes import LaneConfig
-    from kme_tpu.runtime import checkpoint as ck
-    from kme_tpu.runtime.session import LaneSession
-
-    ses = LaneSession(LaneConfig(lanes=8, slots=32, accounts=32,
-                                 max_fills=16, steps=16))
-    ses.process_wire(_stream())
-    met, hist = ses.metrics(), ses.histograms()
-    assert sum(hist["fills_per_order"]) > 0
-    ck.save_session(str(tmp_path), ses, 300)
-    ses2, off = ck.load_session(str(tmp_path))
-    assert off == 300
-    assert ses2.metrics() == met
-    assert ses2.histograms() == hist
 
 
 def test_seq_checkpoint_roundtrip_telemetry(tmp_path):
